@@ -73,6 +73,10 @@ class TPDecoderModel(TinyDecoderModel):
                 # a 3-device host serves tp=2, not a divisibility error
                 tp = max(d for d in range(1, self.HEADS + 1)
                          if self.HEADS % d == 0 and d <= len(devices))
+                if tp < 2:
+                    raise ValueError(
+                        f"{self.name} shards over at least 2 devices and "
+                        f"this host has {len(devices)}; serve decoder_lm")
             if tp > len(devices):
                 raise ValueError(
                     f"tp={tp} but only {len(devices)} devices")

@@ -84,6 +84,7 @@ class LongContextEncoderModel(Model):
             wk = jax.random.normal(kk, (self._dim, self._dim), jnp.float32) * scale
             wv = jax.random.normal(kv, (self._dim, self._dim), jnp.float32) * scale
             wo = jax.random.normal(ko, (self._dim, self._dim), jnp.float32) * scale
+            self.weights = (wq, wk, wv, wo)  # for a reference beside it
 
             heads = self._heads
             head_dim = self._dim // heads

@@ -152,20 +152,25 @@ class DenseNetModel(Model):
             import jax
             import jax.numpy as jnp
 
+            devices = jax.devices()
+            tp = self._tensor_parallel
+            if tp > len(devices):
+                raise ValueError(
+                    f"tensor_parallel={tp} but only {len(devices)} devices")
             self._module = _build_flax_model(
                 self._num_classes, self._width, self._stages
             )
             rng = jax.random.PRNGKey(self._seed)
             dummy = jnp.zeros((1, 224, 224, 3), jnp.bfloat16)
-            self._params = self._module.init(rng, dummy)
+            # one compiled program: eager init dispatches (and compiles)
+            # every op of the forward pass one by one; same values
+            self._params = jax.jit(self._module.init)(rng, dummy)
 
-            if self._tensor_parallel > 1:
+            if tp > 1:
                 from jax.sharding import Mesh
 
                 from ..parallel import shard_params
 
-                devices = jax.devices()
-                tp = min(self._tensor_parallel, len(devices))
                 # (1, tp): serve-time batch stays whole, weights shard on
                 # 'model' (make_mesh's dp-leaning factorization fits training)
                 mesh = Mesh(

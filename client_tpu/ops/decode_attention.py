@@ -12,13 +12,22 @@ the cache traffic.
 Layout: the query row is padded to an [8, d] tile (row 0 live — Mosaic's
 minimum f32 sublane tile); the grid is (batch*heads, nk) with the K axis
 sequential ("arbitrary") so the (m, l, acc) scratch carries across K
-blocks. The per-sequence valid length arrives as a scalar in SMEM; K slots
-above it (unwritten cache tail) are masked in-kernel, so the same compiled
-kernel serves every decode position — no shape-polymorphic retraces, the
-same property the decoder's dense path has (models/decoder.py).
+blocks. The valid lengths arrive as ONE whole vector in SMEM, indexed by
+the grid's row (Mosaic refuses a blocked rank-1 SMEM operand); K slots
+above a row's length (unwritten cache tail) are masked in-kernel, so the
+same compiled kernel serves every decode position — no shape-polymorphic
+retraces, the same property the decoder's dense path has
+(models/decoder.py).
 
-Runs in interpret mode off-TPU (CI exactness vs dense attention); compiled
-to Mosaic on the chip.
+``jax.vmap`` of the kernel folds the mapped axis into the batch axis (a
+``custom_vmap`` rule): the default batching rule would block the SMEM
+vector per mapped row, which Mosaic refuses too, and the kernel is already
+batched. The sequence batcher's slot-batched step (``jax.vmap`` of the
+single-sequence step, models/decoder_batched.py) therefore runs this one
+kernel over [slots, heads, dim].
+
+Runs in interpret mode on the CPU (CI exactness vs dense attention);
+compiled to Mosaic on the chip.
 """
 
 from __future__ import annotations
@@ -45,7 +54,8 @@ def _decode_kernel(pos_ref, k_ref, v_ref, q_ref, o_ref, m_scr, l_scr, acc_scr,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    pos = pos_ref[0]  # last valid cache slot for this sequence/head
+    # last valid cache slot for this sequence/head (whole vector in SMEM)
+    pos = pos_ref[pl.program_id(0)]
 
     # K blocks wholly above pos contribute nothing — skip the whole body
     @pl.when(ik * block_k <= pos)
@@ -94,6 +104,27 @@ def decode_attention(q, k, v, pos, block_k: int = 128,
     [batch, heads, max_len, dim]; pos: [batch] int32 — cache slots
     ``<= pos[b]`` attend (the decoder's position-based mask,
     models/decoder.py). Returns [batch, heads, dim] in q's dtype."""
+    if interpret is None:
+        interpret = not _on_tpu()
+
+    @jax.custom_batching.custom_vmap
+    def call(q, k, v, pos):
+        return _pallas_decode(q, k, v, pos, block_k, interpret)
+
+    @call.def_vmap
+    def _fold_mapped_axis(axis_size, in_batched, q, k, v, pos):
+        def fold(x, batched):
+            if not batched:
+                x = jnp.broadcast_to(x, (axis_size,) + x.shape)
+            return x.reshape((axis_size * x.shape[1],) + x.shape[2:])
+
+        out = call(*(fold(x, b) for x, b in zip((q, k, v, pos), in_batched)))
+        return out.reshape((axis_size, -1) + out.shape[1:]), True
+
+    return call(q, k, v, pos)
+
+
+def _pallas_decode(q, k, v, pos, block_k, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -122,7 +153,7 @@ def decode_attention(q, k, v, pos, block_k: int = 128,
         kernel,
         grid=(bh, nk),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, j: (b,), memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, block_k, dim), lambda b, j: (b, j, 0)),
             pl.BlockSpec((1, block_k, dim), lambda b, j: (b, j, 0)),
             pl.BlockSpec((1, _SUBLANES, dim), lambda b, j: (b, 0, 0)),
@@ -137,7 +168,7 @@ def decode_attention(q, k, v, pos, block_k: int = 128,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
-        interpret=not _on_tpu() if interpret is None else interpret,
+        interpret=interpret,
     )(pos_b, kb, vb, qb)
 
     return out[:, 0, :].reshape(batch, heads, dim)

@@ -73,7 +73,7 @@ def pipeline_forward(w, b, x, mesh, axis: str = "model", n_microbatches: int = 4
             buf_next = lax.ppermute(y, axis, perm)
             return buf_next, y
 
-        buf0 = lax.pvary(jnp.zeros((mb, dim), x.dtype), (axis,))
+        buf0 = lax.pcast(jnp.zeros((mb, dim), x.dtype), (axis,), to="varying")
         _, ys = lax.scan(step, buf0, jnp.arange(total_steps))
         return ys[None]  # [1, T, mb, dim]; concat over devices outside
 

@@ -41,7 +41,7 @@ def ring_attention(q, k, v, mesh, axis: str = "data", causal: bool = False):
     still rotates; a production kernel would also skip the FLOPs).
     """
     import jax.numpy as jnp
-    from jax import lax, shard_map  # requires the jax that also has lax.pvary
+    from jax import lax, shard_map
     from jax.sharding import PartitionSpec as P
 
     n = mesh.shape[axis]
@@ -90,11 +90,14 @@ def ring_attention(q, k, v, mesh, axis: str = "data", causal: bool = False):
             )
             return (k_cur, v_cur, acc_new, m_new, l_new), None
 
-        # pvary: the accumulators must carry the same varying-axes type as
-        # the per-shard data or lax.scan rejects the carry
-        acc0 = lax.pvary(jnp.zeros((batch, heads, sq, dim), jnp.float32), (axis,))
-        m0 = lax.pvary(jnp.full((batch, heads, sq), -jnp.inf, jnp.float32), (axis,))
-        l0 = lax.pvary(jnp.zeros((batch, heads, sq), jnp.float32), (axis,))
+        # the accumulators must carry the same varying-axes type as the
+        # per-shard data or lax.scan rejects the carry
+        def varying(x):
+            return lax.pcast(x, (axis,), to="varying")
+
+        acc0 = varying(jnp.zeros((batch, heads, sq, dim), jnp.float32))
+        m0 = varying(jnp.full((batch, heads, sq), -jnp.inf, jnp.float32))
+        l0 = varying(jnp.zeros((batch, heads, sq), jnp.float32))
         (k_fin, v_fin, acc, m, l), _ = lax.scan(
             step,
             (k_blk.astype(jnp.float32), v_blk.astype(jnp.float32), acc0, m0, l0),

@@ -11,6 +11,11 @@ Usage::
     python -m client_tpu.perf -m simple -u 127.0.0.1:8000 -i http \
         --concurrency-range 1:4 --shared-memory tpu --measurement-requests 200
 
+Run as its own process it is a numpy-only client: it never imports jax, so
+the chip stays with the server's process; ``--shared-memory tpu`` then
+writes the regions' host windows. Device arrays are staged only where the
+harness shares the server's process.
+
 Inputs are generated from the model's metadata (random data per datatype;
 dynamic dims default to 1, override with ``--shape NAME:d1,d2``).
 """
@@ -27,6 +32,14 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from .utils import sorted_percentile as _percentile
+
+
+def _shares_server_process() -> bool:
+    """True where this process also hosts the server (tests, benches and
+    embedders import ``client_tpu.server``; ``python -m client_tpu.perf``
+    never does). Only then may the harness hold device arrays: a chip
+    belongs to one process, and that process is the server's."""
+    return "client_tpu.server.core" in sys.modules
 
 
 def _random_tensor(datatype: str, shape: List[int], rng) -> np.ndarray:
@@ -741,13 +754,16 @@ class PerfRunner:
                           if datatype == "BYTES" else data.nbytes)
                 lease = arena.lease(nbytes, family=family)
                 leases.append(lease)
-                if family == "tpu" and datatype != "BYTES":
+                if (family == "tpu" and datatype != "BYTES"
+                        and _shares_server_process()):
                     import jax
 
                     dev = jax.device_put(data)
                     dev.block_until_ready()
                     lease.write_jax(dev)
                 else:
+                    # a client process of its own stays off jax: the chip
+                    # belongs to the server, which reads the host window
                     lease.write_numpy(data)
                 if native:
                     arena.ensure_registered(client, lease._region)

@@ -5,6 +5,12 @@ development::
 
     python -m client_tpu.serve --http-port 8000 --grpc-port 8001 [--vision]
 
+This process owns the chip: JAX gives a TPU to one process at a time, so
+clients of this server run in other processes and stay off jax (numpy in,
+numpy out — ``client_tpu.http``/``.grpc``/``.utils.tpu_shared_memory`` never
+import it). Compiled programs are kept in the persistent compile cache
+(``client_tpu/compile_cache.py``).
+
 Ctrl-C stops it immediately; SIGTERM drains gracefully — ``v2/health/ready``
 / ``ServerReady`` flip to not-ready first (so multi-endpoint pools route
 away), in-flight requests finish, then the listeners close.
@@ -56,6 +62,10 @@ def main(argv: Optional[List[str]] = None) -> int:
     )
     parser.add_argument("-v", "--verbose", action="store_true")
     args = parser.parse_args(argv)
+
+    from .compile_cache import enable_compile_cache
+
+    print(f"compile cache: {enable_compile_cache()}")
 
     from .models import default_model_zoo
     from .models.simple import IdentityModel

@@ -6,6 +6,11 @@ Equivalent of the reference's image_client.py (parse_model :60, preprocess
 HTTP/GRPC/async switches :262-510) — with the preprocessing running through
 XLA (client_tpu.ops Pallas normalize kernel) instead of numpy/PIL math.
 
+The preprocessing runs on THIS process's JAX device. A TPU belongs to one
+process at a time, so where the server holds the only chip run this client
+with ``JAX_PLATFORMS=cpu`` (the kernel then runs in interpret mode), or use
+ensemble_image_client.py, which leaves the preprocessing to the server.
+
 Works against the bundled densenet_onnx flax model
 (``python -m client_tpu.serve --vision``) or a real tritonserver hosting the
 densenet_onnx fixture. Input images: .npy arrays (HWC uint8) or, when Pillow

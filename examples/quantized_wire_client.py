@@ -5,6 +5,10 @@ ships INT8 bytes over the wire, and dequantizes the response — the classic
 bandwidth play for WAN/DCN hops, impossible to express in the reference
 client without custom model logic (here it is two client-side ops).
 
+The two ops run on THIS process's JAX device. A TPU belongs to one process
+at a time, so where the server holds the only chip run this client with
+``JAX_PLATFORMS=cpu`` (the kernels then run in interpret mode).
+
 Usage: quantized_wire_client.py [-u HOST:PORT]
 """
 

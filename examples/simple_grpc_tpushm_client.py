@@ -2,8 +2,14 @@
 """TPU shared-memory inference over GRPC — the cudashm example, TPU-native.
 
 Equivalent of the reference's simple_grpc_cudashm_client.py with the CUDA IPC
-region replaced by a tpu_shared_memory region: inputs are bound as live
-jax.Arrays, outputs are read back through the device path.
+region replaced by a tpu_shared_memory region.
+
+The SERVER's process owns the chip; this client is numpy-only and never
+imports jax (a TPU belongs to one process at a time). It writes the
+regions' host windows, hands the server their raw handles, and the server
+moves the bytes onto its device and back. Binding live ``jax.Array``s
+(``set_shared_memory_region_from_jax``, ``colocated=True``) is for a client
+that shares the server's process; ``chip_smoke.py`` drives that arm.
 """
 
 import argparse
@@ -20,18 +26,15 @@ def main():
     parser.add_argument("-u", "--url", default="localhost:8001")
     args = parser.parse_args()
 
-    import jax.numpy as jnp
-
     with grpcclient.InferenceServerClient(args.url) as client:
         client.unregister_tpu_shared_memory()
 
-        input0_data = jnp.arange(16, dtype=jnp.int32).reshape(1, 16)
-        input1_data = jnp.ones((1, 16), jnp.int32)
+        input0_data = np.arange(16, dtype=np.int32).reshape(1, 16)
+        input1_data = np.ones((1, 16), np.int32)
         nbytes = 64
 
         shm_ip = tpushm.create_shared_memory_region("input_data", nbytes * 2)
-        tpushm.set_shared_memory_region_from_jax(shm_ip, input0_data)
-        tpushm.set_shared_memory_region_from_jax(shm_ip, input1_data, offset=nbytes)
+        tpushm.set_shared_memory_region(shm_ip, [input0_data, input1_data])
         client.register_tpu_shared_memory(
             "input_data", tpushm.get_raw_handle(shm_ip), 0, nbytes * 2
         )
@@ -55,13 +58,15 @@ def main():
 
         client.infer("simple", inputs, outputs=outputs)
 
-        # device-path read: jax.Array without a wire hop
-        output0 = np.asarray(tpushm.get_contents_as_jax(shm_op, "INT32", [1, 16]))
+        # host-window read: the server wrote its results into the region
+        output0 = tpushm.get_contents_as_numpy(shm_op, "INT32", [1, 16])
         output1 = tpushm.get_contents_as_numpy(shm_op, "INT32", [1, 16], offset=nbytes)
-        expected0 = np.asarray(input0_data + input1_data)
-        expected1 = np.asarray(input0_data - input1_data)
-        if not ((output0 == expected0).all() and (output1 == expected1).all()):
+        ok = bool((output0 == input0_data + input1_data).all()
+                  and (output1 == input0_data - input1_data).all())
+        del output0, output1  # views over the mapping: drop before unmapping
+        if not ok:
             sys.exit("tpu shm infer error: incorrect results")
+        assert "jax" not in sys.modules
 
         print(client.get_tpu_shared_memory_status())
         client.unregister_tpu_shared_memory()

@@ -1,0 +1,162 @@
+"""Ahead-of-time compiles for the chip, without the chip.
+
+The TPU compiler is installed beside the CPU backend and compiles for a
+DESCRIBED ``v5e:2x2`` topology. Interpret-mode tests cannot see what Mosaic
+refuses (a blocked rank-1 SMEM operand, more VMEM than a kernel may hold),
+so the kernels of the serving path are compiled here at the shapes
+``chip_smoke.py`` serves. Nothing runs: a pass says the chip's compiler
+accepts the program, not that its results are right — the interpret-mode
+tests and the chip smoke hold those.
+"""
+
+import importlib
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+import client_tpu.ops as ops
+from client_tpu.ops import decode_attention as decode_mod
+
+# ops.flash_attention the attribute is the wrapper function, not the module
+flash_mod = importlib.import_module("client_tpu.ops.flash_attention")
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """One described v5e device, with the persistent compile cache off: a
+    compile for a described chip is written to the cache but cannot be read
+    back without one."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def as_on_chip(monkeypatch):
+    """Steer ``_on_tpu()`` for code that picks interpret mode by asking the
+    backend (the decoder steps): here JAX still reports the CPU. Cached
+    traces carry the choice, so they are dropped on the way in and out."""
+    for mod in (ops, decode_mod, flash_mod):
+        monkeypatch.setattr(mod, "_on_tpu", lambda: True)
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+def _compiles_with_kernel(fn, chip, *shapes):
+    args = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip),
+        shapes)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+def _s(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+@pytest.mark.parametrize("batch,heads,max_len,dim", [
+    (1, 4, 128, 32),      # the decoder fixture's own shape
+    (8, 16, 4096, 128),   # a serving-sized cache
+])
+def test_decode_attention_compiles(chip, batch, heads, max_len, dim):
+    bf16 = jnp.bfloat16
+    _compiles_with_kernel(
+        lambda q, k, v, p: decode_mod.decode_attention(
+            q, k, v, p, interpret=False),
+        chip,
+        _s((batch, heads, dim), bf16), _s((batch, heads, max_len, dim), bf16),
+        _s((batch, heads, max_len, dim), bf16), _s((batch,), jnp.int32))
+
+
+def test_decode_attention_compiles_under_vmap(chip):
+    """The sequence batcher maps the single-sequence step over its slots."""
+    bf16 = jnp.bfloat16
+    _compiles_with_kernel(
+        jax.vmap(lambda q, k, v, p: decode_mod.decode_attention(
+            q, k, v, p, interpret=False)),
+        chip,
+        _s((8, 1, 4, 32), bf16), _s((8, 1, 4, 128, 32), bf16),
+        _s((8, 1, 4, 128, 32), bf16), _s((8, 1), jnp.int32))
+
+
+def _shapes_of(tree):
+    return jax.tree_util.tree_map(lambda x: _s(x.shape, x.dtype), tree)
+
+
+def test_decoder_step_compiles_with_kernel(chip, as_on_chip):
+    from client_tpu.models.decoder import TinyDecoderModel
+
+    model = TinyDecoderModel(attention_impl="pallas")
+    model._ensure_built()
+    _compiles_with_kernel(
+        model._step_fn, chip, _shapes_of(model._params),
+        _shapes_of(model._fresh_cache()), _s((), jnp.int32),
+        _s((), jnp.int32))
+
+
+def test_batched_decoder_step_compiles_with_kernel(chip, as_on_chip):
+    from client_tpu.models.decoder_batched import BatchedDecoderModel
+
+    model = BatchedDecoderModel(slots=8, attention_impl="pallas")
+    model._ensure_built()
+    try:
+        slots = (model.slots,)
+        _compiles_with_kernel(
+            model._batched_step, chip, _shapes_of(model._decoder._params),
+            _shapes_of(model._caches), _s(slots, jnp.int32),
+            _s(slots, jnp.int32), _s(slots, jnp.bool_))
+    finally:
+        model.unload()
+
+
+@pytest.mark.parametrize("shape,dtype,causal", [
+    ((4, 2048, 8, 128), jnp.bfloat16, True),
+    ((1, 300, 4, 16), jnp.float32, False),  # long_context_encoder, padded
+])
+def test_flash_attention_compiles(chip, shape, dtype, causal):
+    _compiles_with_kernel(
+        lambda q, k, v: flash_mod.flash_attention(
+            q, k, v, causal=causal, interpret=False),
+        chip, _s(shape, dtype), _s(shape, dtype), _s(shape, dtype))
+
+
+@pytest.mark.parametrize("fn,shape,dtype", [
+    (lambda x: ops.normalize_image(x, 2.0 / 255.0, -1.0),
+     (224, 224, 3), jnp.float32),
+    (ops.softmax_probabilities, (1, 1000), jnp.float32),
+    (lambda x: ops.quantize_int8(x, 0.05), (1024, 1024), jnp.float32),
+    (lambda q: ops.dequantize_int8(q, 0.05), (1024, 1024), jnp.int8),
+    # the data plane's own large size: 64 MiB does not fit VMEM whole
+    (lambda x: ops.quantize_int8(x, 0.05), (4096, 4096), jnp.float32),
+    # the widest row the budget admits, where a block is one row tile
+    (ops.softmax_probabilities, (256, 16384), jnp.float32),
+], ids=["normalize_image", "softmax", "quantize_int8", "dequantize_int8",
+        "quantize_int8_64MiB", "softmax_widest"])
+def test_elementwise_kernels_compile(chip, as_on_chip, fn, shape, dtype):
+    _compiles_with_kernel(fn, chip, _s(shape, dtype))
+
+
+def test_row_too_wide_raises_typed_error():
+    """Above the width that fits, a typed error at trace time — never an
+    opaque compiler failure at request time."""
+    with pytest.raises(ops.KernelTooLargeError, match="16384"):
+        jax.eval_shape(lambda x: ops.quantize_int8(x, 0.05),
+                       _s((1, 1 << 24), jnp.float32))

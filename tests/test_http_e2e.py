@@ -2,7 +2,7 @@
 
 This is the reference's integration tier (SURVEY.md §4 tier 2) made
 self-contained: the ``simple`` INT32 sum/diff contract over a live local
-server (BASELINE.md target config #1).
+server (BASELINE.json, config #1).
 """
 
 import numpy as np

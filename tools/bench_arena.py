@@ -12,8 +12,8 @@ The A/B the arena exists for, answered against a live in-process server:
    create/destroy ops == 0 and registration RPCs == 0 while map ops keep
    growing (requests ARE flowing), with p50 no worse than the baseline.
 3. **64-caller size sweep** — concurrency 64 over payloads from 4 KiB to
-   4 MiB through the arena path: the size-invariance claim (CHIP_BENCH's
-   flat p50) restated under high concurrency on the shm data plane.
+   4 MiB through the arena path: the size-invariance claim restated under
+   high concurrency on the shm data plane.
 
 ``--check`` re-validates an existing artifact's acceptance invariants and
 exits non-zero on violation (wired in CI next to the capacity gate via
@@ -284,8 +284,7 @@ def main() -> int:
                     "server-side identity memcpy, not the client data "
                     "plane; the steady-state A/B rows above are the "
                     "size-independent client-side cost evidence (on TPU "
-                    "hardware CHIP_BENCH's ~0.8 ms p50 size-invariance is "
-                    "the matching number)"),
+                    "hardware: not measured)"),
             }
             out["arena_stats_final"] = arena.stats()
         finally:
