@@ -1,0 +1,270 @@
+"""The one process that holds the chip: a cell's model behind the GRPC frontend.
+
+Started by ``benchmark/run.py`` (which never imports jax), it builds the
+cell's model from its configuration file, gives it weights made on the device
+from the seed, serves it through ``ServerCore`` and ``GrpcInferenceServer``
+on a loopback port, and then takes commands, one JSON object a line on
+standard input, answering each with one line on standard output:
+
+- ``mark``: the counters as they stand (compiles so far, the batcher's
+  histogram), so that the window's share can be told from the warm-up's;
+- ``trace_start`` / ``trace_stop``: the profiler, for a few seconds of the
+  window of a traced run;
+- ``finish``: close the frontend, read the counters and the chip's peak
+  memory, free the model and its caches, reduce the trace;
+- ``check``: run the plain reference over the sample of sessions it is sent
+  (the weights are the benchmark's own arrays, made here from the seed);
+- ``reseed``: new weights from another seed (``calibrate.py`` reads a dozen
+  seeds in one process);
+- ``exit``.
+
+The host spans of a traced run are put round the model object's ``execute``
+and ``execute_decoupled`` from outside; spans inside the program are a later
+issue's.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import shutil
+import sys
+import time
+from typing import Any, Dict, List
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if _REPO not in sys.path:
+    sys.path.insert(0, _REPO)
+
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+TABLES = ("embed", "pos", "unembed")  # drawn at 0.02; matrices at fan_in ** -0.5
+
+
+def emit(obj: Dict[str, Any]) -> None:
+    sys.stdout.write(json.dumps(obj) + "\n")
+    sys.stdout.flush()
+
+
+def make_params(template, seed: int):
+    """Weights of the template's shapes and types, drawn on the device in one
+    jitted call: normal, scaled as the program scales its own (0.02 for the
+    tables and the output head, fan_in ** -0.5 for the matrices)."""
+    import jax
+    import jax.numpy as jnp
+
+    paths, treedef = jax.tree_util.tree_flatten_with_path(template)
+    groups: Dict[Any, List[int]] = {}
+    for i, (path, leaf) in enumerate(paths):
+        name = str(getattr(path[0], "key", path[0]))
+        scale = 0.02 if name in TABLES else leaf.shape[0] ** -0.5
+        groups.setdefault((leaf.shape, str(leaf.dtype), scale), []).append(i)
+
+    @jax.jit
+    def draw(key):
+        out = [None] * len(paths)
+        for g, ((shape, dtype, scale), members) in enumerate(groups.items()):
+            block = jax.random.normal(jax.random.fold_in(key, g),
+                                      (len(members),) + shape, jnp.float32)
+            block = (block * scale).astype(dtype)
+            for j, i in enumerate(members):
+                out[i] = block[j]
+        return out
+
+    # a seed may be a little over 2**31: fold its high part in
+    key = jax.random.fold_in(
+        jax.random.key(seed & 0x7FFFFFFF, impl="rbg"), (seed >> 31) & 0x7FFFFFFF)
+    return jax.tree_util.tree_unflatten(treedef, draw(key))
+
+
+def annotate(model) -> None:
+    """Host spans round the model's entry points, from outside."""
+    import jax
+
+    name = model.name
+    execute, decoupled = model.execute, model.execute_decoupled
+
+    def traced_execute(inputs, parameters):
+        with jax.profiler.TraceAnnotation(f"{name}.execute"):
+            return execute(inputs, parameters)
+
+    def traced_decoupled(inputs, parameters):
+        responses = iter(decoupled(inputs, parameters))
+        while True:
+            with jax.profiler.TraceAnnotation(f"{name}.execute_decoupled"):
+                try:
+                    response = next(responses)
+                except StopIteration:
+                    return
+            yield response
+
+    model.execute = traced_execute
+    if getattr(model, "decoupled", False):
+        model.execute_decoupled = traced_decoupled
+
+
+class Served:
+    """The cell's model, its weights, the frontend and the counters."""
+
+    def __init__(self, cell: Dict[str, Any], config: Dict[str, Any],
+                 users: int, seed: int, work_dir: str):
+        import jax
+
+        from client_tpu.server import GrpcInferenceServer, ServerCore
+
+        from benchmark import builders
+
+        self.config = config
+        self.compiles = 0
+        jax.monitoring.register_event_duration_secs_listener(self._on_event)
+        t = time.perf_counter()
+        self.model, self.decoder = builders.resolve(cell["builder"])(
+            config, seed, **cell.get("args", {}))
+        self.decoder._ensure_built()
+        t_built = time.perf_counter()
+        self.reseed(seed)
+        jax.block_until_ready(self.params)
+        t_seeded = time.perf_counter()
+        self.model._ensure_built()
+        print(f"set-up: the program's build {t_built - t:.1f} s, weights from "
+              f"the seed {t_seeded - t_built:.1f} s, the model's own "
+              f"{time.perf_counter() - t_seeded:.1f} s", file=sys.stderr)
+        annotate(self.model)
+        self.core = ServerCore([self.model])
+        # every user may have a request in flight, and a stream holds its
+        # worker for as long as it is open
+        self.frontend = GrpcInferenceServer(
+            self.core, max_workers=users + 4).start()
+        self.trace_dir = os.path.join(work_dir, "trace")
+        self.traced = False
+
+    def _on_event(self, event: str, duration: float, **_: Any) -> None:
+        if event == COMPILE_EVENT:
+            self.compiles += 1
+
+    def reseed(self, seed: int) -> None:
+        self.params = make_params(self.decoder._params, seed)
+        self.decoder._params = self.params
+
+    def counters(self) -> Dict[str, Any]:
+        histogram = getattr(self.model, "batch_histogram", None)
+        return {"compiles": self.compiles,
+                "batch_histogram": dict(histogram) if histogram is not None else None}
+
+    def trace_start(self) -> None:
+        import jax
+
+        shutil.rmtree(self.trace_dir, ignore_errors=True)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 2
+        jax.profiler.start_trace(self.trace_dir, profiler_options=options)
+        self.traced = True
+
+    def trace_stop(self) -> None:
+        import jax
+
+        jax.profiler.stop_trace()
+
+    def finish(self, step_program: str) -> Dict[str, Any]:
+        import jax
+
+        self.frontend.stop(grace=1.0)
+        out = self.counters()
+        out["memory_peak_bytes"] = max(
+            (d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+            for d in jax.local_devices())
+        # free the program's state before the reference runs: the frontend
+        # is closed, the batcher's worker joined, the caches dropped
+        self.model.unload()
+        if hasattr(self.model, "_caches"):
+            self.model._caches = None
+        if self.traced:
+            from benchmark import trace_reduce
+
+            path = trace_reduce.find_xplane(self.trace_dir)
+            out["trace"] = (trace_reduce.reduce(
+                trace_reduce.load_xplane(path), step_program)
+                if path else None)
+            shutil.rmtree(self.trace_dir, ignore_errors=True)
+        return out
+
+    def check(self, sessions, length: int, control: bool) -> Dict[str, Any]:
+        from benchmark import reference
+
+        t = time.perf_counter()
+        out = reference.served_token_gaps(
+            self.params, int(self.config["n_head"]), sessions, length,
+            control=control)
+        out["reference_s"] = time.perf_counter() - t
+        return out
+
+
+def main(argv=None) -> int:
+    parser = argparse.ArgumentParser()
+    parser.add_argument("--cell", required=True)
+    parser.add_argument("--config", required=True)
+    parser.add_argument("--users", type=int, required=True)
+    parser.add_argument("--seed", type=int, required=True)
+    parser.add_argument("--work-dir", required=True)
+    args = parser.parse_args(argv)
+    with open(args.cell) as f:
+        cell = json.load(f)
+    with open(args.config) as f:
+        config = json.load(f)
+
+    from client_tpu.compile_cache import enable_compile_cache
+
+    cache_dir = enable_compile_cache()
+    import jax
+
+    # keep every program, however quickly it compiled: a second run of a
+    # cell then finds all of them
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    device = jax.devices()[0]
+    emit({"event": "device", "platform": device.platform,
+          "kind": device.device_kind, "count": jax.device_count(),
+          "compile_cache": cache_dir})
+
+    served = Served(cell, config, args.users, args.seed, args.work_dir)
+    emit({"event": "ready", "url": served.frontend.url,
+          "model": served.model.name})
+
+    for line in sys.stdin:
+        command = json.loads(line)
+        name = command["cmd"]
+        try:
+            if name == "mark":
+                reply = served.counters()
+            elif name == "trace_start":
+                served.trace_start()
+                reply = {}
+            elif name == "trace_stop":
+                served.trace_stop()
+                reply = {}
+            elif name == "finish":
+                reply = served.finish(cell["step_program"])
+            elif name == "check":
+                reply = served.check(command["sessions"], command["length"],
+                                     command.get("control", False))
+            elif name == "reseed":
+                served.reseed(int(command["seed"]))
+                reply = {}
+            elif name == "exit":
+                emit({"ok": True})
+                return 0
+            else:
+                raise ValueError(f"unknown command {name!r}")
+        except Exception as e:  # the parent decides what a failure means
+            import traceback
+
+            traceback.print_exc()
+            emit({"ok": False, "error": f"{type(e).__name__}: {e}"})
+        else:
+            emit({"ok": True, **reply})
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

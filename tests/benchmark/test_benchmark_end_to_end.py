@@ -1,0 +1,261 @@
+"""Each cell end to end at fixture sizes on the CPU: the users' process, the
+serving child, GRPC between them, the window, the reference's verdict and the
+result line. The look for a chip is skipped (``require_tpu=False``); the
+command itself is seen to refuse a machine without one."""
+
+import hashlib
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+import benchmark_fixture
+from benchmark import calibrate, run
+
+REPO = benchmark_fixture.REPO
+with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
+    BENCH = json.load(_f)
+CELLS = [w["name"] for w in BENCH["workloads"]]
+SECONDS = 1.5
+
+
+@pytest.fixture(scope="module")
+def fixture_root(tmp_path_factory):
+    return benchmark_fixture.make_root(tmp_path_factory.mktemp("bench"))
+
+
+def check_line(result, owed, traced):
+    assert list(result)[:5] == ["correct", "attempted", "failed", "metrics", "device"]
+    assert list(result)[-1] == "compared"
+    json.loads(json.dumps(result))
+    assert result["attempted"] > 0 and result["failed"] == 0
+    device = result["device"]
+    assert device["platform"] == "cpu" and device["kind"] and device["count"] >= 1
+    assert "memory_peak_bytes" in device
+    for name, metric in result["metrics"].items():
+        assert name in owed and metric["unit"] == owed[name]
+        assert isinstance(metric["value"], float) and metric["value"] > 0
+    for number in result["compared"].values():
+        assert number["value"] is not None and "limit" in number
+    if traced:
+        # there is no TPU plane in a CPU trace: the trace's metrics are left
+        # out of the line, never reported as 0
+        assert "step_device_ms" not in result["metrics"]
+        assert "breakdown" not in result
+
+
+@pytest.mark.parametrize("traced", [False, True], ids=["timed", "traced"])
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_at_fixture_size(fixture_root, cell, traced):
+    root, names = fixture_root
+    result = run.run_cell(root, names[cell], seed=2**31 + 17, seconds=SECONDS,
+                          trace=traced, require_tpu=False)
+    assert result["correct"] is True, result["compared"]
+    kind = "per_layer" if traced else "end_to_end"
+    owed = {m["name"]: m["unit"] for m in BENCH[kind]
+            if cell in m.get("workloads", [cell])}
+    check_line(result, owed, traced)
+    if not traced:
+        assert set(result["metrics"]) == set(owed)
+    elif "batch_width_mean" in owed:
+        assert {"batch_width_mean", "server_request_ms",
+                "client_self_ms"} <= set(result["metrics"])
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_an_altered_token_is_not_correct(fixture_root, cell):
+    root, names = fixture_root
+    faulty = os.path.join(root, "tests", "benchmark", "faulty_server.py")
+    result = run.run_cell(root, names[cell], seed=5, seconds=SECONDS, trace=False,
+                          require_tpu=False, server_command=[sys.executable, faulty])
+    assert result["correct"] is False
+    compared = result["compared"]
+    assert compared["served_gap_max"]["value"] > compared["served_gap_max"]["limit"]
+    if "seq" in cell:
+        assert compared["argmax_mismatch"]["value"] > 0
+    assert result["failed"] == 0  # late or wrong is not failed
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_calibration_judges_sound_seeds_and_the_control(fixture_root, cell):
+    """``calibrate.py``'s loop: a seed's served tokens and, in their place,
+    the fp8 control's go through the run's own comparison and limit."""
+    root, names = fixture_root
+    resolved = run.resolve_cell(root, names[cell])
+    with run.Serving(resolved, 11, require_tpu=False) as serving:
+        first, second = calibrate.read_seeds(
+            serving, resolved, [11, 2**31 + 12], control_seeds=1, seconds=1.0)
+    limit = resolved["cell"]["limits"]["served_gap_max"]
+    for reading in (first, second):
+        assert reading["correct"] is True and reading["failed"] == 0
+        assert reading["compared"]["served_gap_max"]["value"] <= limit
+    assert first["control_gap_max"] > limit and first["control_correct"] is False
+    assert "control_correct" not in second
+
+
+def _digests(root):
+    out = {}
+    for folder, _, files in os.walk(root):
+        if ".benchmark_run" in folder or ".jax_cache" in folder:
+            continue
+        for name in files:
+            path = os.path.join(folder, name)
+            if os.path.islink(path) or "__pycache__" in path:
+                continue
+            with open(path, "rb") as f:
+                out[os.path.relpath(path, root)] = hashlib.sha256(f.read()).hexdigest()
+    return out
+
+
+def test_a_later_pr_adds_files_and_edits_none(tmp_path):
+    """A configuration, a mix with its lengths, a builder, a per-layer metric
+    and a cell, as new files and new entries, run by the harness as it
+    stands."""
+    root, _ = benchmark_fixture.make_root(tmp_path)
+    before = _digests(root)
+    home = os.path.join(root, "benchmark")
+
+    def add(obj, *path):
+        assert not os.path.exists(os.path.join(home, *path))
+        with open(os.path.join(home, *path), "w") as f:
+            f.write(obj if isinstance(obj, str) else json.dumps(obj))
+
+    add(dict(benchmark_fixture.TINY_CONFIG, n_layer=1, n_embd=32, n_head=2),
+        "configs", "later.json")
+    add({"source": "fixture", "pool": 4,
+         "prompt": {"mean": 4, "sigma": 0.3, "min": 2, "max": 8},
+         "output": {"mean": 3, "sigma": 0.3, "min": 2, "max": 6}},
+        "lengths", "short.json")
+    add({"api": "stream", "ramp_seconds": 0.3, "lengths": "short"},
+        "traffic", "bursty.json")
+    add("from benchmark import builders\n\n\n"
+        "def generate(config, seed, **args):\n"
+        "    return builders.tiny_lm_generate(config, seed, **args)\n",
+        "later_builders.py")
+    add({"builder": "benchmark.later_builders:generate", "args": {}, "users": 2,
+         "step_program": "jit_step", "limits": {"served_gap_max": 0.01}},
+        "cells", "later.bursty.json")
+    add({"reader": "tokens_per_session.py"}, "layer_metrics", "tokens_per_session.json")
+    add("def read(facts):\n"
+        "    w = facts['window']\n"
+        "    return w['output_tokens_per_s'] * facts['seconds'] / w['sessions_timed']\n",
+        "layer_metrics", "tokens_per_session.py")
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    bench["configs"].append({"name": "later", "source": "fixture", "reduced": [],
+                             "why": "fixture", "file": "benchmark/configs/later.json"})
+    bench["workloads"].append({"name": "later.bursty", "config": "later",
+                               "traffic": "bursty", "chips": 1, "why": "fixture"})
+    bench["per_layer"].append({
+        "name": "tokens_per_session", "unit": "tokens", "better": "higher",
+        "source": "host_clock", "layer": "Client", "moves": "output_tokens_per_s",
+        "workloads": ["later.bursty"]})
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(bench, f)
+
+    result = run.run_cell(root, "later.bursty", seed=3, seconds=SECONDS,
+                          trace=True, require_tpu=False)
+    assert result["correct"] is True, result["compared"]
+    assert result["metrics"]["tokens_per_session"]["value"] > 1
+    after = _digests(root)
+    changed = [p for p in before if p != "BENCHMARK.json" and after[p] != before[p]]
+    assert changed == []
+    assert sorted(set(after) - set(before)) == sorted(
+        "benchmark/" + p for p in (
+            "cells/later.bursty.json", "configs/later.json", "later_builders.py",
+            "layer_metrics/tokens_per_session.json",
+            "layer_metrics/tokens_per_session.py", "lengths/short.json",
+            "traffic/bursty.json"))
+
+
+def _command(root, cell):
+    return subprocess.run(
+        [sys.executable, os.path.join(root, "benchmark", "run.py"), "--workload",
+         cell, "--seed", "1", "--seconds", "1", "--trace", "0"],
+        cwd=root, capture_output=True, text=True, timeout=300,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+
+
+def test_the_command_gives_no_result_without_a_tpu(fixture_root):
+    root, names = fixture_root
+    done = _command(root, names[CELLS[-1]])
+    assert done.returncode != 0 and done.stdout.strip() == ""
+    assert "TPU" in done.stderr
+
+
+def test_the_command_gives_no_result_without_the_program(tmp_path):
+    root, names = benchmark_fixture.make_root(tmp_path)
+    os.unlink(os.path.join(root, "client_tpu"))
+    done = _command(root, names[CELLS[-1]])
+    assert done.returncode != 0 and done.stdout.strip() == ""
+
+
+def test_an_unknown_cell_gives_no_result(fixture_root, capsys):
+    assert run.main(["--workload", "no.such.cell", "--seed", "1",
+                     "--seconds", "1"]) == 1
+    assert capsys.readouterr().out == ""
+
+
+def test_the_result_is_the_last_line_and_the_compared_numbers_end_stderr(
+        monkeypatch, capsys):
+    canned = {"correct": True, "attempted": 3, "failed": 0,
+              "metrics": {"setup_s": {"value": 1.5, "unit": "s"}},
+              "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1,
+                         "memory_peak_bytes": 1},
+              "compared": {"sessions_failed": {"value": 0, "limit": 0},
+                           "served_gap_max": {"value": 0.02, "limit": 0.1}}}
+    monkeypatch.setattr(run, "run_cell", lambda *a, **k: canned)
+    assert run.main(["--workload", "x", "--seed", str(2**31 + 5), "--seconds",
+                     "40", "--trace", "1"]) == 0
+    out, err = capsys.readouterr()
+    assert json.loads(out.strip().splitlines()[-1]) == canned
+    assert err.strip().splitlines()[-2:] == [
+        "compared sessions_failed: 0 (limit 0)",
+        "compared served_gap_max: 0.02 (limit 0.1)"]
+
+
+@pytest.mark.parametrize("records, want", [
+    ([], 0),
+    ([{"error": None, "tokens": [1, 2], "tokens_out": 2, "prompt": [1] * 3,
+       "token_times": [1.0, 2.0]},
+      {"error": None, "tokens": [1], "tokens_out": 2, "prompt": [1] * 9,
+       "token_times": [1.0]},
+      {"error": "x", "tokens": [1, 2], "tokens_out": 2, "prompt": [1] * 9,
+       "token_times": [1.0, 2.0]}], 1),
+    ([{"error": None, "tokens": [1] * n, "tokens_out": n, "prompt": [1] * n,
+       "token_times": [float(n)] * n} for n in range(1, 30)], run.CHECK_SESSIONS),
+])
+def test_the_sample_is_of_finished_sessions_with_the_longest_in_it(records, want):
+    sample = run.pick_sample(records, seed=2**31 + 1)
+    assert len(sample) == want
+    assert sample == run.pick_sample(records, seed=2**31 + 1)
+    if sample:
+        assert len(sample[0]["tokens"]) == max(
+            len(r["tokens"]) for r in records
+            if r["error"] is None and len(r["tokens"]) == r["tokens_out"])
+
+
+@pytest.mark.parametrize("since, until, lengths", [
+    (10.0, 20.0, list(range(10, 21))),  # the ramp's and the late ones left out
+    (25.0, 29.0, [25, 26, 27, 28, 29]),
+    (40.0, 50.0, []),
+])
+def test_the_sample_is_of_sessions_finished_inside_the_window(since, until, lengths):
+    records = [{"error": None, "tokens": [1] * n, "tokens_out": n, "prompt": [1],
+                "token_times": [float(n)] * n} for n in range(1, 30)]
+    sample = run.pick_sample(records, seed=7, since=since, until=until)
+    assert len(sample) == min(len(lengths), run.CHECK_SESSIONS)
+    assert {len(r["tokens"]) for r in sample} <= set(lengths)
+    if sample:
+        assert len(sample[0]["tokens"]) == lengths[-1]
+
+
+def test_window_positions_follow_the_tokens_received():
+    record = {"prompt": [0] * 10, "t_send": 0.0,
+              "token_times": [1.0, 1.1, 1.2, 5.0]}
+    # window 0.5 .. 2.0: half of the prompt's span, and the steps that gave
+    # the tokens at 1.1 and 1.2 (positions 10 and 11)
+    assert run.window_positions([record], 0.5, 1.5) == [0, 1, 2, 3, 4, 10, 11]
+    assert run.window_positions([dict(record, token_times=[])], 0.0, 9.0) == []
