@@ -113,42 +113,58 @@ class TinyDecoderModel(Model):
             return ((x32 - mu) * lax.rsqrt(var + 1e-5)).astype(x.dtype)
 
         def step(params, caches, token, pos):
-            """One decode step. caches: [L] dicts of k/v [H, M, Dh]."""
-            x = params["embed"][token] + params["pos"][pos]  # [D]
+            """One decode step. caches: [L] dicts of k/v [H, M, Dh].
+
+            The named scopes are compile-time metadata: the device
+            operations of a trace carry them, so that device time reads by
+            part of the step whatever the compiler names its fusions."""
+            with jax.named_scope("embed"):
+                x = params["embed"][token] + params["pos"][pos]  # [D]
             new_caches = []
             for layer, cache in zip(params["layers"], caches):
-                h = norm(x)
-                qkv = h @ layer["qkv"]  # [3D]
-                q, k_new, v_new = jnp.split(qkv, 3)
-                q = q.reshape(H, Dh)
-                k_new = k_new.reshape(H, 1, Dh)
-                v_new = v_new.reshape(H, 1, Dh)
-                k = lax.dynamic_update_slice(cache["k"], k_new, (0, pos, 0))
-                v = lax.dynamic_update_slice(cache["v"], v_new, (0, pos, 0))
+                with jax.named_scope("attn_qkv"):
+                    h = norm(x)
+                    qkv = h @ layer["qkv"]  # [3D]
+                    q, k_new, v_new = jnp.split(qkv, 3)
+                    q = q.reshape(H, Dh)
+                    k_new = k_new.reshape(H, 1, Dh)
+                    v_new = v_new.reshape(H, 1, Dh)
+                with jax.named_scope("cache_update"):
+                    k = lax.dynamic_update_slice(
+                        cache["k"], k_new, (0, pos, 0))
+                    v = lax.dynamic_update_slice(
+                        cache["v"], v_new, (0, pos, 0))
                 new_caches.append({"k": k, "v": v})
                 if self._attention_impl == "pallas":
                     from ..ops.decode_attention import decode_attention
 
-                    attn = decode_attention(
-                        q[None], k[None], v[None],
-                        jnp.asarray(pos, jnp.int32).reshape(1),
-                    )[0]  # [H, Dh], bf16 (kernel accumulates fp32)
-                    x = x + (attn.reshape(D) @ layer["proj"])
+                    with jax.named_scope("attention"):
+                        attn = decode_attention(
+                            q[None], k[None], v[None],
+                            jnp.asarray(pos, jnp.int32).reshape(1),
+                        )[0]  # [H, Dh], bf16 (kernel accumulates fp32)
+                    with jax.named_scope("attn_proj"):
+                        x = x + (attn.reshape(D) @ layer["proj"])
                 else:
-                    # position-based mask: only slots <= pos attend
-                    scores = jnp.einsum(
-                        "hd,hmd->hm", q.astype(jnp.float32),
-                        k.astype(jnp.float32)) * (Dh ** -0.5)
-                    mask = jnp.arange(M) <= pos
-                    scores = jnp.where(mask[None, :], scores, -jnp.inf)
-                    probs = jax.nn.softmax(scores, axis=-1)
-                    attn = jnp.einsum(
-                        "hm,hmd->hd", probs, v.astype(jnp.float32))
-                    x = x + (attn.reshape(D).astype(jnp.bfloat16)
-                             @ layer["proj"])
-                h2 = norm(x)
-                x = x + jax.nn.gelu(h2 @ layer["mlp_in"]) @ layer["mlp_out"]
-            logits = (norm(x) @ params["unembed"]).astype(jnp.float32)
+                    with jax.named_scope("attention"):
+                        # position-based mask: only slots <= pos attend
+                        scores = jnp.einsum(
+                            "hd,hmd->hm", q.astype(jnp.float32),
+                            k.astype(jnp.float32)) * (Dh ** -0.5)
+                        mask = jnp.arange(M) <= pos
+                        scores = jnp.where(mask[None, :], scores, -jnp.inf)
+                        probs = jax.nn.softmax(scores, axis=-1)
+                        attn = jnp.einsum(
+                            "hm,hmd->hd", probs, v.astype(jnp.float32))
+                    with jax.named_scope("attn_proj"):
+                        x = x + (attn.reshape(D).astype(jnp.bfloat16)
+                                 @ layer["proj"])
+                with jax.named_scope("mlp"):
+                    h2 = norm(x)
+                    x = x + (jax.nn.gelu(h2 @ layer["mlp_in"])
+                             @ layer["mlp_out"])
+            with jax.named_scope("unembed"):
+                logits = (norm(x) @ params["unembed"]).astype(jnp.float32)
             return logits, new_caches
 
         self._params = params

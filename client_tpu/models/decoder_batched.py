@@ -37,12 +37,23 @@ from typing import Any, Dict, List
 
 import numpy as np
 
+from ..server.timeline import (
+    SPAN_ADMIT,
+    SPAN_BATCH_READBACK,
+    SPAN_COLLECT,
+    SPAN_ROUND_DISPATCH,
+    SPAN_ROUND_PREPARE,
+    SPAN_WAIT_RESULT,
+    BatchMarks,
+    current,
+    span,
+)
 from .base import Model, TensorSpec
 from .decoder import TinyDecoderModel
 
 
 class _SeqRequest:
-    __slots__ = ("seq_id", "tokens", "start", "end", "future")
+    __slots__ = ("seq_id", "tokens", "start", "end", "future", "marks")
 
     def __init__(self, seq_id, tokens, start, end):
         self.seq_id = seq_id
@@ -50,6 +61,9 @@ class _SeqRequest:
         self.start = start
         self.end = end
         self.future: Future = Future()
+        # the request's way through the batcher (server/timeline.py); the
+        # dispatch marks are host times and run ahead of the device
+        self.marks = BatchMarks()
 
     # The caller may cancel() the future (120s timeout) at any moment —
     # set_result/set_exception on a cancelled future raises
@@ -99,6 +113,10 @@ class BatchedDecoderModel(Model):
         self._carry: List[_SeqRequest] = []
         # observability for tests/tuning: rounds executed per batch width
         self.batch_histogram: Dict[int, int] = {}
+        self._rounds = 0  # rounds dispatched so far: the next round's id
+        # ``(width, dispatch_ns)`` of every round, for the statistics verb's
+        # batch_stats; ServerCore.add_model binds its recorder here
+        self.report_batch = None
         self._worker = None  # started lazily with the first build
 
     def inputs(self) -> List[TensorSpec]:
@@ -132,7 +150,10 @@ class BatchedDecoderModel(Model):
                     mask = active.reshape((-1,) + (1,) * (new.ndim - 1))
                     return jnp.where(mask, new, old)
 
-                caches = jax.tree_util.tree_map(sel, new_caches, caches)
+                # a named scope is compile-time metadata: the device
+                # operations of the select carry it in a trace
+                with jax.named_scope("slot_select"):
+                    caches = jax.tree_util.tree_map(sel, new_caches, caches)
                 return logits, caches
 
             self._batched_step = jax.jit(batched_step)
@@ -178,6 +199,10 @@ class BatchedDecoderModel(Model):
         if self._closed:
             raise ValueError("model is shutting down")
         req = _SeqRequest(seq_id, [int(t) for t in tokens], start, end)
+        timeline = current()
+        if timeline is not None:
+            timeline.batch = req.marks
+        req.marks.enqueued = time.perf_counter_ns()
         try:
             # bounded wait: with a wedged worker the queue fills, and an
             # unbounded put() would hang callers before the future timeout
@@ -195,7 +220,8 @@ class BatchedDecoderModel(Model):
             # here (the worker wins harmlessly if it got there first)
             req.fail(ValueError("model is shutting down"))
         try:
-            logits = req.future.result(timeout=120)
+            with span(SPAN_WAIT_RESULT):
+                logits = req.future.result(timeout=120)
         except FuturesTimeout:
             # the worker is wedged or the dispatch is pathologically slow;
             # the caller is gone either way, so surface a gateway-timeout
@@ -208,8 +234,10 @@ class BatchedDecoderModel(Model):
 
             raise InferError(
                 "batched decode timed out after 120s", 504) from None
-        logits_np = np.asarray(logits, dtype=np.float32).reshape(
-            1, self._decoder.VOCAB)
+        with span(SPAN_BATCH_READBACK) as readback:
+            logits_np = np.asarray(logits, dtype=np.float32).reshape(
+                1, self._decoder.VOCAB)
+        req.marks.on_host = readback.end_ns
         return {
             "LOGITS": logits_np,
             "NEXT_TOKEN": np.array([[int(logits_np.argmax())]], dtype=np.int32),
@@ -243,19 +271,23 @@ class BatchedDecoderModel(Model):
         waits for the next round — the reference sequence batcher
         serializes per CORRID the same way)."""
         window, seen, still_carried = [], set(), []
+
+        def take(req: _SeqRequest) -> None:
+            window.append(req)
+            seen.add(req.seq_id)
+            req.marks.collected = time.perf_counter_ns()
+
         for req in self._carry:
             if req.seq_id in seen:
                 still_carried.append(req)  # FIFO within a sequence
             else:
-                window.append(req)
-                seen.add(req.seq_id)
+                take(req)
         self._carry = still_carried
         if not window:
             first = self._queue.get()
             if first is None:
                 return []
-            window.append(first)
-            seen.add(first.seq_id)
+            take(first)
         deadline = time.monotonic() + self._max_delay_s
         while len(window) < self.slots:
             remaining = deadline - time.monotonic()
@@ -274,8 +306,7 @@ class BatchedDecoderModel(Model):
                 # this round
                 self._carry.append(nxt)
                 continue
-            window.append(nxt)
-            seen.add(nxt.seq_id)
+            take(nxt)
         return window
 
     def _admit(self, req: _SeqRequest) -> int:
@@ -320,7 +351,8 @@ class BatchedDecoderModel(Model):
 
     def _run(self) -> None:
         while True:
-            window = self._collect()
+            with span(SPAN_COLLECT):
+                window = self._collect()
             if not window:
                 return
             try:
@@ -333,60 +365,46 @@ class BatchedDecoderModel(Model):
     def _run_window(self, window: List[_SeqRequest]) -> None:
         import jax.numpy as jnp
 
-        # reap BEFORE admitting so a full house of abandoned sequences
-        # frees up for this window's sequence_start requests
-        self._reap_idle(
-            exclude={req.seq_id for req in window}
-            | {r.seq_id for r in self._carry})
-
-        dec = self._decoder
-        active_reqs: List[tuple] = []  # (req, slot)
-        for req in window:
-            try:
-                slot = self._admit(req)
-            except Exception as e:
-                req.fail(e)
-                continue
-            if req.start:
-                # zero pos; cache rows are fully overwritten as the
-                # prompt streams in, and masked reads never see slots
-                # beyond pos, so stale cache content is harmless
-                self._pos[slot] = 0
-            pos_here = int(self._pos[slot])
-            if pos_here + len(req.tokens) > dec.MAX_LEN:
-                req.fail(ValueError(
-                    f"sequence longer than max_len {dec.MAX_LEN}"))
-                with self._lock:
-                    self._free_slot(req.seq_id)
-                continue
-            active_reqs.append((req, slot))
+        with span(SPAN_ADMIT):
+            active_reqs = self._admit_window(window)
 
         # lockstep rounds: each round consumes ONE token from every
         # request that still has tokens left (prompts prefill together)
+        dec = self._decoder
         last_logits: Dict[int, Any] = {}
+        first_round = self._rounds
         try:
             while any(req.tokens for req, _ in active_reqs):
-                tokens = np.zeros((self.slots,), np.int32)
-                active = np.zeros((self.slots,), bool)
-                for req, slot in active_reqs:
-                    if req.tokens:
-                        tokens[slot] = req.tokens.pop(0)
-                        active[slot] = True
-                # snapshot pos: device_put may alias the host buffer
-                # (CPU zero-copy) or read it after dispatch returns
-                # (ImmutableUntilTransferCompletes), so handing JAX
-                # self._pos itself and then mutating it in place races
-                # the in-flight step — the round-3 nondeterminism
-                logits, self._caches = self._batched_step(
-                    dec._params, self._caches,
-                    jnp.asarray(tokens), jnp.asarray(self._pos.copy()),
-                    jnp.asarray(active))
+                with span(SPAN_ROUND_PREPARE):
+                    tokens = np.zeros((self.slots,), np.int32)
+                    active = np.zeros((self.slots,), bool)
+                    for req, slot in active_reqs:
+                        if req.tokens:
+                            tokens[slot] = req.tokens.pop(0)
+                            active[slot] = True
+                    # snapshot pos: device_put may alias the host buffer
+                    # (CPU zero-copy) or read it after dispatch returns
+                    # (ImmutableUntilTransferCompletes), so handing JAX
+                    # self._pos itself and then mutating it in place races
+                    # the in-flight step — the round-3 nondeterminism
+                    on_device = (jnp.asarray(tokens),
+                                 jnp.asarray(self._pos.copy()),
+                                 jnp.asarray(active))
+                with span(SPAN_ROUND_DISPATCH) as dispatch:
+                    logits, self._caches = self._batched_step(
+                        dec._params, self._caches, *on_device)
                 self._pos[active] += 1
-                self.batch_histogram[int(active.sum())] = (
-                    self.batch_histogram.get(int(active.sum()), 0) + 1)
+                width = int(active.sum())
+                self.batch_histogram[width] = (
+                    self.batch_histogram.get(width, 0) + 1)
+                if self.report_batch is not None:
+                    self.report_batch(width, dispatch.ns)
                 for req, slot in active_reqs:
                     if active[slot]:
                         last_logits[slot] = logits[slot]
+                        req.marks.round(dispatch, self._rounds, width,
+                                        last=not req.tokens)
+                self._rounds += 1
         except Exception as e:  # a failed dispatch must not strand callers
             for req, _ in active_reqs:
                 req.fail(e)
@@ -402,10 +420,43 @@ class BatchedDecoderModel(Model):
             if req.end:
                 with self._lock:
                     self._free_slot(req.seq_id)
+            req.marks.rounds_window = self._rounds - first_round
             if slot in last_logits:
+                req.marks.resolved = time.perf_counter_ns()
                 req.resolve(last_logits[slot])
             else:
                 req.fail(ValueError("request executed no decode step"))
+
+    def _admit_window(self, window: List[_SeqRequest]) -> List[tuple]:
+        """``(request, slot)`` of the window's requests that have a slot and
+        room in its cache; the others are failed here."""
+        # reap BEFORE admitting so a full house of abandoned sequences
+        # frees up for this window's sequence_start requests
+        self._reap_idle(
+            exclude={req.seq_id for req in window}
+            | {r.seq_id for r in self._carry})
+
+        active_reqs: List[tuple] = []
+        for req in window:
+            try:
+                slot = self._admit(req)
+            except Exception as e:
+                req.fail(e)
+                continue
+            if req.start:
+                # zero pos; cache rows are fully overwritten as the
+                # prompt streams in, and masked reads never see slots
+                # beyond pos, so stale cache content is harmless
+                self._pos[slot] = 0
+            pos_here = int(self._pos[slot])
+            if pos_here + len(req.tokens) > self._decoder.MAX_LEN:
+                req.fail(ValueError(
+                    f"sequence longer than max_len {self._decoder.MAX_LEN}"))
+                with self._lock:
+                    self._free_slot(req.seq_id)
+                continue
+            active_reqs.append((req, slot))
+        return active_reqs
 
     def _free_slot(self, seq_id) -> None:
         slot = self._slot_of.pop(seq_id, None)

@@ -37,10 +37,20 @@ Wire contract (decoupled — use streaming inference):
 from __future__ import annotations
 
 import threading
+import time
 from typing import Any, Dict, Iterable, List
 
 import numpy as np
 
+from ..server.timeline import (
+    SPAN_DISPATCH,
+    SPAN_FRESH_CACHE,
+    SPAN_PREFILL,
+    SPAN_READBACK,
+    StreamMarks,
+    current,
+    span,
+)
 from .base import Model, TensorSpec
 from .decoder import TinyDecoderModel
 
@@ -106,7 +116,8 @@ class TinyGenerateModel(Model):
             def body(carry, _):
                 caches, token, pos = carry
                 logits, caches = step(params, caches, token, pos)
-                nxt = jnp.argmax(logits).astype(jnp.int32)
+                with jax.named_scope("greedy_argmax"):
+                    nxt = jnp.argmax(logits).astype(jnp.int32)
                 return (caches, nxt, pos + jnp.int32(1)), nxt
 
             (caches, _, _), toks = lax.scan(
@@ -153,13 +164,26 @@ class TinyGenerateModel(Model):
         # room left in the static cache bounds generation length
         budget = min(max_tokens, max_len - int(tokens.size))
 
+        # the stream's marks (server/timeline.py), on the request's timeline
+        # where the core opened one. The dispatch intervals are host times:
+        # a step call returns when the step is enqueued (or when the
+        # allocator has found room for its output cache), not when it has run
+        marks = StreamMarks()
+        timeline = current()
+        if timeline is not None:
+            timeline.stream = marks
+
         # prefill: the single compiled step over the prompt (same executable
         # the decode loop uses — nothing new compiles per prompt length)
-        caches, pos = dec._fresh_cache(), 0
+        with span(SPAN_FRESH_CACHE) as s:
+            caches, pos = dec._fresh_cache(), 0
+        marks.cache_ready = s.end_ns
         logits = None
-        for t in tokens:
-            logits, caches = dec._step_fn(dec._params, caches, int(t), pos)
-            pos += 1
+        with span(SPAN_PREFILL) as s:
+            for t in tokens:
+                logits, caches = dec._step_fn(dec._params, caches, int(t), pos)
+                pos += 1
+        marks.prefill_done = s.end_ns
 
         def response(token_id: int, index: int):
             return {
@@ -168,25 +192,37 @@ class TinyGenerateModel(Model):
             }
 
         emitted = 0
-        next_token = int(np.asarray(logits).argmax())
+
+        def suspended(token_id: int):
+            t_yield = time.perf_counter_ns()
+            yield response(token_id, emitted)
+            marks.yielded.add(time.perf_counter_ns() - t_yield, emitted)
+
+        with span(SPAN_READBACK) as s:
+            next_token = int(np.asarray(logits).argmax())
+        marks.readback.add(s.ns, emitted)
         if chunk == 1:
             # per-token dispatch: one streamed response per device step —
             # honest TTFT/inter-token latency for a perf harness
             while emitted < budget:
-                yield response(next_token, emitted)
+                yield from suspended(next_token)
                 emitted += 1
                 if emitted >= budget or (end_id is not None
                                          and next_token == end_id):
                     return
-                logits, caches = dec._step_fn(
-                    dec._params, caches, next_token, pos)
+                with span(SPAN_DISPATCH) as s:
+                    logits, caches = dec._step_fn(
+                        dec._params, caches, next_token, pos)
+                marks.dispatch.add(s.ns, emitted)
                 pos += 1
-                next_token = int(np.asarray(logits).argmax())
+                with span(SPAN_READBACK) as s:
+                    next_token = int(np.asarray(logits).argmax())
+                marks.readback.add(s.ns, emitted)
             return
 
         # chunked: first token came from prefill; subsequent tokens arrive
         # K at a time from one scan dispatch and stream out burst-wise
-        yield response(next_token, emitted)
+        yield from suspended(next_token)
         emitted += 1
         if end_id is not None and next_token == end_id:
             return
@@ -194,12 +230,16 @@ class TinyGenerateModel(Model):
             k = min(chunk, budget - emitted, max_len - pos)
             if k <= 0:
                 return
-            toks, caches = self._chunk_fn(k)(
-                dec._params, caches, next_token, pos)
+            with span(SPAN_DISPATCH) as s:
+                toks, caches = self._chunk_fn(k)(
+                    dec._params, caches, next_token, pos)
+            marks.dispatch.add(s.ns, emitted)
             pos += k
-            toks = np.asarray(toks).reshape(-1)
+            with span(SPAN_READBACK) as s:
+                toks = np.asarray(toks).reshape(-1)
+            marks.readback.add(s.ns, emitted)
             for t in toks:
-                yield response(int(t), emitted)
+                yield from suspended(int(t))
                 emitted += 1
                 if end_id is not None and int(t) == end_id:
                     return

@@ -29,15 +29,18 @@ from typing import Any, Callable, Dict, List, Tuple
 
 import numpy as np
 
+from .timeline import SPAN_ROUND_DISPATCH, BatchMarks, span
+
 
 class _Pending:
-    __slots__ = ("inputs", "parameters", "future", "enqueued_ns", "rows")
+    __slots__ = ("inputs", "parameters", "future", "marks", "rows")
 
     def __init__(self, inputs, parameters):
         self.inputs = inputs
         self.parameters = parameters
         self.future: Future = Future()
-        self.enqueued_ns = time.perf_counter_ns()
+        self.marks = BatchMarks()
+        self.marks.enqueued = time.perf_counter_ns()
         # rows this request contributes to the stacked batch (axis 0)
         first = next(iter(inputs.values()))
         self.rows = int(first.shape[0]) if first.ndim else 1
@@ -59,9 +62,11 @@ def _compat_key(inputs: Dict[str, np.ndarray],
 class DynamicBatcher:
     """Per-model batching queue in front of ``execute``.
 
-    ``report``: optional callback ``(batch_rows, exec_ns, queue_ns_total,
-    n_requests)`` invoked once per executed batch — the core feeds it into
-    the protocol's ``InferBatchStatistics``.
+    ``report``: optional callback ``(batch_rows, exec_ns)`` invoked once per
+    executed batch — the core feeds it into the protocol's
+    ``InferBatchStatistics``. What one request waited and ran is marked on
+    its own timeline (``submit``'s third argument), as the sequence batcher
+    marks its: ``enqueued``, then the edges of the execution that carried it.
     """
 
     def __init__(
@@ -70,7 +75,7 @@ class DynamicBatcher:
         max_batch: int,
         max_delay_s: float = 0.002,
         max_queue: int = 1024,
-        report: Callable[[int, int, int, int], None] = None,
+        report: Callable[[int, int], None] = None,
     ):
         self._execute = execute
         self._max_batch = max(int(max_batch), 1)
@@ -85,10 +90,12 @@ class DynamicBatcher:
 
     # -- caller side --------------------------------------------------------
     def submit(self, inputs: Dict[str, np.ndarray],
-               parameters: Dict[str, Any]) -> Future:
+               parameters: Dict[str, Any], timeline=None) -> Future:
         if self._closed:
             raise RuntimeError("batcher is closed")
         item = _Pending(inputs, parameters)
+        if timeline is not None:
+            timeline.batch = item.marks
         self._queue.put(item)
         return item.future
 
@@ -151,8 +158,6 @@ class DynamicBatcher:
                 self._run_group(items)
 
     def _run_group(self, items: List[_Pending]) -> None:
-        t0 = time.perf_counter_ns()
-        queue_ns = sum(t0 - it.enqueued_ns for it in items)
         try:
             if len(items) == 1:
                 stacked = items[0].inputs
@@ -162,11 +167,13 @@ class DynamicBatcher:
                     for name in items[0].inputs
                 }
             # safe: the group key pins identical parameters across items
-            outputs = self._execute(stacked, items[0].parameters)
-            exec_ns = time.perf_counter_ns() - t0
+            with span(SPAN_ROUND_DISPATCH) as execution:
+                outputs = self._execute(stacked, items[0].parameters)
             batch_rows = sum(it.rows for it in items)
+            for it in items:
+                it.marks.round(execution, None, batch_rows, last=True)
             if self._report is not None:
-                self._report(batch_rows, exec_ns, queue_ns, len(items))
+                self._report(batch_rows, execution.ns)
             offset = 0
             for it in items:
                 sliced = {

@@ -20,6 +20,13 @@ import numpy as np
 
 from ..models.base import Model
 from ..utils import triton_to_np_dtype
+from .timeline import (
+    COMPILES,
+    SPAN_BUILD_RESPONSE,
+    SPAN_RESOLVE_INPUTS,
+    Timeline,
+    span,
+)
 
 _BUILTIN_SHM_FAMILIES = ("system", "cuda", "tpu")
 
@@ -165,6 +172,11 @@ class _TpuRegion(_Region):
 
 
 class _ModelStats:
+    """What the statistics verb reports for one model. Every successful
+    request counts once in each of Triton's four parts, which add up to its
+    ``success`` ns (``Timeline.parts`` says what each holds for a batched
+    model, for a decoupled one and for any other)."""
+
     def __init__(self):
         self.lock = threading.Lock()
         self.inference_count = 0
@@ -175,29 +187,35 @@ class _ModelStats:
         # client cancel/disconnect mid-stream: neither a success nor a
         # model failure (reference tracks cancelled requests separately)
         self.cancel = [0, 0]
-        self.compute_infer = [0, 0]
+        self.compute_input = [0, 0]
         self.queue = [0, 0]
+        self.compute_infer = [0, 0]
+        self.compute_output = [0, 0]
         self.batches: Dict[int, List[int]] = {}  # batch_size -> [count, ns]
 
-    def record(self, ok: bool, total_ns: int, infer_ns: int, batch: int,
+    def record(self, total_ns: int, parts, batch: int,
                executed: bool = True) -> None:
-        """``executed=False`` for dynamically-batched requests: the model
-        execution is counted once by record_batch, not once per request
-        (reference semantics: execution_count < inference_count under
-        batching)."""
+        """One successful request. ``executed=False`` for a request a
+        batcher carried: the execution is counted once by record_batch, not
+        once per request (reference semantics: execution_count <
+        inference_count under batching)."""
         with self.lock:
-            if ok:
-                self.inference_count += batch
-                if executed:
-                    self.execution_count += 1
-                    self.compute_infer[0] += 1
-                    self.compute_infer[1] += infer_ns
-                self.last_inference = int(time.time() * 1000)
-                self.success[0] += 1
-                self.success[1] += total_ns
-            else:
-                self.fail[0] += 1
-                self.fail[1] += total_ns
+            self.inference_count += batch
+            if executed:
+                self.execution_count += 1
+            self.last_inference = int(time.time() * 1000)
+            self.success[0] += 1
+            self.success[1] += total_ns
+            for row, ns in zip((self.compute_input, self.queue,
+                                self.compute_infer, self.compute_output),
+                               parts):
+                row[0] += 1
+                row[1] += ns
+
+    def record_fail(self, total_ns: int) -> None:
+        with self.lock:
+            self.fail[0] += 1
+            self.fail[1] += total_ns
 
     def record_cancel(self, total_ns: int) -> None:
         with self.lock:
@@ -205,23 +223,17 @@ class _ModelStats:
             self.cancel[1] += total_ns
             self.last_inference = int(time.time() * 1000)
 
-    def record_batch(self, batch_size: int, exec_ns: int, queue_ns: int,
-                     n_requests: int) -> None:
-        """One dynamic-batcher execution (InferBatchStatistics feed).
-
-        ``queue`` counts per REQUEST (Triton semantics — the average must
-        be a request's wait, not the batch's summed waits)."""
+    def record_batch(self, batch_size: int, exec_ns: int) -> None:
+        """One execution by a batcher (InferBatchStatistics feed): a batch
+        of the dynamic batcher, a round of the sequence batcher."""
         with self.lock:
             row = self.batches.setdefault(batch_size, [0, 0])
             row[0] += 1
             row[1] += exec_ns
-            self.queue[0] += n_requests
-            self.queue[1] += queue_ns
             self.execution_count += 1
-            self.compute_infer[0] += 1
-            self.compute_infer[1] += exec_ns
 
     def as_dict(self, name: str, version: str) -> Dict[str, Any]:
+        pair = lambda row: {"count": row[0], "ns": row[1]}
         with self.lock:
             return {
                 "name": name,
@@ -230,23 +242,16 @@ class _ModelStats:
                 "inference_count": self.inference_count,
                 "execution_count": self.execution_count,
                 "inference_stats": {
-                    "success": {"count": self.success[0], "ns": self.success[1]},
-                    "fail": {"count": self.fail[0], "ns": self.fail[1]},
-                    "cancel": {"count": self.cancel[0],
-                               "ns": self.cancel[1]},
-                    "queue": {"count": self.queue[0], "ns": self.queue[1]},
-                    "compute_input": {"count": 0, "ns": 0},
-                    "compute_infer": {
-                        "count": self.compute_infer[0],
-                        "ns": self.compute_infer[1],
-                    },
-                    "compute_output": {"count": 0, "ns": 0},
+                    "success": pair(self.success),
+                    "fail": pair(self.fail),
+                    "cancel": pair(self.cancel),
+                    "queue": pair(self.queue),
+                    "compute_input": pair(self.compute_input),
+                    "compute_infer": pair(self.compute_infer),
+                    "compute_output": pair(self.compute_output),
                 },
                 "batch_stats": [
-                    {
-                        "batch_size": size,
-                        "compute_infer": {"count": row[0], "ns": row[1]},
-                    }
+                    {"batch_size": size, "compute_infer": pair(row)}
                     for size, row in sorted(self.batches.items())
                 ],
             }
@@ -304,6 +309,10 @@ class ServerCore:
         # timings line up (client_tpu.observe; scraped via /metrics)
         self._access: deque = deque(maxlen=1024)
         self._metrics_registry = None
+        # a timeline's marks are perf_counter_ns(); this pair places them on
+        # the wall clock, which is the one the profiler's planes are on
+        self.clock_anchor = (time.time_ns(), time.perf_counter_ns())
+        COMPILES.listen()
         for m in models or []:
             self.add_model(m)
 
@@ -311,7 +320,9 @@ class ServerCore:
     def add_model(self, model: Model) -> None:
         with self._lock:
             self._models[model.name] = model
-            self._stats.setdefault(model.name, _ModelStats())
+            stats = self._stats.setdefault(model.name, _ModelStats())
+        if hasattr(model, "report_batch"):  # a model with a batcher of its own
+            model.report_batch = stats.record_batch
         if hasattr(model, "bind"):  # ensembles resolve members at execute time
             model.bind(self.model)
 
@@ -413,15 +424,55 @@ class ServerCore:
             self._trace_candidates += 1
             return (self._trace_candidates - 1) % rate == 0
 
-    def _record_trace(self, model_name: str, request_id: str, timestamps: Dict[str, int]) -> None:
+    def _record(self, model_name: str, request: Dict[str, Any],
+                tl: Timeline, batch: int = 1) -> None:
+        """The one end-of-request recorder: the statistics verb, the Triton
+        trace record (``trace_level`` ``TIMESTAMPS``, sampled by
+        ``trace_rate`` / ``trace_count``, mirrored to ``trace_file``) and the
+        ``traceparent`` access record all read the request's timeline."""
+        tl.close()
+        parts = tl.parts()
+        self._stats[model_name].record(
+            tl.done - tl.recv, parts, batch, executed=tl.batch is None)
+        ids = None
+        if request.get("traceparent"):
+            from ..observe import parse_traceparent
+
+            ids = parse_traceparent(request["traceparent"])
+        if self._trace_enabled():
+            self._record_trace(model_name, request, tl, ids)
+        if ids is not None:
+            self._record_access(model_name, request, tl, parts, ids)
+
+    def _record_trace(self, model_name: str, request: Dict[str, Any],
+                      tl: Timeline, ids) -> None:
+        """Every mark of the timeline under its name (beside Triton's four
+        timestamps), what was counted on the way, and the identifiers that
+        spans of one request share: the request id, the sequence id, the
+        ``traceparent`` ids where it had one, its first round's id."""
+        counts = tl.counts()
+        record = {
+            "id": None,  # its place in the ring, given under the lock
+            "model_name": model_name,
+            "request_id": request.get("id", ""),
+            "sequence_id": request.get("parameters", {}).get("sequence_id", 0),
+            "first_round_id": counts.get("first_round_id"),
+            "timestamps": {
+                "request_start_ns": tl.recv,
+                "compute_start_ns": tl.model_enter,
+                "compute_end_ns": tl.model_exit,
+                "request_end_ns": tl.done,
+                **tl.marks(),
+            },
+            "counts": counts,
+            "clock_anchor": {"wall_ns": self.clock_anchor[0],
+                             "perf_ns": self.clock_anchor[1]},
+        }
+        if ids is not None:
+            record["trace_id"], record["client_span_id"] = ids[0], ids[1]
         with self._lock:
             self._trace_seq += 1
-            record = {
-                "id": self._trace_seq,
-                "model_name": model_name,
-                "request_id": request_id,
-                "timestamps": timestamps,
-            }
+            record["id"] = self._trace_seq
             self._traces.append(record)
             if len(self._traces) > 1024:
                 del self._traces[: len(self._traces) - 1024]
@@ -438,42 +489,33 @@ class ServerCore:
             return list(self._traces[-count:])
 
     # -- observability (client_tpu.observe counterpart) ----------------------
-    def _observe_access(self, request: Dict[str, Any], model_name: str,
-                        t0: int, t_infer: int, infer_ns: int,
-                        responses: int = 1,
-                        first_response_ns: Optional[int] = None) -> None:
-        """Record a server-side span for a request that carried a W3C
+    def _record_access(self, model_name: str, request: Dict[str, Any],
+                       tl: Timeline, parts, ids) -> None:
+        """A server-side span for a request that carried a W3C
         ``traceparent`` (frontends stash the header/metadata value under
         the reserved ``traceparent`` request key). ``client_span_id`` is
-        the parent id from the header — the client's request span — so one
-        trace id joins client phases to server queue/compute timings.
-        Streamed (decoupled) requests additionally carry their response
-        count and the server-side first-response latency, the join target
-        for the client's StreamSpan TTFT."""
-        traceparent = request.get("traceparent")
-        if not traceparent:
-            return
-        from ..observe import make_span_id, parse_traceparent
+        the parent id from the header, the client's request span, so one
+        trace id joins client phases to the server's timings: ``queue_ns``
+        is the timeline's queue (0 where the model has none) and
+        ``compute_ns`` its compute_infer. Streamed (decoupled) requests
+        additionally carry the server-side first-response latency, the join
+        target for the client's StreamSpan TTFT."""
+        from ..observe import make_span_id
 
-        parsed = parse_traceparent(traceparent)
-        if parsed is None:
-            return
-        trace_id, client_span_id, _sampled = parsed
         record = {
-            "trace_id": trace_id,
-            "client_span_id": client_span_id,
+            "trace_id": ids[0],
+            "client_span_id": ids[1],
             "server_span_id": make_span_id(),
             "model_name": model_name,
             "request_id": request.get("id", ""),
-            # recv -> compute-start: input resolution + batching queue
-            "queue_ns": max(t_infer - t0, 0),
-            "compute_ns": infer_ns,
-            "total_ns": time.perf_counter_ns() - t0,
-            "responses": responses,
+            "queue_ns": parts[1],
+            "compute_ns": parts[2],
+            "total_ns": tl.done - tl.recv,
+            "responses": tl.responses,
             "wall_time_s": time.time(),
         }
-        if first_response_ns is not None:
-            record["first_response_ns"] = max(first_response_ns - t0, 0)
+        if tl.first_response is not None:
+            record["first_response_ns"] = tl.first_response - tl.recv
         with self._lock:
             self._access.append(record)
 
@@ -526,6 +568,12 @@ class ServerCore:
         traced = reg.gauge(
             "client_tpu_server_traced_requests",
             "Traceparent-joined access records currently buffered")
+        compile_count = reg.gauge(
+            "client_tpu_server_compile_count",
+            "XLA compiles in this process since the first server core")
+        compile_seconds = reg.gauge(
+            "client_tpu_server_compile_seconds",
+            "Cumulative XLA compile time in this process")
 
         def collect():
             live.set(1.0 if self.live else 0.0)
@@ -546,6 +594,8 @@ class ServerCore:
                     stats["compute_infer"]["ns"] / 1e9)
             with self._lock:
                 traced.set(len(self._access))
+            compile_count.set(COMPILES.count)
+            compile_seconds.set(COMPILES.ns / 1e9)
 
         reg.add_collector(collect)
         with self._lock:
@@ -662,7 +712,7 @@ class ServerCore:
         each response: {"model_name","model_version","id","parameters",
         "outputs": [{name, datatype, shape, "array"|"shm"}]}.
         """
-        t0 = time.perf_counter_ns()
+        tl = Timeline()
         model = self.model(model_name, model_version)
         if not model.ready:
             raise InferError(f"Request for unknown model: '{model_name}' is not ready", 400)
@@ -671,23 +721,22 @@ class ServerCore:
                 f"model '{model_name}' is a decoupled model: use streaming inference", 400
             )
         if model.decoupled:
-            # delegate to the incremental generator (it owns stats/tracing
+            # delegate to the incremental generator (it owns the recording
             # for the decoupled path); materializing here keeps infer()'s
             # list-of-responses contract
             return list(self._decoupled_stream(
-                model, model_name, model_version, request, t0))
+                model, model_name, model_version, request, tl))
+        stats = self._stats[model_name]
         try:
-            inputs = self._resolve_inputs(model, request)
+            with span(SPAN_RESOLVE_INPUTS) as s:
+                inputs = self._resolve_inputs(model, request)
+            tl.inputs_resolved = s.end_ns
             params = request.get("parameters", {})
-            t_infer = time.perf_counter_ns()
-            batched = False
+            tl.model_enter = time.perf_counter_ns()
             if self._batchable(model, params):
-                batched = True
                 try:
-                    raw_responses = [
-                        self._batcher_for(model).submit(inputs, params).result(
-                            timeout=self.batch_timeout_s)
-                    ]
+                    raw = self._batcher_for(model).submit(
+                        inputs, params, tl).result(timeout=self.batch_timeout_s)
                 except FuturesTimeoutError:
                     raise InferError(
                         f"batched inference timed out after "
@@ -697,30 +746,26 @@ class ServerCore:
                         504,
                     )
             else:
-                raw_responses = [model.execute(inputs, params)]
-            infer_ns = time.perf_counter_ns() - t_infer
+                with tl:  # the model's code finds it as timeline.current()
+                    raw = model.execute(inputs, params)
+            tl.model_exit = time.perf_counter_ns()
         except InferError:
-            self._stats[model_name].record(False, time.perf_counter_ns() - t0, 0, 0)
+            stats.record_fail(time.perf_counter_ns() - tl.recv)
             raise
         except Exception as e:
-            self._stats[model_name].record(False, time.perf_counter_ns() - t0, 0, 0)
+            stats.record_fail(time.perf_counter_ns() - tl.recv)
             raise InferError(f"inference failed: {e}", 400)
 
-        responses = []
-        for raw in raw_responses:
-            responses.append(
-                self._build_response(model, model_version, request, raw)
-            )
-        self._trace_request(model_name, request, t0, t_infer, infer_ns)
-        self._observe_access(request, model_name, t0, t_infer, infer_ns)
+        with span(SPAN_BUILD_RESPONSE) as s:
+            response = self._build_response(model, model_version, request, raw)
+        tl.done = s.end_ns
+        tl.responses = 1
         batch = 1
-        if responses and model.effective_max_batch_size():
-            first = next(iter(raw_responses[0].values()))
+        if model.effective_max_batch_size():
+            first = next(iter(raw.values()))
             batch = int(first.shape[0]) if first.ndim else 1
-        self._stats[model_name].record(
-            True, time.perf_counter_ns() - t0, infer_ns, batch,
-            executed=not batched)
-        return responses
+        self._record(model_name, request, tl, batch)
+        return [response]
 
     def infer_stream(self, model_name: str, model_version: str,
                      request: Dict[str, Any]):
@@ -739,85 +784,63 @@ class ServerCore:
             raise InferError(
                 f"Request for unknown model: '{model_name}' is not ready", 400)
         yield from self._decoupled_stream(
-            model, model_name, model_version, request, time.perf_counter_ns())
+            model, model_name, model_version, request, Timeline())
 
     def _decoupled_stream(self, model: Model, model_name: str,
                           model_version: str, request: Dict[str, Any],
-                          t0: int):
+                          tl: Timeline):
         """Drive ``execute_decoupled`` lazily, building + yielding each
-        response as it is produced. Owns stats and trace recording for the
-        whole decoupled request (exactly-once, whether it completes, fails
+        response as it is produced. Owns the recording for the whole
+        decoupled request (exactly-once, whether it completes, fails
         mid-stream, or the consumer abandons the generator)."""
-        recorded = False
-
-        def record(ok: bool, infer_ns: int):
-            nonlocal recorded
-            if recorded:
-                return
-            recorded = True
-            # inference_count counts the REQUEST once, regardless of how
-            # many responses streamed (reference decoupled semantics:
-            # response count != request count)
-            self._stats[model_name].record(
-                ok, time.perf_counter_ns() - t0, infer_ns, 1 if ok else 0)
-
+        stats = self._stats[model_name]
         try:
-            inputs = self._resolve_inputs(model, request)
+            with span(SPAN_RESOLVE_INPUTS) as s:
+                inputs = self._resolve_inputs(model, request)
+            tl.inputs_resolved = s.end_ns
             params = request.get("parameters", {})
         except InferError:
-            record(False, 0)
+            stats.record_fail(time.perf_counter_ns() - tl.recv)
             raise
         except Exception as e:
-            record(False, 0)
+            stats.record_fail(time.perf_counter_ns() - tl.recv)
             raise InferError(f"inference failed: {e}", 400)
 
-        t_infer = time.perf_counter_ns()
-        gen = model.execute_decoupled(inputs, params)
-        n_responses = 0
-        t_first: Optional[int] = None
+        tl.model_enter = time.perf_counter_ns()
+        gen = iter(model.execute_decoupled(inputs, params))
         try:
-            for raw in gen:
-                response = self._build_response(
-                    model, model_version, request, raw)
-                if t_first is None:
-                    t_first = time.perf_counter_ns()
-                n_responses += 1
+            while True:
+                # the timeline is current while the model's generator runs
+                # and not while this one is suspended at its yield
+                try:
+                    with tl:
+                        raw = next(gen)
+                except StopIteration:
+                    break
+                with span(SPAN_BUILD_RESPONSE) as s:
+                    response = self._build_response(
+                        model, model_version, request, raw)
+                if tl.first_response is None:
+                    tl.first_response = s.end_ns
+                tl.responses += 1
                 yield response
         except GeneratorExit:
             # consumer went away mid-stream (client cancel/disconnect):
             # a separate cancel bucket — counting it as success made
             # abandonment indistinguishable from completed generations
-            self._stats[model_name].record_cancel(
-                time.perf_counter_ns() - t0)
+            stats.record_cancel(time.perf_counter_ns() - tl.recv)
             raise
         except InferError:
-            record(False, 0)
+            stats.record_fail(time.perf_counter_ns() - tl.recv)
             raise
         except Exception as e:
-            record(False, 0)
+            stats.record_fail(time.perf_counter_ns() - tl.recv)
             raise InferError(f"inference failed: {e}", 400)
-        infer_ns = time.perf_counter_ns() - t_infer
-        record(True, infer_ns)
-        self._trace_request(model_name, request, t0, t_infer, infer_ns)
-        self._observe_access(request, model_name, t0, t_infer, infer_ns,
-                             responses=n_responses,
-                             first_response_ns=t_first)
-
-    def _trace_request(self, model_name: str, request: Dict[str, Any],
-                       t0: int, t_infer: int, infer_ns: int) -> None:
-        """Shared per-request trace capture (sync infer + decoupled stream)."""
-        if not self._trace_enabled():
-            return
-        self._record_trace(
-            model_name,
-            request.get("id", ""),
-            {
-                "request_start_ns": t0,
-                "compute_start_ns": t_infer,
-                "compute_end_ns": t_infer + infer_ns,
-                "request_end_ns": time.perf_counter_ns(),
-            },
-        )
+        tl.model_exit = time.perf_counter_ns()
+        # inference_count counts the REQUEST once, regardless of how many
+        # responses streamed (reference decoupled semantics: response count
+        # != request count)
+        self._record(model_name, request, tl)
 
     # -- dynamic batching ---------------------------------------------------
     def _batchable(self, model: Model, params: Dict[str, Any]) -> bool:

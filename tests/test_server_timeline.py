@@ -1,0 +1,378 @@
+"""The request timeline inside the serving path (``server/timeline.py``): the
+marks of a request through the core, the sequence batcher and the stream
+path, the statistics verb filled from them, the one end-of-request recorder,
+the host spans of a profiler session and the named scopes of the steps.
+
+Counts and orderings only: no wall-clock threshold anywhere.
+"""
+
+import glob
+import re
+import threading
+
+import numpy as np
+import pytest
+
+from client_tpu.models.batched import BatchedMatMulModel
+from client_tpu.models.decoder_batched import BatchedDecoderModel
+from client_tpu.models.generate import TinyGenerateModel
+from client_tpu.models.simple import AddSubModel
+from client_tpu.server import ServerCore, timeline
+
+PARTS = ("compute_input", "queue", "compute_infer", "compute_output")
+TRACEPARENT = "00-0af7651916cd43dd8448eb211c80319c-b7ad6b7169203331-01"
+
+
+def _tokens(tokens, request_id="", **parameters):
+    return {"id": request_id, "parameters": parameters, "inputs": [{
+        "name": "TOKENS", "datatype": "INT32", "shape": [1, len(tokens)],
+        "array": np.array([tokens], np.int32)}]}
+
+
+def _session(core, seq, prompt, outputs):
+    """One user of the sequence API: the prompt, then a request a token."""
+    first = core.infer("decoder_lm_batched", "", _tokens(
+        prompt, f"s{seq}-prompt", sequence_id=seq, sequence_start=True))
+    token = int(first[0]["outputs"][1]["array"][0, 0])
+    for i in range(outputs):
+        reply = core.infer("decoder_lm_batched", "", _tokens(
+            [token], f"s{seq}-{i}", sequence_id=seq,
+            sequence_end=i == outputs - 1))
+        token = int(reply[0]["outputs"][1]["array"][0, 0])
+
+
+def _generate(core, prompt, max_tokens, **request):
+    return list(core.infer_stream("tiny_lm_generate", "", dict(request, inputs=[
+        {"name": "TOKENS", "datatype": "INT32", "shape": [1, len(prompt)],
+         "array": np.array([prompt], np.int32)},
+        {"name": "MAX_TOKENS", "datatype": "INT32", "shape": [1],
+         "array": np.array([max_tokens], np.int32)}])))
+
+
+def _add_sub(core):
+    a = np.arange(16, dtype=np.int32).reshape(1, 16)
+    return core.infer("simple", "", {"id": "plain", "inputs": [
+        {"name": name, "datatype": "INT32", "shape": [1, 16], "array": a}
+        for name in ("INPUT0", "INPUT1")]})
+
+
+def _matmul(core, model):
+    x = np.ones((1, model.IN_DIM), np.float32)
+    return core.infer(model.name, "", {"inputs": [
+        {"name": "X", "datatype": "FP32", "shape": [1, model.IN_DIM],
+         "array": x}]})
+
+
+def _concurrently(*calls):
+    errors = []
+
+    def run(call):
+        try:
+            call()
+        except Exception as e:  # shown below
+            errors.append(e)
+
+    threads = [threading.Thread(target=run, args=(call,)) for call in calls]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+    assert not errors, errors
+    assert not any(t.is_alive() for t in threads)
+
+
+@pytest.fixture
+def traced_core():
+    """Both language-model paths, a plain model and a dynamically batched
+    one behind one core that records every request."""
+    batched = BatchedDecoderModel(seed=0, slots=4)
+    matmul = BatchedMatMulModel()
+    core = ServerCore([batched, TinyGenerateModel(seed=0), AddSubModel(), matmul])
+    core.trace_settings.update(trace_level=["TIMESTAMPS"], trace_rate="1")
+    yield core, batched, matmul
+    batched.unload()
+
+
+def _drive_all(core, matmul):
+    _concurrently(*[
+        (lambda seq=seq: _session(core, seq, list(range(1, 2 + 3 * seq)), 4))
+        for seq in (1, 2, 3)])
+    _generate(core, [1, 2, 3], 5, id="stream")
+    _add_sub(core)
+    _concurrently(*[lambda: _matmul(core, matmul)] * 3)
+
+
+def test_four_parts_add_up_to_success_for_every_model(traced_core):
+    core, _, matmul = traced_core
+    _drive_all(core, matmul)
+    rows = core.statistics()["model_stats"]
+    assert {row["name"] for row in rows} == {
+        "decoder_lm_batched", "tiny_lm_generate", "simple", matmul.name}
+    for row in rows:
+        stats = row["inference_stats"]
+        assert stats["success"]["count"] > 0 and stats["fail"]["count"] == 0
+        assert sum(stats[part]["ns"] for part in PARTS) == stats["success"]["ns"]
+        assert all(stats[part]["count"] == stats["success"]["count"]
+                   for part in PARTS)
+        assert all(stats[part]["ns"] >= 0 for part in PARTS)
+
+
+def test_the_parts_say_what_each_path_has(traced_core):
+    core, _, matmul = traced_core
+    _drive_all(core, matmul)
+    stats = {row["name"]: row["inference_stats"]
+             for row in core.statistics()["model_stats"]}
+    # the slot batcher: a real queue, over a drained run one count a request
+    batched = stats["decoder_lm_batched"]
+    assert batched["queue"]["ns"] > 0
+    assert batched["queue"]["count"] == batched["success"]["count"] == 15
+    # a stream has no queue and says so; the generator's time is less than
+    # the stream's, the rest being the cache, the responses and their writes
+    stream = stats["tiny_lm_generate"]
+    assert stream["queue"] == {"count": 1, "ns": 0}
+    assert 0 < stream["compute_infer"]["ns"] < stream["success"]["ns"]
+    assert stream["compute_input"]["ns"] > 0 and stream["compute_output"]["ns"] > 0
+    # a model that marks nothing: no queue, and execute is its compute_infer
+    assert stats["simple"]["queue"] == {"count": 1, "ns": 0}
+    assert stats["simple"]["compute_infer"]["ns"] > 0
+    # the dynamic batcher marks its requests as the sequence batcher does
+    assert stats[matmul.name]["queue"]["ns"] > 0
+
+
+def test_marks_of_every_request_are_monotone(traced_core):
+    core, _, matmul = traced_core
+    _drive_all(core, matmul)
+    orders = {
+        "decoder_lm_batched": (
+            "recv", "inputs_resolved", "model_enter", "enqueued", "collected",
+            "first_dispatch", "last_dispatch", "resolved", "on_host",
+            "model_exit", "done"),
+        "tiny_lm_generate": (
+            "recv", "inputs_resolved", "model_enter", "cache_ready",
+            "prefill_done", "first_response", "model_exit", "done"),
+        "simple": ("recv", "inputs_resolved", "model_enter", "model_exit", "done"),
+        matmul.name: ("recv", "inputs_resolved", "model_enter", "enqueued",
+                      "first_dispatch", "last_dispatch", "model_exit", "done"),
+    }
+    records = core.recent_traces(1000)
+    assert {r["model_name"] for r in records} == set(orders)
+    for record in records:
+        stamps = record["timestamps"]
+        order = orders[record["model_name"]]
+        # with TIMESTAMPS a record has every mark of its path
+        assert set(order) <= set(stamps), (record["model_name"], sorted(stamps))
+        times = [stamps[name] for name in order]
+        assert times == sorted(times), (record["model_name"], stamps)
+        # Triton's own four stay, as aliases of the marks
+        assert stamps["request_start_ns"] == stamps["recv"]
+        assert stamps["request_end_ns"] == stamps["done"]
+        anchor = record["clock_anchor"]
+        assert anchor["wall_ns"] > 0 and anchor["perf_ns"] <= stamps["recv"]
+
+
+def test_a_record_carries_the_identifiers_its_spans_share(traced_core):
+    core, _, _ = traced_core
+    core.infer("decoder_lm_batched", "", dict(
+        _tokens([5, 6], "with-parent", sequence_id=77, sequence_start=True,
+                sequence_end=True), traceparent=TRACEPARENT))
+    record = core.recent_traces()[-1]
+    assert record["request_id"] == "with-parent" and record["sequence_id"] == 77
+    assert record["trace_id"] == TRACEPARENT.split("-")[1]
+    assert record["client_span_id"] == TRACEPARENT.split("-")[2]
+    counts = record["counts"]
+    assert record["first_round_id"] == counts["first_round_id"] == 0
+    assert counts["rounds_own"] == counts["rounds_window"] == 2
+    assert counts["round_widths"] == [1, 1] and counts["responses"] == 1
+    # the access record's queue is the timeline's queue, not recv -> execute
+    access = core.access_records()[-1]
+    stamps = record["timestamps"]
+    assert access["queue_ns"] == stamps["first_dispatch"] - stamps["enqueued"]
+    assert access["compute_ns"] == stamps["last_dispatch"] - stamps["first_dispatch"]
+    assert access["total_ns"] == stamps["done"] - stamps["recv"]
+
+
+def test_a_window_mate_of_a_prompt_is_held_for_its_rounds():
+    """A single-token request that shares a window with a longer prompt is
+    resolved after the prompt's last round, not after its own."""
+    # a gather long enough that both requests land in one window, which
+    # closes as soon as both slots are taken
+    model = BatchedDecoderModel(seed=0, slots=2, max_delay_s=30.0)
+    core = ServerCore([model])
+    core.trace_settings.update(trace_level=["TIMESTAMPS"], trace_rate="1")
+    try:
+        _concurrently(
+            lambda: core.infer("decoder_lm_batched", "", _tokens(
+                [7], "single", sequence_id=1, sequence_start=True)),
+            lambda: core.infer("decoder_lm_batched", "", _tokens(
+                [1, 2, 3, 4, 5], "prompt", sequence_id=2, sequence_start=True)))
+    finally:
+        model.unload()
+    records = {r["request_id"]: r for r in core.recent_traces()}
+    single, prompt = records["single"], records["prompt"]
+    assert single["counts"]["rounds_own"] == 1
+    assert single["counts"]["rounds_window"] == 5 > single["counts"]["rounds_own"]
+    assert single["counts"]["round_widths"] == [2]
+    assert single["timestamps"]["resolved"] > single["timestamps"]["last_dispatch"]
+    assert prompt["counts"]["rounds_own"] == prompt["counts"]["rounds_window"] == 5
+    assert prompt["counts"]["round_widths"] == [2, 1, 1, 1, 1]
+    assert single["first_round_id"] == prompt["first_round_id"] == 0
+    # the single request's last dispatch returned before the prompt's did
+    assert (single["timestamps"]["last_dispatch"]
+            < prompt["timestamps"]["last_dispatch"]
+            <= single["timestamps"]["resolved"])
+
+
+def test_batch_stats_rounds_equal_the_batch_histogram(traced_core):
+    core, batched, matmul = traced_core
+    _drive_all(core, matmul)
+    row = core.statistics("decoder_lm_batched")["model_stats"][0]
+    rounds = {r["batch_size"]: r["compute_infer"]["count"]
+              for r in row["batch_stats"]}
+    assert rounds == batched.batch_histogram and sum(rounds.values()) > 0
+    assert all(r["compute_infer"]["ns"] > 0 for r in row["batch_stats"])
+    # an execution is a round, a request an inference
+    assert row["execution_count"] == sum(rounds.values())
+    assert row["inference_count"] == 15
+
+
+@pytest.mark.parametrize("prompt, max_tokens, chunk, want", [
+    ([1, 2, 3], 6, 1, {"dispatch": 5, "readback": 6, "yielded": 6}),
+    ([4], 1, 1, {"dispatch": 0, "readback": 1, "yielded": 1}),
+    ([1, 2], 7, 3, {"dispatch": 2, "readback": 3, "yielded": 7}),
+], ids=["a-token-a-dispatch", "one-token", "chunked"])
+def test_a_streams_interval_counts_follow_its_tokens(
+        traced_core, prompt, max_tokens, chunk, want):
+    core, _, _ = traced_core
+    responses = _generate(core, prompt, max_tokens, parameters={"chunk": chunk})
+    assert len(responses) == max_tokens
+    counts = core.recent_traces()[-1]["counts"]
+    assert counts["responses"] == max_tokens
+    for name, count in want.items():
+        interval = counts[name]
+        assert interval["count"] == count, name
+        if count:
+            assert 0 <= interval["longest_at"] < max_tokens
+            assert 0 < interval["longest_ns"] <= interval["ns"]
+
+
+def test_with_trace_level_off_no_record_is_built():
+    model = BatchedDecoderModel(seed=0, slots=2)
+    core = ServerCore([model, TinyGenerateModel(seed=0)])
+    assert core.trace_settings["trace_level"] == ["OFF"]
+    try:
+        _session(core, 1, [1, 2], 2)
+        _generate(core, [1, 2, 3], 3, traceparent=TRACEPARENT)
+    finally:
+        model.unload()
+    assert core.recent_traces() == []
+    # statistics and the traceparent join do not wait for a trace setting
+    assert core.statistics("decoder_lm_batched")["model_stats"][0][
+        "inference_stats"]["success"]["count"] == 3
+    access = core.access_records()
+    assert len(access) == 1 and access[0]["responses"] == 3
+    assert access[0]["queue_ns"] == 0 < access[0]["first_response_ns"]
+
+
+def test_a_model_finds_no_timeline_outside_a_request():
+    """The timeline is current only while the core runs the model's code."""
+    assert timeline.current() is None
+    model = BatchedDecoderModel(seed=0, slots=2)
+    try:
+        out = model.execute({"TOKENS": np.array([[3, 4]], np.int32)}, {
+            "sequence_id": 9, "sequence_start": True, "sequence_end": True})
+    finally:
+        model.unload()
+    assert out["NEXT_TOKEN"].shape == (1, 1)
+    core = ServerCore([TinyGenerateModel(seed=0)])
+    stream = core.infer_stream("tiny_lm_generate", "", {"inputs": [
+        {"name": "TOKENS", "datatype": "INT32", "shape": [1, 2],
+         "array": np.array([[1, 2]], np.int32)}]})
+    next(stream)
+    assert timeline.current() is None  # not while the stream is suspended
+    stream.close()
+    cancelled = core.statistics()["model_stats"][0]["inference_stats"]["cancel"]
+    assert cancelled["count"] == 1
+
+
+def test_a_profiler_session_holds_every_span_name(tmp_path, traced_core):
+    import jax
+    from jax.profiler import ProfileData
+
+    core, _, matmul = traced_core
+    _session(core, 50, [1, 2], 1)  # built and compiled before the session
+    _generate(core, [1], 1)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _drive_all(core, matmul)
+    finally:
+        jax.profiler.stop_trace()
+    found = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    assert len(found) == 1
+    host = {event.name
+            for plane in ProfileData.from_file(found[0]).planes
+            if plane.name == "/host:CPU"
+            for line in plane.lines for event in line.events}
+    assert set(timeline.SPAN_NAMES) <= host, set(timeline.SPAN_NAMES) - host
+
+
+def test_compiles_are_counted_by_the_program():
+    import jax
+    import jax.numpy as jnp
+
+    core = ServerCore([AddSubModel()])
+    x = jnp.arange(7)  # made, and its program compiled, before the count
+    before = (timeline.COMPILES.count, timeline.COMPILES.ns)
+    jax.jit(lambda x: x * 3 + before[0])(x).block_until_ready()
+    assert timeline.COMPILES.count == before[0] + 1
+    assert timeline.COMPILES.ns > before[1]
+    text = core.metrics_registry().prometheus_text()
+    assert f"client_tpu_server_compile_count {timeline.COMPILES.count}" in text
+    assert "client_tpu_server_compile_seconds" in text
+    # one listener a process, however many cores
+    ServerCore([])
+    jax.jit(lambda x: x * 5 - before[0])(x).block_until_ready()
+    assert timeline.COMPILES.count == before[0] + 2
+
+
+def test_a_request_that_compiled_says_so():
+    core = ServerCore([TinyGenerateModel(seed=3)])
+    core.trace_settings.update(trace_level=["TIMESTAMPS"], trace_rate="1")
+    _generate(core, [1, 2], 2)  # builds the decoder and compiles its step
+    _generate(core, [1, 2], 2)
+    first, second = core.recent_traces()
+    assert first["counts"]["compiled_ns"] > 0
+    assert second["counts"]["compiled_ns"] == 0
+
+
+STEP_SCOPES = ("embed", "attn_qkv", "cache_update", "attention", "attn_proj",
+               "mlp", "unembed")
+
+
+def test_the_steps_keep_their_jit_names_and_carry_their_scopes():
+    """``step_device_ms`` finds the programs as ``jit_step`` and
+    ``jit_batched_step``; device time reads by the scopes."""
+    import jax.numpy as jnp
+
+    model = BatchedDecoderModel(seed=0, slots=2)
+    generate = TinyGenerateModel(decoder=model._decoder)
+    try:
+        model._ensure_built()
+        decoder = model._decoder
+        step = decoder._step_fn.lower(
+            decoder._params, decoder._fresh_cache(), 0, 0)
+        row = lambda dtype: jnp.zeros((2,), dtype)
+        batched = model._batched_step.lower(
+            decoder._params, model._caches, row(jnp.int32), row(jnp.int32),
+            row(jnp.bool_))
+        chunk = generate._chunk_fn(2).lower(
+            decoder._params, decoder._fresh_cache(), 0, 0)
+    finally:
+        model.unload()
+    for lowered, name, scopes in (
+            (step, "jit_step", STEP_SCOPES),
+            (batched, "jit_batched_step", STEP_SCOPES + ("slot_select",)),
+            (chunk, "jit_decode_k", STEP_SCOPES + ("greedy_argmax",))):
+        text = lowered.as_text(debug_info=True)
+        assert f"module @{name} " in text
+        for scope in scopes:  # at the head of an operation's name, or inside it
+            assert re.search(rf'["/]{scope}["/]', text), (name, scope)
