@@ -112,8 +112,54 @@ class TinyDecoderModel(Model):
             var = jnp.var(x32, axis=-1, keepdims=True)
             return ((x32 - mu) * lax.rsqrt(var + 1e-5)).astype(x.dtype)
 
-        def step(params, caches, token, pos):
+        def write_rows(caches, rows, pos):
+            """``caches`` (k, v), each [H, M, Dh], with ``rows``, each
+            [H, 1, Dh], at ``pos``."""
+            return tuple(lax.dynamic_update_slice(cache, row, (0, pos, 0))
+                         for cache, row in zip(caches, rows))
+
+        @jax.custom_batching.custom_vmap
+        def write_slot_rows(caches, rows, pos, active):
+            """The same for one slot of the batcher: where ``active`` is
+            false the slot's caches stay as they are."""
+            return lax.cond(active, lambda: write_rows(caches, rows, pos),
+                            lambda: caches)
+
+        @write_slot_rows.def_vmap
+        def write_active_rows(slots, batched, caches, rows, pos, active):
+            """The slot batcher's ``vmap`` of the above, over stacked caches
+            [slots, H, M, Dh]: one turn an active slot, each writing that
+            slot's two rows into the donated buffers where they lie, so the
+            work follows the round's width and an inactive slot (a full one,
+            a freed one) is not touched at all. ``vmap``'s own rule makes a
+            scatter of the per-slot position (and a select of the ``cond``),
+            for which the chip's compiler lays every stacked cache out anew
+            and back again each round."""
+            if not all(jax.tree_util.tree_leaves(batched)):
+                raise NotImplementedError("every operand is a slot's own")
+            active_first = jnp.argsort(~active, stable=True)
+
+            def write(turn, caches):
+                slot = active_first[turn]
+                return tuple(
+                    lax.dynamic_update_slice(
+                        cache,
+                        lax.dynamic_index_in_dim(slot_rows, slot, keepdims=True),
+                        (slot, 0, pos[slot], 0))
+                    for cache, slot_rows in zip(caches, rows))
+
+            width = jnp.sum(active, dtype=jnp.int32)
+            return lax.fori_loop(0, width, write, caches), (True, True)
+
+        def step(params, caches, token, pos, active=None):
             """One decode step. caches: [L] dicts of k/v [H, M, Dh].
+
+            The step owns ``caches``: the jitted programs donate them, so
+            the returned caches are the same buffers with row ``pos``
+            written and the caller's handle on the old ones is dead.
+            ``active`` is the slot batcher's alone (a slot that rides along
+            with ``active`` false writes no row); the single-sequence
+            programs never pass it.
 
             The named scopes are compile-time metadata: the device
             operations of a trace carry them, so that device time reads by
@@ -130,10 +176,9 @@ class TinyDecoderModel(Model):
                     k_new = k_new.reshape(H, 1, Dh)
                     v_new = v_new.reshape(H, 1, Dh)
                 with jax.named_scope("cache_update"):
-                    k = lax.dynamic_update_slice(
-                        cache["k"], k_new, (0, pos, 0))
-                    v = lax.dynamic_update_slice(
-                        cache["v"], v_new, (0, pos, 0))
+                    held, rows = (cache["k"], cache["v"]), (k_new, v_new)
+                    k, v = (write_rows(held, rows, pos) if active is None
+                            else write_slot_rows(held, rows, pos, active))
                 new_caches.append({"k": k, "v": v})
                 if self._attention_impl == "pallas":
                     from ..ops.decode_attention import decode_attention
@@ -168,7 +213,7 @@ class TinyDecoderModel(Model):
             return logits, new_caches
 
         self._params = params
-        self._step_fn = jax.jit(step)
+        self._step_fn = jax.jit(step, donate_argnums=1)
 
     def _ensure_built(self):
         with self._lock:
@@ -228,10 +273,18 @@ class TinyDecoderModel(Model):
             # for prefill and decode (static shapes; cache carries history)
             caches, pos = state["caches"], state["pos"]
             logits = None
-            for t in tokens:
-                logits, caches = self._step_fn(
-                    self._params, caches, int(t), pos)
-                pos += 1
+            try:
+                for t in tokens:
+                    logits, caches = self._step_fn(
+                        self._params, caches, int(t), pos)
+                    pos += 1
+            except Exception:
+                # the step owned the caches it was given: after a failure
+                # the sequence has no state, and says so to its next request
+                with self._lock:
+                    self._sequences.pop(seq_id, None)
+                    self._seq_locks.pop(seq_id, None)
+                raise
 
             with self._lock:
                 if end:

@@ -14,10 +14,13 @@ stacked on device ([slots, heads, max_len, head_dim] per layer), a
 coalescer thread gathers whatever sequence requests are in flight inside a
 ~2 ms window, and ONE jitted batched step (``jax.vmap`` of the decoder's
 single-sequence step — the identical math, so tokens are bit-comparable)
-advances them all. Slots whose sequence has no pending request this round
-ride along masked: their cache/pos updates are discarded by a
-``jnp.where`` select, which keeps the executable static-shape — the same
-compile-once property the single-sequence decoder has. Prompts longer than
+advances them all. The step owns the stacked caches (they are donated to
+it) and writes one [heads, 1, head_dim] row a layer for every active slot,
+in place. Slots whose sequence has no pending request this round ride along
+masked: the step writes no row of theirs (``active``, decoder.py's
+``write_active_rows``) and their logits are dropped, which keeps the
+executable static-shape — the same compile-once property the
+single-sequence decoder has. Prompts longer than
 one token naturally lockstep: each coalescer round consumes the next token
 of every gathered request, so two sequences prefilling together share
 every dispatch.
@@ -135,37 +138,18 @@ class BatchedDecoderModel(Model):
                 return
             self._decoder._ensure_built()
             import jax
-            import jax.numpy as jnp
 
             dec = self._decoder
             S = self.slots
-            Dh = dec.D_MODEL // dec.HEADS
-            step1 = dec._step_fn  # (params, caches, token, pos) per sequence
-            vstep = jax.vmap(step1, in_axes=(None, 0, 0, 0))
+            # (params, caches, token, pos, active) per sequence; a slot
+            # that is not ``active`` writes no cache row, inside the step
+            vstep = jax.vmap(dec._step_fn, in_axes=(None, 0, 0, 0, 0))
 
             def batched_step(params, caches, tokens, pos, active):
-                logits, new_caches = vstep(params, caches, tokens, pos)
+                return vstep(params, caches, tokens, pos, active)
 
-                def sel(new, old):
-                    mask = active.reshape((-1,) + (1,) * (new.ndim - 1))
-                    return jnp.where(mask, new, old)
-
-                # a named scope is compile-time metadata: the device
-                # operations of the select carry it in a trace
-                with jax.named_scope("slot_select"):
-                    caches = jax.tree_util.tree_map(sel, new_caches, caches)
-                return logits, caches
-
-            self._batched_step = jax.jit(batched_step)
-            self._caches = [
-                {
-                    "k": jnp.zeros((S, dec.HEADS, dec.MAX_LEN, Dh),
-                                   jnp.bfloat16),
-                    "v": jnp.zeros((S, dec.HEADS, dec.MAX_LEN, Dh),
-                                   jnp.bfloat16),
-                }
-                for _ in range(dec.LAYERS)
-            ]
+            self._batched_step = jax.jit(batched_step, donate_argnums=1)
+            self._caches = self._fresh_caches()
             # positions live HOST-side (0 on start, +1 per active token —
             # fully derivable without a device readback) and ship to the
             # device each round alongside the token vector; carrying them
@@ -179,6 +163,17 @@ class BatchedDecoderModel(Model):
                 target=self._run, name="sequence-batcher", daemon=True)
             self._worker.start()
             self._built = True
+
+    def _fresh_caches(self):
+        """Every slot's cache, stacked: [slots, heads, max_len, head_dim] a
+        layer, zeros."""
+        import jax.numpy as jnp
+
+        dec = self._decoder
+        shape = (self.slots, dec.HEADS, dec.MAX_LEN, dec.D_MODEL // dec.HEADS)
+        return [{"k": jnp.zeros(shape, jnp.bfloat16),
+                 "v": jnp.zeros(shape, jnp.bfloat16)}
+                for _ in range(dec.LAYERS)]
 
     # -- serving (caller side) ----------------------------------------------
     def execute(self, inputs: Dict[str, np.ndarray],
@@ -363,6 +358,7 @@ class BatchedDecoderModel(Model):
                     req.fail(e)
 
     def _run_window(self, window: List[_SeqRequest]) -> None:
+        import jax
         import jax.numpy as jnp
 
         with span(SPAN_ADMIT):
@@ -408,12 +404,20 @@ class BatchedDecoderModel(Model):
         except Exception as e:  # a failed dispatch must not strand callers
             for req, _ in active_reqs:
                 req.fail(e)
+            with self._lock:
                 # a failed step ends the sequence regardless of req.end:
                 # the client has no valid continuation state (the cache may
                 # be partially updated), and keeping the slot would leak
                 # capacity one failed window at a time
-                with self._lock:
-                    self._free_slot(req.seq_id)
+                ended = [req.seq_id for req, _ in active_reqs]
+                if any(leaf.is_deleted() for leaf in
+                       jax.tree_util.tree_leaves(self._caches)):
+                    # the step had taken every slot's cache with it: all
+                    # live sequences end, and the next window starts clean
+                    self._caches = self._fresh_caches()
+                    ended = list(self._slot_of)
+                for seq_id in ended:
+                    self._free_slot(seq_id)
             return
 
         for req, slot in active_reqs:

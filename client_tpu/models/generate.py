@@ -124,7 +124,7 @@ class TinyGenerateModel(Model):
                 body, (caches, token, pos), None, length=k)
             return toks, caches
 
-        fn = jax.jit(decode_k)
+        fn = jax.jit(decode_k, donate_argnums=1)
         with self._lock:
             self._chunk_fns.setdefault(k, fn)
         return self._chunk_fns[k]
@@ -166,8 +166,9 @@ class TinyGenerateModel(Model):
 
         # the stream's marks (server/timeline.py), on the request's timeline
         # where the core opened one. The dispatch intervals are host times:
-        # a step call returns when the step is enqueued (or when the
-        # allocator has found room for its output cache), not when it has run
+        # a step call returns when the step is enqueued, not when it has run
+        # (the step writes the cache it is given in place, so the call waits
+        # for no room)
         marks = StreamMarks()
         timeline = current()
         if timeline is not None:
@@ -183,6 +184,11 @@ class TinyGenerateModel(Model):
             for t in tokens:
                 logits, caches = dec._step_fn(dec._params, caches, int(t), pos)
                 pos += 1
+                # one step of a stream in the device's queue at a time, in
+                # prefill as in decode: no step call waits for room any
+                # more, and a prompt enqueued whole holds every other
+                # stream's next token behind it
+                logits.block_until_ready()
         marks.prefill_done = s.end_ns
 
         def response(token_id: int, index: int):

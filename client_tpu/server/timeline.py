@@ -12,8 +12,9 @@ access record all come from it.
 Marks are ``time.perf_counter_ns()`` of the host. **The dispatch marks are
 host times**: a jitted call returns when the program is enqueued, not when
 the device has run it, so the host's dispatch runs ahead of the device by as
-many steps as the allocator lets it (each undonated step holds its own output
-cache until the one before has run). ``enqueued`` and ``on_host`` are exact:
+many steps as the runtime's queue takes (a step writes its donated cache in
+place and allocates no other, so no call waits for room). ``enqueued`` and
+``on_host`` are exact:
 the first precedes any device work of the request, the second follows the
 copy of its result to the host.
 

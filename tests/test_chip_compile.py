@@ -160,3 +160,55 @@ def test_row_too_wide_raises_typed_error():
     with pytest.raises(ops.KernelTooLargeError, match="16384"):
         jax.eval_shape(lambda x: ops.quantize_int8(x, 0.05),
                        _s((1, 1 << 24), jnp.float32))
+
+
+# -- the cache is written where it lies --------------------------------------
+
+
+def _published_widths_decoder():
+    """The decoder at gpt2-large's head and cache widths (20 heads of 64,
+    1,024 positions), two layers deep and with the fixture's vocabulary: the
+    chip's compiler chooses layouts by these widths."""
+    from client_tpu.models.decoder import TinyDecoderModel
+
+    cls = type("WideDecoder", (TinyDecoderModel,), {
+        "D_MODEL": 1280, "HEADS": 20, "LAYERS": 2, "MAX_LEN": 1024})
+    decoder = cls(seed=0)
+    decoder._ensure_built()
+    return decoder
+
+
+@pytest.mark.parametrize("program", ["jit_step", "jit_batched_step"])
+def test_the_step_writes_its_donated_caches_in_place_on_the_chip(chip, program):
+    """What the CPU cannot show: compiled for a v5e, the step aliases every
+    cache to an output and moves no whole cache into another layout. (The
+    scatter that ``vmap`` alone makes of the batcher's row writes has each
+    stacked cache copied to a row-major layout and back every round.)"""
+    import re
+
+    from client_tpu.models.decoder_batched import BatchedDecoderModel
+
+    decoder = _published_widths_decoder()
+    scalar = _s((), jnp.int32)
+    if program == "jit_step":
+        fn, caches = decoder._step_fn, decoder._fresh_cache()
+        rest = (scalar, scalar)
+    else:
+        model = BatchedDecoderModel(slots=16)
+        model._decoder = decoder  # composed before the batcher builds
+        model._ensure_built()
+        model.unload()  # the worker thread; the program stays
+        fn, caches = model._batched_step, model._caches
+        rest = (_s((16,), jnp.int32), _s((16,), jnp.int32),
+                _s((16,), jnp.bool_))
+    args = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip),
+        (_shapes_of(decoder._params), _shapes_of(caches)) + rest)
+    text = fn.lower(*args).compile().as_text()
+    assert f"HloModule {program}," in text
+    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
+    assert aliased.count("-alias)") == 2 * decoder.LAYERS
+    shape = ",".join(str(n) for n in caches[0]["k"].shape)
+    entry = text[text.index("\nENTRY "):]
+    relaid = re.findall(rf"= bf16\[{shape}\]\{{[^}}]*\}} copy\(", entry)
+    assert not relaid, f"{len(relaid)} whole caches copied to another layout"

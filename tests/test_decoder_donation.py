@@ -1,0 +1,258 @@
+"""The KV cache is written in place.
+
+``jit_step``, ``jit_batched_step`` and ``jit_decode_k`` own the caches they
+are given: every cache leaf is donated and aliased to an output, the batched
+step writes one row a layer of each *active* slot and touches no other, and a
+step that fails after it took its caches leaves the models serving (the
+sequence is gone, and says so; no client sees a deleted array).
+
+Counts and equalities only: the CPU deletes a donated input as the chip does.
+"""
+
+import re
+
+import numpy as np
+import pytest
+
+from client_tpu.models.decoder import TinyDecoderModel
+from client_tpu.models.decoder_batched import BatchedDecoderModel
+from client_tpu.models.generate import TinyGenerateModel
+
+SLOTS = 4
+MAX_LEN = TinyDecoderModel.MAX_LEN
+PROGRAMS = ("jit_step", "jit_batched_step", "jit_decode_k")
+
+
+@pytest.fixture(scope="module")
+def built():
+    model = BatchedDecoderModel(seed=0, slots=SLOTS)
+    model._ensure_built()
+    yield model, model._decoder, TinyGenerateModel(decoder=model._decoder)
+    model.unload()
+
+
+def _leaves(caches):
+    import jax
+
+    return jax.tree_util.tree_leaves(caches)
+
+
+def _program(built, name):
+    """The jitted program of that name and arguments for one call of it."""
+    import jax.numpy as jnp
+
+    model, decoder, generate = built
+    if name == "jit_batched_step":
+        row = lambda dtype: jnp.zeros((SLOTS,), dtype)
+        return model._batched_step, (
+            decoder._params, model._fresh_caches(), row(jnp.int32),
+            row(jnp.int32), jnp.ones((SLOTS,), bool))
+    fn = decoder._step_fn if name == "jit_step" else generate._chunk_fn(2)
+    return fn, (decoder._params, decoder._fresh_cache(), 3, 0)
+
+
+@pytest.mark.parametrize("name", PROGRAMS)
+def test_every_cache_leaf_is_aliased_to_an_output(built, name):
+    fn, args = _program(built, name)
+    text = fn.lower(*args).as_text()
+    assert f"module @{name} " in text
+    assert len(re.findall(r"tf\.aliasing_output", text)) == 2 * built[1].LAYERS
+    assert "jax.buffer_donor" not in text  # donated and left without an output
+
+
+@pytest.mark.parametrize("name", PROGRAMS)
+def test_the_donated_caches_are_dead_after_the_call(built, name):
+    fn, args = _program(built, name)
+    given = _leaves(args[1])
+    _, returned = fn(*args)
+    assert len(given) == 2 * built[1].LAYERS
+    assert all(leaf.is_deleted() for leaf in given)
+    assert not any(leaf.is_deleted() for leaf in _leaves(returned))
+
+
+def _filled_caches(model, seed):
+    """Stacked caches of noise (so that any write shows), as numpy and on
+    the device."""
+    import jax
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(seed)
+    host = jax.tree_util.tree_map(
+        lambda leaf: np.asarray(jnp.asarray(
+            rng.standard_normal(leaf.shape, dtype=np.float32), leaf.dtype)),
+        model._fresh_caches())
+    return host, jax.tree_util.tree_map(jnp.asarray, host)
+
+
+def _rows_changed(before, after):
+    """Positions at which one slot's [H, M, Dh] cache differs, bit for bit."""
+    differs = before.view(np.uint16) != after.view(np.uint16)
+    return np.flatnonzero(differs.any(axis=(0, 2))).tolist()
+
+
+@pytest.mark.parametrize("pos, active", [
+    # a mixed round
+    ([5, 9, 0, 17], [True, False, True, False]),
+    # a full slot, whose position clamps onto its last live row, and a freed
+    # one (whatever position it was left at) ride beside one active slot
+    ([MAX_LEN, 3, MAX_LEN - 1, 0], [False, True, False, False]),
+    # every slot, and none
+    ([1, 2, 3, 4], [True, True, True, True]),
+    ([1, MAX_LEN, 3, 4], [False, False, False, False]),
+], ids=["mixed", "full_and_freed", "all", "none"])
+def test_a_round_writes_row_pos_of_each_active_slot_and_nothing_else(
+        built, pos, active):
+    import jax.numpy as jnp
+
+    model, decoder, _ = built
+    before, caches = _filled_caches(model, seed=5)
+    _, after = model._batched_step(
+        decoder._params, caches, jnp.arange(SLOTS, dtype=jnp.int32) + 11,
+        jnp.asarray(pos, jnp.int32), jnp.asarray(active))
+    for layer_before, layer_after in zip(before, after):
+        for half in ("k", "v"):
+            got = np.asarray(layer_after[half])
+            for slot in range(SLOTS):
+                changed = _rows_changed(layer_before[half][slot], got[slot])
+                assert changed == ([pos[slot]] if active[slot] else []), (
+                    half, slot)
+
+
+def test_the_batched_rows_are_the_single_slot_steps_rows(built):
+    """The batcher's rule for the row writes against the one-slot definition
+    it stands for: each slot through ``jit_step`` with its own ``active``."""
+    import jax
+    import jax.numpy as jnp
+
+    model, decoder, _ = built
+    pos, active = [5, 9, 0, 17], [True, False, True, False]
+    tokens = [11, 12, 13, 14]
+    before, caches = _filled_caches(model, seed=6)
+    _, after = model._batched_step(
+        decoder._params, caches, jnp.asarray(tokens, jnp.int32),
+        jnp.asarray(pos, jnp.int32), jnp.asarray(active))
+    for slot in range(SLOTS):
+        one = jax.tree_util.tree_map(lambda a: jnp.asarray(a[slot]), before)
+        _, one = decoder._step_fn(
+            decoder._params, one, tokens[slot], pos[slot], active[slot])
+        for got, want in zip(_leaves(after), _leaves(one)):
+            got = np.asarray(got[slot], np.float32)
+            want = np.asarray(want, np.float32)
+            if active[slot]:
+                np.testing.assert_allclose(got, want, atol=2e-2)
+            else:
+                assert got.tobytes() == want.tobytes()
+
+
+# -- a step that fails after it took its caches ------------------------------
+
+
+def _fails_after(step):
+    """``step``, run for its donation, then an error as a device would give."""
+    def failing(*args):
+        step(*args)
+        raise RuntimeError("the device fell over")
+    return failing
+
+
+def _request(model, seq, tokens, start=False, end=False):
+    return model.execute(
+        {"TOKENS": np.array([tokens], np.int32)},
+        {"sequence_id": seq, "sequence_start": start, "sequence_end": end})
+
+
+def test_decoder_lm_drops_the_sequence_whose_step_failed():
+    model = TinyDecoderModel(seed=0)
+    first = _request(model, 7, [1, 2, 3], start=True)
+    _request(model, 8, [4, 5], start=True)
+    step = model._step_fn
+    model._step_fn = _fails_after(step)
+    with pytest.raises(RuntimeError, match="fell over"):
+        _request(model, 7, [9])
+    model._step_fn = step
+    # typed, and not "Array has been deleted"
+    with pytest.raises(ValueError, match="no live state"):
+        _request(model, 7, [9])
+    assert model.live_sequences() == 1
+    _request(model, 8, [6], end=True)  # the other sequence never noticed
+    again = _request(model, 7, [1, 2, 3], start=True, end=True)
+    np.testing.assert_array_equal(again["LOGITS"], first["LOGITS"])
+    assert model.live_sequences() == 0
+
+
+def test_the_batcher_serves_again_after_a_step_took_the_caches_and_failed():
+    reference = TinyDecoderModel(seed=0)
+    want = _request(reference, 1, [1, 2, 3], start=True, end=True)
+    model = BatchedDecoderModel(seed=0, slots=SLOTS)
+    try:
+        _request(model, 21, [1, 2, 3], start=True)
+        _request(model, 22, [4, 5], start=True)
+        step = model._batched_step
+        model._batched_step = _fails_after(step)
+        with pytest.raises(RuntimeError, match="fell over"):
+            _request(model, 21, [9])
+        model._batched_step = step
+        # every slot's cache went with the step: the bystander ended too
+        assert model.live_sequences() == 0
+        with pytest.raises(ValueError, match="no live state"):
+            _request(model, 22, [6])
+        assert not any(leaf.is_deleted() for leaf in _leaves(model._caches))
+        got = _request(model, 23, [1, 2, 3], start=True, end=True)
+        assert int(got["NEXT_TOKEN"][0, 0]) == int(want["NEXT_TOKEN"][0, 0])
+        np.testing.assert_allclose(got["LOGITS"], want["LOGITS"], atol=1e-2)
+    finally:
+        model.unload()
+
+
+def test_the_batcher_keeps_bystanders_when_the_step_failed_before_it_ran():
+    model = BatchedDecoderModel(seed=0, slots=SLOTS)
+    try:
+        _request(model, 31, [1, 2, 3], start=True)
+        _request(model, 32, [4, 5], start=True)
+        step = model._batched_step
+
+        def refused(*args):
+            raise RuntimeError("refused before dispatch")
+
+        model._batched_step = refused
+        with pytest.raises(RuntimeError, match="refused"):
+            _request(model, 31, [9])
+        model._batched_step = step
+        assert model.live_sequences() == 1  # the window's sequence alone
+        _request(model, 32, [6], end=True)
+    finally:
+        model.unload()
+
+
+# -- what paced the streams before, the allocator's wait, is gone -------------
+
+
+def test_a_streams_prefill_waits_for_each_of_its_steps():
+    """No step call waits for room any more, so the prefill waits itself: a
+    prompt enqueued whole would hold every other stream's next token."""
+    model = TinyGenerateModel(seed=0)
+    model._ensure_built()
+    decoder = model._decoder
+    step, waits = decoder._step_fn, []
+
+    class Watched:
+        def __init__(self, logits):
+            self.logits = logits
+
+        def block_until_ready(self):
+            waits.append(1)
+            return self.logits.block_until_ready()
+
+        def __array__(self, *args, **kwargs):
+            return np.asarray(self.logits)
+
+    def watched_step(*args):
+        logits, caches = step(*args)
+        return Watched(logits), caches
+
+    decoder._step_fn = watched_step
+    out = list(model.execute_decoupled(
+        {"TOKENS": np.array([[1, 2, 3, 4]], np.int32),
+         "MAX_TOKENS": np.array([3], np.int32)}, {}))
+    assert len(out) == 3
+    assert len(waits) == 4  # the prompt's; a decode step's read-back waits

@@ -218,8 +218,12 @@ def bench_generate(jax, jnp, np, prompt=32, k=64):
     k = min(k, dec.MAX_LEN - pos - 1)
     chunk_fn = model._chunk_fn(k)
 
+    # both programs own the cache they are given (donated), so each call
+    # hands the returned one to the next; the rows from ``pos`` on are
+    # rewritten every time, which changes no timing
     def chunked(token, p):
-        out, _ = chunk_fn(dec._params, caches, token, p)
+        nonlocal caches
+        out, caches = chunk_fn(dec._params, caches, token, p)
         return out
 
     dt_chunked = _timed_single_dispatch(chunked, first, pos, iters_inside=k)
@@ -227,7 +231,9 @@ def bench_generate(jax, jnp, np, prompt=32, k=64):
     # per-token: block every step — the feed-back loop round-trips the
     # host for the argmax, so serving really does pay this per token
     def one_step(token, p):
-        return dec._step_fn(dec._params, caches, token, p)[0]
+        nonlocal caches
+        logits, caches = dec._step_fn(dec._params, caches, token, p)
+        return logits
 
     dt_token = _timed_single_dispatch(
         one_step, first, pos, iters_inside=1, repeats=7)
