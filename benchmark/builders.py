@@ -9,6 +9,10 @@ module (or ``"<module>:<function>"`` of a later one) and its arguments.
 Every builder returns ``(model, decoder)``: the model ``ServerCore`` serves
 and the decoder whose ``_params`` the benchmark replaces with weights it made
 on the device from the seed.
+
+The two builders here are the GPT-2 family's: they read the configuration
+through ``shapes.sizes``. A model of another family brings its builder as a
+new module beside its arithmetic and reference (``benchmark/family.py``).
 """
 
 from __future__ import annotations

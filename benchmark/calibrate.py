@@ -6,12 +6,14 @@
 Not run by the benchmark. One serving process, many seeds: each seed gets new
 weights (``reseed``) and its own traffic at the cell's own load for
 ``--seconds`` seconds, sessions in flight at the close run to their end, and
-the plain reference reads the widest gap over the usual sample: the lower
-reading. On the first ``--control-seeds`` seeds the fp8 pass is run over the
-same prompts and tokens as well; the gap of the token it puts first is the
-upper reading, and it goes through the run's own comparison in the served
-tokens' place, which has to say ``control_correct: false``. One JSON object
-a seed on standard output.
+the configuration's own plain reference (``benchmark/family.py``) reads the
+widest gap over the usual sample: the lower reading. On the first
+``--control-seeds`` seeds that reference's control (for the GPT-2 family the
+fp8 pass) is run over the same prompts and tokens as well; the gap of the
+token it puts first is the upper reading, and it goes through the run's own
+comparison in the served tokens' place, which has to say
+``control_correct: false``. A reference that has no control fails here, by
+its module's name. One JSON object a seed on standard output.
 """
 
 from __future__ import annotations

@@ -1,4 +1,9 @@
-"""The plain reference: a GPT-2-style decoder's full forward pass in float32.
+"""The GPT-2 family's reference: the decoder's full forward pass in float32.
+
+The module a configuration gets where it names no ``"reference"``
+(``benchmark/family.py`` has the contract: ``served_token_gaps(params,
+config, sessions, length, control=False)``); a model of another family brings
+its own as a new file, with its own control behind ``control``.
 
 Straightforward ``jax.numpy``: no cache, no batching tricks, no kernels, and
 nothing imported from the program. The weights come in as data (the
@@ -108,7 +113,7 @@ def _gaps(logits, chosen):
     return jnp.max(logits, axis=-1) - picked
 
 
-def served_token_gaps(params: Dict[str, Any], heads: int,
+def served_token_gaps(params: Dict[str, Any], config: Dict[str, Any],
                       sessions: Sequence[Dict[str, Any]], length: int,
                       control: bool = False, block: int = 4) -> Dict[str, Any]:
     """Teacher-force the reference over each session's prompt and served
@@ -122,7 +127,9 @@ def served_token_gaps(params: Dict[str, Any], heads: int,
 
     ``sessions``: ``{"prompt": [...], "tokens": [...]}``; every row is padded
     to ``length`` positions, so one compiled program serves every sample.
+    Of ``config`` this family's reference reads the number of heads.
     """
+    heads = int(config["n_head"])
     served: List[float] = []
     lowered: List[float] = []
     for at in range(0, len(sessions), block):

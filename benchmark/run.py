@@ -10,10 +10,22 @@ one JSON object as its last line, and stops the child.
 
 Everything that belongs to one cell is found by name from ``BENCHMARK.json``:
 ``configs/<configuration>.json``, ``traffic/<mix>.json`` (which names its
-``lengths/<name>.json``), ``cells/<cell>.json`` and
+``lengths/<name>.json``), ``cells/<cell>.json`` (which names its builder) and
 ``layer_metrics/<metric>.json`` (with a reader module beside it where the
-metric is computed and not simply looked up). A new cell, mix, configuration
-or per-layer metric is new files and new entries, and no edit here.
+metric is computed and not simply looked up). A configuration names its
+family's own arithmetic and reference modules (``benchmark/family.py`` has
+the contract; absent, the GPT-2 family's ``shapes.py`` and ``reference.py``),
+and this file, ``server.py``, ``calibrate.py`` and the readers ask those and
+read no other key of a configuration. A new cell, mix, per-layer metric,
+configuration or family of models is new files and new entries, and no edit
+here.
+
+A traced run hands its readers, in ``facts``: ``trace`` (``trace_reduce``'s
+reduction, with device time by named scope under ``scopes``), ``server`` (the
+statistics verb's seven pairs over the window), ``registry`` (the window's
+difference of every series of the program's metrics registry labelled with
+the served model), ``client``, ``batch_histogram``, ``work``, ``window``,
+``peaks``.
 """
 
 from __future__ import annotations
@@ -36,7 +48,7 @@ if _ROOT not in sys.path:
 
 import numpy as np  # noqa: E402
 
-from benchmark import shapes, stats  # noqa: E402
+from benchmark import family, stats  # noqa: E402
 from benchmark.sessions import SessionEngine, SessionPlan  # noqa: E402
 
 CHECK_SESSIONS = 8  # sessions the reference is run over, the longest among them
@@ -176,7 +188,8 @@ def _model_stats(client, model: str) -> Dict[str, int]:
     reply = client.get_inference_statistics(model)
     row = reply["model_stats"][0]["inference_stats"]
     return {f"{kind}_{field}": int(row.get(kind, {}).get(field, 0))
-            for kind in ("success", "fail", "queue", "compute_infer")
+            for kind in ("success", "fail", "cancel", "queue", "compute_input",
+                         "compute_infer", "compute_output")
             for field in ("count", "ns")}
 
 
@@ -236,7 +249,7 @@ class Serving:
     def __init__(self, cell: Dict[str, Any], seed: int, require_tpu: bool = True,
                  server_command: Optional[List[str]] = None):
         traffic = cell["traffic"]
-        self.vocab = shapes.sizes(cell["config"])["vocab"]
+        self.vocab = family.arithmetic(cell["config"]).vocab(cell["config"])
         work_dir = os.path.join(cell["root"], ".benchmark_run", cell["entry"]["name"])
         os.makedirs(work_dir, exist_ok=True)
         command = (server_command
@@ -400,7 +413,10 @@ def run_cell(root: str, workload: str, seed: int, seconds: float, trace: bool,
             "trace": finished.get("trace"), "client": client, "server": server,
             "batch_histogram": _delta(finished["batch_histogram"],
                                       counters0["batch_histogram"]),
-            "work": shapes.work(config, window_positions(records, t0, seconds)),
+            "registry": _delta(finished.get("registry"),
+                               counters0.get("registry")),
+            "work": family.arithmetic(config).work(
+                config, window_positions(records, t0, seconds)),
         }
         # only a TPU has peaks to be held against; an unknown kind of TPU is
         # an error, not a default
@@ -411,8 +427,12 @@ def run_cell(root: str, workload: str, seed: int, seconds: float, trace: bool,
         if facts["trace"]:
             device_out["busy_s"] = facts["trace"]["busy_s"]
             device_out["window_s"] = facts["trace"]["window_s"]
-            result["breakdown"] = {"device_ops": facts["trace"]["device_ops"],
-                                   "idle_gaps": facts["trace"]["idle_gaps"]}
+            scopes = facts["trace"].get("scopes", [])
+            result["breakdown"] = {
+                "device_ops": facts["trace"]["device_ops"],
+                "idle_gaps": facts["trace"]["idle_gaps"],
+                "device_scopes": [[name, s] for name, s, _, _ in scopes[:10]]}
+            result["scopes"] = scopes  # every row, with counts and mean us
     for m in owed:
         if values.get(m["name"]) is not None:
             result["metrics"][m["name"]] = {"value": values[m["name"]],

@@ -7,13 +7,16 @@ on a loopback port, and then takes commands, one JSON object a line on
 standard input, answering each with one line on standard output:
 
 - ``mark``: the counters as they stand (compiles so far, the batcher's
-  histogram), so that the window's share can be told from the warm-up's;
+  histogram, every series of the program's own metrics registry that is
+  labelled with the served model), so that the window's share can be told
+  from the warm-up's;
 - ``trace_start`` / ``trace_stop``: the profiler, for a few seconds of the
   window of a traced run;
 - ``finish``: close the frontend, read the counters and the chip's peak
   memory, free the model and its caches, reduce the trace;
-- ``check``: run the plain reference over the sample of sessions it is sent
-  (the weights are the benchmark's own arrays, made here from the seed);
+- ``check``: run the configuration's own plain reference
+  (``benchmark/family.py``) over the sample of sessions it is sent (the
+  weights are the benchmark's own arrays, made here from the seed);
 - ``reseed``: new weights from another seed (``calibrate.py`` reads a dozen
   seeds in one process);
 - ``exit``.
@@ -39,6 +42,8 @@ if _REPO not in sys.path:
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 TABLES = ("embed", "pos", "unembed")  # drawn at 0.02; matrices at fan_in ** -0.5
+HLO_TYPES = {"bfloat16": "bf16", "float16": "f16", "float32": "f32",
+             "float64": "f64", "int8": "s8", "int32": "s32", "uint8": "u8"}
 
 
 def emit(obj: Dict[str, Any]) -> None:
@@ -46,27 +51,37 @@ def emit(obj: Dict[str, Any]) -> None:
     sys.stdout.flush()
 
 
-def make_params(template, seed: int):
+def make_params(template, seed: int, init_scale=None):
     """Weights of the template's shapes and types, drawn on the device in one
-    jitted call: normal, scaled as the program scales its own (0.02 for the
-    tables and the output head, fan_in ** -0.5 for the matrices)."""
+    jitted call: normal, at the deviation (or ``(mean, deviation)``) the
+    family's ``init_scale(path, leaf)`` gives a leaf, ``path`` being its keys
+    as strings; where it gives ``None``, or there is none, scaled as the
+    program scales its own (0.02 for the tables and the output head,
+    ``shape[0] ** -0.5`` for the matrices)."""
     import jax
     import jax.numpy as jnp
 
     paths, treedef = jax.tree_util.tree_flatten_with_path(template)
     groups: Dict[Any, List[int]] = {}
     for i, (path, leaf) in enumerate(paths):
-        name = str(getattr(path[0], "key", path[0]))
-        scale = 0.02 if name in TABLES else leaf.shape[0] ** -0.5
-        groups.setdefault((leaf.shape, str(leaf.dtype), scale), []).append(i)
+        keys = tuple(str(getattr(k, "key", getattr(k, "idx", k))) for k in path)
+        drawn = init_scale(keys, leaf) if init_scale is not None else None
+        if drawn is None:
+            drawn = 0.02 if keys[0] in TABLES else leaf.shape[0] ** -0.5
+        mean, scale = drawn if isinstance(drawn, tuple) else (0.0, drawn)
+        groups.setdefault(
+            (leaf.shape, str(leaf.dtype), float(mean), float(scale)), []).append(i)
 
     @jax.jit
     def draw(key):
         out = [None] * len(paths)
-        for g, ((shape, dtype, scale), members) in enumerate(groups.items()):
+        for g, ((shape, dtype, mean, scale), members) in enumerate(groups.items()):
             block = jax.random.normal(jax.random.fold_in(key, g),
                                       (len(members),) + shape, jnp.float32)
-            block = (block * scale).astype(dtype)
+            block = block * scale
+            if mean:
+                block = block + mean
+            block = block.astype(dtype)
             for j, i in enumerate(members):
                 out[i] = block[j]
         return out
@@ -112,9 +127,10 @@ class Served:
 
         from client_tpu.server import GrpcInferenceServer, ServerCore
 
-        from benchmark import builders
+        from benchmark import builders, family
 
         self.config = config
+        self.init_scale = getattr(family.arithmetic(config), "init_scale", None)
         self.compiles = 0
         jax.monitoring.register_event_duration_secs_listener(self._on_event)
         t = time.perf_counter()
@@ -143,13 +159,45 @@ class Served:
             self.compiles += 1
 
     def reseed(self, seed: int) -> None:
-        self.params = make_params(self.decoder._params, seed)
+        self.params = make_params(self.decoder._params, seed, self.init_scale)
         self.decoder._params = self.params
+
+    def registry(self) -> Dict[str, float]:
+        """Every series of the program's own metrics registry that carries
+        the served model's label, as it stands: ``<metric>`` (with its other
+        labels, ``<metric>{k=v}``) -> value; a histogram gives ``_count`` and
+        ``_sum``. What the program counts for a model reaches a reader by
+        adding a series there."""
+        out: Dict[str, float] = {}
+        for name, metric in self.core.metrics_registry().snapshot().items():
+            for series in metric["series"]:
+                labels = dict(series["labels"])
+                if labels.pop("model", None) != self.model.name:
+                    continue
+                key = name + ("{" + ",".join(
+                    f"{k}={v}" for k, v in sorted(labels.items())) + "}"
+                    if labels else "")
+                if "value" in series:
+                    out[key] = series["value"]
+                else:
+                    out[key + "_count"] = series["count"]
+                    out[key + "_sum"] = series["sum"]
+        return out
 
     def counters(self) -> Dict[str, Any]:
         histogram = getattr(self.model, "batch_histogram", None)
         return {"compiles": self.compiles,
-                "batch_histogram": dict(histogram) if histogram is not None else None}
+                "batch_histogram": dict(histogram) if histogram is not None else None,
+                "registry": self.registry()}
+
+    def weight_operands(self) -> Dict[str, str]:
+        """The weights' arrays as the trace's operations name them
+        (``bf16[1280,3840]``), for ``trace_reduce.reduce`` to call by name."""
+        import jax
+
+        return {"{}[{}]".format(HLO_TYPES.get(str(leaf.dtype), str(leaf.dtype)),
+                                ",".join(str(d) for d in leaf.shape)): "weights"
+                for leaf in jax.tree_util.tree_leaves(self.params)}
 
     def trace_start(self) -> None:
         import jax
@@ -184,18 +232,24 @@ class Served:
 
             path = trace_reduce.find_xplane(self.trace_dir)
             out["trace"] = (trace_reduce.reduce(
-                trace_reduce.load_xplane(path), step_program)
+                trace_reduce.load_xplane(path), step_program,
+                self.weight_operands())
                 if path else None)
             shutil.rmtree(self.trace_dir, ignore_errors=True)
         return out
 
     def check(self, sessions, length: int, control: bool) -> Dict[str, Any]:
-        from benchmark import reference
+        from benchmark import family
 
+        reference = family.reference(self.config, control=control)
         t = time.perf_counter()
         out = reference.served_token_gaps(
-            self.params, int(self.config["n_head"]), sessions, length,
-            control=control)
+            self.params, self.config, sessions, length,
+            **({"control": True} if control else {}))
+        if control and "control_gap_max" not in out:
+            raise AttributeError(
+                f"the configuration's reference module {reference.__name__!r} "
+                "gave no control_gap_max")
         out["reference_s"] = time.perf_counter() - t
         return out
 

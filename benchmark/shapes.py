@@ -1,10 +1,16 @@
-"""Sizes, bytes and FLOPs of a decoder configuration, from its shapes alone.
+"""The GPT-2 family's arithmetic: sizes, bytes and FLOPs from shapes alone.
 
 The yardstick's own arithmetic: nothing here reads the program. A
-configuration file carries the published keys of a GPT-2-style
-``config.json`` (``n_layer``, ``n_embd``, ``n_head``, ``n_positions``,
-``vocab_size``, ``n_inner``); weights and cache are bfloat16 (2 bytes) and
-logits float32, as ``dtype`` in the file states.
+configuration file of this family carries the published keys of a
+GPT-2-style ``config.json`` (``n_layer``, ``n_embd``, ``n_head``,
+``n_positions``, ``vocab_size``, ``n_inner``); weights and cache are bfloat16
+(2 bytes) and logits float32, as ``dtype`` in the file states.
+
+It is the module a configuration gets where it names no ``"arithmetic"``
+(``benchmark/family.py`` has the contract: ``vocab``, ``max_len``, ``work``,
+``step_least``, ``total_params``); a model of another family brings its own
+as a new file. The harness reaches this one only through that resolver; the
+GPT-2 builders use ``sizes`` directly.
 """
 
 from __future__ import annotations
@@ -26,6 +32,16 @@ def sizes(config: Dict[str, Any]) -> Dict[str, int]:
         raise ValueError(f"n_embd {d} is not a multiple of n_head {heads}")
     return {"vocab": int(config["vocab_size"]), "d_model": d, "heads": heads,
             "layers": int(config["n_layer"]), "max_len": int(config["n_positions"])}
+
+
+def vocab(config: Dict[str, Any]) -> int:
+    """The ids traffic draws from."""
+    return sizes(config)["vocab"]
+
+
+def max_len(config: Dict[str, Any]) -> int:
+    """The positions a sequence may reach: the cache's length."""
+    return sizes(config)["max_len"]
 
 
 def matmul_params(config: Dict[str, Any]) -> int:
@@ -90,3 +106,16 @@ def work(config: Dict[str, Any], positions: Iterable[int]) -> Dict[str, float]:
         cache += token_cache_bytes(config, p)
     return {"tokens_processed": tokens, "flops": flops, "cache_bytes": cache,
             "row_bytes": tokens * step_row_bytes(config)}
+
+
+def step_least(config: Dict[str, Any], work: Dict[str, float],
+               width: float) -> Dict[str, float]:
+    """Least bytes and FLOPs of a mean step of ``width`` sequences: every
+    matmul weight once, and for each sequence the mean token's cache rows,
+    table rows and logits row (``work`` is this module's, over the window).
+    The same count whatever implements the step."""
+    tokens = work["tokens_processed"]
+    step_bytes = (step_weight_bytes(config)
+                  + width * (work["cache_bytes"] + work["row_bytes"]) / tokens)
+    step_flops = width * work["flops"] / tokens
+    return {"bytes": step_bytes, "flops": step_flops}

@@ -7,7 +7,7 @@ import re
 
 import pytest
 
-from benchmark import run, shapes
+from benchmark import family, run
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -32,13 +32,15 @@ def test_top_level_keys():
 @pytest.mark.parametrize("cell", CELLS)
 def test_cell_resolves_to_files_that_exist(cell):
     resolved = run.resolve_cell(ROOT, cell)
-    assert shapes.sizes(resolved["config"])["layers"] > 0
+    arithmetic = family.arithmetic(resolved["config"])
+    assert arithmetic.total_params(resolved["config"]) > 0
+    assert arithmetic.vocab(resolved["config"]) > 1
     assert resolved["traffic"]["api"] in ("sequence", "stream")
     assert resolved["cell"]["users"] >= 1 and "users" not in resolved["traffic"]
     lengths = resolved["traffic"]["lengths"]
     assert lengths["source"] and lengths["pool"] >= 1
     longest = lengths["prompt"]["max"] + lengths["output"]["max"]
-    assert longest <= shapes.sizes(resolved["config"])["max_len"]
+    assert longest <= arithmetic.max_len(resolved["config"])
     assert resolved["cell"]["limits"]["served_gap_max"] > 0
     assert resolved["cell"]["step_program"].startswith("jit_")
     assert {m["name"] for m in resolved["end_to_end"]} >= {"setup_s"}

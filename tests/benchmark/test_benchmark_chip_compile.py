@@ -88,7 +88,7 @@ def test_cell_step_compiles_for_v5e(name, one_chip, no_compile_cache, monkeypatc
     import jax
     import jax.numpy as jnp
 
-    from benchmark import builders, shapes
+    from benchmark import builders, family
 
     config, cell = _cell(name)
     model, decoder = builders.resolve(cell["builder"])(config, 0, **cell["args"])
@@ -104,7 +104,7 @@ def test_cell_step_compiles_for_v5e(name, one_chip, no_compile_cache, monkeypatc
     finally:
         model.unload()  # the batcher's worker thread, where there is one
     n_params = sum(int(np.prod(p.shape)) for p in jax.tree_util.tree_leaves(params))
-    assert n_params == shapes.total_params(config)
+    assert n_params == family.arithmetic(config).total_params(config)
 
     scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     if hasattr(model, "_batched_step"):

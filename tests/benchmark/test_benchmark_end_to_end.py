@@ -109,12 +109,110 @@ def _digests(root):
     return out
 
 
-def test_a_later_pr_adds_files_and_edits_none(tmp_path):
-    """A configuration, a mix with its lengths, a builder, a per-layer metric
-    and a cell, as new files and new entries, run by the harness as it
-    stands."""
-    root, _ = benchmark_fixture.make_root(tmp_path)
-    before = _digests(root)
+def _gpt2_again(add):
+    """The GPT-2 family at other numbers: a configuration and a builder that
+    lean on the family's own files."""
+    add(dict(benchmark_fixture.TINY_CONFIG, n_layer=1, n_embd=32, n_head=2),
+        "configs", "later.json")
+    add("from benchmark import builders\n\n\n"
+        "def generate(config, seed, **args):\n"
+        "    return builders.tiny_lm_generate(config, seed, **args)\n",
+        "later_builders.py")
+    return ["configs/later.json", "later_builders.py"]
+
+
+LATER_ARITHMETIC = """
+MARK = 4321.0
+
+
+def vocab(config):
+    return config["vocab_size"]
+
+
+def max_len(config):
+    return config["max_position_embeddings"]
+
+
+def total_params(config):
+    d, v = config["hidden_size"], config["vocab_size"]
+    return (12 * d * d * config["num_hidden_layers"] + 2 * d * v
+            + d * config["max_position_embeddings"])
+
+
+def work(config, positions):
+    tokens = sum(1 for _ in positions)
+    return {"tokens_processed": tokens, "flops": 1e6 * tokens, "mark": MARK}
+
+
+def step_least(config, work, width):
+    # 1/8 ms at the fixture's peaks, whatever the step
+    return {"bytes": 1e9 * 0.125e-3 * width, "flops": 1.0}
+
+
+def init_scale(path, leaf):
+    return 0.03 if path[0] == "unembed" else None
+"""
+LATER_REFERENCE = """
+from benchmark import reference as gpt2
+
+
+def served_token_gaps(params, config, sessions, length{control}):
+    return gpt2.served_token_gaps(
+        params, {{"n_head": config["num_attention_heads"]}}, sessions, length{passed})
+"""
+LATER_BUILDER = """
+from benchmark.builders import NoDraw
+
+
+def generate(config, seed, **args):
+    from client_tpu.models.decoder import TinyDecoderModel
+    from client_tpu.models.generate import TinyGenerateModel
+
+    cls = type("LaterDecoder", (TinyDecoderModel,), {
+        "VOCAB": config["vocab_size"], "D_MODEL": config["hidden_size"],
+        "HEADS": config["num_attention_heads"],
+        "LAYERS": config["num_hidden_layers"],
+        "MAX_LEN": config["max_position_embeddings"]})
+    decoder = cls(seed=NoDraw(), **args)
+    decoder._ensure_built()
+    return TinyGenerateModel(decoder=decoder), decoder
+"""
+FACT_READERS = {  # metric -> the fact it reads, as a reader file of its own
+    "work_mark": "facts['work']['mark']",
+    "registry_success":
+        "facts['registry']['client_tpu_server_request_success_count']",
+    "server_success": "float(facts['server']['success_count'])",
+    "server_output_pairs": "float(facts['server']['compute_output_count']"
+                           " + facts['server']['compute_input_count'])",
+}
+
+
+def _another_family(add, control=True):
+    """A family that is not GPT-2's: a configuration with none of its keys,
+    its own arithmetic (with an ``init_scale``), reference and builder, and
+    readers of what a traced run hands them, all as new files."""
+    add({"source": "fixture", "model_type": "later", "hidden_size": 32,
+         "num_hidden_layers": 1, "num_attention_heads": 2,
+         "max_position_embeddings": 64, "vocab_size": 300, "dtype": "bfloat16",
+         "arithmetic": "benchmark.later_arithmetic",
+         "reference": "benchmark.later_reference", "reduced": []},
+        "configs", "later.json")
+    add(LATER_ARITHMETIC, "later_arithmetic.py")
+    add(LATER_REFERENCE.format(
+        control=", control=False" if control else "",
+        passed=", control=control" if control else ""), "later_reference.py")
+    add(LATER_BUILDER, "later_builders.py")
+    files = ["configs/later.json", "later_arithmetic.py", "later_builders.py",
+             "later_reference.py"]
+    for name, fact in FACT_READERS.items():
+        add({"reader": name + ".py"}, "layer_metrics", name + ".json")
+        add(f"def read(facts):\n    return {fact}\n", "layer_metrics", name + ".py")
+        files += [f"layer_metrics/{name}.json", f"layer_metrics/{name}.py"]
+    return files
+
+
+def _later_pr(root, family, **options):
+    """What a later PR brings, as new files and new entries; the files."""
     home = os.path.join(root, "benchmark")
 
     def add(obj, *path):
@@ -122,18 +220,13 @@ def test_a_later_pr_adds_files_and_edits_none(tmp_path):
         with open(os.path.join(home, *path), "w") as f:
             f.write(obj if isinstance(obj, str) else json.dumps(obj))
 
-    add(dict(benchmark_fixture.TINY_CONFIG, n_layer=1, n_embd=32, n_head=2),
-        "configs", "later.json")
+    files = family(add, **options)
     add({"source": "fixture", "pool": 4,
          "prompt": {"mean": 4, "sigma": 0.3, "min": 2, "max": 8},
          "output": {"mean": 3, "sigma": 0.3, "min": 2, "max": 6}},
         "lengths", "short.json")
     add({"api": "stream", "ramp_seconds": 0.3, "lengths": "short"},
         "traffic", "bursty.json")
-    add("from benchmark import builders\n\n\n"
-        "def generate(config, seed, **args):\n"
-        "    return builders.tiny_lm_generate(config, seed, **args)\n",
-        "later_builders.py")
     add({"builder": "benchmark.later_builders:generate", "args": {}, "users": 2,
          "step_program": "jit_step", "limits": {"served_gap_max": 0.01}},
         "cells", "later.bursty.json")
@@ -148,26 +241,88 @@ def test_a_later_pr_adds_files_and_edits_none(tmp_path):
                              "why": "fixture", "file": "benchmark/configs/later.json"})
     bench["workloads"].append({"name": "later.bursty", "config": "later",
                                "traffic": "bursty", "chips": 1, "why": "fixture"})
-    bench["per_layer"].append({
-        "name": "tokens_per_session", "unit": "tokens", "better": "higher",
-        "source": "host_clock", "layer": "Client", "moves": "output_tokens_per_s",
-        "workloads": ["later.bursty"]})
+    readers = ["tokens_per_session"] + [
+        f[len("layer_metrics/"):-len(".json")] for f in files
+        if f.startswith("layer_metrics/") and f.endswith(".json")]
+    for name in readers:
+        bench["per_layer"].append({
+            "name": name, "unit": "tokens", "better": "higher",
+            "source": "host_clock", "layer": "Client", "moves": "output_tokens_per_s",
+            "workloads": ["later.bursty"]})
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f)
+    return sorted("benchmark/" + p for p in files + [
+        "cells/later.bursty.json", "layer_metrics/tokens_per_session.json",
+        "layer_metrics/tokens_per_session.py", "lengths/short.json",
+        "traffic/bursty.json"])
+
+
+@pytest.fixture
+def forget_later_modules():
+    """The later PR's modules are imported from a temporary root by name."""
+    yield
+    for name in [m for m in sys.modules if m.startswith("benchmark.later_")]:
+        del sys.modules[name]
+
+
+@pytest.mark.parametrize("family", [_gpt2_again, _another_family],
+                         ids=["gpt2_again", "another_family"])
+def test_a_later_pr_adds_files_and_edits_none(tmp_path, monkeypatch, family,
+                                              forget_later_modules):
+    """A configuration, a mix with its lengths, a builder, a per-layer metric
+    and a cell, as new files and new entries, run by the harness as it
+    stands: once of the GPT-2 family again, and once of a family that brings
+    its own arithmetic, reference, builder and weight scales, under a
+    configuration with none of the GPT-2 keys."""
+    root, _ = benchmark_fixture.make_root(tmp_path)
+    before = _digests(root)
+    added = _later_pr(root, family)
+    # the users' process finds the later PR's modules where the command
+    # would: under the root it runs from
+    monkeypatch.setattr("benchmark.__path__", [os.path.join(root, "benchmark")])
 
     result = run.run_cell(root, "later.bursty", seed=3, seconds=SECONDS,
                           trace=True, require_tpu=False)
     assert result["correct"] is True, result["compared"]
-    assert result["metrics"]["tokens_per_session"]["value"] > 1
+    metrics = {k: v["value"] for k, v in result["metrics"].items()}
+    assert metrics["tokens_per_session"] > 1
     after = _digests(root)
     changed = [p for p in before if p != "BENCHMARK.json" and after[p] != before[p]]
     assert changed == []
-    assert sorted(set(after) - set(before)) == sorted(
-        "benchmark/" + p for p in (
-            "cells/later.bursty.json", "configs/later.json", "later_builders.py",
-            "layer_metrics/tokens_per_session.json",
-            "layer_metrics/tokens_per_session.py", "lengths/short.json",
-            "traffic/bursty.json"))
+    assert sorted(set(after) - set(before)) == added
+    if family is not _another_family:
+        return
+    config = run.resolve_cell(root, "later.bursty")["config"]
+    assert not {"n_embd", "n_head", "n_layer", "n_positions", "n_inner"} & set(config)
+    # the window's work was counted by the family's own arithmetic
+    assert metrics["work_mark"] == 4321.0
+    # ... and so is the step's roofline: 1/8 ms of least time over a step of
+    # 1 ms, a number the GPT-2 arithmetic cannot give
+    facts = {"config": config, "trace": {"step_device_ms": 1.0},
+             "peaks": {"hbm_bytes_per_s": 1e9, "flops_per_s": 1e12},
+             "work": {"tokens_processed": 7}, "batch_histogram": None}
+    home = os.path.join(root, "benchmark")
+    assert run.read_layer_metric(home, "step_roofline", facts) == pytest.approx(12.5)
+    # the registry's series are the window's difference, as the statistics
+    # verb's pairs are, and not the total since the warm-up (four sessions
+    # and the ramp's more); the verb's seven pairs are all there
+    assert abs(metrics["registry_success"] - metrics["server_success"]) <= 2
+    assert metrics["server_success"] >= result["counts"]["sessions_finished"] > 0
+    assert metrics["server_output_pairs"] == 2 * metrics["server_success"]
+
+
+def test_a_reference_without_a_control_fails_calibration_by_name(
+        tmp_path, monkeypatch, forget_later_modules):
+    root, _ = benchmark_fixture.make_root(tmp_path)
+    _later_pr(root, _another_family, control=False)
+    monkeypatch.setattr("benchmark.__path__", [os.path.join(root, "benchmark")])
+    resolved = run.resolve_cell(root, "later.bursty")
+    with run.Serving(resolved, 5, require_tpu=False) as serving:
+        # a run needs no control, and this reference can judge one
+        sound = list(calibrate.read_seeds(serving, resolved, [5], 0, seconds=1.0))
+        assert sound[0]["correct"] is True
+        with pytest.raises(run.NoResult, match="benchmark.later_reference.*control"):
+            list(calibrate.read_seeds(serving, resolved, [6], 1, seconds=1.0))
 
 
 def _command(root, cell):

@@ -9,7 +9,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from benchmark import builders, reference, run
+from benchmark import builders, family, reference, run
 from benchmark.server import make_params
 
 CONFIG = {"n_layer": 3, "n_embd": 64, "n_head": 4, "n_positions": 48,
@@ -57,15 +57,14 @@ def sessions(decoder):
 
 def test_served_tokens_lie_at_the_references_best(decoder):
     read = reference.served_token_gaps(
-        decoder._params, CONFIG["n_head"], sessions(decoder), length=40)
+        decoder._params, CONFIG, sessions(decoder), length=40)
     assert read["positions"] == len(PROMPTS) * NEW_TOKENS
     assert 0.0 <= read["served_gap_max"] < 0.02
 
 
 def test_the_fp8_control_does_not(decoder):
     read = reference.served_token_gaps(
-        decoder._params, CONFIG["n_head"], sessions(decoder), length=40,
-        control=True)
+        decoder._params, CONFIG, sessions(decoder), length=40, control=True)
     assert read["control_gap_max"] > 3 * max(read["served_gap_max"], 0.005)
     # through the run's own comparison, at a limit between the two readings
     exact = {"sessions_failed": 0, "argmax_mismatch": 0, "compiles_in_window": 0}
@@ -79,15 +78,14 @@ def test_the_fp8_control_does_not(decoder):
 def test_a_wrong_token_reads_as_a_wide_gap(decoder):
     rows = sessions(decoder)
     rows[1]["tokens"][7] = (rows[1]["tokens"][7] + 1) % CONFIG["vocab_size"]
-    read = reference.served_token_gaps(
-        decoder._params, CONFIG["n_head"], rows, length=40)
+    read = reference.served_token_gaps(decoder._params, CONFIG, rows, length=40)
     assert read["served_gap_max"] > 0.1
 
 
 def test_a_session_longer_than_the_room_is_refused(decoder):
     with pytest.raises(ValueError, match="room for"):
         reference.served_token_gaps(
-            decoder._params, CONFIG["n_head"],
+            decoder._params, CONFIG,
             [{"prompt": [1] * 30, "tokens": [2] * 20}], length=40)
 
 
@@ -110,6 +108,54 @@ def test_weights_come_from_the_seed(decoder, seed):
     assert float(jnp.std(a["embed"].astype(jnp.float32))) == pytest.approx(0.02, rel=0.1)
     assert float(jnp.std(a["layers"][0]["qkv"].astype(jnp.float32))) == pytest.approx(
         CONFIG["n_embd"] ** -0.5, rel=0.1)
+
+
+@pytest.mark.parametrize("init_scale, want", [
+    (None, {"embed": 0.02, "qkv": CONFIG["n_embd"] ** -0.5}),
+    (lambda path, leaf: None, {"embed": 0.02, "qkv": CONFIG["n_embd"] ** -0.5}),
+    (lambda path, leaf: 0.05 if path[-1] == "qkv" else None,
+     {"embed": 0.02, "qkv": 0.05}),
+    (lambda path, leaf: (1.0, 0.01) if path == ("embed",) else 0.1,
+     {"embed": 0.01, "embed_mean": 1.0, "qkv": 0.1}),
+], ids=["no_family_rule", "family_says_nothing", "one_leaf", "mean_and_deviation"])
+def test_a_leaf_is_drawn_as_the_family_says_and_as_before_where_it_says_nothing(
+        decoder, init_scale, want):
+    drawn = make_params(decoder._params, 7, init_scale)
+    embed = drawn["embed"].astype(jnp.float32)
+    qkv = drawn["layers"][1]["qkv"].astype(jnp.float32)
+    assert float(jnp.std(embed)) == pytest.approx(want["embed"], rel=0.1)
+    assert float(jnp.mean(embed)) == pytest.approx(want.get("embed_mean", 0.0), abs=0.01)
+    assert float(jnp.std(qkv)) == pytest.approx(want["qkv"], rel=0.1)
+    if init_scale is not None and want == {"embed": 0.02, "qkv": CONFIG["n_embd"] ** -0.5}:
+        # a family that says nothing gets the very weights of one that has no rule
+        plain = make_params(decoder._params, 7)
+        assert bool(jnp.array_equal(drawn["layers"][1]["qkv"], plain["layers"][1]["qkv"]))
+    seen = []
+    make_params(decoder._params, 7, lambda path, leaf: seen.append(path))
+    assert ("embed",) in seen and ("layers", "0", "qkv") in seen
+
+
+def test_the_reference_reads_its_sizes_from_the_configuration(decoder):
+    rows = sessions(decoder)[:1]
+    read = reference.served_token_gaps(decoder._params, CONFIG, rows, length=40)
+    other = reference.served_token_gaps(
+        decoder._params, dict(CONFIG, n_head=2), rows, length=40)
+    assert other["served_gap_max"] > 10 * max(read["served_gap_max"], 0.001)
+
+
+def test_a_family_module_that_lacks_part_of_the_contract_is_named(tmp_path, monkeypatch):
+    (tmp_path / "half_arithmetic.py").write_text("def vocab(config):\n    return 3\n")
+    (tmp_path / "half_reference.py").write_text(
+        "def served_token_gaps(params, config, sessions, length):\n    return {}\n")
+    monkeypatch.syspath_prepend(str(tmp_path))
+    config = {"arithmetic": "half_arithmetic", "reference": "half_reference"}
+    with pytest.raises(AttributeError, match="half_arithmetic.*max_len, work"):
+        family.arithmetic(config)
+    assert family.reference(config).__name__ == "half_reference"
+    with pytest.raises(AttributeError, match="half_reference.*control"):
+        family.reference(config, control=True)
+    assert family.reference({}) is reference
+    assert family.reference({}, control=True) is reference
 
 
 def test_the_batcher_serves_the_same_decoder_at_the_configured_sizes():

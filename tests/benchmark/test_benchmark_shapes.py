@@ -6,7 +6,7 @@ import os
 
 import pytest
 
-from benchmark import shapes
+from benchmark import family, shapes
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -70,6 +70,28 @@ def test_work_adds_up_over_positions(name):
     assert total["flops"] == sum(shapes.token_flops(c, p) for p in (0, 1, 2, 10))
     assert total["cache_bytes"] == HAND[name]["row"] * (1 + 2 + 3 + 11)
     assert total["row_bytes"] == 4 * shapes.step_row_bytes(c)
+
+
+@pytest.mark.parametrize("name, positions", [("gpt2-large", 1024),
+                                             ("cerebras-gpt-1.3b", 2048)])
+def test_what_the_harness_asks_of_the_family(name, positions):
+    c = config(name)
+    assert family.arithmetic(c) is shapes  # no "arithmetic" key: the GPT-2 family's
+    assert shapes.vocab(c) == HAND[name]["vocab"] and shapes.max_len(c) == positions
+
+
+@pytest.mark.parametrize("name", sorted(HAND))
+@pytest.mark.parametrize("width", [1.0, 2.5])
+def test_step_least_is_the_weights_once_and_the_mean_tokens_rows(name, width):
+    c, h = config(name), HAND[name]
+    total = shapes.work(c, [0, 1, 2, 10])
+    least = shapes.step_least(c, total, width)
+    # the four tokens' cache rows: 1 + 2 + 3 + 11 = 17; a row of each table
+    # in and a row of logits out for each
+    rows = h["row"] * 17 + 4 * (2 * h["d"] * 2 + h["vocab"] * 4)
+    assert least["bytes"] == 2 * h["matmul"] + width * rows / 4
+    assert least["flops"] == width * total["flops"] / 4
+    assert set(least) == {"bytes", "flops"}
 
 
 @pytest.mark.parametrize("broken, message", [
