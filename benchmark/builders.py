@@ -8,7 +8,10 @@ module (or ``"<module>:<function>"`` of a later one) and its arguments.
 
 Every builder returns ``(model, decoder)``: the model ``ServerCore`` serves
 and the decoder whose ``_params`` the benchmark replaces with weights it made
-on the device from the seed.
+on the device from the seed. Only their shapes and types are read, so a
+builder may leave ``_params`` as a tree of ``jax.ShapeDtypeStruct``
+(``benchmark/family.py``); the two here hand over zeros made on the device,
+which ``server.Served.reseed`` lets go of before it draws.
 
 The two builders here are the GPT-2 family's: they read the configuration
 through ``shapes.sizes``. A model of another family brings its builder as a

@@ -4,16 +4,18 @@
         --control-seeds 3 --seconds 20
 
 Not run by the benchmark. One serving process, many seeds: each seed gets new
-weights (``reseed``) and its own traffic at the cell's own load for
-``--seconds`` seconds, sessions in flight at the close run to their end, and
-the configuration's own plain reference (``benchmark/family.py``) reads the
-widest gap over the usual sample: the lower reading. On the first
-``--control-seeds`` seeds that reference's control (for the GPT-2 family the
-fp8 pass) is run over the same prompts and tokens as well; the gap of the
+weights (``reseed``: the old ones are let go of first, the chip holds one
+copy) and its own traffic at the cell's own load for ``--seconds`` seconds,
+sessions in flight at the close run to their end, and the configuration's own
+plain reference (``benchmark/family.py``) reads the widest gap over the usual
+sample: the lower reading. Whatever other numbers that reference returns are
+held, each by the limit the cell's file names for it, as in a run. On the
+first ``--control-seeds`` seeds that reference's control (for the GPT-2 family
+the fp8 pass) is run over the same prompts and tokens as well; the gap of the
 token it puts first is the upper reading, and it goes through the run's own
-comparison in the served tokens' place, which has to say
-``control_correct: false``. A reference that has no control fails here, by
-its module's name. One JSON object a seed on standard output.
+comparison in the served gap's place, the other readings as they stood, which
+has to say ``control_correct: false``. A reference that has no control fails
+here, by its module's name. One JSON object a seed on standard output.
 """
 
 from __future__ import annotations
@@ -53,12 +55,14 @@ def read_seeds(serving: "run.Serving", cell: Dict[str, Any], seeds: List[int],
                "first_error": failed[0] if failed else None,
                "sessions_checked": len(sample),
                **{k2: v for k2, v in read.items() if k2 != "ok"}}
+        readings = {**run.own_readings(read), **exact}
         out["compared"], out["correct"] = run.judge(
-            dict(exact, served_gap_max=read.get("served_gap_max")), limits)
+            dict(readings, served_gap_max=read.get("served_gap_max")), limits)
         if "control_gap_max" in read:
-            # the control in the program's place, through the same comparison
+            # the control in the program's place, through the same comparison:
+            # its gap for the served one, the family's other readings as read
             _, out["control_correct"] = run.judge(
-                dict(exact, served_gap_max=read["control_gap_max"]), limits)
+                dict(readings, served_gap_max=read["control_gap_max"]), limits)
         yield out
 
 

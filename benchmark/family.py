@@ -25,12 +25,25 @@ arithmetic (imports no jax: the users' process loads it)
     step of ``width`` sequences: the least bytes the chip must move and the
     FLOPs the model needs, the same count whatever implements the step (for
     routed experts the experts its tokens reach, not those held).
-    ``total_params(config)``: as the served code lays them out.
+    ``total_params(config)``: as the served code lays them out, and of what
+    is held here where the configuration is cut (below).
     ``init_scale(path, leaf)``, optional: the standard deviation
     ``server.make_params`` draws a leaf at, or ``(mean, deviation)``;
-    ``path`` is the leaf's keys as strings. ``None``, or no such function,
-    keeps the rule the GPT-2 family is drawn by (0.02 for the tables and
-    the head, ``shape[0] ** -0.5`` for the rest).
+    ``path`` is the leaf's keys as strings, and of ``leaf`` only ``shape``
+    and ``dtype`` may be read (it may be a ``jax.ShapeDtypeStruct``).
+    ``None``, or no such function, keeps the rule the GPT-2 family is drawn
+    by (0.02 for the tables and the head, ``shape[0] ** -0.5`` for the
+    rest): stacked experts ``[experts, d, f]`` need a rule of their own.
+    ``fixture(config)``, optional: ``(configuration, limits)`` for the CPU
+    tests (``tests/benchmark/benchmark_fixture.py``): the family's
+    configuration at fixture size (a few layers of every kind it has, tens
+    of units wide, a few hundred ids, 64 positions or more, naming the same
+    ``arithmetic`` and ``reference``) and the limits every cell of the
+    family is held to there, by the names its cell files use. Each cell of
+    ``BENCHMARK.json`` is then run on the CPU through its own builder,
+    arithmetic, reference and control at that size, with no edit to the
+    tests. No such function: the GPT-2 family's fixture and
+    ``{"served_gap_max": 0.01}``.
 
 reference (imports nothing of the program; takes the weights as data)
     ``served_token_gaps(params, config, sessions, length, control=False)``:
@@ -38,7 +51,33 @@ reference (imports nothing of the program; takes the weights as data)
     for the tokens the pass in the nearest lower precision puts first:
     ``control_gap_max``. ``calibrate.py`` holds every family's limit between
     a sound run and that control; a reference without it fails there by
-    name.
+    name. Every other number it returns is a reading of the family's own
+    (say the share of positions it set aside because two router scores tie
+    within rounding): ``run.py`` and ``calibrate.py`` hand each to ``judge``
+    by its name, a limit named for it in ``cells/<cell>.json`` holds it like
+    ``served_gap_max`` and prints it under ``compared``, and a limit whose
+    reading is absent is not met. The control is judged with its gap in the
+    served gap's place and those other readings as they stood.
+
+builder (``cells/<cell>.json``: ``"<module>:<function>"``)
+    ``builder(config, seed, **args)`` returns ``(model, decoder)``: the model
+    ``ServerCore`` serves and the object whose ``_params`` are the weights.
+    After ``decoder._ensure_built()`` the tree at ``decoder._params`` is the
+    template: ``server.Served`` takes each leaf's ``shape`` and ``dtype``
+    from it once, and before ``model._ensure_built()`` puts weights drawn
+    from the seed there (``reseed``: what was there is let go of first, so
+    the chip holds one copy, as long as nothing else keeps a reference). The builder may leave that tree as
+    ``jax.ShapeDtypeStruct``; ``decoder._ensure_built()`` must then allocate
+    no weight. The step must read ``decoder._params`` at each call.
+
+a configuration that is cut to the chip's share (``model-configs`` section 4)
+    ``"reduced"``, in the file and in ``BENCHMARK.json`` alike, lists the keys
+    changed from the source. Where it is not empty the file also carries
+    ``"published"`` (for each such key the source's own value; where both are
+    numbers the file's is the smaller) and ``"deployment"`` (a sentence: over
+    how many chips each layer is divided, how, and which layers are kept),
+    and ``total_params`` counts what is held, not what is published.
+    ``tests/benchmark`` holds every configuration to that.
 """
 
 from __future__ import annotations

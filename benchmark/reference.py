@@ -113,6 +113,24 @@ def _gaps(logits, chosen):
     return jnp.max(logits, axis=-1) - picked
 
 
+def teacher_forced(sessions: Sequence[Dict[str, Any]], length: int, rows: int):
+    """``sessions`` as ``rows`` rows of ``length`` positions: the tokens fed
+    (prompt, then served tokens), at each position that produced a served
+    token that token, and where those positions are."""
+    tokens = np.zeros((rows, length), np.int32)
+    target = np.zeros((rows, length), np.int32)
+    valid = np.zeros((rows, length), bool)
+    for r, s in enumerate(sessions):
+        full = list(s["prompt"]) + list(s["tokens"])
+        if len(full) > length:
+            raise ValueError(f"session of {len(full)} tokens, room for {length}")
+        tokens[r, :len(full)] = full
+        first = len(s["prompt"]) - 1  # the position that produced token 0
+        target[r, first:len(full) - 1] = s["tokens"]
+        valid[r, first:len(full) - 1] = True
+    return tokens, target, valid
+
+
 def served_token_gaps(params: Dict[str, Any], config: Dict[str, Any],
                       sessions: Sequence[Dict[str, Any]], length: int,
                       control: bool = False, block: int = 4) -> Dict[str, Any]:
@@ -133,18 +151,7 @@ def served_token_gaps(params: Dict[str, Any], config: Dict[str, Any],
     served: List[float] = []
     lowered: List[float] = []
     for at in range(0, len(sessions), block):
-        rows = sessions[at:at + block]
-        tokens = np.zeros((block, length), np.int32)
-        target = np.zeros((block, length), np.int32)
-        valid = np.zeros((block, length), bool)
-        for r, s in enumerate(rows):
-            full = list(s["prompt"]) + list(s["tokens"])
-            if len(full) > length:
-                raise ValueError(f"session of {len(full)} tokens, room for {length}")
-            tokens[r, :len(full)] = full
-            first = len(s["prompt"]) - 1  # the position that produced token 0
-            target[r, first:len(full) - 1] = s["tokens"]
-            valid[r, first:len(full) - 1] = True
+        tokens, target, valid = teacher_forced(sessions[at:at + block], length, block)
         logits = forward(params, tokens, heads)
         served.extend(np.asarray(_gaps(logits, jnp.asarray(target)))[valid]
                       .tolist())

@@ -16,7 +16,9 @@ metric is computed and not simply looked up). A configuration names its
 family's own arithmetic and reference modules (``benchmark/family.py`` has
 the contract; absent, the GPT-2 family's ``shapes.py`` and ``reference.py``),
 and this file, ``server.py``, ``calibrate.py`` and the readers ask those and
-read no other key of a configuration. A new cell, mix, per-layer metric,
+read no other key of a configuration. Every number the reference returns is
+handed to ``judge`` by its name, so a family's reading of its own is held by
+the limit its cell's file names for it. A new cell, mix, per-layer metric,
 configuration or family of models is new files and new entries, and no edit
 here.
 
@@ -232,10 +234,21 @@ def pick_sample(records: List[Dict[str, Any]], seed: int,
     return [longest] + [rest[i] for i in sorted(picks)]
 
 
+def own_readings(checked: Dict[str, Any]) -> Dict[str, float]:
+    """Every number of the reference's answer, by its own name: beside
+    ``served_gap_max`` a family's reference may return readings of its own
+    (the share of positions it set aside as near ties of the router), and a
+    limit named for one in ``cells/<cell>.json`` holds it."""
+    return {name: value for name, value in checked.items()
+            if isinstance(value, (int, float)) and not isinstance(value, bool)}
+
+
 def judge(readings: Dict[str, Any],
           limits: Dict[str, float]) -> Tuple[Dict[str, Any], bool]:
-    """Every number compared beside its limit, and the verdict. A number
-    that was not read is not within its limit."""
+    """Every number compared beside its limit, and the verdict: the three
+    exact ones and every one the cell's file names a limit for, be it
+    ``served_gap_max`` or a reading of the family's own. A number that was
+    not read is not within its limit."""
     exact = {"sessions_failed": 0, "argmax_mismatch": 0, "compiles_in_window": 0}
     compared = {name: {"value": readings.get(name), "limit": limit}
                 for name, limit in {**exact, **limits}.items()}
@@ -390,6 +403,7 @@ def run_cell(root: str, workload: str, seed: int, seconds: float, trace: bool,
         checked = serving.check(sample, plan.longest)
 
     compared, correct = judge({
+        **own_readings(checked),
         "sessions_failed": len(failed),
         "argmax_mismatch": sum(r["argmax_mismatch"] for r in records),
         "compiles_in_window": finished["compiles"] - compiles_warm,

@@ -2,9 +2,11 @@
 
 Started by ``benchmark/run.py`` (which never imports jax), it builds the
 cell's model from its configuration file, gives it weights made on the device
-from the seed, serves it through ``ServerCore`` and ``GrpcInferenceServer``
-on a loopback port, and then takes commands, one JSON object a line on
-standard input, answering each with one line on standard output:
+from the seed (of the shapes the builder handed over: the chip holds one copy
+of them, ``benchmark/family.py``), serves it through ``ServerCore`` and
+``GrpcInferenceServer`` on a loopback port, and then takes commands, one JSON
+object a line on standard input, answering each with one line on standard
+output:
 
 - ``mark``: the counters as they stand (compiles so far, the batcher's
   histogram, every series of the program's own metrics registry that is
@@ -17,8 +19,8 @@ standard input, answering each with one line on standard output:
 - ``check``: run the configuration's own plain reference
   (``benchmark/family.py``) over the sample of sessions it is sent (the
   weights are the benchmark's own arrays, made here from the seed);
-- ``reseed``: new weights from another seed (``calibrate.py`` reads a dozen
-  seeds in one process);
+- ``reseed``: new weights from another seed, the old ones let go of first
+  (``calibrate.py`` reads a dozen seeds in one process);
 - ``exit``.
 
 The host spans of a traced run are put round the model object's ``execute``
@@ -29,7 +31,9 @@ issue's.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
+import math
 import os
 import shutil
 import sys
@@ -51,45 +55,106 @@ def emit(obj: Dict[str, Any]) -> None:
     sys.stdout.flush()
 
 
-def make_params(template, seed: int, init_scale=None):
-    """Weights of the template's shapes and types, drawn on the device in one
-    jitted call: normal, at the deviation (or ``(mean, deviation)``) the
-    family's ``init_scale(path, leaf)`` gives a leaf, ``path`` being its keys
-    as strings; where it gives ``None``, or there is none, scaled as the
-    program scales its own (0.02 for the tables and the output head,
-    ``shape[0] ** -0.5`` for the matrices)."""
-    import jax
-    import jax.numpy as jnp
+BLOCK_BYTES = 2 << 30  # the largest float32 block one call of the draw makes
 
-    paths, treedef = jax.tree_util.tree_flatten_with_path(template)
+
+def weight_groups(template, init_scale=None) -> Dict[Any, List[int]]:
+    """The template's leaves, numbered as they flatten, by ``(shape, dtype,
+    mean, deviation)``: the leaves of one group are drawn as one block."""
+    import jax
+
     groups: Dict[Any, List[int]] = {}
+    paths, _ = jax.tree_util.tree_flatten_with_path(template)
     for i, (path, leaf) in enumerate(paths):
         keys = tuple(str(getattr(k, "key", getattr(k, "idx", k))) for k in path)
         drawn = init_scale(keys, leaf) if init_scale is not None else None
         if drawn is None:
             drawn = 0.02 if keys[0] in TABLES else leaf.shape[0] ** -0.5
         mean, scale = drawn if isinstance(drawn, tuple) else (0.0, drawn)
-        groups.setdefault(
-            (leaf.shape, str(leaf.dtype), float(mean), float(scale)), []).append(i)
+        groups.setdefault((tuple(leaf.shape), str(leaf.dtype), float(mean),
+                           float(scale)), []).append(i)
+    return groups
+
+
+def block_nbytes(group, members: int = 1) -> int:
+    """The float32 block of ``members`` leaves of a group."""
+    return 4 * math.prod(group[0]) * members
+
+
+def make_params(template, seed: int, init_scale=None,
+                block_bytes: int = BLOCK_BYTES):
+    """Weights of the template's shapes and types, drawn on the device from
+    the seed: normal, at the deviation (or ``(mean, deviation)``) the family's
+    ``init_scale(path, leaf)`` gives a leaf, ``path`` being its keys as
+    strings; where it gives ``None``, or there is none, scaled as the program
+    scales its own (0.02 for the tables and the output head,
+    ``shape[0] ** -0.5`` for the matrices).
+
+    Of the template only each leaf's ``shape`` and ``dtype`` are read, here
+    and by ``init_scale``: it may be a tree of ``jax.ShapeDtypeStruct``, and a
+    tree of arrays draws the same.
+
+    The leaves of one shape, type and deviation are a group, drawn as one
+    float32 block under a key folded from the seed's by the group's number.
+    The groups whose block is at most ``block_bytes`` (2 GiB) are drawn in
+    one jitted call together. A larger group (stacked experts) is cut into
+    runs of as many members as fit in ``block_bytes``, one at least, each run
+    drawn by a call of its own under a key folded from the group's by the
+    run's number, and waited for: what lives on the chip beside the finished
+    leaves is then one run, its bits and its cast (the chip's compiler keeps
+    no float32 block; ``peak_bytes_in_use`` counts neither, they are the
+    program's temporaries)."""
+    import jax
+    import jax.numpy as jnp
+
+    groups = weight_groups(template, init_scale)
+    whole = [block_nbytes(group, len(members)) <= block_bytes
+             for group, members in groups.items()]
+
+    def block(key, n, shape, dtype, mean, scale):
+        drawn = jax.random.normal(key, (n,) + shape, jnp.float32) * scale
+        if mean:
+            drawn = drawn + mean
+        return drawn.astype(dtype)
 
     @jax.jit
     def draw(key):
-        out = [None] * len(paths)
-        for g, ((shape, dtype, mean, scale), members) in enumerate(groups.items()):
-            block = jax.random.normal(jax.random.fold_in(key, g),
-                                      (len(members),) + shape, jnp.float32)
-            block = block * scale
-            if mean:
-                block = block + mean
-            block = block.astype(dtype)
-            for j, i in enumerate(members):
-                out[i] = block[j]
+        out = {}
+        for g, (group, members) in enumerate(groups.items()):
+            if whole[g]:
+                drawn = block(jax.random.fold_in(key, g), len(members), *group)
+                out.update((i, drawn[j]) for j, i in enumerate(members))
         return out
+
+    @functools.partial(jax.jit, static_argnums=(1, 2))
+    def draw_run(key, n, group):
+        drawn = block(key, n, *group)
+        return [drawn[j] for j in range(n)]
 
     # a seed may be a little over 2**31: fold its high part in
     key = jax.random.fold_in(
         jax.random.key(seed & 0x7FFFFFFF, impl="rbg"), (seed >> 31) & 0x7FFFFFFF)
-    return jax.tree_util.tree_unflatten(treedef, draw(key))
+    out = draw(key)
+    for g, (group, members) in enumerate(groups.items()):
+        if whole[g]:
+            continue
+        n = max(1, block_bytes // block_nbytes(group))
+        for r, first in enumerate(range(0, len(members), n)):
+            run = members[first:first + n]
+            run_key = jax.random.fold_in(jax.random.fold_in(key, g), r)
+            out.update(zip(run, jax.block_until_ready(
+                draw_run(run_key, len(run), group))))
+    return jax.tree_util.tree_unflatten(
+        jax.tree_util.tree_structure(template), [out[i] for i in range(len(out))])
+
+
+def shapes_of(tree):
+    """The tree as a template: a ``jax.ShapeDtypeStruct`` for each leaf, be
+    the leaf an array or already a shape."""
+    import jax
+
+    return jax.tree_util.tree_map(
+        lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype), tree)
 
 
 def annotate(model) -> None:
@@ -137,6 +202,10 @@ class Served:
         self.model, self.decoder = builders.resolve(cell["builder"])(
             config, seed, **cell.get("args", {}))
         self.decoder._ensure_built()
+        # the template is shapes, taken once from what the builder handed
+        # over, arrays or shapes alike; no draw ever reads the old weights
+        self.template = shapes_of(self.decoder._params)
+        self.params = None
         t_built = time.perf_counter()
         self.reseed(seed)
         jax.block_until_ready(self.params)
@@ -159,8 +228,12 @@ class Served:
             self.compiles += 1
 
     def reseed(self, seed: int) -> None:
-        self.params = make_params(self.decoder._params, seed, self.init_scale)
-        self.decoder._params = self.params
+        """New weights in the old ones' place. The chip holds one copy: what
+        the decoder holds (the last seed's, or what the builder left there)
+        is let go of before the draw, which reads the shapes alone."""
+        self.params = self.decoder._params = None
+        self.params = self.decoder._params = make_params(
+            self.template, seed, self.init_scale)
 
     def registry(self) -> Dict[str, float]:
         """Every series of the program's own metrics registry that carries
@@ -197,7 +270,7 @@ class Served:
 
         return {"{}[{}]".format(HLO_TYPES.get(str(leaf.dtype), str(leaf.dtype)),
                                 ",".join(str(d) for d in leaf.shape)): "weights"
-                for leaf in jax.tree_util.tree_leaves(self.params)}
+                for leaf in jax.tree_util.tree_leaves(self.template)}
 
     def trace_start(self) -> None:
         import jax

@@ -4,13 +4,15 @@ cell's entry against the files it names."""
 import json
 import os
 import re
+import sys
 
 import pytest
 
-from benchmark import family, run
+import benchmark_fixture
+from benchmark import run
+from benchmark_fixture import NAME
 
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+ROOT = benchmark_fixture.REPO
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 
 with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
@@ -31,20 +33,7 @@ def test_top_level_keys():
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_cell_resolves_to_files_that_exist(cell):
-    resolved = run.resolve_cell(ROOT, cell)
-    arithmetic = family.arithmetic(resolved["config"])
-    assert arithmetic.total_params(resolved["config"]) > 0
-    assert arithmetic.vocab(resolved["config"]) > 1
-    assert resolved["traffic"]["api"] in ("sequence", "stream")
-    assert resolved["cell"]["users"] >= 1 and "users" not in resolved["traffic"]
-    lengths = resolved["traffic"]["lengths"]
-    assert lengths["source"] and lengths["pool"] >= 1
-    longest = lengths["prompt"]["max"] + lengths["output"]["max"]
-    assert longest <= arithmetic.max_len(resolved["config"])
-    assert resolved["cell"]["limits"]["served_gap_max"] > 0
-    assert resolved["cell"]["step_program"].startswith("jit_")
-    assert {m["name"] for m in resolved["end_to_end"]} >= {"setup_s"}
-    assert len(resolved["end_to_end"]) >= 2 and resolved["per_layer"]
+    benchmark_fixture.check_cell_resolves(ROOT, cell)
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -64,15 +53,71 @@ def test_a_pair_of_configuration_and_traffic_appears_once():
 
 @pytest.mark.parametrize("config", BENCH["configs"], ids=lambda c: c["name"])
 def test_configuration(config):
-    assert sorted(config) == ["file", "name", "reduced", "source", "why"]
-    assert NAME.match(config["name"]) and config["source"].startswith("https://")
-    assert any(config["file"].startswith(p + "/") for p in BENCH["paths"])
+    benchmark_fixture.check_configuration(ROOT, BENCH, config)
+
+
+@pytest.mark.parametrize("name", ["gpt2-large", "cerebras-gpt-1.3b"])
+def test_the_present_configurations_are_not_cut(name):
+    config = next(c for c in BENCH["configs"] if c["name"] == name)
     with open(os.path.join(ROOT, config["file"])) as f:
-        stated = json.load(f)
-    assert stated["source"] == config["source"]
-    assert stated["reduced"] == config["reduced"] == []
-    assert stated["departures"] and "dtype" in stated["assumed"]
-    assert config["name"] in {w["config"] for w in BENCH["workloads"]}
+        assert json.load(f)["reduced"] == config["reduced"] == []
+
+
+CUT = {"source": "https://example.org/cut/config.json", "hidden_size": 64,
+       "num_hidden_layers": 3, "num_experts": 4, "vocab_size": 128,
+       "reduced": ["num_hidden_layers", "num_experts", "vocab_size"],
+       "published": {"num_hidden_layers": 12, "num_experts": 16, "vocab_size": 512},
+       "deployment": "each layer is divided over 4 chips, a quarter of the experts "
+                     "and of the vocabulary on each; the first 3 layers are kept",
+       "departures": ["random weights"], "assumed": {"dtype": "bfloat16"},
+       "arithmetic": "cut_arithmetic"}
+CUT_ARITHMETIC = """
+def vocab(config):
+    return config["vocab_size"]
+def max_len(config):
+    return 64
+def work(config, positions):
+    return {"tokens_processed": 0, "flops": 0.0}
+def step_least(config, work, width):
+    return {"bytes": 1.0, "flops": 1.0}
+def total_params(config):
+    config = HELD
+    d = config["hidden_size"]
+    return (config["num_hidden_layers"] * config["num_experts"] * 3 * d * d
+            + 2 * config["vocab_size"] * d)
+"""
+
+
+@pytest.mark.parametrize("fault, change", [
+    ("sound", {}),
+    ("no_published", {"published": None}),
+    ("a_key_not_published", {"published": {"num_hidden_layers": 12, "num_experts": 16}}),
+    ("not_smaller", {"num_experts": 32}),
+    ("no_deployment", {"deployment": None}),
+    ("deployment_names_no_chips", {"deployment": "the first 3 layers are kept"}),
+    ("a_key_the_file_lacks", {"reduced": CUT["reduced"] + ["num_heads"]}),
+    ("total_params_of_what_is_published", {}),
+], ids=lambda v: v if isinstance(v, str) else "")
+def test_a_cut_configuration_says_its_cut(tmp_path, monkeypatch, fault, change):
+    """``check_configuration`` on a configuration that is not in
+    ``BENCHMARK.json``: ``reduced`` may say something, and then the file must
+    say the rest. Each fault differs from the sound file in one thing."""
+    stated = {k: v for k, v in {**CUT, **change}.items() if v is not None}
+    (tmp_path / "benchmark").mkdir()
+    (tmp_path / "benchmark" / "cut.json").write_text(json.dumps(stated))
+    (tmp_path / "cut_arithmetic.py").write_text(CUT_ARITHMETIC.replace(
+        "HELD", '{**config, **config["published"]}'
+        if fault == "total_params_of_what_is_published" else "config"))
+    monkeypatch.syspath_prepend(str(tmp_path))
+    monkeypatch.delitem(sys.modules, "cut_arithmetic", raising=False)
+    entry = {"name": "cut", "source": CUT["source"], "file": "benchmark/cut.json",
+             "reduced": stated["reduced"], "why": "a test"}
+    bench = {"paths": ["benchmark"], "workloads": [{"config": "cut"}]}
+    if fault == "sound":
+        benchmark_fixture.check_configuration(str(tmp_path), bench, entry)
+    else:
+        with pytest.raises((AssertionError, KeyError)):
+            benchmark_fixture.check_configuration(str(tmp_path), bench, entry)
 
 
 @pytest.mark.parametrize("metric", METRICS, ids=lambda m: m["name"])

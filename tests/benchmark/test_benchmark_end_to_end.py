@@ -6,6 +6,7 @@ command itself is seen to refuse a machine without one."""
 import hashlib
 import json
 import os
+import shutil
 import subprocess
 import sys
 
@@ -46,53 +47,95 @@ def check_line(result, owed, traced):
         assert "breakdown" not in result
 
 
-@pytest.mark.parametrize("traced", [False, True], ids=["timed", "traced"])
-@pytest.mark.parametrize("cell", CELLS)
-def test_cell_at_fixture_size(fixture_root, cell, traced):
-    root, names = fixture_root
+def cell_at_fixture_size(root, names, cell, traced):
+    """A cell's twin through a whole run; the metrics owed are those the
+    root's own ``BENCHMARK.json`` lists for it."""
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
     result = run.run_cell(root, names[cell], seed=2**31 + 17, seconds=SECONDS,
                           trace=traced, require_tpu=False)
     assert result["correct"] is True, result["compared"]
     kind = "per_layer" if traced else "end_to_end"
-    owed = {m["name"]: m["unit"] for m in BENCH[kind]
-            if cell in m.get("workloads", [cell])}
+    owed = {m["name"]: m["unit"] for m in bench[kind]
+            if names[cell] in m.get("workloads", [names[cell]])}
     check_line(result, owed, traced)
     if not traced:
         assert set(result["metrics"]) == set(owed)
     elif "batch_width_mean" in owed:
         assert {"batch_width_mean", "server_request_ms",
                 "client_self_ms"} <= set(result["metrics"])
+    return result
 
 
-@pytest.mark.parametrize("cell", CELLS)
-def test_an_altered_token_is_not_correct(fixture_root, cell):
-    root, names = fixture_root
+def an_altered_token_is_not_correct(root, names, cell):
     faulty = os.path.join(root, "tests", "benchmark", "faulty_server.py")
     result = run.run_cell(root, names[cell], seed=5, seconds=SECONDS, trace=False,
                           require_tpu=False, server_command=[sys.executable, faulty])
     assert result["correct"] is False
     compared = result["compared"]
     assert compared["served_gap_max"]["value"] > compared["served_gap_max"]["limit"]
-    if "seq" in cell:
+    if run.resolve_cell(root, names[cell])["traffic"]["api"] == "sequence":
         assert compared["argmax_mismatch"]["value"] > 0
     assert result["failed"] == 0  # late or wrong is not failed
 
 
-@pytest.mark.parametrize("cell", CELLS)
-def test_calibration_judges_sound_seeds_and_the_control(fixture_root, cell):
+def calibration_judges_sound_seeds_and_the_control(root, names, cell):
     """``calibrate.py``'s loop: a seed's served tokens and, in their place,
-    the fp8 control's go through the run's own comparison and limit."""
-    root, names = fixture_root
+    the family's control's go through the run's own comparison and limits."""
     resolved = run.resolve_cell(root, names[cell])
     with run.Serving(resolved, 11, require_tpu=False) as serving:
         first, second = calibrate.read_seeds(
             serving, resolved, [11, 2**31 + 12], control_seeds=1, seconds=1.0)
-    limit = resolved["cell"]["limits"]["served_gap_max"]
+    limits = resolved["cell"]["limits"]
     for reading in (first, second):
         assert reading["correct"] is True and reading["failed"] == 0
-        assert reading["compared"]["served_gap_max"]["value"] <= limit
-    assert first["control_gap_max"] > limit and first["control_correct"] is False
+        assert set(limits) <= set(reading["compared"])
+        for name, limit in limits.items():
+            assert reading["compared"][name]["value"] <= limit
+    assert first["control_gap_max"] > limits["served_gap_max"]
+    assert first["control_correct"] is False
     assert "control_correct" not in second
+    return first, second
+
+
+@pytest.mark.parametrize("traced", [False, True], ids=["timed", "traced"])
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_at_fixture_size(fixture_root, cell, traced):
+    cell_at_fixture_size(*fixture_root, cell, traced)
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_an_altered_token_is_not_correct(fixture_root, cell):
+    an_altered_token_is_not_correct(*fixture_root, cell)
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_calibration_judges_sound_seeds_and_the_control(fixture_root, cell):
+    calibration_judges_sound_seeds_and_the_control(*fixture_root, cell)
+
+
+def test_two_cells_that_end_in_the_same_word_get_two_twins(tmp_path):
+    root = benchmark_fixture.copy_root(tmp_path)
+    home = os.path.join(root, "benchmark")
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    first = next(w for w in bench["workloads"] if w["name"] == "gpt2-large.seq16")
+    shutil.copy(os.path.join(home, "cells", "gpt2-large.seq16.json"),
+                os.path.join(home, "cells", "cerebras-gpt-1.3b.seq16.json"))
+    bench["workloads"].append(dict(first, name="cerebras-gpt-1.3b.seq16",
+                                   config="cerebras-gpt-1.3b"))
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(bench, f)
+    names = benchmark_fixture.add_fixtures(root)
+    assert len(set(names.values())) == len(names) == len(CELLS) + 1
+    twins = [run.resolve_cell(root, names[c])
+             for c in ("gpt2-large.seq16", "cerebras-gpt-1.3b.seq16")]
+    assert twins[0]["cell_path"] != twins[1]["cell_path"]
+    assert [t["entry"]["config"] for t in twins] == [
+        "tiny-gpt2-large", "tiny-cerebras-gpt-1.3b"]
+    # a family with no fixture of its own gets the GPT-2 family's and 0.01
+    assert all(t["config"] == benchmark_fixture.TINY_CONFIG
+               and t["cell"]["limits"] == {"served_gap_max": 0.01} for t in twins)
 
 
 def _digests(root):
@@ -118,7 +161,7 @@ def _gpt2_again(add):
         "def generate(config, seed, **args):\n"
         "    return builders.tiny_lm_generate(config, seed, **args)\n",
         "later_builders.py")
-    return ["configs/later.json", "later_builders.py"]
+    return ["configs/later.json", "later_builders.py"], {}, {}
 
 
 LATER_ARITHMETIC = """
@@ -208,7 +251,31 @@ def _another_family(add, control=True):
         add({"reader": name + ".py"}, "layer_metrics", name + ".json")
         add(f"def read(facts):\n    return {fact}\n", "layer_metrics", name + ".py")
         files += [f"layer_metrics/{name}.json", f"layer_metrics/{name}.py"]
-    return files
+    return files, {}, {}
+
+
+CUT_FAMILY = os.path.join(os.path.dirname(os.path.abspath(__file__)), "cut_family")
+
+
+def _cut_family(add):
+    """A family with none of the GPT-2 keys whose configuration is cut to a
+    chip's share (``reduced`` of three keys, ``published``, ``deployment``),
+    whose experts are stacked ``[experts, d, f]``, whose builder leaves
+    ``_params`` as shapes, whose arithmetic has a ``fixture`` and whose
+    reference returns a second reading, held by a limit in its cell file:
+    ``tests/benchmark/cut_family/``, copied in as a later PR's new files."""
+    files = []
+    for name in sorted(os.listdir(CUT_FAMILY)):
+        with open(os.path.join(CUT_FAMILY, name)) as f:
+            path = ("configs", name) if name.endswith(".json") else (name,)
+            add(f.read(), *path)
+        files.append("/".join(path))
+    with open(os.path.join(CUT_FAMILY, "later.json")) as f:
+        stated = json.load(f)
+    return (files,
+            {"builder": "benchmark.later_cut_builders:generate",
+             "limits": {"served_gap_max": 0.1, "near_tie_share": 0.25}},
+            {"source": stated["source"], "reduced": stated["reduced"]})
 
 
 def _later_pr(root, family, **options):
@@ -220,7 +287,7 @@ def _later_pr(root, family, **options):
         with open(os.path.join(home, *path), "w") as f:
             f.write(obj if isinstance(obj, str) else json.dumps(obj))
 
-    files = family(add, **options)
+    files, cell, entry = family(add, **options)
     add({"source": "fixture", "pool": 4,
          "prompt": {"mean": 4, "sigma": 0.3, "min": 2, "max": 8},
          "output": {"mean": 3, "sigma": 0.3, "min": 2, "max": 6}},
@@ -228,7 +295,7 @@ def _later_pr(root, family, **options):
     add({"api": "stream", "ramp_seconds": 0.3, "lengths": "short"},
         "traffic", "bursty.json")
     add({"builder": "benchmark.later_builders:generate", "args": {}, "users": 2,
-         "step_program": "jit_step", "limits": {"served_gap_max": 0.01}},
+         "step_program": "jit_step", "limits": {"served_gap_max": 0.01}, **cell},
         "cells", "later.bursty.json")
     add({"reader": "tokens_per_session.py"}, "layer_metrics", "tokens_per_session.json")
     add("def read(facts):\n"
@@ -238,7 +305,8 @@ def _later_pr(root, family, **options):
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         bench = json.load(f)
     bench["configs"].append({"name": "later", "source": "fixture", "reduced": [],
-                             "why": "fixture", "file": "benchmark/configs/later.json"})
+                             "why": "fixture", "file": "benchmark/configs/later.json",
+                             **entry})
     bench["workloads"].append({"name": "later.bursty", "config": "later",
                                "traffic": "bursty", "chips": 1, "why": "fixture"})
     readers = ["tokens_per_session"] + [
@@ -265,21 +333,72 @@ def forget_later_modules():
         del sys.modules[name]
 
 
-@pytest.mark.parametrize("family", [_gpt2_again, _another_family],
-                         ids=["gpt2_again", "another_family"])
+def _through_the_benchmarks_own_checks(root, before, added):
+    """The later PR's cut configuration and its cell, held to what every
+    configuration and cell of ``BENCHMARK.json`` is held to: the fixtures are
+    made after its entries are there, so its cell gets its twin, of its own
+    family's fixture, like any other."""
+    import jax
+
+    from benchmark import builders
+
+    names = benchmark_fixture.add_fixtures(root)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    entry = next(c for c in bench["configs"] if c["name"] == "later")
+    assert len(entry["reduced"]) == 3
+    benchmark_fixture.check_configuration(root, bench, entry)
+    real = benchmark_fixture.check_cell_resolves(root, "later.bursty")
+    assert not {"n_embd", "n_head", "n_layer", "n_positions"} & set(real["config"])
+    twin = run.resolve_cell(root, names["later.bursty"])
+    limits = {"served_gap_max": 0.003, "near_tie_share": 0.3}
+    assert twin["config"]["hidden_size"] == 32 and twin["cell"]["limits"] == limits
+    assert twin["config"]["reference"] == real["config"]["reference"]
+    # its builder hands over shapes, stacked experts among them
+    _, decoder = builders.resolve(twin["cell"]["builder"])(twin["config"], 0)
+    decoder._ensure_built()
+    leaves = jax.tree_util.tree_leaves(decoder._params)
+    assert all(isinstance(leaf, jax.ShapeDtypeStruct) for leaf in leaves)
+    assert decoder._params["layers"][1]["experts_in"].shape == (4, 32, 32)
+
+    for traced in (False, True):
+        result = cell_at_fixture_size(root, names, "later.bursty", traced)
+        near = result["compared"]["near_tie_share"]
+        assert near["limit"] == 0.3 and 0.0 <= near["value"] <= 0.3
+        assert result["compared"]["served_gap_max"]["limit"] == 0.003
+        assert list(result)[-1] == "compared"
+    an_altered_token_is_not_correct(root, names, "later.bursty")
+    first, _ = calibration_judges_sound_seeds_and_the_control(
+        root, names, "later.bursty")
+    assert first["compared"]["near_tie_share"]["limit"] == 0.3
+
+    after = _digests(root)
+    assert [p for p in before if p != "BENCHMARK.json" and after[p] != before[p]] == []
+    assert set(added) <= set(after) - set(before)
+
+
+@pytest.mark.parametrize("family", [_gpt2_again, _another_family, _cut_family],
+                         ids=["gpt2_again", "another_family", "cut_family"])
 def test_a_later_pr_adds_files_and_edits_none(tmp_path, monkeypatch, family,
                                               forget_later_modules):
     """A configuration, a mix with its lengths, a builder, a per-layer metric
     and a cell, as new files and new entries, run by the harness as it
-    stands: once of the GPT-2 family again, and once of a family that brings
-    its own arithmetic, reference, builder and weight scales, under a
-    configuration with none of the GPT-2 keys."""
-    root, _ = benchmark_fixture.make_root(tmp_path)
+    stands: once of the GPT-2 family again, once of a family that brings its
+    own arithmetic, reference, builder and weight scales under a
+    configuration with none of the GPT-2 keys, and once of a family whose
+    configuration is cut, taken through the benchmark's own parametrised
+    checks at its own fixture."""
+    root = benchmark_fixture.copy_root(tmp_path)
+    if family is not _cut_family:
+        benchmark_fixture.add_fixtures(root)
     before = _digests(root)
     added = _later_pr(root, family)
     # the users' process finds the later PR's modules where the command
     # would: under the root it runs from
     monkeypatch.setattr("benchmark.__path__", [os.path.join(root, "benchmark")])
+    if family is _cut_family:
+        _through_the_benchmarks_own_checks(root, before, added)
+        return
 
     result = run.run_cell(root, "later.bursty", seed=3, seconds=SECONDS,
                           trace=True, require_tpu=False)
