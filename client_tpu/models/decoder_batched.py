@@ -10,20 +10,35 @@ regime batching exists for, since an [S, ...] step costs barely more than
 a [1, ...] step until S fills the MXU tile.
 
 ``decoder_lm_batched`` is the TPU-first version: per-slot KV caches live
-stacked on device ([slots, heads, max_len, head_dim] per layer), a
-coalescer thread gathers whatever sequence requests are in flight inside a
-~2 ms window, and ONE jitted batched step (``jax.vmap`` of the decoder's
-single-sequence step — the identical math, so tokens are bit-comparable)
-advances them all. The step owns the stacked caches (they are donated to
-it) and writes one [heads, 1, head_dim] row a layer for every active slot,
-in place. Slots whose sequence has no pending request this round ride along
-masked: the step writes no row of theirs (``active``, decoder.py's
-``write_active_rows``) and their logits are dropped, which keeps the
-executable static-shape — the same compile-once property the
-single-sequence decoder has. Prompts longer than
-one token naturally lockstep: each coalescer round consumes the next token
-of every gathered request, so two sequences prefilling together share
-every dispatch.
+stacked on device ([slots, heads, max_len, head_dim] per layer), and ONE
+jitted batched step (``jax.vmap`` of the decoder's single-sequence step —
+the identical math, so tokens are bit-comparable) advances every sequence
+that has a request in progress. The step owns the stacked caches (they are
+donated to it) and writes one [heads, 1, head_dim] row a layer for every
+active slot, in place.
+
+**The unit of scheduling is the round**, one dispatch of that step. The
+worker keeps one table of the requests in progress, one a sequence. Before
+each dispatch it takes whatever has arrived into the table
+(``sequence_start`` takes a slot there; a second request of a sequence
+that has one in the table is carried, FIFO, until that one has left), and
+the round consumes the next token of every request of the table: a
+one-token request is in the table for one round, a prompt for one round a
+token, and two prompts in progress share every dispatch. A request leaves
+the table with the round that consumes its last token. A round's logits
+come to the host once, all rows in one transfer, and every request it
+answers gets its row as a view; ``sequence_end`` frees its slot there. The
+answers are handed out as soon as the next round, if there is one to send,
+has been dispatched: a request waits for no other request's rounds to run,
+only for that one dispatch call, which the woken callers would otherwise
+slow while the device stands idle. At most ``ROUNDS_IN_FLIGHT`` rounds are
+dispatched and not yet read back, so a request waits for at most that many
+rounds before its own, however long a prompt beside it is; while the device
+works, the round in flight is the gather. A slot whose sequence has no
+request in the table is masked: the step writes no row of its
+(``active``, decoder.py's ``write_active_rows``) and its logits row goes
+unread, which keeps the executable static-shape — the same compile-once
+property the single-sequence decoder has.
 
 Weights come from a composed TinyDecoderModel (same seed ⇒ greedy tokens
 match the unbatched fixture token-for-token — pinned by the tests).
@@ -31,12 +46,13 @@ match the unbatched fixture token-for-token — pinned by the tests).
 
 from __future__ import annotations
 
+import collections
 import queue
 import threading
 import time
 from concurrent.futures import Future, InvalidStateError
 from concurrent.futures import TimeoutError as FuturesTimeout
-from typing import Any, Dict, List
+from typing import Any, Deque, Dict, List, Tuple
 
 import numpy as np
 
@@ -54,15 +70,25 @@ from ..server.timeline import (
 from .base import Model, TensorSpec
 from .decoder import TinyDecoderModel
 
+# Rounds dispatched and not yet read back, at most. It is the fairness rule:
+# a worker that ran ahead of the device would enqueue a prompt's every round
+# at once, and each decode request would join behind them all. Chosen on the
+# chip (PERF.md section 6, PR 29): with 1 the device waits for the host's
+# turn between two rounds; with 2 it never waits, and every request waits for
+# the round in flight and the one dispatched behind it, which costs more.
+ROUNDS_IN_FLIGHT = 1
+
 
 class _SeqRequest:
-    __slots__ = ("seq_id", "tokens", "start", "end", "future", "marks")
+    __slots__ = ("seq_id", "tokens", "start", "end", "future", "marks",
+                 "rounds_before")
 
-    def __init__(self, seq_id, tokens, start, end):
+    def __init__(self, seq_id, tokens, start, end, rounds_before):
         self.seq_id = seq_id
         self.tokens = tokens  # list of ints, consumed one per round
         self.start = start
         self.end = end
+        self.rounds_before = rounds_before  # rounds dispatched when it came
         self.future: Future = Future()
         # the request's way through the batcher (server/timeline.py); the
         # dispatch marks are host times and run ahead of the device
@@ -71,7 +97,7 @@ class _SeqRequest:
     # The caller may cancel() the future (120s timeout) at any moment —
     # set_result/set_exception on a cancelled future raises
     # InvalidStateError, and an unguarded raise inside the worker's
-    # resolution loop would strand every later request in the window.
+    # resolution loop would strand every later request of the round.
     def resolve(self, value) -> None:
         try:
             if not self.future.done():
@@ -96,24 +122,32 @@ class BatchedDecoderModel(Model):
     stateful = True
 
     def __init__(self, seed: int = 0, slots: int = 8,
-                 max_delay_s: float = 0.002, attention_impl: str = "einsum",
-                 idle_ttl_s: float = 300.0):
+                 attention_impl: str = "einsum", idle_ttl_s: float = 300.0):
         super().__init__()
         self._decoder = TinyDecoderModel(seed=seed,
                                          attention_impl=attention_impl)
         self.slots = int(slots)
-        self._max_delay_s = max_delay_s
         # Idle-sequence reaper TTL (reference semantics:
         # max_sequence_idle_microseconds in tritonserver's sequence
         # batcher). Must exceed the 120 s caller timeout so a slot whose
-        # window is merely slow is never reclaimed under an in-flight step.
+        # round is merely slow is never reclaimed under an in-flight step.
         self._idle_ttl_s = float(idle_ttl_s)
         self._last_seen: Dict[Any, float] = {}
         self._lock = threading.Lock()
         self._built = False
         self._queue: "queue.Queue[_SeqRequest]" = queue.Queue(maxsize=1024)
         self._closed = False
+        self._taking = True  # the worker takes requests off the queue
+        # the worker's own: the requests in progress, one a sequence, with
+        # their slots; later requests of those sequences, FIFO; the rounds
+        # dispatched and not yet read back, oldest first: ``(round id,
+        # logits on the device, the requests it answers)``; and the answers
+        # read back and not yet handed out: ``(request, row, round id)``
+        self._table: Dict[Any, Tuple[_SeqRequest, int]] = {}
         self._carry: List[_SeqRequest] = []
+        self._in_flight: Deque[
+            Tuple[int, Any, List[Tuple[_SeqRequest, int]]]] = collections.deque()
+        self._answers: List[Tuple[_SeqRequest, np.ndarray, int]] = []
         # observability for tests/tuning: rounds executed per batch width
         self.batch_histogram: Dict[int, int] = {}
         self._rounds = 0  # rounds dispatched so far: the next round's id
@@ -153,9 +187,8 @@ class BatchedDecoderModel(Model):
             # positions live HOST-side (0 on start, +1 per active token —
             # fully derivable without a device readback) and ship to the
             # device each round alongside the token vector; carrying them
-            # on-device would cost a blocking readback per request in
-            # _run_window, the exact per-dispatch cost the batcher
-            # amortizes
+            # on-device would cost a blocking readback per request, the
+            # exact per-dispatch cost the batcher amortizes
             self._pos = np.zeros((S,), np.int32)
             self._slot_of: Dict[Any, int] = {}
             self._free = list(range(S))
@@ -193,7 +226,8 @@ class BatchedDecoderModel(Model):
             raise ValueError("continuation requests carry exactly one token")
         if self._closed:
             raise ValueError("model is shutting down")
-        req = _SeqRequest(seq_id, [int(t) for t in tokens], start, end)
+        req = _SeqRequest(seq_id, [int(t) for t in tokens], start, end,
+                          self._rounds)
         timeline = current()
         if timeline is not None:
             timeline.batch = req.marks
@@ -216,23 +250,21 @@ class BatchedDecoderModel(Model):
             req.fail(ValueError("model is shutting down"))
         try:
             with span(SPAN_WAIT_RESULT):
-                logits = req.future.result(timeout=120)
+                # the request's row of its last round's logits, on the host
+                row = req.future.result(timeout=120)
         except FuturesTimeout:
             # the worker is wedged or the dispatch is pathologically slow;
             # the caller is gone either way, so surface a gateway-timeout
             # rather than an untyped 500. The slot is NOT freed here — the
-            # window may still be in flight and a new sequence claiming the
-            # slot would share its cache; the window's own error path (or
-            # sequence_end) reclaims it.
+            # request may still be in the table and a new sequence claiming
+            # the slot would share its cache; the round's own error path
+            # (or sequence_end) reclaims it.
             req.future.cancel()
             from ..server.core import InferError
 
             raise InferError(
                 "batched decode timed out after 120s", 504) from None
-        with span(SPAN_BATCH_READBACK) as readback:
-            logits_np = np.asarray(logits, dtype=np.float32).reshape(
-                1, self._decoder.VOCAB)
-        req.marks.on_host = readback.end_ns
+        logits_np = row.reshape(1, self._decoder.VOCAB)
         return {
             "LOGITS": logits_np,
             "NEXT_TOKEN": np.array([[int(logits_np.argmax())]], dtype=np.int32),
@@ -259,50 +291,103 @@ class BatchedDecoderModel(Model):
                 req.fail(ValueError("model is shutting down"))
         super().unload()
 
-    # -- coalescer worker ----------------------------------------------------
-    def _collect(self) -> List[_SeqRequest]:
-        """One window: at most one request per sequence (two requests on a
-        sequence must observe each other's cache updates, so the second
-        waits for the next round — the reference sequence batcher
-        serializes per CORRID the same way)."""
-        window, seen, still_carried = [], set(), []
-
-        def take(req: _SeqRequest) -> None:
-            window.append(req)
-            seen.add(req.seq_id)
-            req.marks.collected = time.perf_counter_ns()
-
-        for req in self._carry:
-            if req.seq_id in seen:
-                still_carried.append(req)  # FIFO within a sequence
-            else:
-                take(req)
-        self._carry = still_carried
-        if not window:
-            first = self._queue.get()
-            if first is None:
-                return []
-            take(first)
-        deadline = time.monotonic() + self._max_delay_s
-        while len(window) < self.slots:
-            remaining = deadline - time.monotonic()
-            if remaining <= 0:
-                break
+    # -- the worker: one turn a round ----------------------------------------
+    def _run(self) -> None:
+        # until unload's sentinel comes off the queue; then what is begun
+        # is ended
+        while (self._taking or self._table or self._carry or self._in_flight
+               or self._answers):
             try:
-                nxt = self._queue.get(timeout=remaining)
-            except queue.Empty:
-                break
-            if nxt is None:
-                self._queue.put(None)
-                break
-            if nxt.seq_id in seen:
-                # serialize per CORRID but KEEP collecting: a fast client's
+                self._turn()
+            except Exception as e:  # the worker thread must NEVER die — a
+                # dead coalescer wedges every future request on the model
+                self._abandon(e)
+
+    def _turn(self) -> None:
+        """Take what has arrived into the table, dispatch one round over the
+        table, hand out the answers of the round read back before it, and
+        read back as many rounds as the bound asks for.
+
+        The dispatch comes before the answers because every answer wakes a
+        caller's thread, and those threads would take the interpreter from
+        the dispatch while the device stands idle: answers first read a gap
+        of 42.6 ms on the chip, dispatch first 34.6 (PERF.md section 6,
+        PR 29). An answer waits for that one dispatch call, never for a
+        round to run."""
+        with span(SPAN_COLLECT):
+            arrivals = self._arrivals()
+        if arrivals:
+            with span(SPAN_ADMIT):
+                self._admit_arrivals(arrivals)
+        if self._table:
+            self._dispatch_round()
+        self._answer()
+        # with nothing to dispatch there is nothing to wait with either
+        while self._in_flight and (len(self._in_flight) >= ROUNDS_IN_FLIGHT
+                                   or not self._table):
+            self._read_back()
+
+    def _arrivals(self) -> List[_SeqRequest]:
+        """The requests that join the next round: at most one a sequence,
+        and none of a sequence that has a request in the table (two requests
+        on a sequence must observe each other's cache updates, so the second
+        waits its turn — the reference sequence batcher serializes per CORRID
+        the same way). The carried requests come first, then everything on
+        the queue; with nothing to run and no answer to hand out it waits
+        there for a request."""
+        arrivals: List[_SeqRequest] = []
+        carried, self._carry = self._carry, []
+        busy = set(self._table)
+
+        def sort(req: _SeqRequest) -> None:
+            if req.seq_id in busy:
+                # serialize per CORRID but KEEP taking: a fast client's
                 # back-to-back request must not shut other sequences out of
                 # this round
-                self._carry.append(nxt)
+                self._carry.append(req)  # FIFO within a sequence
+            else:
+                busy.add(req.seq_id)
+                arrivals.append(req)
+
+        for req in carried:
+            sort(req)
+        while self._taking:
+            try:
+                req = self._queue.get(
+                    block=not (arrivals or self._table or self._answers))
+            except queue.Empty:
+                break
+            if req is None:
+                self._taking = False
+            else:
+                sort(req)
+        return arrivals
+
+    def _admit_arrivals(self, arrivals: List[_SeqRequest]) -> None:
+        """Into the table with its slot goes each arrival that has one and
+        room in its cache; the others are failed here."""
+        # reap BEFORE admitting so a full house of abandoned sequences
+        # frees up for these sequence_start requests
+        self._reap_idle(exclude={req.seq_id for req in arrivals})
+        for req in arrivals:
+            try:
+                slot = self._admit(req)
+            except Exception as e:
+                req.fail(e)
                 continue
-            take(nxt)
-        return window
+            if req.start:
+                # zero pos; cache rows are fully overwritten as the
+                # prompt streams in, and masked reads never see slots
+                # beyond pos, so stale cache content is harmless
+                self._pos[slot] = 0
+            if int(self._pos[slot]) + len(req.tokens) > self._decoder.MAX_LEN:
+                req.fail(ValueError(
+                    f"sequence longer than max_len {self._decoder.MAX_LEN}"))
+                with self._lock:
+                    self._free_slot(req.seq_id)
+                continue
+            req.marks.collected = time.perf_counter_ns()
+            self._table[req.seq_id] = (req, slot)
 
     def _admit(self, req: _SeqRequest) -> int:
         """Resolve the request to a slot (allocating on sequence_start)."""
@@ -333,134 +418,129 @@ class BatchedDecoderModel(Model):
         Covers the 120 s-timeout abandonment path: a client that times out
         mid-sequence and walks away would otherwise hold one of ``slots``
         forever (only a same-id restart or unload reclaimed it). Sequences
-        with a request in the current window or carried for the next round
-        are excluded — they are active by definition.
+        with a request arriving, in the table or carried are excluded —
+        they are active by definition.
         """
+        busy = set(exclude) | set(self._table)
+        busy.update(req.seq_id for req in self._carry)
         now = time.monotonic()
         with self._lock:
             for seq_id, last in list(self._last_seen.items()):
-                if seq_id in exclude:
+                if seq_id in busy:
                     continue
                 if now - last > self._idle_ttl_s:
                     self._free_slot(seq_id)
 
-    def _run(self) -> None:
-        while True:
-            with span(SPAN_COLLECT):
-                window = self._collect()
-            if not window:
-                return
-            try:
-                self._run_window(window)
-            except Exception as e:  # the worker thread must NEVER die — a
-                # dead coalescer wedges every future request on the model
-                for req in window:
-                    req.fail(e)
-
-    def _run_window(self, window: List[_SeqRequest]) -> None:
-        import jax
-        import jax.numpy as jnp
-
-        with span(SPAN_ADMIT):
-            active_reqs = self._admit_window(window)
-
-        # lockstep rounds: each round consumes ONE token from every
-        # request that still has tokens left (prompts prefill together)
-        dec = self._decoder
-        last_logits: Dict[int, Any] = {}
-        first_round = self._rounds
+    def _dispatch_round(self) -> None:
+        """One round: the next token of every request of the table, in one
+        dispatch. A request whose tokens are spent leaves the table for the
+        round's record, to be answered when the round is read back."""
+        members = list(self._table.values())
+        with span(SPAN_ROUND_PREPARE):
+            tokens = np.zeros((self.slots,), np.int32)
+            active = np.zeros((self.slots,), bool)
+            for req, slot in members:
+                tokens[slot] = req.tokens.pop(0)
+                active[slot] = True
+            # snapshot pos: the step's device_put may alias the host buffer
+            # (CPU zero-copy) or read it after dispatch returns
+            # (ImmutableUntilTransferCompletes), so handing JAX
+            # self._pos itself and then mutating it in place races
+            # the in-flight step — the round-3 nondeterminism. The three
+            # go to the step as they are: it puts them on the device
+            # inside its one call, where three calls of jnp.asarray
+            # each let go of the interpreter on the way
+            pos = self._pos.copy()
         try:
-            while any(req.tokens for req, _ in active_reqs):
-                with span(SPAN_ROUND_PREPARE):
-                    tokens = np.zeros((self.slots,), np.int32)
-                    active = np.zeros((self.slots,), bool)
-                    for req, slot in active_reqs:
-                        if req.tokens:
-                            tokens[slot] = req.tokens.pop(0)
-                            active[slot] = True
-                    # snapshot pos: device_put may alias the host buffer
-                    # (CPU zero-copy) or read it after dispatch returns
-                    # (ImmutableUntilTransferCompletes), so handing JAX
-                    # self._pos itself and then mutating it in place races
-                    # the in-flight step — the round-3 nondeterminism
-                    on_device = (jnp.asarray(tokens),
-                                 jnp.asarray(self._pos.copy()),
-                                 jnp.asarray(active))
-                with span(SPAN_ROUND_DISPATCH) as dispatch:
-                    logits, self._caches = self._batched_step(
-                        dec._params, self._caches, *on_device)
-                self._pos[active] += 1
-                width = int(active.sum())
-                self.batch_histogram[width] = (
-                    self.batch_histogram.get(width, 0) + 1)
-                if self.report_batch is not None:
-                    self.report_batch(width, dispatch.ns)
-                for req, slot in active_reqs:
-                    if active[slot]:
-                        last_logits[slot] = logits[slot]
-                        req.marks.round(dispatch, self._rounds, width,
-                                        last=not req.tokens)
-                self._rounds += 1
+            with span(SPAN_ROUND_DISPATCH) as dispatch:
+                logits, self._caches = self._batched_step(
+                    self._decoder._params, self._caches, tokens, pos, active)
         except Exception as e:  # a failed dispatch must not strand callers
-            for req, _ in active_reqs:
-                req.fail(e)
-            with self._lock:
-                # a failed step ends the sequence regardless of req.end:
-                # the client has no valid continuation state (the cache may
-                # be partially updated), and keeping the slot would leak
-                # capacity one failed window at a time
-                ended = [req.seq_id for req, _ in active_reqs]
-                if any(leaf.is_deleted() for leaf in
-                       jax.tree_util.tree_leaves(self._caches)):
-                    # the step had taken every slot's cache with it: all
-                    # live sequences end, and the next window starts clean
-                    self._caches = self._fresh_caches()
-                    ended = list(self._slot_of)
-                for seq_id in ended:
-                    self._free_slot(seq_id)
+            self._table.clear()
+            self._fail_round(e, members)
             return
+        self._pos[active] += 1
+        width = len(members)
+        self.batch_histogram[width] = self.batch_histogram.get(width, 0) + 1
+        if self.report_batch is not None:
+            self.report_batch(width, dispatch.ns)
+        answered = []
+        for req, slot in members:
+            if not req.marks.rounds_own:
+                req.marks.rounds_waited = self._rounds - req.rounds_before
+            req.marks.round(dispatch, self._rounds, width, last=not req.tokens)
+            if not req.tokens:
+                del self._table[req.seq_id]
+                answered.append((req, slot))
+        if answered:
+            # the transfer begins when the step ends, with no host thread
+            # having to be scheduled in between
+            logits.copy_to_host_async()
+        self._in_flight.append((self._rounds, logits, answered))
+        self._rounds += 1
 
-        for req, slot in active_reqs:
+    def _read_back(self) -> None:
+        """The oldest round in flight: wait for it and bring its logits to
+        the host in one transfer; each request that ended with it has its
+        row, a view, from here on, and ``sequence_end`` gives its slot up
+        here, so that the next round's arrivals find it. A round that
+        answers nobody (prompts midway) is waited for all the same, which
+        is what holds the bound."""
+        round_id, logits, answered = self._in_flight[0]
+        with span(SPAN_BATCH_READBACK) as readback:
+            if answered:
+                rows = np.asarray(logits)
+            else:
+                logits.block_until_ready()
+        self._in_flight.popleft()
+        for req, slot in answered:
             if req.end:
                 with self._lock:
                     self._free_slot(req.seq_id)
-            req.marks.rounds_window = self._rounds - first_round
-            if slot in last_logits:
-                req.marks.resolved = time.perf_counter_ns()
-                req.resolve(last_logits[slot])
-            else:
-                req.fail(ValueError("request executed no decode step"))
+            req.marks.on_host = readback.end_ns
+            self._answers.append((req, rows[slot], round_id))
 
-    def _admit_window(self, window: List[_SeqRequest]) -> List[tuple]:
-        """``(request, slot)`` of the window's requests that have a slot and
-        room in its cache; the others are failed here."""
-        # reap BEFORE admitting so a full house of abandoned sequences
-        # frees up for this window's sequence_start requests
-        self._reap_idle(
-            exclude={req.seq_id for req in window}
-            | {r.seq_id for r in self._carry})
+    def _answer(self) -> None:
+        """Hand out the answers that are on the host."""
+        answers, self._answers = self._answers, []
+        for req, row, round_id in answers:
+            req.marks.rounds_held = self._rounds - round_id - 1
+            req.marks.resolved = time.perf_counter_ns()
+            req.resolve(row)
 
-        active_reqs: List[tuple] = []
-        for req in window:
-            try:
-                slot = self._admit(req)
-            except Exception as e:
-                req.fail(e)
-                continue
-            if req.start:
-                # zero pos; cache rows are fully overwritten as the
-                # prompt streams in, and masked reads never see slots
-                # beyond pos, so stale cache content is harmless
-                self._pos[slot] = 0
-            pos_here = int(self._pos[slot])
-            if pos_here + len(req.tokens) > self._decoder.MAX_LEN:
-                req.fail(ValueError(
-                    f"sequence longer than max_len {self._decoder.MAX_LEN}"))
-                with self._lock:
-                    self._free_slot(req.seq_id)
-                continue
-            active_reqs.append((req, slot))
-        return active_reqs
+    def _fail_round(self, exc: BaseException,
+                    members: List[Tuple[_SeqRequest, int]],
+                    caches_lost: bool = False) -> None:
+        """A step that failed fails the requests of its round, and ends
+        their sequences regardless of req.end: the client has no valid
+        continuation state (the cache may be partially updated), and keeping
+        the slot would leak capacity one failed round at a time."""
+        import jax
+
+        for req, _ in members:
+            req.fail(exc)
+        with self._lock:
+            ended = [req.seq_id for req, _ in members]
+            if caches_lost or any(leaf.is_deleted() for leaf in
+                                  jax.tree_util.tree_leaves(self._caches)):
+                # the step had taken every slot's cache with it: all
+                # live sequences end, and the next round starts clean
+                self._caches = self._fresh_caches()
+                ended = list(self._slot_of)
+            for seq_id in ended:
+                self._free_slot(seq_id)
+
+    def _abandon(self, exc: BaseException) -> None:
+        """A turn failed outside a dispatch (a round's logits that cannot be
+        read are a step that failed on the device, and every later round
+        was fed its caches): every request begun fails with its round."""
+        self._answer()  # what is on the host already is sound
+        begun = list(self._table.values())
+        for _, _, answered in self._in_flight:
+            begun.extend(answered)
+        self._table.clear()
+        self._in_flight.clear()
+        self._fail_round(exc, begun, caches_lost=True)
 
     def _free_slot(self, seq_id) -> None:
         slot = self._slot_of.pop(seq_id, None)

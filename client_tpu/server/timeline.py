@@ -16,7 +16,7 @@ many steps as the runtime's queue takes (a step writes its donated cache in
 place and allocates no other, so no call waits for room). ``enqueued`` and
 ``on_host`` are exact:
 the first precedes any device work of the request, the second follows the
-copy of its result to the host.
+copy of its last round's logits to the host.
 
 :func:`span` is the one helper both come from: a
 ``jax.profiler.TraceAnnotation`` of a fixed name, which lands on the host
@@ -120,24 +120,32 @@ class Interval:
 
 class BatchMarks:
     """A request's way through a batcher. ``enqueued``: put on the batcher's
-    queue; ``collected``: taken into a window; ``first_dispatch``: start of
-    the dispatch of the first round that carries one of its tokens;
-    ``last_dispatch``: return of the dispatch of the round that consumed its
-    last token; ``resolved``: its future set, after the window's last round;
-    ``on_host``: its result copied to the host in the caller's thread."""
+    queue; ``collected``: taken into the table of requests in progress;
+    ``first_dispatch``: start of the dispatch of the first round that carries
+    one of its tokens; ``last_dispatch``: return of the dispatch of the round
+    that consumed its last token; ``on_host``: that round's logits on the
+    host, one transfer for all its rows, in the batcher's thread;
+    ``resolved``: its future set, after its own last round's logits reached
+    the host and the next round, if there was one to send, was dispatched.
+    Counted: ``rounds_own``, the rounds that carried a token of its;
+    ``rounds_waited``, the rounds dispatched between ``enqueued`` and
+    ``first_dispatch``; ``rounds_held``, the rounds dispatched between its
+    last round and ``resolved``; either is at most the batcher's bound on
+    rounds in flight."""
 
     __slots__ = ("enqueued", "collected", "first_dispatch", "last_dispatch",
-                 "resolved", "on_host", "rounds_own", "rounds_window",
-                 "first_round_id", "round_widths")
+                 "on_host", "resolved", "rounds_own", "rounds_waited",
+                 "rounds_held", "first_round_id", "round_widths")
     MARKS = ("enqueued", "collected", "first_dispatch", "last_dispatch",
-             "resolved", "on_host")
+             "on_host", "resolved")
 
     def __init__(self):
         self.enqueued = self.collected = None
         self.first_dispatch = self.last_dispatch = None
-        self.resolved = self.on_host = None
+        self.on_host = self.resolved = None
         self.rounds_own = 0
-        self.rounds_window = 0
+        self.rounds_waited = 0
+        self.rounds_held = 0
         self.first_round_id: Optional[int] = None
         self.round_widths: List[int] = []
 
@@ -216,9 +224,9 @@ class Timeline:
         Triton's four, which add up to ``done - recv``.
 
         A batched request: ``recv`` to ``enqueued``; to ``first_dispatch``;
-        its own rounds, to ``last_dispatch``; the rest: held for the
-        window-mates' rounds, the device's backlog, the result's copy to the
-        host, the response. A stream: ``recv`` to ``cache_ready``; no queue;
+        its own rounds, to ``last_dispatch``; the rest: its last round on
+        the device, that round's transfer to the host, the next round's
+        dispatch, the response. A stream: ``recv`` to ``cache_ready``; no queue;
         the time inside the generator; the time suspended at ``yield``. Any
         other: ``recv`` to ``model_enter``; no queue; the model's
         ``execute``; the response."""
@@ -251,7 +259,8 @@ class Timeline:
                                "compiled_ns": self.compiled_ns}
         if self.batch is not None:
             b = self.batch
-            out.update(rounds_own=b.rounds_own, rounds_window=b.rounds_window,
+            out.update(rounds_own=b.rounds_own, rounds_waited=b.rounds_waited,
+                       rounds_held=b.rounds_held,
                        first_round_id=b.first_round_id,
                        round_widths=list(b.round_widths))
         if self.stream is not None:

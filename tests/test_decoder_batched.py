@@ -14,8 +14,14 @@ import time
 import numpy as np
 import pytest
 
+from client_tpu.models import decoder_batched
 from client_tpu.models.decoder import TinyDecoderModel
-from client_tpu.models.decoder_batched import BatchedDecoderModel
+from client_tpu.models.decoder_batched import (
+    ROUNDS_IN_FLIGHT,
+    BatchedDecoderModel,
+)
+from client_tpu.server import ServerCore
+from tests.conftest import GatedStep
 
 
 def _drive(model, seq, prompt, n=6, jitter=None):
@@ -62,7 +68,8 @@ def test_concurrent_sequences_match_unbatched():
 
 
 def test_stress_window_composition_invariance():
-    """Invariant: window composition never changes any sequence's tokens.
+    """Invariant: what else a round carries never changes any sequence's
+    tokens.
 
     20 seeded iterations of randomly-timed concurrent clients — including
     mid-flight restarts, the round-3 flake's second repro — against one
@@ -70,7 +77,7 @@ def test_stress_window_composition_invariance():
     decoder's every time. Guards the round-3 nondeterminism (in-place
     mutation of the host pos buffer racing the async dispatch)."""
     ref = TinyDecoderModel(seed=0)
-    bat = BatchedDecoderModel(seed=0, slots=4, max_delay_s=0.004)
+    bat = BatchedDecoderModel(seed=0, slots=4)
     pool = [[1, 2, 3], [9, 8, 7, 6], [42], [5, 6], [77, 1], [3]]
     expected = {}
 
@@ -288,3 +295,245 @@ def test_served_over_grpc_sequence_api():
     assert bat.live_sequences() == 0
     assert any(width > 1 for width in bat.batch_histogram), (
         "3 concurrent wire sessions never shared a dispatch")
+
+
+# -- the policy: scheduling by the round. Counts and orderings only. ---------
+
+def _traced(model):
+    core = ServerCore([model])
+    core.trace_settings.update(trace_level=["TIMESTAMPS"], trace_rate="1")
+    return core
+
+
+def _request(tokens, request_id, **parameters):
+    return {"id": request_id, "parameters": parameters, "inputs": [{
+        "name": "TOKENS", "datatype": "INT32", "shape": [1, len(tokens)],
+        "array": np.array([tokens], np.int32)}]}
+
+
+class _Callers:
+    """Requests sent from threads of their own; ``join`` hands back each
+    one's answer, or the error it raised, by request id."""
+
+    def __init__(self, core):
+        self._core, self._threads, self.answers = core, [], {}
+
+    def send(self, tokens, request_id, **parameters):
+        def call():
+            try:
+                self.answers[request_id] = self._core.infer(
+                    "decoder_lm_batched", "",
+                    _request(tokens, request_id, **parameters))
+            except Exception as e:
+                self.answers[request_id] = e
+
+        self._threads.append(threading.Thread(target=call))
+        self._threads[-1].start()
+
+    def join(self):
+        for t in self._threads:
+            t.join(timeout=120)
+        assert not any(t.is_alive() for t in self._threads)
+        return self.answers
+
+
+def _records(core):
+    return {r["request_id"]: r for r in core.recent_traces(1000)}
+
+
+def test_a_request_joins_a_round_while_a_prompt_is_in_progress():
+    model = BatchedDecoderModel(seed=0, slots=2)
+    core, gate = _traced(model), GatedStep(model)
+    callers = _Callers(core)
+    try:
+        callers.send([1, 2, 3, 4, 5, 6], "prompt", sequence_id=1,
+                     sequence_start=True)
+        gate.at(0)  # round 0, the prompt's first token
+        callers.send([7], "single", sequence_id=2, sequence_start=True)
+        gate.queued(1)
+        gate.let(6)
+        answers = callers.join()
+    finally:
+        gate.let(100)
+        model.unload()
+    assert not any(isinstance(a, Exception) for a in answers.values()), answers
+    single, prompt = (_records(core)[name] for name in ("single", "prompt"))
+    counts = single["counts"]
+    assert counts["rounds_waited"] <= ROUNDS_IN_FLIGHT
+    # it waited for no round to run: only for the next one's dispatch call
+    assert counts["rounds_own"] == 1
+    assert counts["rounds_held"] <= ROUNDS_IN_FLIGHT
+    assert prompt["counts"]["rounds_held"] == 0
+    assert counts["round_widths"] == [2]  # it shared its round with the prompt
+    # it joined, and was answered, before the prompt's last round was sent
+    assert single["first_round_id"] == 1 < prompt["first_round_id"] + 5
+    assert prompt["counts"]["rounds_own"] == 6
+    assert (single["timestamps"]["resolved"]
+            < prompt["timestamps"]["last_dispatch"])
+    assert gate.calls == 6  # the single request cost no round of its own
+
+
+def test_rounds_dispatched_ahead_of_the_device_are_bounded():
+    """A prompt's rounds are not enqueued at once: never are more than
+    ``ROUNDS_IN_FLIGHT`` dispatched beyond the last one read back."""
+    model = BatchedDecoderModel(seed=0, slots=2)
+    model._ensure_built()
+    step, read_back = model._batched_step, model._read_back
+    dispatched, back, ahead = [], [], []
+
+    def counted_step(*args):
+        dispatched.append(1)
+        ahead.append(len(dispatched) - len(back))
+        return step(*args)
+
+    def counted_read_back():
+        read_back()
+        back.append(1)
+
+    model._batched_step, model._read_back = counted_step, counted_read_back
+    try:
+        model.execute({"TOKENS": np.array([list(range(1, 61))], np.int32)},
+                      {"sequence_id": 1, "sequence_start": True,
+                       "sequence_end": True})
+    finally:
+        model.unload()
+    assert len(dispatched) == len(back) == 60
+    assert max(ahead) == ROUNDS_IN_FLIGHT
+
+
+def test_one_host_transfer_a_round_whatever_the_width(monkeypatch):
+    spans = []
+
+    class counting_span(decoder_batched.span):
+        def __init__(self, name):
+            spans.append(name)
+            super().__init__(name)
+
+    monkeypatch.setattr(decoder_batched, "span", counting_span)
+    model = BatchedDecoderModel(seed=0, slots=4)
+    core, gate = _traced(model), GatedStep(model)
+    callers = _Callers(core)
+    try:
+        callers.send([1, 2, 3], "prompt", sequence_id=1, sequence_start=True)
+        gate.at(0)
+        for seq in (2, 3, 4):  # three more for round 1: it is four wide
+            callers.send([seq], f"single-{seq}", sequence_id=seq,
+                         sequence_start=True, sequence_end=True)
+        gate.queued(3)
+        gate.let(3)
+        answers = callers.join()
+    finally:
+        gate.let(100)
+        model.unload()
+    assert not any(isinstance(a, Exception) for a in answers.values()), answers
+    assert model.batch_histogram == {1: 2, 4: 1}
+    assert spans.count(decoder_batched.SPAN_BATCH_READBACK) == 3
+    assert spans.count(decoder_batched.SPAN_ROUND_DISPATCH) == 3
+    # the three answered by the four-wide round hold rows of one array: the
+    # round's logits came to the host once, and each got a view
+    rows = [answers[f"single-{seq}"][0]["outputs"][0]["array"]
+            for seq in (2, 3, 4)]
+    row_bytes = rows[0].nbytes
+    for row in rows:
+        assert not row.flags.owndata and row.dtype == np.float32
+        apart = abs(row.ctypes.data - rows[0].ctypes.data)
+        assert apart % row_bytes == 0 and apart < model.slots * row_bytes
+
+
+def test_sequence_end_frees_its_slot_at_its_own_round():
+    """... while its round-mate's prompt still runs, and a new sequence takes
+    that slot in the next round."""
+    model = BatchedDecoderModel(seed=0, slots=2)
+    core, gate = _traced(model), GatedStep(model)
+    callers = _Callers(core)
+    try:
+        callers.send(list(range(1, 9)), "prompt", sequence_id=1,
+                     sequence_start=True)
+        gate.at(0)
+        callers.send([7], "ending", sequence_id=2, sequence_start=True,
+                     sequence_end=True)
+        gate.queued(1)
+        gate.let()
+        gate.at(1)  # round 1: the prompt and the ending sequence
+        callers.send([9], "new", sequence_id=3, sequence_start=True)
+        gate.queued(1)
+        gate.let(7)
+        answers = callers.join()
+    finally:
+        gate.let(100)
+        model.unload()
+    # both slots were taken when the new sequence arrived: it got the one
+    # the ended sequence gave up as round 1 was read back
+    assert not any(isinstance(a, Exception) for a in answers.values()), answers
+    records = _records(core)
+    assert records["ending"]["first_round_id"] == 1
+    assert records["new"]["first_round_id"] == 2
+    assert records["new"]["counts"]["round_widths"] == [2]
+    assert records["prompt"]["counts"]["rounds_own"] == 8
+    assert gate.calls == 8
+
+
+def test_a_step_that_raises_fails_its_own_rounds_requests_only():
+    expected = _drive(TinyDecoderModel(seed=0), 1, [1, 2, 3], n=3)
+    model = BatchedDecoderModel(seed=0, slots=3)
+    core, gate = _traced(model), GatedStep(model, fail_at=4)
+    callers = _Callers(core)
+
+    def bystander(tokens, **parameters):
+        reply = core.infer("decoder_lm_batched", "", _request(
+            tokens, "bystander", sequence_id=1, **parameters))
+        return int(reply[0]["outputs"][1]["array"][0, 0])
+
+    try:
+        gate.let(3)
+        tokens = [bystander([1, 2, 3], sequence_start=True)]
+        # sequence 1 is live now and has no request in progress
+        callers.send([4, 5, 6], "struck", sequence_id=2, sequence_start=True)
+        gate.let()  # call 3: its first token
+        gate.at(4)  # call 4, which will raise, is at the gate
+        callers.send([8], "later", sequence_id=3, sequence_start=True,
+                     sequence_end=True)
+        gate.queued(1)
+        gate.let(100)
+        answers = callers.join()
+        assert isinstance(answers["struck"], Exception)
+        assert "step 4 failed" in str(answers["struck"])
+        assert not isinstance(answers["later"], Exception), answers["later"]
+        # the failed round ended its own sequence and no other: the
+        # bystander goes on from the cache it had, to the unbatched tokens
+        assert model.live_sequences() == 1
+        tokens.append(bystander(tokens[-1:]))
+        tokens.append(bystander(tokens[-1:], sequence_end=True))
+    finally:
+        gate.let(100)
+        model.unload()
+    assert tokens == expected
+    assert model.live_sequences() == 0
+    assert gate.calls == 8
+
+
+def test_a_turn_that_fails_outside_a_dispatch_strands_nobody():
+    """A round whose logits cannot be read fails every request begun and
+    ends every live sequence; the worker lives and serves the next one."""
+    model = BatchedDecoderModel(seed=0, slots=2)
+    one = {"TOKENS": np.array([[5]], np.int32)}
+    model.execute(one, {"sequence_id": 1, "sequence_start": True})
+    read_back = model._read_back
+
+    def unreadable():
+        model._read_back = read_back
+        raise RuntimeError("the round's logits are gone")
+
+    model._read_back = unreadable
+    try:
+        with pytest.raises(RuntimeError, match="logits are gone"):
+            model.execute(one, {"sequence_id": 2, "sequence_start": True})
+        assert model.live_sequences() == 0
+        with pytest.raises(ValueError, match="no live state"):
+            model.execute(one, {"sequence_id": 1})
+        out = model.execute(one, {"sequence_id": 3, "sequence_start": True,
+                                  "sequence_end": True})
+        assert out["NEXT_TOKEN"].shape == (1, 1)
+    finally:
+        model.unload()
+    assert model.live_sequences() == 0

@@ -14,10 +14,14 @@ import numpy as np
 import pytest
 
 from client_tpu.models.batched import BatchedMatMulModel
-from client_tpu.models.decoder_batched import BatchedDecoderModel
+from client_tpu.models.decoder_batched import (
+    ROUNDS_IN_FLIGHT,
+    BatchedDecoderModel,
+)
 from client_tpu.models.generate import TinyGenerateModel
 from client_tpu.models.simple import AddSubModel
 from client_tpu.server import ServerCore, timeline
+from tests.conftest import GatedStep
 
 PARTS = ("compute_input", "queue", "compute_infer", "compute_output")
 TRACEPARENT = "00-0af7651916cd43dd8448eb211c80319c-b7ad6b7169203331-01"
@@ -145,7 +149,7 @@ def test_marks_of_every_request_are_monotone(traced_core):
     orders = {
         "decoder_lm_batched": (
             "recv", "inputs_resolved", "model_enter", "enqueued", "collected",
-            "first_dispatch", "last_dispatch", "resolved", "on_host",
+            "first_dispatch", "last_dispatch", "on_host", "resolved",
             "model_exit", "done"),
         "tiny_lm_generate": (
             "recv", "inputs_resolved", "model_enter", "cache_ready",
@@ -181,7 +185,8 @@ def test_a_record_carries_the_identifiers_its_spans_share(traced_core):
     assert record["client_span_id"] == TRACEPARENT.split("-")[2]
     counts = record["counts"]
     assert record["first_round_id"] == counts["first_round_id"] == 0
-    assert counts["rounds_own"] == counts["rounds_window"] == 2
+    assert counts["rounds_own"] == 2
+    assert counts["rounds_waited"] == counts["rounds_held"] == 0
     assert counts["round_widths"] == [1, 1] and counts["responses"] == 1
     # the access record's queue is the timeline's queue, not recv -> execute
     access = core.access_records()[-1]
@@ -191,35 +196,52 @@ def test_a_record_carries_the_identifiers_its_spans_share(traced_core):
     assert access["total_ns"] == stamps["done"] - stamps["recv"]
 
 
-def test_a_window_mate_of_a_prompt_is_held_for_its_rounds():
-    """A single-token request that shares a window with a longer prompt is
-    resolved after the prompt's last round, not after its own."""
-    # a gather long enough that both requests land in one window, which
-    # closes as soon as both slots are taken
-    model = BatchedDecoderModel(seed=0, slots=2, max_delay_s=30.0)
+def test_a_round_mate_of_a_prompt_is_answered_after_its_own_round():
+    """A single-token request that arrives with a longer prompt shares the
+    prompt's first round and is answered after it, while the prompt still
+    has rounds to go: nobody is held for another's rounds."""
+    model = BatchedDecoderModel(seed=0, slots=2)
     core = ServerCore([model])
     core.trace_settings.update(trace_level=["TIMESTAMPS"], trace_rate="1")
+    gate = GatedStep(model)
     try:
-        _concurrently(
+        # a round held at the gate, so that both requests are on the queue
+        # when the next one is made up
+        held = threading.Thread(target=core.infer, args=(
+            "decoder_lm_batched", "", _tokens(
+                [9], "held", sequence_id=9, sequence_start=True,
+                sequence_end=True)))
+        held.start()
+        gate.at(0)
+        mates = threading.Thread(target=_concurrently, args=(
             lambda: core.infer("decoder_lm_batched", "", _tokens(
                 [7], "single", sequence_id=1, sequence_start=True)),
             lambda: core.infer("decoder_lm_batched", "", _tokens(
-                [1, 2, 3, 4, 5], "prompt", sequence_id=2, sequence_start=True)))
+                [1, 2, 3, 4, 5], "prompt", sequence_id=2, sequence_start=True))))
+        mates.start()
+        gate.queued(2)
+        gate.let(6)
+        for thread in (held, mates):
+            thread.join(timeout=120)
+            assert not thread.is_alive()
     finally:
+        gate.let(100)
         model.unload()
     records = {r["request_id"]: r for r in core.recent_traces()}
     single, prompt = records["single"], records["prompt"]
     assert single["counts"]["rounds_own"] == 1
-    assert single["counts"]["rounds_window"] == 5 > single["counts"]["rounds_own"]
+    assert single["counts"]["rounds_held"] <= ROUNDS_IN_FLIGHT
+    assert single["counts"]["rounds_waited"] <= ROUNDS_IN_FLIGHT
     assert single["counts"]["round_widths"] == [2]
-    assert single["timestamps"]["resolved"] > single["timestamps"]["last_dispatch"]
-    assert prompt["counts"]["rounds_own"] == prompt["counts"]["rounds_window"] == 5
+    assert prompt["counts"]["rounds_own"] == 5
+    assert prompt["counts"]["rounds_held"] == 0
     assert prompt["counts"]["round_widths"] == [2, 1, 1, 1, 1]
-    assert single["first_round_id"] == prompt["first_round_id"] == 0
-    # the single request's last dispatch returned before the prompt's did
+    assert single["first_round_id"] == prompt["first_round_id"] == 1
+    # answered after its own round, before the prompt's last was dispatched
     assert (single["timestamps"]["last_dispatch"]
-            < prompt["timestamps"]["last_dispatch"]
-            <= single["timestamps"]["resolved"])
+            <= single["timestamps"]["on_host"]
+            <= single["timestamps"]["resolved"]
+            < prompt["timestamps"]["last_dispatch"])
 
 
 def test_batch_stats_rounds_equal_the_batch_histogram(traced_core):
