@@ -8,8 +8,14 @@ held here by ``tests/test_chip_compile.py``, which compiles for a described
 topology without one.
 """
 
+import fcntl
 import os
+import shutil
+import subprocess
 import sys
+from pathlib import Path
+
+import pytest
 
 _flags = os.environ.get("XLA_FLAGS", "")
 if "--xla_force_host_platform_device_count" not in _flags:
@@ -19,30 +25,47 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-def native_built() -> bool:
-    """Build (or locate) the native library; shared by the native test tiers
-    so no test module needs to import another test module."""
-    import subprocess
-    from pathlib import Path
+NATIVE_BUILD = Path(__file__).resolve().parent.parent / "native" / "build"
 
-    build = Path(__file__).resolve().parent.parent / "native" / "build"
-    targets = [build / "native_smoke", build / "libclient_tpu_http.so",
-               build / "hpack_tool"]
-    if all(t.exists() for t in targets):
-        return True
-    native = build.parent
-    try:
-        subprocess.run(
-            ["cmake", "-S", str(native), "-B", str(build), "-G", "Ninja"],
-            check=True, capture_output=True, timeout=120,
-        )
-        subprocess.run(
-            ["ninja", "-C", str(build)], check=True, capture_output=True,
-            timeout=300,
-        )
-        return True
-    except Exception:
-        return False
+
+@pytest.fixture(scope="session")
+def native_build():
+    """``native/build``, built and up to date: the one place in ``tests/``
+    that starts ``cmake`` or ``ninja``. Every ``xdist`` worker that has a
+    native test takes the lock in turn, so one of them builds and the others
+    find ``ninja`` with nothing to do. Skips only where a tool is absent; a
+    build that fails is a failure, with the build's own output."""
+    absent = [tool for tool in ("cmake", "ninja") if not shutil.which(tool)]
+    if not any(shutil.which(cxx) for cxx in ("c++", "g++", "clang++")):
+        absent.append("a C++ compiler")
+    if absent:
+        pytest.skip(f"native toolchain unavailable: no {', '.join(absent)}")
+    NATIVE_BUILD.mkdir(exist_ok=True)
+    with open(NATIVE_BUILD / ".pytest.lock", "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        configured = (NATIVE_BUILD / "build.ninja").exists()
+        steps = [] if configured else [["cmake", "-S", str(NATIVE_BUILD.parent),
+                                        "-B", str(NATIVE_BUILD), "-G", "Ninja"]]
+        for step in steps + [["ninja", "-C", str(NATIVE_BUILD)]]:
+            proc = subprocess.run(step, capture_output=True, text=True, timeout=900)
+            if proc.returncode:
+                pytest.fail(f"{' '.join(step)} exited {proc.returncode}:\n"
+                            f"{proc.stdout[-4000:]}\n{proc.stderr[-4000:]}",
+                            pytrace=False)
+    return NATIVE_BUILD
+
+
+def standing_behind(client, followers: int) -> None:
+    """Wait until ``followers`` callers stand behind the leaders of a
+    ``CachingClient``'s singleflight groups: with the stub's wire request
+    held meanwhile, what collapses is a count and not who arrived inside a
+    window."""
+    import time
+
+    deadline = time.monotonic() + 60
+    while sum(f.followers for f in list(client._flights.values())) < followers:
+        assert time.monotonic() < deadline, "the callers never arrived"
+        time.sleep(0.001)
 
 
 class GatedStep:

@@ -96,11 +96,7 @@ def test_memory_growth_example(servers):
     _run("memory_growth_test.py", ["-u", http_server.url, "-r", "200"])
 
 
-def test_native_grpc_example(servers):
-    from tests.conftest import native_built
-
-    if not native_built():
-        pytest.skip("native toolchain unavailable")
+def test_native_grpc_example(servers, native_build):
     _, grpc_server = servers
     _run("simple_native_grpc_client.py", ["-u", grpc_server.url])
 

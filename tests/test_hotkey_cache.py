@@ -46,6 +46,7 @@ from client_tpu.resilience import ResiliencePolicy
 from client_tpu.server import HttpInferenceServer, ServerCore
 from client_tpu.testing import ChaosProxy, Fault
 from client_tpu.utils import InferenceServerException
+from tests.conftest import standing_behind
 
 
 # -- helpers ------------------------------------------------------------------
@@ -88,10 +89,13 @@ class StubInner(InferenceServerClientBase):
 
     _FRONTEND = "stub"
 
-    def __init__(self, delay_s=0.0, fail=None):
+    def __init__(self, held=False, fail=None):
         super().__init__()
         self.calls = 0
-        self.delay_s = delay_s
+        # a held stub answers no wire request until the test sets ``release``
+        self.release = threading.Event()
+        if not held:
+            self.release.set()
         self.fail = fail  # optional exception instance to raise
         self.unloaded = []
         self._lock = threading.Lock()
@@ -99,8 +103,7 @@ class StubInner(InferenceServerClientBase):
     def infer(self, model_name, inputs, **kwargs):
         with self._lock:
             self.calls += 1
-        if self.delay_s:
-            time.sleep(self.delay_s)
+        assert self.release.wait(timeout=60), "the wire request was never released"
         if self.fail is not None:
             raise self.fail
         return FakeResult(inputs)
@@ -119,15 +122,15 @@ class AioStubInner(InferenceServerClientBase):
     _FRONTEND = "stub_aio"
     _BATCH_AIO = True
 
-    def __init__(self, delay_s=0.0):
+    def __init__(self):
         super().__init__()
         self.calls = 0
-        self.delay_s = delay_s
+        self.release = None  # an asyncio.Event holds the wire request
 
     async def infer(self, model_name, inputs, **kwargs):
         self.calls += 1
-        if self.delay_s:
-            await asyncio.sleep(self.delay_s)
+        if self.release is not None:
+            await asyncio.wait_for(self.release.wait(), timeout=60)
         return FakeResult(inputs)
 
     async def close(self):
@@ -155,6 +158,18 @@ def _run_threads(n, fn):
         t.start()
     for t in threads:
         t.join(timeout=60)
+    return errors
+
+
+def _run_collapsed(client, inner, n, fn):
+    """``_run_threads`` over a held stub: the leader's wire request is let go
+    once the other ``n - 1`` callers stand behind it."""
+    errors = []
+    runner = threading.Thread(target=lambda: errors.extend(_run_threads(n, fn)))
+    runner.start()
+    standing_behind(client, n - 1)
+    inner.release.set()
+    runner.join(timeout=60)
     return errors
 
 
@@ -190,7 +205,7 @@ def test_cache_lookup_phase_registered():
 
 # -- singleflight -------------------------------------------------------------
 def test_singleflight_collapses_to_one_wire_request(arena):
-    inner = StubInner(delay_s=0.05)
+    inner = StubInner(held=True)
     client = CachingClient(inner, cache=ResponseCache(ttl_s=30.0,
                                                       arena=arena))
     results = [None] * 16
@@ -199,7 +214,7 @@ def test_singleflight_collapses_to_one_wire_request(arena):
         _, inp = _fp32_input(7.0)
         results[i] = client.infer("m", [inp])
 
-    assert _run_threads(16, call) == []
+    assert _run_collapsed(client, inner, 16, call) == []
     assert inner.calls == 1, f"expected 1 wire request, got {inner.calls}"
     ref = results[0].as_numpy("Y")
     for r in results[1:]:
@@ -211,7 +226,7 @@ def test_singleflight_collapses_to_one_wire_request(arena):
 
 
 def test_singleflight_without_cache(arena):
-    inner = StubInner(delay_s=0.05)
+    inner = StubInner(held=True)
     client = CachingClient(inner, cache=None, singleflight=True)
     results = [None] * 8
 
@@ -219,7 +234,7 @@ def test_singleflight_without_cache(arena):
         _, inp = _fp32_input(3.0)
         results[i] = client.infer("m", [inp])
 
-    assert _run_threads(8, call) == []
+    assert _run_collapsed(client, inner, 8, call) == []
     assert inner.calls == 1
     # no cache: a later identical call is a fresh wire request
     _, inp = _fp32_input(3.0)
@@ -229,7 +244,7 @@ def test_singleflight_without_cache(arena):
 
 def test_singleflight_leader_failure_fans_same_typed_error(arena):
     boom = InferenceServerException("server exploded", status="500")
-    inner = StubInner(delay_s=0.05, fail=boom)
+    inner = StubInner(held=True, fail=boom)
     client = CachingClient(inner, cache=ResponseCache(ttl_s=30.0,
                                                       arena=arena))
     caught = [None] * 8
@@ -241,7 +256,7 @@ def test_singleflight_leader_failure_fans_same_typed_error(arena):
         except InferenceServerException as e:
             caught[i] = e
 
-    assert _run_threads(8, call) == []
+    assert _run_collapsed(client, inner, 8, call) == []
     assert inner.calls == 1
     # every caller got the SAME typed error object
     assert all(e is boom for e in caught), caught
@@ -255,7 +270,8 @@ def test_singleflight_leader_failure_fans_same_typed_error(arena):
 
 def test_singleflight_aio_collapses():
     async def main():
-        inner = AioStubInner(delay_s=0.05)
+        inner = AioStubInner()
+        inner.release = asyncio.Event()
         arena = ShmArena(name_prefix="hotkey_aio")
         try:
             client = AioCachingClient(
@@ -265,7 +281,18 @@ def test_singleflight_aio_collapses():
                 _, inp = _fp32_input(4.0)
                 return await client.infer("m", [inp])
 
-            results = await asyncio.gather(*[call() for _ in range(12)])
+            async def release():
+                # the last task of the gather: every turn of the loop it
+                # gives up lets each caller run to where it waits, so after
+                # a hundred the eleven stand behind the leader whatever the
+                # clock did
+                for _ in range(100):
+                    await asyncio.sleep(0)
+                assert inner.calls == 1
+                inner.release.set()
+
+            *results, _ = await asyncio.wait_for(asyncio.gather(
+                *[call() for _ in range(12)], release()), timeout=60)
             assert inner.calls == 1
             ref = results[0].as_numpy("Y")
             for r in results[1:]:

@@ -1,5 +1,6 @@
-"""Native (C++) library tests: build if needed, run the smoke binary against
-a live in-process server, and exercise the ctypes binding."""
+"""Native (C++) library tests: run the smoke binary against a live in-process
+server, and exercise the ctypes binding. ``conftest.native_build`` builds
+``native/`` once a run; every test here stands behind it."""
 
 import os
 import subprocess
@@ -14,12 +15,16 @@ BUILD = NATIVE / "build"
 SMOKE = BUILD / "native_smoke"
 LIB = BUILD / "libclient_tpu_http.so"
 
+pytestmark = pytest.mark.usefixtures("native_build")
 
-from tests.conftest import native_built as _ensure_built
 
-pytestmark = pytest.mark.skipif(
-    not _ensure_built(), reason="native toolchain unavailable"
-)
+def test_native_library_builds(native_build):
+    """A broken ``native/`` is red: the fixture fails with the build's output
+    where the tools are there, and what every other native test runs exists
+    once it returns."""
+    for target in ("libclient_tpu_http.so", "native_smoke", "native_bench",
+                   "hpack_tool", "leak_check", "dual_client_test"):
+        assert (native_build / target).is_file(), f"{target} not built"
 
 
 @pytest.fixture(scope="module")
@@ -280,7 +285,6 @@ def _load_hpack_encoder():
         _sys.path.remove(_HPACK_PKG)
 
 
-@pytest.mark.skipif(not SMOKE.exists(), reason="native toolchain unavailable")
 def test_hpack_decoder_against_reference_encoder():
     """Random header sequences encoded by the reference HPACK encoder
     (dynamic table + huffman + indexed fields across blocks) must decode
@@ -290,7 +294,6 @@ def test_hpack_decoder_against_reference_encoder():
     import string
 
     encoder = _load_hpack_encoder()
-    assert HPACK_TOOL.exists()
 
     rng = random.Random(42)
     blocks = []
@@ -339,13 +342,10 @@ def test_hpack_decoder_against_reference_encoder():
 LEAK_CHECK = BUILD / "leak_check"
 
 
-@pytest.mark.skipif(not SMOKE.exists(), reason="native toolchain unavailable")
 def test_native_leak_check(server, grpc_server):
     """ASan/LSan-instrumented lifecycle churn over both native clients
     (reference memory_leak_test.cc's role; no valgrind in this image).
     LeakSanitizer fails the process on any leak at exit."""
-    if not LEAK_CHECK.exists():
-        pytest.skip("leak_check not built (stale build dir)")
     proc = subprocess.run(
         [str(LEAK_CHECK), "30"], capture_output=True, text=True, timeout=300,
         env={

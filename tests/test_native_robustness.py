@@ -14,11 +14,7 @@ import time
 
 import pytest
 
-from tests.conftest import native_built as _ensure_built
-
-pytestmark = pytest.mark.skipif(
-    not _ensure_built(), reason="native toolchain unavailable"
-)
+pytestmark = pytest.mark.usefixtures("native_build")
 
 
 class _ByteServer:

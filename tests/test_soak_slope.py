@@ -1,12 +1,21 @@
-"""Real soak tier: wall-clock RSS-slope leak hunting (``pytest -m soak``).
+"""Leak tier: every client path in a loop, its memory read before and after.
 
 The reference's ``memory_leak_test.cc`` (324 LoC) loops inferences for
-external leak tooling over hours; this tier is the in-repo equivalent:
-each test drives one client path for ``CLIENT_TPU_SOAK_SECONDS`` (default
-60 in CI; set 600+ for a true soak), samples resident-set size on a steady
-cadence, then fits a least-squares slope over the steady-state half of the
-samples and fails on sustained growth. Deselected by default via pyproject
-``addopts = -m 'not soak'``; run explicitly with ``pytest -m soak``.
+external leak tooling over hours; this file is the in-repo equivalent, in two
+forms of the same ten rows:
+
+- **Counted** (``CLIENT_TPU_SOAK_SECONDS`` unset; what tier-1 and a plain
+  ``pytest`` run): ``WARMUP_ITERS`` steps, then ``COUNTED_ITERS`` more, and the
+  growth between the two points, after ``gc.collect()`` and ``malloc_trim``,
+  stays under an absolute budget: resident set for every row, and
+  ``tracemalloc``'s traced total for the rows whose client is this process.
+  No clock is read, so a busy machine changes how long a row takes and
+  nothing else. Writes no file.
+- **Timed** (``CLIENT_TPU_SOAK_SECONDS=600 pytest -m soak``; an operator's
+  soak, and the form the committed ``SOAK_r0*.json`` came from): each row
+  drives its path for that many seconds, samples resident-set size on a steady
+  cadence, fits a least-squares slope over the final third of the samples,
+  fails on sustained growth and writes ``SOAK_latest.json``.
 """
 
 import gc
@@ -16,6 +25,7 @@ import subprocess
 import sys
 import threading
 import time
+import tracemalloc
 from pathlib import Path
 
 import numpy as np
@@ -28,7 +38,8 @@ import client_tpu.utils.tpu_shared_memory as tpushm
 
 pytestmark = pytest.mark.soak
 
-SOAK_SECONDS = float(os.environ.get("CLIENT_TPU_SOAK_SECONDS", "60"))
+# 0: the counted form
+SOAK_SECONDS = float(os.environ.get("CLIENT_TPU_SOAK_SECONDS", "0"))
 SAMPLE_EVERY = max(SOAK_SECONDS / 60.0, 1.0)
 # Sustained growth budget. Runs >= 1800 s assert leak-scale (64 KB/min):
 # the r05 instrumented 3600 s grpc_stream capture (SOAK_STREAM_r05.json)
@@ -118,11 +129,56 @@ def _malloc_trim() -> None:
         pass  # non-glibc: raw == trimmed
 
 
+# The counted form. A step that kept its 256 KiB payload would grow by 75 MiB
+# over COUNTED_ITERS; one that kept a kilobyte of Python objects, by 300 KiB
+# of traced memory. What a clean path adds past warm-up does not scale with the
+# count: allocator and transport high-water, and 10 to 40 KB a row of traced
+# memory while ``integrity.py``'s ring of 4,096 overhead samples fills.
+WARMUP_ITERS = 100
+COUNTED_ITERS = 300
+MAX_RSS_GROWTH_KB = 16 * 1024
+MAX_TRACED_GROWTH_KB = 128
+
+
+def _settled_kb(pid: int):
+    """(resident set, traced Python memory) in KB with nothing left to
+    collect; the second is None for another process."""
+    gc.collect()
+    if pid:
+        return _rss_kb(pid), None
+    _malloc_trim()
+    return _rss_kb(), tracemalloc.get_traced_memory()[0] // 1024
+
+
+def _soak_counted(name: str, step, pid: int):
+    if not pid:
+        tracemalloc.start()
+    try:
+        for _ in range(WARMUP_ITERS):
+            step()
+        rss_before, traced_before = _settled_kb(pid)
+        for _ in range(COUNTED_ITERS):
+            step()
+        rss_after, traced_after = _settled_kb(pid)
+    finally:
+        tracemalloc.stop()
+    assert rss_after - rss_before < MAX_RSS_GROWTH_KB, (
+        f"{name}: RSS {rss_before} -> {rss_after} KB over {COUNTED_ITERS} "
+        f"steps after {WARMUP_ITERS} of warm-up")
+    if not pid:
+        assert traced_after - traced_before < MAX_TRACED_GROWTH_KB, (
+            f"{name}: traced Python memory {traced_before} -> {traced_after} "
+            f"KB over {COUNTED_ITERS} steps after {WARMUP_ITERS} of warm-up")
+
+
 def _soak(name: str, step, pid: int = 0, trim: bool = False):
-    """Run ``step()`` in a loop for SOAK_SECONDS, sampling RSS; assert the
-    steady-state slope is flat. ``pid`` samples another process (native).
-    ``trim=True`` samples post-``malloc_trim`` (own process only) and
-    additionally records the raw pre-trim slope."""
+    """Run ``step()`` in a loop and assert that memory does not grow: by the
+    count where ``SOAK_SECONDS`` is 0, else for SOAK_SECONDS, sampling RSS and
+    asserting that the steady-state slope is flat. ``pid`` samples another
+    process (native). ``trim=True`` samples post-``malloc_trim`` (own process
+    only) and additionally records the raw pre-trim slope."""
+    if not SOAK_SECONDS:
+        return _soak_counted(name, step, pid)
     deadline = time.monotonic() + SOAK_SECONDS
     samples = []
     raw_samples = []
@@ -223,8 +279,9 @@ def servers():
 def _dump_results(servers):
     yield
     if not RESULTS:
-        # a run that exercised no _soak rows (e.g. only the probe-tool
-        # smoke) must not rewrite a committed artifact's config block
+        # the counted form records nothing and leaves the tree as it found
+        # it; a timed run that exercised no _soak rows (e.g. only the
+        # probe-tool smoke) must not rewrite a committed artifact's config
         return
     # default to a gitignored scratch file: committed round artifacts
     # (SOAK_rNN.json) are historical records and must only be rewritten by
@@ -390,12 +447,14 @@ def test_soak_tpu_shm_churn(servers):
 def test_soak_stream_probe_tool(tmp_path):
     """The instrumented attribution tool (tools/soak_stream_probe.py) keeps
     working end-to-end: both phases produce samples with every metric
-    series and computed slopes. Short phases — this pins the harness, not
-    the numbers (SOAK_STREAM_r05.json is the committed measurement)."""
+    series and computed slopes. Phases as short as three samples need (the
+    tool samples four times in a phase shorter than two minutes) — this pins
+    the harness, not the numbers (SOAK_STREAM_r05.json is the committed
+    measurement)."""
     out = tmp_path / "probe_smoke.json"
     proc = subprocess.run(
         [sys.executable, "tools/soak_stream_probe.py",
-         "--seconds", "65", "--ab-seconds", "65", "--out", str(out)],
+         "--seconds", "8", "--ab-seconds", "8", "--out", str(out)],
         capture_output=True, text=True, timeout=500, cwd=REPO,
     )
     assert proc.returncode == 0, proc.stderr[-800:]
@@ -413,12 +472,29 @@ def test_soak_stream_probe_tool(tmp_path):
     assert data["arena_max_1"]["arena_max"] == "1"
 
 
-NATIVE_BENCH = REPO / "native" / "build" / "native_bench"
+def _ten_more_served(client, proc):
+    """A step of the native row's counted form. The bench counts its own
+    iterations where nobody can read them; the server counts what it
+    answered: a step waits for ten more."""
+    def served() -> int:
+        stats = client.get_inference_statistics("identity_fp32")
+        return stats["model_stats"][0]["inference_count"]
+
+    target = served()
+
+    def step():
+        nonlocal target
+        target += 10
+        deadline = time.monotonic() + 60
+        while served() < target:
+            assert proc.poll() is None, "native_bench exited early"
+            assert time.monotonic() < deadline, "native_bench stalled"
+            time.sleep(0.005)
+    return step
 
 
-@pytest.mark.skipif(not NATIVE_BENCH.exists(), reason="native_bench not built")
 @pytest.mark.parametrize("arenas", ["default", "pinned"])
-def test_soak_native_client(servers, arenas):
+def test_soak_native_client(servers, native_build, arenas):
     """The C++ client under sustained load, RSS sampled from outside
     (reference memory_leak_test.cc's role for the native library).
 
@@ -442,17 +518,22 @@ def test_soak_native_client(servers, arenas):
         env["MALLOC_ARENA_MAX"] = "1"
         name = "native_client_arena1"
     proc = subprocess.Popen(
-        [str(NATIVE_BENCH), str(1 << 16), str(10_000_000)],
+        [str(native_build / "native_bench"), str(1 << 16), str(10_000_000)],
         env=env,
         stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL,
     )
     try:
-        time.sleep(min(5.0, SOAK_SECONDS / 10))  # let it reach steady state
-        def step():
-            assert proc.poll() is None, "native_bench exited early"
-            time.sleep(0.25)
-        _soak(name, step, pid=proc.pid)
-        RESULTS[name]["trim_every"] = 200
+        if SOAK_SECONDS:
+            time.sleep(min(5.0, SOAK_SECONDS / 10))  # let it reach steady state
+
+            def step():
+                assert proc.poll() is None, "native_bench exited early"
+                time.sleep(0.25)
+            _soak(name, step, pid=proc.pid)
+            RESULTS[name]["trim_every"] = 200
+        else:
+            with httpclient.InferenceServerClient(servers.http_url) as client:
+                _soak(name, _ten_more_served(client, proc), pid=proc.pid)
     finally:
         proc.terminate()
         proc.wait(timeout=10)

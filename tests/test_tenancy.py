@@ -59,6 +59,7 @@ from client_tpu.tenancy import (
     TenantSpec,
     parse_tenancy_spec,
 )
+from tests.conftest import standing_behind
 
 
 # -- helpers ------------------------------------------------------------------
@@ -101,17 +102,19 @@ class StubInner(InferenceServerClientBase):
 
     _FRONTEND = "stub"
 
-    def __init__(self, delay_s=0.0):
+    def __init__(self, held=False):
         super().__init__()
         self.calls = 0
-        self.delay_s = delay_s
+        # a held stub answers no wire request until the test sets ``release``
+        self.release = threading.Event()
+        if not held:
+            self.release.set()
         self._lock = threading.Lock()
 
     def infer(self, model_name, inputs, **kwargs):
         with self._lock:
             self.calls += 1
-        if self.delay_s:
-            time.sleep(self.delay_s)
+        assert self.release.wait(timeout=60), "the wire request was never released"
         return FakeResult(inputs)
 
     def close(self):
@@ -525,7 +528,7 @@ def test_cache_never_serves_across_tenants(arena):
 
 
 def test_singleflight_never_collapses_across_tenants():
-    inner = StubInner(delay_s=0.25)
+    inner = StubInner(held=True)
     client = CachingClient(inner, cache=None, singleflight=True)
     tenants = ["a", "b", "a", "b"]
 
@@ -534,7 +537,14 @@ def test_singleflight_never_collapses_across_tenants():
         r = client.infer("stub", [x], tenant=tenants[i])
         assert np.allclose(r.as_numpy("Y"), 10.0)
 
-    errors = _run_threads(4, fn)
+    errors = []
+    runner = threading.Thread(target=lambda: errors.extend(_run_threads(4, fn)))
+    runner.start()
+    # the wire requests are held until each twin stands behind a leader
+    standing_behind(client, 2)
+    assert [f.followers for f in client._flights.values()] == [1, 1]
+    inner.release.set()
+    runner.join(timeout=60)
     assert not errors
     # one leader per tenant: the same-tenant twin collapsed onto it, the
     # other tenant never did
@@ -646,11 +656,30 @@ def test_bench_tenancy_artifact_claims():
 # -- tenancy smoke: live adversarial isolation --------------------------------
 @pytest.mark.tenancy_smoke
 def test_tenancy_isolation_smoke():
-    """Re-run both bench arms shortened against a live server and
-    re-judge the isolation invariants (the ``capacity_gate --tenancy``
-    body): compliant capacity within tolerance of the isolated baseline,
-    zero compliant sheds, every adversary reject typed over_quota."""
+    """Both bench arms shortened against a live server, judged by what the
+    quota and the weighted-fair queues guarantee at any speed of the
+    machine, in counts: the adversary is the arms' only difference, no
+    compliant request is shed or fails, every adversary reject is a typed
+    ``over_quota`` shed and never an error, and the snapshot names the
+    adversary. (The capacity ratio of two timed replays is the operator's
+    ``capacity_gate --tenancy``; here the queue-wait cap is out of reach, so
+    no shed is the machine's.)"""
     import tools.bench_tenancy as bench
 
-    verdict = bench.probe_isolation(duration_s=2.0, attempts=2)
-    assert verdict["problems"] == [], verdict["problems"]
+    arms = bench.run_arms(duration_s=2.0, max_queue_wait_s=60.0)
+    isolated = arms["isolated"]["tenants"]
+    adversarial = arms["adversarial"]["tenants"]
+    for arm in (isolated, adversarial):
+        for tenant in bench.COMPLIANT:
+            row = arm[tenant]
+            assert (row["issued"], row["shed"], row["errors"]) == (
+                isolated[tenant]["issued"], 0, 0), (tenant, row)
+            assert row["ok"] == row["issued"] > 0, (tenant, row)
+    adversary = adversarial[bench.ADVERSARY]
+    assert bench.ADVERSARY not in isolated
+    assert adversary["errors"] == 0, adversary
+    assert set(adversary["shed_by_reason"]) == {"over_quota"}, adversary
+    assert adversary["ok"] + adversary["shed"] == adversary["issued"], adversary
+    noisy = [v["tenant"]
+             for v in arms["adversarial"]["tenancy"]["noisy_neighbors"]]
+    assert noisy == [bench.ADVERSARY]

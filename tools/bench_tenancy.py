@@ -94,11 +94,14 @@ REPLAY_WORKERS = 32
 
 
 @contextlib.contextmanager
-def arm_runner():
+def arm_runner(max_queue_wait_s: float = 0.05):
     """A fresh in-process server + a PerfRunner with the tenancy-armed
     admission controller (both arms use the SAME runner config; the arm
     is the trace). Shared with ``tools/capacity_gate.py --tenancy`` so
-    the gate re-runs exactly this definition."""
+    the gate re-runs exactly this definition. ``max_queue_wait_s`` is the
+    controller's queue-wait cap (PerfRunner's default): the one shed a slow
+    machine causes by itself is ``queue_timeout``, and a caller that counts
+    sheds by reason raises the cap to take the machine out of the count."""
     import numpy as np
 
     from client_tpu.http import InferenceServerClient, InferInput
@@ -120,6 +123,7 @@ def arm_runner():
             server.url, "http", "simple",
             endpoints=[server.url],
             admission=True,
+            admission_max_queue_wait_s=max_queue_wait_s,
             tenancy=TENANCY_SPEC,
         )
         feature = ("1-replica PoolClient, admission controller with "
@@ -225,25 +229,28 @@ def check(doc: Dict[str, Any]) -> int:
     return failures
 
 
+def run_arms(duration_s: float, **runner_kwargs) -> Dict[str, Any]:
+    """Both arms on a shortened twin of the workload, each against a fresh
+    server: ``{"isolated": ..., "adversarial": ...}``."""
+    from client_tpu import trace as trace_mod
+
+    arms = {}
+    for name, spec in (("isolated", ISOLATED_SPEC),
+                       ("adversarial", ADVERSARIAL_SPEC)):
+        tr = trace_mod.generate(spec, seed=TRACE_SEED, duration_s=duration_s)
+        with arm_runner(**runner_kwargs) as (runner, _):
+            arms[name] = run_arm(runner, tr, name)
+    return arms
+
+
 def probe_isolation(duration_s: float, attempts: int) -> Dict[str, Any]:
     """Re-run both arms on a shortened twin of the workload and re-judge
     the isolation invariants live — the ``capacity_gate --tenancy``
     body. Returns ``{"arms": ..., "problems": [...]}``."""
-    from client_tpu import trace as trace_mod
-
     problems: list = []
     verdict: Dict[str, Any] = {"attempts": []}
     for attempt in range(max(1, attempts)):
-        iso_tr = trace_mod.generate(ISOLATED_SPEC, seed=TRACE_SEED,
-                                    duration_s=duration_s)
-        adv_tr = trace_mod.generate(ADVERSARIAL_SPEC, seed=TRACE_SEED,
-                                    duration_s=duration_s)
-        arms = {}
-        with arm_runner() as (runner, _):
-            arms["isolated"] = run_arm(runner, iso_tr, "isolated")
-        with arm_runner() as (runner, _):
-            arms["adversarial"] = run_arm(runner, adv_tr, "adversarial")
-        doc = {"arms": arms}
+        arms = run_arms(duration_s)
         problems = []
         iso_ok, adv_ok = (_compliant_ok(arms["isolated"]),
                           _compliant_ok(arms["adversarial"]))
@@ -267,7 +274,7 @@ def probe_isolation(duration_s: float, attempts: int) -> Dict[str, Any]:
             "compliant_ok": {"isolated": iso_ok, "adversarial": adv_ok},
             "problems": list(problems),
         })
-        verdict["arms"] = doc["arms"]
+        verdict["arms"] = arms
         if not problems:
             break
     verdict["problems"] = problems

@@ -5,9 +5,9 @@ bounded?" open (raw tail slope 125.3 KB/min, steeper than the whole-run
 48.9). This tool instruments the loop itself instead of re-measuring the
 symptom:
 
-  - every 30 s: raw RSS, post-``malloc_trim`` RSS, ``mallinfo2`` (in-use
-    heap / free-but-unreturned / mmapped), and the ``tracemalloc`` traced
-    total — so Python-level reachable growth, glibc retention, and OS-view
+  - every 30 s (a quarter of the phase where that is shorter): raw RSS,
+    post-``malloc_trim`` RSS, ``mallinfo2`` (in-use heap /
+    free-but-unreturned / mmapped), and the ``tracemalloc`` traced total — so Python-level reachable growth, glibc retention, and OS-view
     RSS are separated in ONE trace;
   - an A/B at the process level: the same loop re-run with
     ``MALLOC_ARENA_MAX=1`` in the same artifact, pinning (or refuting) the
@@ -39,7 +39,7 @@ import time
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-SAMPLE_EVERY_S = 30.0
+SAMPLE_EVERY_S = 30.0  # at most: a phase under two minutes takes four samples
 
 
 def _rss_kb() -> int:
@@ -110,6 +110,7 @@ def child_loop(url: str, seconds: float) -> dict:
     payload = np.random.default_rng(7).integers(
         0, 1000, (1, 65536)).astype(np.int32)
     samples: list = []
+    sample_every = min(SAMPLE_EVERY_S, seconds / 4.0)
     t_start = time.monotonic()
 
     with grpcclient.InferenceServerClient(url) as client:
@@ -146,7 +147,7 @@ def child_loop(url: str, seconds: float) -> dict:
                     _malloc_trim()
                     entry["rss_trimmed_kb"] = _rss_kb()
                     samples.append(entry)
-                    next_sample = now + SAMPLE_EVERY_S
+                    next_sample = now + sample_every
         finally:
             client.stop_stream()
 
@@ -156,6 +157,7 @@ def child_loop(url: str, seconds: float) -> dict:
     return {
         "iters": iters,
         "seconds": seconds,
+        "sample_every_s": sample_every,
         "errors": errors[:3],
         "arena_max": os.environ.get("MALLOC_ARENA_MAX", "default"),
         "samples": samples,
@@ -220,7 +222,7 @@ def main() -> int:
         assert line.startswith("PORT"), line
         url = f"127.0.0.1:{line.split()[1]}"
 
-        out = {"url": url, "sample_every_s": SAMPLE_EVERY_S}
+        out = {"url": url}
         plan = [("default_arenas", args.seconds, None)]
         if args.ab_seconds > 0:
             plan.append(("arena_max_1", args.ab_seconds, "1"))
