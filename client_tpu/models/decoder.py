@@ -10,10 +10,17 @@ LLM serving loop drives it.
 TPU-first choices:
 - the KV cache is STATIC-SHAPE ([max_len, ...] preallocated,
   ``lax.dynamic_update_slice`` at the current position) so the decode step
-  compiles ONCE and every token reuses the same executable — no
+  compiles once a RUNG and every token reuses one of a few executables — no
   shape-polymorphic retraces;
 - the attention mask is position-based (iota <= pos) rather than
-  shape-based, so one compiled step serves every position;
+  shape-based, so one compiled step serves every position under its rung;
+- the attention reads the LIVE PREFIX of the cache, not every reserved
+  position: ``live`` is a compile-time length from a short ladder
+  (``ladder``: max_len, max_len/4, ... down to 256), the host, which holds
+  every position as an int, picks the shortest rung that covers the step
+  (``rung_for``), and every rung is compiled before the first step is
+  dispatched (``_ensure_warm``), never on demand. The row at ``pos`` is
+  written into the whole cache first; the prefix that is read contains it;
 - weights and math are bf16 (MXU-native) with fp32 softmax/logits.
 
 Wire contract (stateful, one token per request after the start request):
@@ -26,11 +33,33 @@ Wire contract (stateful, one token per request after the start request):
 from __future__ import annotations
 
 import threading
-from typing import Any, Dict, List
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
 from .base import Model, TensorSpec
+
+# the shortest rung of a ladder: under it a step is its weights and the
+# prefix is not worth a program
+SHORTEST_RUNG = 256
+
+
+class RungCount:
+    """Steps dispatched, by the rung they read: one count a served model,
+    which ``ServerCore.metrics_registry`` reads as
+    ``client_tpu_server_decode_steps{model,live}``."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._steps: Dict[int, int] = {}
+
+    def add(self, live: int) -> None:
+        with self._lock:
+            self._steps[live] = self._steps.get(live, 0) + 1
+
+    def by_rung(self) -> Dict[int, int]:
+        with self._lock:
+            return dict(self._steps)
 
 
 class TinyDecoderModel(Model):
@@ -59,6 +88,13 @@ class TinyDecoderModel(Model):
         self._lock = threading.Lock()
         self._params = None
         self._step_fn = None
+        # the lengths ``_step_fn`` takes as ``live``, shortest first: the
+        # ladder where ``_build`` below made the step, the whole length alone
+        # where a subclass jits a step of its own (decoder_tp.py)
+        self._rungs = (self.MAX_LEN,)
+        self._warm = False  # every rung's program is compiled
+        self._warm_lock = threading.Lock()
+        self.steps_by_rung = RungCount()
         self._sequences: Dict[Any, Dict[str, Any]] = {}
         # per-sequence serialization: concurrent requests on one sequence_id
         # must not interleave read-compute-write (lost KV updates otherwise;
@@ -73,6 +109,24 @@ class TinyDecoderModel(Model):
             TensorSpec("LOGITS", "FP32", [1, self.VOCAB]),
             TensorSpec("NEXT_TOKEN", "INT32", [1, 1]),
         ]
+
+    # -- the ladder ----------------------------------------------------------
+    @classmethod
+    def ladder(cls) -> Tuple[int, ...]:
+        """The lengths a step's attention may read, shortest first:
+        ``MAX_LEN``, a quarter of it, a sixteenth ... for as long as the rung
+        is at least ``SHORTEST_RUNG``. A function of ``MAX_LEN`` alone. The
+        ratio is the set-up budget speaking: every rung is one more program
+        to build before serving (PERF.md section 6, PR 31)."""
+        rungs = [cls.MAX_LEN]
+        while rungs[0] % 4 == 0 and rungs[0] // 4 >= SHORTEST_RUNG:
+            rungs.insert(0, rungs[0] // 4)
+        return tuple(rungs)
+
+    def rung_for(self, reach: int) -> int:
+        """The shortest rung that covers ``reach`` positions: a step at
+        ``pos`` reaches ``pos + 1``."""
+        return next(live for live in self._rungs if live >= reach)
 
     # -- model ---------------------------------------------------------------
     def _build(self):
@@ -151,8 +205,72 @@ class TinyDecoderModel(Model):
             width = jnp.sum(active, dtype=jnp.int32)
             return lax.fori_loop(0, width, write, caches), (True, True)
 
-        def step(params, caches, token, pos, active=None):
+        def one_layer(layer, cache, x, pos, active, *, live):
+            """One layer of the step: ``(x, cache)`` with the row at ``pos``
+            written."""
+            with jax.named_scope("attn_qkv"):
+                h = norm(x)
+                qkv = h @ layer["qkv"]  # [3D]
+                q, k_new, v_new = jnp.split(qkv, 3)
+                q = q.reshape(H, Dh)
+                k_new = k_new.reshape(H, 1, Dh)
+                v_new = v_new.reshape(H, 1, Dh)
+            with jax.named_scope("cache_update"):
+                held, rows = (cache["k"], cache["v"]), (k_new, v_new)
+                k, v = (write_rows(held, rows, pos) if active is None
+                        else write_slot_rows(held, rows, pos, active))
+            if self._attention_impl == "pallas":
+                from ..ops.decode_attention import decode_attention
+
+                with jax.named_scope("attention"):
+                    attn = decode_attention(
+                        q[None], k[None], v[None],
+                        jnp.asarray(pos, jnp.int32).reshape(1),
+                    )[0]  # [H, Dh], bf16 (kernel accumulates fp32)
+                with jax.named_scope("attn_proj"):
+                    x = x + (attn.reshape(D) @ layer["proj"])
+            else:
+                with jax.named_scope("attention"):
+                    # position-based mask: only slots <= pos attend,
+                    # and they lie in the prefix (the row just written
+                    # at ``pos`` among them)
+                    scores = jnp.einsum(
+                        "hd,hmd->hm", q.astype(jnp.float32),
+                        k[:, :live].astype(jnp.float32)) * (Dh ** -0.5)
+                    mask = jnp.arange(live) <= pos
+                    scores = jnp.where(mask[None, :], scores, -jnp.inf)
+                    probs = jax.nn.softmax(scores, axis=-1)
+                    attn = jnp.einsum(
+                        "hm,hmd->hd", probs,
+                        v[:, :live].astype(jnp.float32))
+                with jax.named_scope("attn_proj"):
+                    x = x + (attn.reshape(D).astype(jnp.bfloat16)
+                             @ layer["proj"])
+            with jax.named_scope("mlp"):
+                h2 = norm(x)
+                x = x + (jax.nn.gelu(h2 @ layer["mlp_in"])
+                         @ layer["mlp_out"])
+            return x, {"k": k, "v": v}
+
+        # The slot batcher's layer is a jitted call: every layer has the same
+        # shapes, so its program traces the body, batches it under ``vmap``
+        # and lowers it once and not once a layer, which was most of what a
+        # program costs a warm set-up (1.4 s of Python at 36 layers, 0.15 s
+        # so), and there are as many programs as rungs. The chip's compiler
+        # inlines the calls: operation for operation the program it made
+        # without them. A single sequence's step stays one flat trace: the
+        # CPU's compiler rounds a bfloat16 carried across a call that it
+        # keeps in float32 within one computation, and decoder_tp.py's step
+        # is held bit-equal to this one there.
+        one_slot_layer = jax.jit(one_layer, static_argnames="live")
+
+        def step(params, caches, token, pos, active=None, *, live=M):
             """One decode step. caches: [L] dicts of k/v [H, M, Dh].
+
+            ``live`` is a compile-time length, a rung of the ladder that
+            covers ``pos``: the attention reads that prefix of the cache and
+            no reserved position beyond it. At ``M`` it is the whole cache.
+            The Pallas kernel takes no notice of it.
 
             The step owns ``caches``: the jitted programs donate them, so
             the returned caches are the same buffers with row ``pos``
@@ -167,58 +285,62 @@ class TinyDecoderModel(Model):
             with jax.named_scope("embed"):
                 x = params["embed"][token] + params["pos"][pos]  # [D]
             new_caches = []
+            a_layer = one_layer if active is None else one_slot_layer
             for layer, cache in zip(params["layers"], caches):
-                with jax.named_scope("attn_qkv"):
-                    h = norm(x)
-                    qkv = h @ layer["qkv"]  # [3D]
-                    q, k_new, v_new = jnp.split(qkv, 3)
-                    q = q.reshape(H, Dh)
-                    k_new = k_new.reshape(H, 1, Dh)
-                    v_new = v_new.reshape(H, 1, Dh)
-                with jax.named_scope("cache_update"):
-                    held, rows = (cache["k"], cache["v"]), (k_new, v_new)
-                    k, v = (write_rows(held, rows, pos) if active is None
-                            else write_slot_rows(held, rows, pos, active))
-                new_caches.append({"k": k, "v": v})
-                if self._attention_impl == "pallas":
-                    from ..ops.decode_attention import decode_attention
-
-                    with jax.named_scope("attention"):
-                        attn = decode_attention(
-                            q[None], k[None], v[None],
-                            jnp.asarray(pos, jnp.int32).reshape(1),
-                        )[0]  # [H, Dh], bf16 (kernel accumulates fp32)
-                    with jax.named_scope("attn_proj"):
-                        x = x + (attn.reshape(D) @ layer["proj"])
-                else:
-                    with jax.named_scope("attention"):
-                        # position-based mask: only slots <= pos attend
-                        scores = jnp.einsum(
-                            "hd,hmd->hm", q.astype(jnp.float32),
-                            k.astype(jnp.float32)) * (Dh ** -0.5)
-                        mask = jnp.arange(M) <= pos
-                        scores = jnp.where(mask[None, :], scores, -jnp.inf)
-                        probs = jax.nn.softmax(scores, axis=-1)
-                        attn = jnp.einsum(
-                            "hm,hmd->hd", probs, v.astype(jnp.float32))
-                    with jax.named_scope("attn_proj"):
-                        x = x + (attn.reshape(D).astype(jnp.bfloat16)
-                                 @ layer["proj"])
-                with jax.named_scope("mlp"):
-                    h2 = norm(x)
-                    x = x + (jax.nn.gelu(h2 @ layer["mlp_in"])
-                             @ layer["mlp_out"])
+                x, cache = a_layer(layer, cache, x, pos, active, live=live)
+                new_caches.append(cache)
             with jax.named_scope("unembed"):
                 logits = (norm(x) @ params["unembed"]).astype(jnp.float32)
             return logits, new_caches
 
         self._params = params
-        self._step_fn = jax.jit(step, donate_argnums=1)
+        self._step_fn = jax.jit(step, donate_argnums=1,
+                                static_argnames="live")
+        if self._attention_impl == "einsum":
+            self._rungs = self.ladder()
 
     def _ensure_built(self):
         with self._lock:
             if self._step_fn is None:
                 self._build()
+
+    def _step_at(self, caches, token, pos, live: int):
+        """The jitted step at one rung. The whole length is the step's own
+        default, and all that a subclass's step knows."""
+        if live == self.MAX_LEN:
+            return self._step_fn(self._params, caches, token, pos)
+        return self._step_fn(self._params, caches, token, pos, live=live)
+
+    def _ensure_warm(self) -> None:
+        """Every rung's program compiled, by one real step a rung on a
+        scratch cache, before the first step of a sequence is dispatched:
+        a session that crosses a rung in the middle of serving finds its
+        program there. A ladder of one rung has nothing to build ahead: its
+        one program is compiled by its first step. Whoever fails here fails
+        alone; the next caller tries again. (Not on a thread beside the
+        frontend's start: what a second program costs a warm set-up is its
+        trace, which holds the interpreter.)"""
+        if self._warm:
+            return
+        with self._warm_lock:
+            if self._warm:
+                return
+            if len(self._rungs) > 1:
+                caches = self._fresh_cache()
+                for live in self._rungs:
+                    _, caches = self._step_at(caches, 0, 0, live)
+            self._warm = True
+
+    def decode_step(self, caches, token: int, pos: int,
+                    count: Optional[RungCount] = None):
+        """One token of one sequence, at the shortest rung that covers it;
+        ``(logits, caches)`` as the jitted step gives them. Every model that
+        steps a single sequence through this decoder steps it here, and
+        hands in its own count of steps by rung."""
+        self._ensure_warm()
+        live = self.rung_for(pos + 1)
+        (self.steps_by_rung if count is None else count).add(live)
+        return self._step_at(caches, token, pos, live)
 
     def _fresh_cache(self):
         import jax.numpy as jnp
@@ -269,14 +391,14 @@ class TinyDecoderModel(Model):
                     raise ValueError(
                         f"sequence longer than max_len {self.MAX_LEN}")
 
-            # the compiled step runs one token at a time — same executable
-            # for prefill and decode (static shapes; cache carries history)
+            # the compiled step runs one token at a time — the same
+            # executables for prefill and decode (static shapes; cache
+            # carries history)
             caches, pos = state["caches"], state["pos"]
             logits = None
             try:
                 for t in tokens:
-                    logits, caches = self._step_fn(
-                        self._params, caches, int(t), pos)
+                    logits, caches = self.decode_step(caches, int(t), pos)
                     pos += 1
             except Exception:
                 # the step owned the caches it was given: after a failure

@@ -38,7 +38,11 @@ works, the round in flight is the gather. A slot whose sequence has no
 request in the table is masked: the step writes no row of its
 (``active``, decoder.py's ``write_active_rows``) and its logits row goes
 unread, which keeps the executable static-shape — the same compile-once
-property the single-sequence decoder has.
+property the single-sequence decoder has. Once a rung, that is: a round's
+attention reads the prefix of the caches that covers the furthest of the
+round's members (decoder.py's ladder; a slot that rides along inactive may
+hold any older position, its row is not read), and the worker compiles
+every rung's program before it takes its first request.
 
 Weights come from a composed TinyDecoderModel (same seed ⇒ greedy tokens
 match the unbatched fixture token-for-token — pinned by the tests).
@@ -47,6 +51,7 @@ match the unbatched fixture token-for-token — pinned by the tests).
 from __future__ import annotations
 
 import collections
+import functools
 import queue
 import threading
 import time
@@ -68,7 +73,7 @@ from ..server.timeline import (
     span,
 )
 from .base import Model, TensorSpec
-from .decoder import TinyDecoderModel
+from .decoder import RungCount, TinyDecoderModel
 
 # Rounds dispatched and not yet read back, at most. It is the fairness rule:
 # a worker that ran ahead of the device would enqueue a prompt's every round
@@ -148,8 +153,11 @@ class BatchedDecoderModel(Model):
         self._in_flight: Deque[
             Tuple[int, Any, List[Tuple[_SeqRequest, int]]]] = collections.deque()
         self._answers: List[Tuple[_SeqRequest, np.ndarray, int]] = []
-        # observability for tests/tuning: rounds executed per batch width
+        # observability for tests/tuning: rounds executed per batch width,
+        # and per rung of the decoder's ladder
         self.batch_histogram: Dict[int, int] = {}
+        self.steps_by_rung = RungCount()
+        self._warm = False  # every rung's program is compiled
         self._rounds = 0  # rounds dispatched so far: the next round's id
         # ``(width, dispatch_ns)`` of every round, for the statistics verb's
         # batch_stats; ServerCore.add_model binds its recorder here
@@ -176,13 +184,18 @@ class BatchedDecoderModel(Model):
             dec = self._decoder
             S = self.slots
             # (params, caches, token, pos, active) per sequence; a slot
-            # that is not ``active`` writes no cache row, inside the step
-            vstep = jax.vmap(dec._step_fn, in_axes=(None, 0, 0, 0, 0))
+            # that is not ``active`` writes no cache row, inside the step.
+            # ``live`` is the round's, one compile-time length for all slots
 
-            def batched_step(params, caches, tokens, pos, active):
-                return vstep(params, caches, tokens, pos, active)
+            def batched_step(params, caches, tokens, pos, active, *,
+                             live=dec.MAX_LEN):
+                return jax.vmap(
+                    functools.partial(dec._step_fn, live=live),
+                    in_axes=(None, 0, 0, 0, 0))(
+                        params, caches, tokens, pos, active)
 
-            self._batched_step = jax.jit(batched_step, donate_argnums=1)
+            self._batched_step = jax.jit(batched_step, donate_argnums=1,
+                                         static_argnames="live")
             self._caches = self._fresh_caches()
             # positions live HOST-side (0 on start, +1 per active token —
             # fully derivable without a device readback) and ship to the
@@ -293,6 +306,11 @@ class BatchedDecoderModel(Model):
 
     # -- the worker: one turn a round ----------------------------------------
     def _run(self) -> None:
+        try:
+            # while whoever built the model starts its frontend
+            self._ensure_warm()
+        except Exception:
+            pass  # the first round tries again, and fails its requests
         # until unload's sentinel comes off the queue; then what is begun
         # is ended
         while (self._taking or self._table or self._carry or self._in_flight
@@ -431,6 +449,31 @@ class BatchedDecoderModel(Model):
                 if now - last > self._idle_ttl_s:
                     self._free_slot(seq_id)
 
+    def _step_at(self, tokens, pos, active, live: int):
+        """The jitted step over every slot at one rung; the whole length is
+        the step's own default. It owns the stacked caches."""
+        params = self._decoder._params
+        if live == self._decoder.MAX_LEN:
+            return self._batched_step(params, self._caches, tokens, pos, active)
+        return self._batched_step(
+            params, self._caches, tokens, pos, active, live=live)
+
+    def _ensure_warm(self) -> None:
+        """Every rung's program compiled before the first round, by one
+        real step a rung over the slots' own caches with no slot active (no
+        row is written, the caches come back as they were): a round that
+        crosses a rung in the middle of serving finds its program there. A
+        ladder of one rung has nothing to build ahead. The worker's alone."""
+        if self._warm:
+            return
+        rungs = self._decoder._rungs
+        if len(rungs) > 1:
+            zeros = np.zeros((self.slots,), np.int32)
+            nobody = np.zeros((self.slots,), bool)
+            for live in rungs:
+                _, self._caches = self._step_at(zeros, zeros, nobody, live)
+        self._warm = True
+
     def _dispatch_round(self) -> None:
         """One round: the next token of every request of the table, in one
         dispatch. A request whose tokens are spent leaves the table for the
@@ -451,10 +494,12 @@ class BatchedDecoderModel(Model):
             # inside its one call, where three calls of jnp.asarray
             # each let go of the interpreter on the way
             pos = self._pos.copy()
+            # the shortest rung that covers the furthest member
+            live = self._decoder.rung_for(int(pos[active].max()) + 1)
         try:
+            self._ensure_warm()
             with span(SPAN_ROUND_DISPATCH) as dispatch:
-                logits, self._caches = self._batched_step(
-                    self._decoder._params, self._caches, tokens, pos, active)
+                logits, self._caches = self._step_at(tokens, pos, active, live)
         except Exception as e:  # a failed dispatch must not strand callers
             self._table.clear()
             self._fail_round(e, members)
@@ -462,6 +507,7 @@ class BatchedDecoderModel(Model):
         self._pos[active] += 1
         width = len(members)
         self.batch_histogram[width] = self.batch_histogram.get(width, 0) + 1
+        self.steps_by_rung.add(live)
         if self.report_batch is not None:
             self.report_batch(width, dispatch.ns)
         answered = []

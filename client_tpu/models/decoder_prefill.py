@@ -36,7 +36,7 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 from .base import Model, TensorSpec
-from .decoder import TinyDecoderModel
+from .decoder import RungCount, TinyDecoderModel
 from .decoder_tp import TPDecoderModel
 
 
@@ -67,6 +67,7 @@ class PrefillDecoderModel(Model):
             TPDecoderModel(seed=seed, tp=tp_degree, mesh=mesh, axis=axis)
             if tp else TinyDecoderModel(seed=seed))
         self.name = "decoder_lm_tp_prefill" if tp else "decoder_lm_prefill"
+        self.steps_by_rung = RungCount()
 
     def inputs(self) -> List[TensorSpec]:
         return [TensorSpec("TOKENS", "INT32", [-1, -1])]
@@ -100,11 +101,11 @@ class PrefillDecoderModel(Model):
                 caches = inner._fresh_cache()
                 logits = None
                 # one compiled step per token, fresh cache per row: the
-                # same executable (and therefore the same bits) as serving
+                # same executables (and therefore the same bits) as serving
                 # the row through the sequence API in one start+end request
                 for pos, tok in enumerate(row.tolist()):
-                    logits, caches = inner._step_fn(
-                        inner._params, caches, int(tok), pos)
+                    logits, caches = inner.decode_step(
+                        caches, int(tok), pos, self.steps_by_rung)
                 rows.append(
                     np.asarray(logits, dtype=np.float32).reshape(-1))
         logits_np = np.stack(rows).astype(np.float32)

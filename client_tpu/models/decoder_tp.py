@@ -174,6 +174,7 @@ class TPDecoderModel(TinyDecoderModel):
             logits = (norm(x) @ params["unembed"]).astype(jnp.float32)
             return logits, new_caches
 
+        self._rungs = (M,)  # this step reads the whole cache
         self._step_fn = jax.jit(
             step, out_shardings=(
                 NamedSharding(mesh, P()),

@@ -55,7 +55,7 @@ from typing import Any, Dict, Iterable, List
 import numpy as np
 
 from .base import Model, TensorSpec
-from .decoder import TinyDecoderModel
+from .decoder import RungCount, TinyDecoderModel
 
 
 def _kv_shape(dec: TinyDecoderModel) -> List[int]:
@@ -76,6 +76,7 @@ class DisaggPrefillModel(Model):
         # bit-exactness across serving styles requires ONE parameter set
         self._decoder = (decoder if decoder is not None
                          else TinyDecoderModel(seed=seed))
+        self.steps_by_rung = RungCount()
 
     def inputs(self) -> List[TensorSpec]:
         return [TensorSpec("TOKENS", "INT32", [1, -1])]
@@ -99,13 +100,14 @@ class DisaggPrefillModel(Model):
         if tokens.size >= dec.MAX_LEN:
             raise ValueError(f"prompt longer than max_len {dec.MAX_LEN}")
 
-        # same compiled step the monolithic paths use — nothing new
-        # compiles, and the produced cache is bit-identical to the state
-        # tiny_lm_generate would hold after the same token sequence
+        # same compiled step, rung for rung, the monolithic paths use —
+        # nothing new compiles, and the produced cache is bit-identical to
+        # the state tiny_lm_generate would hold after the same token sequence
         caches, pos = dec._fresh_cache(), 0
         logits = None
         for t in tokens:
-            logits, caches = dec._step_fn(dec._params, caches, int(t), pos)
+            logits, caches = dec.decode_step(
+                caches, int(t), pos, self.steps_by_rung)
             pos += 1
 
         # [L*2, H, M, Dh] fp32: exact widening of the bf16 cache
@@ -136,6 +138,7 @@ class KvDecodeModel(Model):
         super().__init__()
         self._decoder = (decoder if decoder is not None
                          else TinyDecoderModel(seed=seed))
+        self.steps_by_rung = RungCount()
 
     def inputs(self) -> List[TensorSpec]:
         return [
@@ -217,7 +220,7 @@ class KvDecodeModel(Model):
                 return
             if pos >= M:
                 return  # static cache exhausted
-            logits, caches = dec._step_fn(
-                dec._params, caches, next_token, pos)
+            logits, caches = dec.decode_step(
+                caches, next_token, pos, self.steps_by_rung)
             pos += 1
             next_token = int(np.asarray(logits).argmax())

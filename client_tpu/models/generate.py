@@ -11,13 +11,17 @@ weights with the stateful ``decoder_lm`` fixture so greedy generation is
 bit-exact across both serving styles (the cross-check the tests pin).
 
 TPU-first choices:
-- one compiled decode step (static-shape KV cache, position-based mask —
-  see decoder.py) serves prefill AND every generated token: no
-  shape-polymorphic retraces, ever;
+- one compiled decode step a rung of the decoder's ladder (static-shape KV
+  cache, position-based mask, attention over the live prefix — see
+  decoder.py) serves prefill AND every generated token: no
+  shape-polymorphic retraces, ever, and every rung is compiled before the
+  first stream's first step (``_ensure_built``);
 - multi-token decoding runs INSIDE XLA via ``lax.scan`` when the request
   sets the ``chunk`` parameter > 1: the greedy argmax→feed-back loop is a
   scan carry, so K tokens cost one device dispatch instead of K (the
-  dispatch-bound regime is exactly where this wins);
+  dispatch-bound regime is exactly where this wins); these programs, one a
+  K and compiled on demand, read the whole cache: a ladder would multiply
+  them;
   chunk=1 (the default) dispatches per token, which is what a
   streaming-latency harness should measure;
 - greedy argmax happens on-device in int32 — the host only ever sees the
@@ -52,7 +56,7 @@ from ..server.timeline import (
     span,
 )
 from .base import Model, TensorSpec
-from .decoder import TinyDecoderModel
+from .decoder import RungCount, TinyDecoderModel
 
 
 class TinyGenerateModel(Model):
@@ -75,6 +79,7 @@ class TinyGenerateModel(Model):
         self._decoder = decoder if decoder is not None else TinyDecoderModel(seed=seed)
         self._lock = threading.Lock()
         self._chunk_fns: Dict[int, Any] = {}  # scan length K -> jitted fn
+        self.steps_by_rung = RungCount()  # per-token steps; not a chunk's
 
     def inputs(self) -> List[TensorSpec]:
         return [
@@ -92,6 +97,7 @@ class TinyGenerateModel(Model):
     # -- compiled pieces -----------------------------------------------------
     def _ensure_built(self):
         self._decoder._ensure_built()
+        self._decoder._ensure_warm()
 
     def _chunk_fn(self, k: int):
         """Jitted K-token greedy decode: the argmax→feed-back loop as a
@@ -174,15 +180,17 @@ class TinyGenerateModel(Model):
         if timeline is not None:
             timeline.stream = marks
 
-        # prefill: the single compiled step over the prompt (same executable
-        # the decode loop uses — nothing new compiles per prompt length)
+        # prefill: the single compiled step over the prompt (the same
+        # executables the decode loop uses — nothing new compiles per prompt
+        # length)
         with span(SPAN_FRESH_CACHE) as s:
             caches, pos = dec._fresh_cache(), 0
         marks.cache_ready = s.end_ns
         logits = None
         with span(SPAN_PREFILL) as s:
             for t in tokens:
-                logits, caches = dec._step_fn(dec._params, caches, int(t), pos)
+                logits, caches = dec.decode_step(
+                    caches, int(t), pos, self.steps_by_rung)
                 pos += 1
                 # one step of a stream in the device's queue at a time, in
                 # prefill as in decode: no step call waits for room any
@@ -217,8 +225,8 @@ class TinyGenerateModel(Model):
                                          and next_token == end_id):
                     return
                 with span(SPAN_DISPATCH) as s:
-                    logits, caches = dec._step_fn(
-                        dec._params, caches, next_token, pos)
+                    logits, caches = dec.decode_step(
+                        caches, next_token, pos, self.steps_by_rung)
                 marks.dispatch.add(s.ns, emitted)
                 pos += 1
                 with span(SPAN_READBACK) as s:

@@ -575,6 +575,12 @@ class ServerCore:
             "client_tpu_server_compile_seconds",
             "Cumulative XLA compile time in this process")
 
+        decode_steps = reg.gauge(
+            "client_tpu_server_decode_steps",
+            "Decode steps dispatched, by the rung of the decoder's ladder "
+            "they read: the live positions of the cache",
+            ("model", "live"))
+
         def collect():
             live.set(1.0 if self.live else 0.0)
             ready.set(1.0 if (self.live and self.ready) else 0.0)
@@ -594,6 +600,12 @@ class ServerCore:
                     stats["compute_infer"]["ns"] / 1e9)
             with self._lock:
                 traced.set(len(self._access))
+                models = list(self._models.items())
+            for name, model in models:
+                count = getattr(model, "steps_by_rung", None)
+                if count is not None:  # a model that steps a decoder
+                    for rung, steps in count.by_rung().items():
+                        decode_steps.labels(name, rung).set(steps)
             compile_count.set(COMPILES.count)
             compile_seconds.set(COMPILES.ns / 1e9)
 

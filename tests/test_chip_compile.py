@@ -178,17 +178,23 @@ def _published_widths_decoder():
     return decoder
 
 
+@pytest.mark.parametrize("live", [256, 1024])
 @pytest.mark.parametrize("program", ["jit_step", "jit_batched_step"])
-def test_the_step_writes_its_donated_caches_in_place_on_the_chip(chip, program):
+def test_the_step_writes_its_donated_caches_in_place_on_the_chip(
+        chip, program, live):
     """What the CPU cannot show: compiled for a v5e, the step aliases every
-    cache to an output and moves no whole cache into another layout. (The
-    scatter that ``vmap`` alone makes of the batcher's row writes has each
-    stacked cache copied to a row-major layout and back every round.)"""
+    cache to an output and moves no whole cache into another layout, at
+    every rung of the ladder. (The scatter that ``vmap`` alone makes of the
+    batcher's row writes has each stacked cache copied to a row-major layout
+    and back every round.) At the short rung the attention takes the prefix
+    of the cache as it lies: no slice of it is materialised, and no whole
+    cache is moved through fast memory ahead of the read."""
     import re
 
     from client_tpu.models.decoder_batched import BatchedDecoderModel
 
     decoder = _published_widths_decoder()
+    assert decoder._rungs == (256, 1024)
     scalar = _s((), jnp.int32)
     if program == "jit_step":
         fn, caches = decoder._step_fn, decoder._fresh_cache()
@@ -198,17 +204,26 @@ def test_the_step_writes_its_donated_caches_in_place_on_the_chip(chip, program):
         model._decoder = decoder  # composed before the batcher builds
         model._ensure_built()
         model.unload()  # the worker thread; the program stays
-        fn, caches = model._batched_step, model._caches
+        fn, caches = model._batched_step, model._fresh_caches()
         rest = (_s((16,), jnp.int32), _s((16,), jnp.int32),
                 _s((16,), jnp.bool_))
     args = jax.tree_util.tree_map(
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip),
         (_shapes_of(decoder._params), _shapes_of(caches)) + rest)
-    text = fn.lower(*args).compile().as_text()
+    text = fn.lower(*args, live=live).compile().as_text()
     assert f"HloModule {program}," in text
     aliased = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
     assert aliased.count("-alias)") == 2 * decoder.LAYERS
-    shape = ",".join(str(n) for n in caches[0]["k"].shape)
+    dims = caches[0]["k"].shape
+    shape = ",".join(str(n) for n in dims)
     entry = text[text.index("\nENTRY "):]
     relaid = re.findall(rf"= bf16\[{shape}\]\{{[^}}]*\}} copy\(", entry)
     assert not relaid, f"{len(relaid)} whole caches copied to another layout"
+    if live < decoder.MAX_LEN:
+        prefix = ",".join(str(n) for n in dims[:-2] + (live, dims[-1]))
+        sliced = re.findall(rf"= bf16\[{prefix}\]\{{[^}}]*\}} [a-z-]+\(", entry)
+        assert not sliced, f"{len(sliced)} prefixes materialised: {sliced[:2]}"
+        staged = re.findall(
+            rf"bf16\[[0-9,]*{dims[-2]},{dims[-1]}\]\{{[^}}]*\}}[^=]*"
+            r"(?:copy-start|slice-start)\(", entry)
+        assert not staged, f"{len(staged)} caches staged: {staged[:2]}"
