@@ -44,22 +44,58 @@ from .base import Model, TensorSpec
 SHORTEST_RUNG = 256
 
 
+def ladder_of(max_len: int) -> Tuple[int, ...]:
+    """The lengths a step's attention may read, shortest first: ``max_len``,
+    a quarter of it, a sixteenth ... for as long as the rung is at least
+    ``SHORTEST_RUNG``. The ratio is the set-up budget speaking: every rung is
+    one more program to build before serving (PERF.md section 6, PR 31)."""
+    rungs = [max_len]
+    while rungs[0] % 4 == 0 and rungs[0] // 4 >= SHORTEST_RUNG:
+        rungs.insert(0, rungs[0] // 4)
+    return tuple(rungs)
+
+
 class RungCount:
-    """Steps dispatched, by the rung they read: one count a served model,
-    which ``ServerCore.metrics_registry`` reads as
-    ``client_tpu_server_decode_steps{model,live}``."""
+    """What a served model's decoder was asked for, counted: steps
+    dispatched by the rung they read, which ``ServerCore.metrics_registry``
+    reads as ``client_tpu_server_decode_steps{model,live}``; of those the
+    steps that attended to a chosen subset of the cache (``selecting_steps``);
+    and the prompts' side: tokens prefilled, the dispatches that took
+    (``prefill_chunks``) and the host's time from a stream's cache to its
+    last prefill dispatch's return (``prefill_ns``)."""
+
+    TOTALS = ("selecting_steps", "prefill_tokens", "prefill_chunks", "prefill_ns")
 
     def __init__(self):
         self._lock = threading.Lock()
         self._steps: Dict[int, int] = {}
+        self._totals = dict.fromkeys(self.TOTALS, 0)
 
     def add(self, live: int) -> None:
         with self._lock:
             self._steps[live] = self._steps.get(live, 0) + 1
 
+    def add_selecting(self) -> None:
+        with self._lock:
+            self._totals["selecting_steps"] += 1
+
+    def add_prefill(self, tokens: int, chunks: int = 1) -> None:
+        """``tokens`` prompt tokens prefilled in ``chunks`` dispatches."""
+        with self._lock:
+            self._totals["prefill_tokens"] += tokens
+            self._totals["prefill_chunks"] += chunks
+
+    def add_prefill_ns(self, ns: int) -> None:
+        with self._lock:
+            self._totals["prefill_ns"] += ns
+
     def by_rung(self) -> Dict[int, int]:
         with self._lock:
             return dict(self._steps)
+
+    def totals(self) -> Dict[str, int]:
+        with self._lock:
+            return dict(self._totals)
 
 
 class TinyDecoderModel(Model):
@@ -113,15 +149,8 @@ class TinyDecoderModel(Model):
     # -- the ladder ----------------------------------------------------------
     @classmethod
     def ladder(cls) -> Tuple[int, ...]:
-        """The lengths a step's attention may read, shortest first:
-        ``MAX_LEN``, a quarter of it, a sixteenth ... for as long as the rung
-        is at least ``SHORTEST_RUNG``. A function of ``MAX_LEN`` alone. The
-        ratio is the set-up budget speaking: every rung is one more program
-        to build before serving (PERF.md section 6, PR 31)."""
-        rungs = [cls.MAX_LEN]
-        while rungs[0] % 4 == 0 and rungs[0] // 4 >= SHORTEST_RUNG:
-            rungs.insert(0, rungs[0] // 4)
-        return tuple(rungs)
+        """``ladder_of(MAX_LEN)``: a function of ``MAX_LEN`` alone."""
+        return ladder_of(cls.MAX_LEN)
 
     def rung_for(self, reach: int) -> int:
         """The shortest rung that covers ``reach`` positions: a step at
@@ -342,6 +371,39 @@ class TinyDecoderModel(Model):
         (self.steps_by_rung if count is None else count).add(live)
         return self._step_at(caches, token, pos, live)
 
+    def prefill(self, caches, tokens, pos: int,
+                count: Optional[RungCount] = None):
+        """A prompt's ``tokens`` from ``pos`` on through the cache:
+        ``(logits, caches)`` after the last. Here it is the compiled step
+        over the prompt, a token a dispatch (the same executables the decode
+        loop uses: nothing new compiles per prompt length), each waited for:
+        one step of a stream in the device's queue at a time, in prefill as
+        in decode, since a prompt enqueued whole holds every other stream's
+        next token behind it. The loop is the one ``generate.py`` held until
+        PR 32, with nothing added inside it; the prompt is counted once,
+        after it. A decoder with a prefill program of its own overrides
+        this."""
+        count = self.steps_by_rung if count is None else count
+        logits = None
+        for t in tokens:
+            logits, caches = self.decode_step(caches, int(t), pos, count)
+            pos += 1
+            logits.block_until_ready()
+        count.add_prefill(len(tokens), chunks=len(tokens))
+        return logits, caches
+
+    def _advance(self, caches, tokens, pos: int):
+        """A request's ``tokens`` through the cache on the sequence API:
+        the compiled step a token at a time, the same executables for a
+        prompt and a continuation (static shapes; the cache carries the
+        history), enqueued without waiting. A decoder with a prefill program
+        overrides this for prompts."""
+        logits = None
+        for t in tokens:
+            logits, caches = self.decode_step(caches, int(t), pos)
+            pos += 1
+        return logits, caches
+
     def _fresh_cache(self):
         import jax.numpy as jnp
 
@@ -391,15 +453,10 @@ class TinyDecoderModel(Model):
                     raise ValueError(
                         f"sequence longer than max_len {self.MAX_LEN}")
 
-            # the compiled step runs one token at a time — the same
-            # executables for prefill and decode (static shapes; cache
-            # carries history)
             caches, pos = state["caches"], state["pos"]
-            logits = None
             try:
-                for t in tokens:
-                    logits, caches = self.decode_step(caches, int(t), pos)
-                    pos += 1
+                logits, caches = self._advance(caches, tokens, pos)
+                pos += len(tokens)
             except Exception:
                 # the step owned the caches it was given: after a failure
                 # the sequence has no state, and says so to its next request

@@ -180,24 +180,17 @@ class TinyGenerateModel(Model):
         if timeline is not None:
             timeline.stream = marks
 
-        # prefill: the single compiled step over the prompt (the same
-        # executables the decode loop uses — nothing new compiles per prompt
-        # length)
+        # prefill: the decoder's own (``TinyDecoderModel``: the single
+        # compiled step over the prompt, each step waited for; a decoder with
+        # a prefill program: a chunk of tokens a dispatch)
         with span(SPAN_FRESH_CACHE) as s:
             caches, pos = dec._fresh_cache(), 0
         marks.cache_ready = s.end_ns
-        logits = None
         with span(SPAN_PREFILL) as s:
-            for t in tokens:
-                logits, caches = dec.decode_step(
-                    caches, int(t), pos, self.steps_by_rung)
-                pos += 1
-                # one step of a stream in the device's queue at a time, in
-                # prefill as in decode: no step call waits for room any
-                # more, and a prompt enqueued whole holds every other
-                # stream's next token behind it
-                logits.block_until_ready()
+            logits, caches = dec.prefill(caches, tokens, pos, self.steps_by_rung)
+            pos += int(tokens.size)
         marks.prefill_done = s.end_ns
+        self.steps_by_rung.add_prefill_ns(marks.prefill_done - marks.cache_ready)
 
         def response(token_id: int, index: int):
             return {

@@ -580,6 +580,25 @@ class ServerCore:
             "Decode steps dispatched, by the rung of the decoder's ladder "
             "they read: the live positions of the cache",
             ("model", "live"))
+        # what the decoder counts beside its steps (models/decoder.py:
+        # RungCount.TOTALS), one series a served model each
+        decoder_totals = {
+            "selecting_steps": reg.gauge(
+                "client_tpu_server_selecting_steps",
+                "Decode steps that attended to a chosen subset of the cache "
+                "(a step past the indexer's topk positions)", ("model",)),
+            "prefill_tokens": reg.gauge(
+                "client_tpu_server_prefill_tokens",
+                "Prompt tokens prefilled", ("model",)),
+            "prefill_chunks": reg.gauge(
+                "client_tpu_server_prefill_chunks",
+                "Prefill dispatches (a decoder without a prefill program: "
+                "one a token)", ("model",)),
+            "prefill_ns": reg.gauge(
+                "client_tpu_server_prefill_ns",
+                "Host time of the streams' prefills, cache_ready to "
+                "prefill_done", ("model",)),
+        }
 
         def collect():
             live.set(1.0 if self.live else 0.0)
@@ -606,6 +625,8 @@ class ServerCore:
                 if count is not None:  # a model that steps a decoder
                     for rung, steps in count.by_rung().items():
                         decode_steps.labels(name, rung).set(steps)
+                    for total, value in count.totals().items():
+                        decoder_totals[total].labels(name).set(value)
             compile_count.set(COMPILES.count)
             compile_seconds.set(COMPILES.ns / 1e9)
 

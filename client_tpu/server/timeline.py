@@ -44,13 +44,14 @@ SPAN_WAIT_RESULT = "client_tpu.batcher.wait_result"
 SPAN_BATCH_READBACK = "client_tpu.batcher.readback"
 SPAN_FRESH_CACHE = "client_tpu.generate.fresh_cache"
 SPAN_PREFILL = "client_tpu.generate.prefill"
+SPAN_PREFILL_CHUNK = "client_tpu.generate.prefill_chunk"
 SPAN_DISPATCH = "client_tpu.generate.dispatch"
 SPAN_READBACK = "client_tpu.generate.readback"
 SPAN_NAMES = (
     SPAN_RESOLVE_INPUTS, SPAN_BUILD_RESPONSE, SPAN_COLLECT, SPAN_ADMIT,
     SPAN_ROUND_PREPARE, SPAN_ROUND_DISPATCH, SPAN_WAIT_RESULT,
-    SPAN_BATCH_READBACK, SPAN_FRESH_CACHE, SPAN_PREFILL, SPAN_DISPATCH,
-    SPAN_READBACK)
+    SPAN_BATCH_READBACK, SPAN_FRESH_CACHE, SPAN_PREFILL, SPAN_PREFILL_CHUNK,
+    SPAN_DISPATCH, SPAN_READBACK)
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 

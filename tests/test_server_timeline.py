@@ -320,12 +320,26 @@ def test_a_profiler_session_holds_every_span_name(tmp_path, traced_core):
     import jax
     from jax.profiler import ProfileData
 
+    from client_tpu.models.routed_decoder import RoutedDecoderModel
+
     core, _, matmul = traced_core
     _session(core, 50, [1, 2], 1)  # built and compiled before the session
     _generate(core, [1], 1)
+    # the one decoder with a prefill program, whose dispatches take
+    # ``prefill_chunk`` (the GPT-2 decoder's prompt is the step a token)
+    routed = RoutedDecoderModel({
+        "hidden_size": 32, "num_hidden_layers": 1, "num_attention_heads": 2,
+        "num_key_value_heads": 1, "head_dim": 16, "moe_intermediate_size": 16,
+        "num_experts": 4, "num_experts_per_tok": 2, "rms_norm_eps": 1e-6,
+        "rope_theta": 1e7, "vocab_size": 64, "max_position_embeddings": 64,
+        "sa_config": {"indexer_head_dim": 8, "indexer_num_heads": 2,
+                      "q_chunk_size": 4, "kv_chunk_size": 4, "topk": 8}}, seed=0)
+    routed._ensure_built()
+    routed._ensure_warm()
     jax.profiler.start_trace(str(tmp_path))
     try:
         _drive_all(core, matmul)
+        routed.prefill(routed._fresh_cache(), [1, 2, 3], 0)
     finally:
         jax.profiler.stop_trace()
     found = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
