@@ -643,9 +643,18 @@ def phase_language_model(served: Served, sizes: Sizes,
                     lambda i: _decode_sequence(
                         served, "decoder_lm", 5000 + i, prompts[i], n),
                     range(count)))
-            # the same compiled step behind both serving styles: bit-equal
-            check(reference[0][0] == generated,
-                  "decoder_lm sequence tokens differ from tiny_lm_generate's")
+            # the streams share a round, a program of another shape than
+            # decoder_lm's step: decoder_lm is fed the stream's tokens, and
+            # each has to lie within a near-tie of decoder_lm's own choice
+            _, forced = _decode_sequence(
+                served, "decoder_lm", 6000, prompts[0], n, feed=generated)
+            behind = max(float(row.max() - row[token])
+                         for row, token in zip(forced, generated))
+            check(behind <= 2 * LM_LOGIT_TOL,
+                  f"tiny_lm_generate chose a token {behind} under decoder_lm's")
+            ph.fields["stream_behind_decoder_lm"] = behind
+            ph.fields["stream_round_widths"] = sorted(
+                served.models["tiny_lm_generate"].rounds_by_width)
             ph.fields["logit_tol"] = LM_LOGIT_TOL
             for model in ("decoder_lm_batched", "decoder_lm_pallas",
                           "decoder_lm_batched_pallas"):

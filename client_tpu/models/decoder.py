@@ -32,6 +32,8 @@ Wire contract (stateful, one token per request after the start request):
 
 from __future__ import annotations
 
+import functools
+import math
 import threading
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -42,6 +44,15 @@ from .base import Model, TensorSpec
 # the shortest rung of a ladder: under it a step is its weights and the
 # prefix is not worth a program
 SHORTEST_RUNG = 256
+# the slots whose caches one turn of a round's attention reads, at most: the
+# round reads the occupied slots rounded up to a multiple of ``slots_a_turn``
+SLOTS_A_TURN = 4
+
+
+def slots_a_turn(slots: int) -> int:
+    """The slots a turn of a round's attention takes of a table of
+    ``slots``: the turns are whole, so it divides them."""
+    return math.gcd(slots, SLOTS_A_TURN)
 
 
 def ladder_of(max_len: int) -> Tuple[int, ...]:
@@ -124,6 +135,11 @@ class TinyDecoderModel(Model):
         self._lock = threading.Lock()
         self._params = None
         self._step_fn = None
+        # the round of a table of slots (``_fresh_table``), for a model whose
+        # streams share a dispatch (generate.py); None where this decoder
+        # has no such program: the Pallas kernel takes the whole cache of
+        # every slot, and a subclass that jits a step of its own brings none
+        self._round_fn = None
         # the lengths ``_step_fn`` takes as ``live``, shortest first: the
         # ladder where ``_build`` below made the step, the whole length alone
         # where a subclass jits a step of its own (decoder_tp.py)
@@ -201,25 +217,13 @@ class TinyDecoderModel(Model):
             return tuple(lax.dynamic_update_slice(cache, row, (0, pos, 0))
                          for cache, row in zip(caches, rows))
 
-        @jax.custom_batching.custom_vmap
-        def write_slot_rows(caches, rows, pos, active):
-            """The same for one slot of the batcher: where ``active`` is
-            false the slot's caches stay as they are."""
-            return lax.cond(active, lambda: write_rows(caches, rows, pos),
-                            lambda: caches)
-
-        @write_slot_rows.def_vmap
-        def write_active_rows(slots, batched, caches, rows, pos, active):
-            """The slot batcher's ``vmap`` of the above, over stacked caches
-            [slots, H, M, Dh]: one turn an active slot, each writing that
-            slot's two rows into the donated buffers where they lie, so the
-            work follows the round's width and an inactive slot (a full one,
-            a freed one) is not touched at all. ``vmap``'s own rule makes a
-            scatter of the per-slot position (and a select of the ``cond``),
-            for which the chip's compiler lays every stacked cache out anew
-            and back again each round."""
-            if not all(jax.tree_util.tree_leaves(batched)):
-                raise NotImplementedError("every operand is a slot's own")
+        def write_table_rows(caches, rows, pos, active):
+            """Stacked caches (k, v), each [slots, H, M, Dh], with ``rows``,
+            each [W, H, 1, Dh], of the table's leading W slots at ``pos``
+            [W], where ``active`` [W]: one turn an active slot, each writing
+            that slot's two rows into the donated buffers where they lie, so
+            the work follows the number of active slots and an inactive one
+            (a full one, a freed one) is not touched at all."""
             active_first = jnp.argsort(~active, stable=True)
 
             def write(turn, caches):
@@ -232,53 +236,85 @@ class TinyDecoderModel(Model):
                     for cache, slot_rows in zip(caches, rows))
 
             width = jnp.sum(active, dtype=jnp.int32)
-            return lax.fori_loop(0, width, write, caches), (True, True)
+            return lax.fori_loop(0, width, write, caches)
 
-        def one_layer(layer, cache, x, pos, active, *, live):
-            """One layer of the step: ``(x, cache)`` with the row at ``pos``
-            written."""
+        @jax.custom_batching.custom_vmap
+        def write_slot_rows(caches, rows, pos, active):
+            """``write_rows`` for one slot of the batcher: where ``active``
+            is false the slot's caches stay as they are."""
+            return lax.cond(active, lambda: write_rows(caches, rows, pos),
+                            lambda: caches)
+
+        @write_slot_rows.def_vmap
+        def write_active_rows(slots, batched, caches, rows, pos, active):
+            """The slot batcher's ``vmap`` of the above, over stacked caches:
+            ``write_table_rows``. ``vmap``'s own rule makes a scatter of the
+            per-slot position (and a select of the ``cond``), for which the
+            chip's compiler lays every stacked cache out anew and back again
+            each round."""
+            if not all(jax.tree_util.tree_leaves(batched)):
+                raise NotImplementedError("every operand is a slot's own")
+            return write_table_rows(caches, rows, pos, active), (True, True)
+
+        def embed(params, token, pos):
+            with jax.named_scope("embed"):
+                return params["embed"][token] + params["pos"][pos]  # [D]
+
+        def qkv_rows(layer, x):
+            """The query [H, Dh] and the new key and value rows, each
+            [H, 1, Dh], of one token."""
             with jax.named_scope("attn_qkv"):
                 h = norm(x)
                 qkv = h @ layer["qkv"]  # [3D]
                 q, k_new, v_new = jnp.split(qkv, 3)
-                q = q.reshape(H, Dh)
-                k_new = k_new.reshape(H, 1, Dh)
-                v_new = v_new.reshape(H, 1, Dh)
+                return (q.reshape(H, Dh), k_new.reshape(H, 1, Dh),
+                        v_new.reshape(H, 1, Dh))
+
+        def attention(q, k, v, pos, *, live):
+            """The attention of one token over a sequence's cache ``k``,
+            ``v`` with the row at ``pos`` written: [H, Dh]."""
+            with jax.named_scope("attention"):
+                if self._attention_impl == "pallas":
+                    from ..ops.decode_attention import decode_attention
+
+                    return decode_attention(
+                        q[None], k[None], v[None],
+                        jnp.asarray(pos, jnp.int32).reshape(1),
+                    )[0]  # bf16 (kernel accumulates fp32)
+                # position-based mask: only slots <= pos attend, and they
+                # lie in the prefix (the row just written at ``pos`` among
+                # them)
+                scores = jnp.einsum(
+                    "hd,hmd->hm", q.astype(jnp.float32),
+                    k[:, :live].astype(jnp.float32)) * (Dh ** -0.5)
+                mask = jnp.arange(live) <= pos
+                scores = jnp.where(mask[None, :], scores, -jnp.inf)
+                probs = jax.nn.softmax(scores, axis=-1)
+                return jnp.einsum(
+                    "hm,hmd->hd", probs, v[:, :live].astype(jnp.float32))
+
+        def rest_of_layer(layer, x, attn):
+            """The layer after its attention ``attn`` [H, Dh]."""
+            with jax.named_scope("attn_proj"):
+                x = x + (attn.reshape(D).astype(jnp.bfloat16) @ layer["proj"])
+            with jax.named_scope("mlp"):
+                h2 = norm(x)
+                return x + (jax.nn.gelu(h2 @ layer["mlp_in"])
+                            @ layer["mlp_out"])
+
+        def unembed(params, x):
+            with jax.named_scope("unembed"):
+                return (norm(x) @ params["unembed"]).astype(jnp.float32)
+
+        def one_layer(layer, cache, x, pos, active, *, live):
+            """One layer of the step: ``(x, cache)`` with the row at ``pos``
+            written."""
+            q, k_new, v_new = qkv_rows(layer, x)
             with jax.named_scope("cache_update"):
                 held, rows = (cache["k"], cache["v"]), (k_new, v_new)
                 k, v = (write_rows(held, rows, pos) if active is None
                         else write_slot_rows(held, rows, pos, active))
-            if self._attention_impl == "pallas":
-                from ..ops.decode_attention import decode_attention
-
-                with jax.named_scope("attention"):
-                    attn = decode_attention(
-                        q[None], k[None], v[None],
-                        jnp.asarray(pos, jnp.int32).reshape(1),
-                    )[0]  # [H, Dh], bf16 (kernel accumulates fp32)
-                with jax.named_scope("attn_proj"):
-                    x = x + (attn.reshape(D) @ layer["proj"])
-            else:
-                with jax.named_scope("attention"):
-                    # position-based mask: only slots <= pos attend,
-                    # and they lie in the prefix (the row just written
-                    # at ``pos`` among them)
-                    scores = jnp.einsum(
-                        "hd,hmd->hm", q.astype(jnp.float32),
-                        k[:, :live].astype(jnp.float32)) * (Dh ** -0.5)
-                    mask = jnp.arange(live) <= pos
-                    scores = jnp.where(mask[None, :], scores, -jnp.inf)
-                    probs = jax.nn.softmax(scores, axis=-1)
-                    attn = jnp.einsum(
-                        "hm,hmd->hd", probs,
-                        v[:, :live].astype(jnp.float32))
-                with jax.named_scope("attn_proj"):
-                    x = x + (attn.reshape(D).astype(jnp.bfloat16)
-                             @ layer["proj"])
-            with jax.named_scope("mlp"):
-                h2 = norm(x)
-                x = x + (jax.nn.gelu(h2 @ layer["mlp_in"])
-                         @ layer["mlp_out"])
+            x = rest_of_layer(layer, x, attention(q, k, v, pos, live=live))
             return x, {"k": k, "v": v}
 
         # The slot batcher's layer is a jitted call: every layer has the same
@@ -311,22 +347,94 @@ class TinyDecoderModel(Model):
             The named scopes are compile-time metadata: the device
             operations of a trace carry them, so that device time reads by
             part of the step whatever the compiler names its fusions."""
-            with jax.named_scope("embed"):
-                x = params["embed"][token] + params["pos"][pos]  # [D]
+            x = embed(params, token, pos)
             new_caches = []
             a_layer = one_layer if active is None else one_slot_layer
             for layer, cache in zip(params["layers"], caches):
                 x, cache = a_layer(layer, cache, x, pos, active, live=live)
                 new_caches.append(cache)
-            with jax.named_scope("unembed"):
-                logits = (norm(x) @ params["unembed"]).astype(jnp.float32)
-            return logits, new_caches
+            return unembed(params, x), new_caches
+
+        def over_slots(part, in_axes):
+            """``vmap`` of one of the step's parts, as a jitted call: a
+            scope named inside a call keeps its name under ``vmap`` (named
+            in the batched function itself it would read ``vmap(mlp)``, and
+            a trace is read by the plain names)."""
+            return jax.vmap(jax.jit(part), in_axes)
+
+        def round_layer(layer, cache, x, pos, active, turns, *, live):
+            """One layer of a round over a table: ``vmap`` of the step's own
+            parts over the slots, round the one row write that takes the
+            whole table where it lies. The products with the weights take
+            every slot (a slot more costs them nothing: they read the
+            weights). The attention takes the slots ``a_turn`` at a time for
+            ``turns`` turns, the occupied ones and no slot's cache beyond:
+            a round costs the caches of its live streams, in width as the
+            rung makes it in length, in one program a rung."""
+            slots = x.shape[0]
+            a_turn = slots_a_turn(slots)
+            q, k_new, v_new = over_slots(qkv_rows, (None, 0))(layer, x)
+            with jax.named_scope("cache_update"):
+                k, v = write_table_rows((cache["k"], cache["v"]),
+                                        (k_new, v_new), pos, active)
+            attend = over_slots(functools.partial(attention, live=live), 0)
+
+            def turn(n, attn):
+                at = n * a_turn
+                those = functools.partial(
+                    lax.dynamic_slice_in_dim, start_index=at,
+                    slice_size=a_turn)
+                # the slots of the turn and the prefix of their positions,
+                # one slice of the table as it lies
+                prefix = lambda cache: lax.dynamic_slice(
+                    cache, (at, 0, 0, 0), (a_turn, H, live, Dh))
+                return lax.dynamic_update_slice_in_dim(
+                    attn, attend(those(q), prefix(k), prefix(v), those(pos)),
+                    at, 0)
+
+            with jax.named_scope("attention"):  # the loop's own operations
+                attn = lax.fori_loop(
+                    0, turns, turn, jnp.zeros((slots, H, Dh), jnp.float32))
+            x = over_slots(rest_of_layer, (None, 0, 0))(layer, x, attn)
+            return x, {"k": k, "v": v}
+
+        a_round_layer = jax.jit(round_layer, static_argnames="live")
+
+        def round_program():
+            # traced as ``jit_step``: a round is the step of a model whose
+            # streams share it, and a trace is read by that name
+            def step(params, caches, fed, ctl, *, live):
+                """One round over a table of slots. caches: [L] dicts of k/v
+                [slots, H, M, Dh], donated. ``fed`` int32 [slots]: the
+                tokens the round before chose, still on the device. ``ctl``
+                int32 [3, slots], the host's word a slot: a token of its own
+                (a prompt's) or -1 for the fed one; the position; whether a
+                stream sits there. Returns the greedy choice of every slot,
+                int32 [slots], and the caches."""
+                given, pos, active = ctl[0], ctl[1], ctl[2] > 0
+                slots = active.shape[0]
+                occupied = jnp.max(jnp.where(active, jnp.arange(slots) + 1, 0))
+                turns = -(-occupied // slots_a_turn(slots))
+                token = jnp.where(given >= 0, given, fed)
+                x = over_slots(embed, (None, 0, 0))(params, token, pos)
+                new_caches = []
+                for layer, cache in zip(params["layers"], caches):
+                    x, cache = a_round_layer(
+                        layer, cache, x, pos, active, turns, live=live)
+                    new_caches.append(cache)
+                logits = over_slots(unembed, (None, 0))(params, x)
+                with jax.named_scope("greedy_argmax"):
+                    return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
+                            new_caches)
+
+            return jax.jit(step, donate_argnums=1, static_argnames="live")
 
         self._params = params
         self._step_fn = jax.jit(step, donate_argnums=1,
                                 static_argnames="live")
         if self._attention_impl == "einsum":
             self._rungs = self.ladder()
+            self._round_fn = round_program()
 
     def _ensure_built(self):
         with self._lock:
@@ -415,6 +523,16 @@ class TinyDecoderModel(Model):
             }
             for _ in range(self.LAYERS)
         ]
+
+    def _fresh_table(self, slots: int):
+        """``slots`` caches, stacked: [slots, heads, max_len, head_dim] a
+        layer, zeros."""
+        import jax.numpy as jnp
+
+        shape = (slots, self.HEADS, self.MAX_LEN, self.D_MODEL // self.HEADS)
+        return [{"k": jnp.zeros(shape, jnp.bfloat16),
+                 "v": jnp.zeros(shape, jnp.bfloat16)}
+                for _ in range(self.LAYERS)]
 
     # -- serving -------------------------------------------------------------
     def execute(self, inputs: Dict[str, np.ndarray], parameters: Dict[str, Any]):

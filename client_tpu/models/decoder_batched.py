@@ -211,15 +211,8 @@ class BatchedDecoderModel(Model):
             self._built = True
 
     def _fresh_caches(self):
-        """Every slot's cache, stacked: [slots, heads, max_len, head_dim] a
-        layer, zeros."""
-        import jax.numpy as jnp
-
-        dec = self._decoder
-        shape = (self.slots, dec.HEADS, dec.MAX_LEN, dec.D_MODEL // dec.HEADS)
-        return [{"k": jnp.zeros(shape, jnp.bfloat16),
-                 "v": jnp.zeros(shape, jnp.bfloat16)}
-                for _ in range(dec.LAYERS)]
+        """Every slot's cache, stacked, zeros."""
+        return self._decoder._fresh_table(self.slots)
 
     # -- serving (caller side) ----------------------------------------------
     def execute(self, inputs: Dict[str, np.ndarray],

@@ -175,6 +175,7 @@ class TPDecoderModel(TinyDecoderModel):
             return logits, new_caches
 
         self._rungs = (M,)  # this step reads the whole cache
+        self._round_fn = None  # the base's round reads the base's weights
         self._step_fn = jax.jit(
             step, out_shardings=(
                 NamedSharding(mesh, P()),

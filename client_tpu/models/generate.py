@@ -10,22 +10,47 @@ exactly this shape: the client sends one request carrying the prompt and
 weights with the stateful ``decoder_lm`` fixture so greedy generation is
 bit-exact across both serving styles (the cross-check the tests pin).
 
-TPU-first choices:
-- one compiled decode step a rung of the decoder's ladder (static-shape KV
-  cache, position-based mask, attention over the live prefix — see
-  decoder.py) serves prefill AND every generated token: no
-  shape-polymorphic retraces, ever, and every rung is compiled before the
-  first stream's first step (``_ensure_built``);
-- multi-token decoding runs INSIDE XLA via ``lax.scan`` when the request
-  sets the ``chunk`` parameter > 1: the greedy argmax→feed-back loop is a
-  scan carry, so K tokens cost one device dispatch instead of K (the
-  dispatch-bound regime is exactly where this wins); these programs, one a
-  K and compiled on demand, read the whole cache: a ladder would multiply
-  them;
-  chunk=1 (the default) dispatches per token, which is what a
-  streaming-latency harness should measure;
-- greedy argmax happens on-device in int32 — the host only ever sees the
-  emitted token ids, one int per token.
+Two paths, chosen by what the composed decoder provides and sharing no
+scheduling logic:
+
+**The streams share a round** where the decoder offers the round program
+and the stacked caches (``decoder._round_fn``: ``TinyDecoderModel`` and its
+subclasses with ``attention_impl="einsum"``). ``stream_rounds.py`` has the
+mechanism; in short:
+- a table of ``slots`` caches is reserved once at build (default
+  ``DEFAULT_SLOTS``), donated to every round and written in place. A stream
+  takes the lowest free slot at admission, gives it back when its budget is
+  spent, its ``END_ID`` is emitted, its client cancels or its generator is
+  closed, and waits, first come first seated, where none is free; no stream
+  is refused for want of a slot;
+- one worker dispatches the rounds: a round consumes the next token of
+  every seated stream, a prompt token for a stream still in its prompt, the
+  token the round before chose for a decoding one. That token is chosen on
+  the device (greedy argmax, int32) and fed back there; the host sees one
+  int32 a slot a round and supplies prompt tokens alone. A stream's
+  ``execute_decoupled`` validates, hands over its prompt, budget and
+  ``END_ID`` and then only waits for its tokens and yields them;
+- a round's program has a rung (the live prefix of the positions:
+  decoder.py's ladder), and its attention reads the caches of the occupied
+  slots alone, ``decoder.SLOTS_A_TURN`` slots a turn of a loop that ends
+  after the highest occupied one: one program a rung, each compiled in
+  ``_ensure_built``, before the first round;
+- ``chunk`` > 1 keeps its wire meaning on this path: after the first token
+  the tokens are delivered K at a time, off the same rounds.
+
+**A stream steps its own sequence** where the decoder has no round
+(``RoutedDecoderModel``, whose experts a ``vmap`` over slots would gather a
+slot and whose prefill is a chunk program; ``attention_impl="pallas"``,
+whose kernel takes every slot's whole cache; a subclass that jits its own
+step): a cache a session, the decoder's own ``prefill``, one dispatch a
+token, the logits' argmax on the host; ``chunk`` > 1 runs K steps INSIDE
+XLA via ``lax.scan`` (``decode_k``: the greedy argmax→feed-back loop is a
+scan carry, so K tokens cost one device dispatch; one program a K, compiled
+on demand, over the whole cache).
+
+On either path one compiled program a rung serves prefill AND every
+generated token: no shape-polymorphic retraces, and the host only ever
+sees the emitted token ids.
 
 Wire contract (decoupled — use streaming inference):
   inputs:  TOKENS     INT32[1, -1]  prompt token ids
@@ -35,14 +60,15 @@ Wire contract (decoupled — use streaming inference):
                                     stops AFTER emitting it)
   outputs: NEXT_TOKEN INT32[1, 1]   one generated token per response
            INDEX      INT32[1, 1]   0-based position of that token
-  request parameters: "chunk": int — tokens per device dispatch (default 1)
+  request parameters: "chunk": int — tokens per burst (default 1)
 """
 
 from __future__ import annotations
 
+import itertools
 import threading
 import time
-from typing import Any, Dict, Iterable, List
+from typing import Any, Dict, Iterable, List, Optional
 
 import numpy as np
 
@@ -57,6 +83,16 @@ from ..server.timeline import (
 )
 from .base import Model, TensorSpec
 from .decoder import RungCount, TinyDecoderModel
+from .stream_rounds import StreamRounds
+
+
+def _suspended(marks: StreamMarks, token_id: int, index: int):
+    """Yield the token's response, and mark how long the stream stayed
+    suspended there."""
+    t_yield = time.perf_counter_ns()
+    yield {"NEXT_TOKEN": np.array([[token_id]], dtype=np.int32),
+           "INDEX": np.array([[index]], dtype=np.int32)}
+    marks.yielded.add(time.perf_counter_ns() - t_yield, index)
 
 
 class TinyGenerateModel(Model):
@@ -69,8 +105,13 @@ class TinyGenerateModel(Model):
     decoupled = True
 
     DEFAULT_MAX_TOKENS = 16
+    # the reserved caches of the round path: as many streams as one chip's
+    # users in the largest stream cell (16 x 402.7 MB + 2.84 GB of weights
+    # for cerebras-gpt-1.3b, 16 x 188.7 MB + 1.68 GB for gpt2-large)
+    DEFAULT_SLOTS = 16
 
-    def __init__(self, seed: int = 0, decoder: TinyDecoderModel = None):
+    def __init__(self, seed: int = 0, decoder: TinyDecoderModel = None,
+                 slots: int = DEFAULT_SLOTS):
         super().__init__()
         # weight/step sharing by composition: generation must agree with the
         # sequence-API decoder token-for-token. Pass the zoo's decoder_lm
@@ -79,7 +120,20 @@ class TinyGenerateModel(Model):
         self._decoder = decoder if decoder is not None else TinyDecoderModel(seed=seed)
         self._lock = threading.Lock()
         self._chunk_fns: Dict[int, Any] = {}  # scan length K -> jitted fn
-        self.steps_by_rung = RungCount()  # per-token steps; not a chunk's
+        # per-token steps, not a chunk's; a round counts once, at its rung
+        self.steps_by_rung = RungCount()
+        # the round path's (stream_rounds.py), empty on the other: the table
+        # and its worker, made by ``_ensure_built``; rounds by the streams
+        # they carried and by the slots their attention read; streams that
+        # found no free slot at their admission; and ``(members,
+        # dispatch_ns)`` of every round for the statistics verb's
+        # batch_stats (ServerCore.add_model binds its recorder here)
+        self.slots = int(slots)
+        self._rounds: Optional[StreamRounds] = None
+        self.batch_histogram: Dict[int, int] = {}
+        self.rounds_by_width: Dict[int, int] = {}
+        self.slot_waits = 0
+        self.report_batch = None
 
     def inputs(self) -> List[TensorSpec]:
         return [
@@ -96,8 +150,25 @@ class TinyGenerateModel(Model):
 
     # -- compiled pieces -----------------------------------------------------
     def _ensure_built(self):
+        """Every program of the path the decoder provides, compiled: the
+        round's at every rung, and none of a single sequence's, or else
+        every rung of the decoder's own step."""
         self._decoder._ensure_built()
-        self._decoder._ensure_warm()
+        if self._decoder._round_fn is None:
+            self._decoder._ensure_warm()
+            return
+        with self._lock:
+            if self._rounds is None:
+                self._rounds = StreamRounds(self)
+
+    def unload(self) -> None:
+        """The worker joined, its streams failed, the table let go of; a
+        later ``_ensure_built`` makes them anew."""
+        with self._lock:
+            rounds, self._rounds = self._rounds, None
+        if rounds is not None:
+            rounds.close()
+        super().unload()
 
     def _chunk_fn(self, k: int):
         """Jitted K-token greedy decode: the argmax→feed-back loop as a
@@ -171,15 +242,48 @@ class TinyGenerateModel(Model):
         budget = min(max_tokens, max_len - int(tokens.size))
 
         # the stream's marks (server/timeline.py), on the request's timeline
-        # where the core opened one. The dispatch intervals are host times:
-        # a step call returns when the step is enqueued, not when it has run
-        # (the step writes the cache it is given in place, so the call waits
-        # for no room)
+        # where the core opened one
         marks = StreamMarks()
         timeline = current()
         if timeline is not None:
             timeline.stream = marks
+        rounds = self._rounds
+        if rounds is not None:
+            yield from self._ride(rounds, [int(t) for t in tokens], budget,
+                                  end_id, chunk, marks)
+        else:
+            yield from self._step_alone(tokens, budget, end_id, chunk, marks)
 
+    def _ride(self, rounds: StreamRounds, prompt: List[int], budget: int,
+              end_id, chunk: int, marks: StreamMarks):
+        """The round path: the stream waits for its tokens and yields them,
+        the first alone and the rest ``chunk`` at a time. The marks are the
+        worker's: ``cache_ready`` the slot taken, ``prefill_done`` the
+        return of the dispatch of the round that consumed the last prompt
+        token, a token's ``dispatch`` and ``readback`` those of the round
+        that carried it."""
+        stream = rounds.open(prompt, budget, end_id, marks)
+        try:
+            tokens = stream.tokens()
+            with span(SPAN_PREFILL):  # admission, its prompt's rounds, the
+                burst = list(itertools.islice(tokens, 1))  # last's read-back
+            emitted = 0
+            while burst:
+                for token in burst:
+                    yield from _suspended(marks, token, emitted)
+                    emitted += 1
+                burst = list(itertools.islice(tokens, chunk))
+        finally:
+            stream.gone = True  # ended, cancelled or closed: the slot is free
+
+    def _step_alone(self, tokens, budget: int, end_id, chunk: int,
+                    marks: StreamMarks):
+        """The per-stream path: a cache of its own and a dispatch a token.
+        The dispatch intervals are host times: a step call returns when the
+        step is enqueued, not when it has run (the step writes the cache it
+        is given in place, so the call waits for no room)."""
+        dec = self._decoder
+        max_len = dec.MAX_LEN
         # prefill: the decoder's own (``TinyDecoderModel``: the single
         # compiled step over the prompt, each step waited for; a decoder with
         # a prefill program: a chunk of tokens a dispatch)
@@ -192,19 +296,7 @@ class TinyGenerateModel(Model):
         marks.prefill_done = s.end_ns
         self.steps_by_rung.add_prefill_ns(marks.prefill_done - marks.cache_ready)
 
-        def response(token_id: int, index: int):
-            return {
-                "NEXT_TOKEN": np.array([[token_id]], dtype=np.int32),
-                "INDEX": np.array([[index]], dtype=np.int32),
-            }
-
         emitted = 0
-
-        def suspended(token_id: int):
-            t_yield = time.perf_counter_ns()
-            yield response(token_id, emitted)
-            marks.yielded.add(time.perf_counter_ns() - t_yield, emitted)
-
         with span(SPAN_READBACK) as s:
             next_token = int(np.asarray(logits).argmax())
         marks.readback.add(s.ns, emitted)
@@ -212,7 +304,7 @@ class TinyGenerateModel(Model):
             # per-token dispatch: one streamed response per device step —
             # honest TTFT/inter-token latency for a perf harness
             while emitted < budget:
-                yield from suspended(next_token)
+                yield from _suspended(marks, next_token, emitted)
                 emitted += 1
                 if emitted >= budget or (end_id is not None
                                          and next_token == end_id):
@@ -229,7 +321,7 @@ class TinyGenerateModel(Model):
 
         # chunked: first token came from prefill; subsequent tokens arrive
         # K at a time from one scan dispatch and stream out burst-wise
-        yield from suspended(next_token)
+        yield from _suspended(marks, next_token, emitted)
         emitted += 1
         if end_id is not None and next_token == end_id:
             return
@@ -246,7 +338,7 @@ class TinyGenerateModel(Model):
                 toks = np.asarray(toks).reshape(-1)
             marks.readback.add(s.ns, emitted)
             for t in toks:
-                yield from suspended(int(t))
+                yield from _suspended(marks, int(t), emitted)
                 emitted += 1
                 if end_id is not None and int(t) == end_id:
                     return

@@ -580,6 +580,16 @@ class ServerCore:
             "Decode steps dispatched, by the rung of the decoder's ladder "
             "they read: the live positions of the cache",
             ("model", "live"))
+        # a model whose streams share a round (models/stream_rounds.py)
+        stream_rounds = reg.gauge(
+            "client_tpu_server_stream_rounds",
+            "Rounds dispatched for a model's streams, by their width: the "
+            "leading slots of the table whose caches the attention read",
+            ("model", "width"))
+        stream_slot_waits = reg.gauge(
+            "client_tpu_server_stream_slot_waits",
+            "Streams that found no free slot at their admission and waited "
+            "for one", ("model",))
         # what the decoder counts beside its steps (models/decoder.py:
         # RungCount.TOTALS), one series a served model each
         decoder_totals = {
@@ -627,6 +637,11 @@ class ServerCore:
                         decode_steps.labels(name, rung).set(steps)
                     for total, value in count.totals().items():
                         decoder_totals[total].labels(name).set(value)
+                rounds = getattr(model, "rounds_by_width", None)
+                if rounds:  # it has run rounds: the slot table is in use
+                    for width, n in dict(rounds).items():
+                        stream_rounds.labels(name, width).set(n)
+                    stream_slot_waits.labels(name).set(model.slot_waits)
             compile_count.set(COMPILES.count)
             compile_seconds.set(COMPILES.ns / 1e9)
 

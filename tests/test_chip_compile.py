@@ -227,3 +227,64 @@ def test_the_step_writes_its_donated_caches_in_place_on_the_chip(
             rf"bf16\[[0-9,]*{dims[-2]},{dims[-1]}\]\{{[^}}]*\}}[^=]*"
             r"(?:copy-start|slice-start)\(", entry)
         assert not staged, f"{len(staged)} caches staged: {staged[:2]}"
+
+
+def _outside_fusions(text):
+    """The operations the compiled program runs as such: the lines of every
+    computation but the fused ones (what a fusion computes inside itself
+    never lies in memory)."""
+    lines, fused = [], False
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            fused = line.startswith(("%fused_computation", "fused_computation"))
+        elif not fused:
+            lines.append(line)
+    return "\n".join(lines)
+
+
+@pytest.mark.parametrize("live", [256, 1024])
+def test_the_round_writes_its_table_in_place_and_reads_it_where_it_lies(
+        chip, live):
+    """The stream model's round (``decoder._round_fn``, traced as
+    ``jit_step``) at every rung, held to what the steps above are held to:
+    every stacked cache aliased to an output and none laid out anew; and the
+    slots a turn of its attention reads, a prefix of their positions, are
+    read from the table as it lies: nothing longer or wider is cut out of
+    it, and nothing is set aside in the chip's memory."""
+    import re
+
+    from client_tpu.models.decoder import SLOTS_A_TURN
+
+    decoder = _published_widths_decoder()
+    caches = decoder._fresh_table(16)
+    args = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip),
+        (_shapes_of(decoder._params), _shapes_of(caches),
+         _s((16,), jnp.int32), _s((3, 16), jnp.int32)))
+    text = decoder._round_fn.lower(*args, live=live).compile().as_text()
+    assert "HloModule jit_step," in text
+    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
+    assert aliased.count("-alias)") == 2 * decoder.LAYERS
+    run = _outside_fusions(text)
+    assert " while(" in run  # the turns of the attention, and the row writes
+    slots, heads, length, dim = caches[0]["k"].shape
+    relaid = re.findall(
+        rf"= bf16\[{slots},{heads},{length},{dim}\]\{{[^}}]*\}} copy\(", run)
+    assert not relaid, f"{len(relaid)} whole caches copied to another layout"
+    # what a turn reads is its slots' prefix and no position beyond; where
+    # the compiler sets such a slice aside, it does so in fast memory
+    # (``S(1)`` in the layout) and not in a buffer of the chip's memory
+    set_aside = re.findall(
+        rf"= (?:bf16|f32)\[(\d+),{heads},(\d+),{dim}\](\{{[^}}]*\}}) "
+        r"(?!parameter|get-tuple-element|while|tuple|dynamic-update-slice)",
+        run)
+    for some, positions, layout in set_aside:
+        if int(positions) == 1:
+            continue  # a token's new row, on its way into the table
+        assert (int(some), int(positions)) == (SLOTS_A_TURN, live), (
+            some, positions)
+        assert "S(1)" in layout, layout
+    staged = re.findall(
+        rf"bf16\[[0-9,]*{length},{dim}\]\{{[^}}]*\}}[^=]*"
+        r"(?:copy-start|slice-start)\(", run)
+    assert not staged, f"{len(staged)} caches staged: {staged[:2]}"

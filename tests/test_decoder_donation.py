@@ -231,8 +231,10 @@ def test_a_streams_prefill_waits_for_each_of_its_steps():
     """No step call waits for room any more, so the prefill waits itself: a
     prompt enqueued whole would hold every other stream's next token."""
     model = TinyGenerateModel(seed=0)
-    model._ensure_built()
     decoder = model._decoder
+    decoder._ensure_built()
+    decoder._round_fn = None  # the per-stream path: a decoder without a round
+    model._ensure_built()
     step, waits = decoder._step_fn, []
 
     class Watched:

@@ -35,6 +35,15 @@ def _decoder(held=False):
     return decoder
 
 
+def _stepping_alone(decoder):
+    """The decoder as one that offers no round program: its streams step
+    their own sequences through ``decode_step`` (tests/test_stream_rounds.py
+    has the streams that share a round)."""
+    decoder._ensure_built()
+    decoder._round_fn = None
+    return decoder
+
+
 def _sized(max_len):
     return type("Sized", (TinyDecoderModel,), {"MAX_LEN": max_len})
 
@@ -223,7 +232,7 @@ def test_no_rung_is_compiled_on_demand():
     """After ``_ensure_built`` every rung's program is there: the first step
     of any rung, in the middle of serving, compiles nothing."""
     timeline.COMPILES.listen()
-    stream = TinyGenerateModel(decoder=LongDecoder(seed=0))
+    stream = TinyGenerateModel(decoder=_stepping_alone(LongDecoder(seed=0)))
     stream._ensure_built()
     decoder = stream._decoder
     assert decoder._warm
@@ -286,7 +295,7 @@ def test_a_failed_build_of_the_rungs_is_the_first_steps_to_report():
     """Nothing falls back to another rung, and nothing comes online later:
     the step that needs the rungs raises what their build raised, and the
     next one tries again."""
-    decoder = _decoder()
+    decoder = _stepping_alone(_decoder())
     step = decoder._step_fn
 
     def refused(*args, **kwargs):

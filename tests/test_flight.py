@@ -553,7 +553,10 @@ def test_e2e_cross_layer_stitch_on_one_timeline():
             np.testing.assert_array_equal(result.as_numpy("OUTPUT0"),
                                           expected)
         finally:
-            pool.close()
+            # the cache's entry holds a lease of the process's arena: closed
+            # here, so that a later test file of this worker finds nothing
+            # leased (tests/test_disagg.py counts the leased bytes)
+            client.close()
     timelines = [t for t in tel.flight.retained() if t.frontend == "cache"]
     assert len(timelines) == 1  # ONE timeline for the whole composition
     t = timelines[0]

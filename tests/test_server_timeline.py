@@ -18,6 +18,7 @@ from client_tpu.models.decoder_batched import (
     ROUNDS_IN_FLIGHT,
     BatchedDecoderModel,
 )
+from client_tpu.models.decoder import TinyDecoderModel
 from client_tpu.models.generate import TinyGenerateModel
 from client_tpu.models.simple import AddSubModel
 from client_tpu.server import ServerCore, timeline
@@ -257,15 +258,31 @@ def test_batch_stats_rounds_equal_the_batch_histogram(traced_core):
     assert row["inference_count"] == 15
 
 
-@pytest.mark.parametrize("prompt, max_tokens, chunk, want", [
-    ([1, 2, 3], 6, 1, {"dispatch": 5, "readback": 6, "yielded": 6}),
-    ([4], 1, 1, {"dispatch": 0, "readback": 1, "yielded": 1}),
-    ([1, 2], 7, 3, {"dispatch": 2, "readback": 3, "yielded": 7}),
-], ids=["a-token-a-dispatch", "one-token", "chunked"])
+@pytest.mark.parametrize("path, prompt, max_tokens, chunk, want", [
+    ("rounds", [1, 2, 3], 6, 1, {"dispatch": 5, "readback": 6, "yielded": 6}),
+    ("rounds", [4], 1, 1, {"dispatch": 0, "readback": 1, "yielded": 1}),
+    # a burst's tokens rode a round each
+    ("rounds", [1, 2], 7, 3, {"dispatch": 6, "readback": 7, "yielded": 7}),
+    ("alone", [1, 2, 3], 6, 1, {"dispatch": 5, "readback": 6, "yielded": 6}),
+    ("alone", [4], 1, 1, {"dispatch": 0, "readback": 1, "yielded": 1}),
+    # ``decode_k``: a dispatch and a read-back a burst
+    ("alone", [1, 2], 7, 3, {"dispatch": 2, "readback": 3, "yielded": 7}),
+], ids=["a-token-a-round", "one-token-a-round", "chunked-on-rounds",
+        "a-token-a-dispatch", "one-token", "chunked"])
 def test_a_streams_interval_counts_follow_its_tokens(
-        traced_core, prompt, max_tokens, chunk, want):
-    core, _, _ = traced_core
-    responses = _generate(core, prompt, max_tokens, parameters={"chunk": chunk})
+        path, prompt, max_tokens, chunk, want):
+    decoder = TinyDecoderModel(seed=0)
+    decoder._ensure_built()
+    if path == "alone":  # as a decoder that offers no round program
+        decoder._round_fn = None
+    model = TinyGenerateModel(decoder=decoder)
+    core = ServerCore([model])
+    core.trace_settings.update(trace_level=["TIMESTAMPS"], trace_rate="1")
+    try:
+        responses = _generate(core, prompt, max_tokens,
+                              parameters={"chunk": chunk})
+    finally:
+        model.unload()
     assert len(responses) == max_tokens
     counts = core.recent_traces()[-1]["counts"]
     assert counts["responses"] == max_tokens
