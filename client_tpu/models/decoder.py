@@ -73,14 +73,20 @@ class RungCount:
     steps that attended to a chosen subset of the cache (``selecting_steps``);
     and the prompts' side: tokens prefilled, the dispatches that took
     (``prefill_chunks``) and the host's time from a stream's cache to its
-    last prefill dispatch's return (``prefill_ns``)."""
+    last prefill dispatch's return (``prefill_ns``); and, for a decoder that
+    keeps a window beside summaries of what came before it, the rows of each
+    kind its decode steps attended to and the summaries its tokens completed
+    (``window_rows_read``, ``summary_rows_read``, ``summaries_written``), all
+    counted on the host from positions."""
 
     TOTALS = ("selecting_steps", "prefill_tokens", "prefill_chunks", "prefill_ns")
+    ROWS = ("window_rows_read", "summary_rows_read", "summaries_written")
 
     def __init__(self):
         self._lock = threading.Lock()
         self._steps: Dict[int, int] = {}
         self._totals = dict.fromkeys(self.TOTALS, 0)
+        self._rows = dict.fromkeys(self.ROWS, 0)
 
     def add(self, live: int) -> None:
         with self._lock:
@@ -100,6 +106,11 @@ class RungCount:
         with self._lock:
             self._totals["prefill_ns"] += ns
 
+    def add_rows(self, window: int, summary: int, written: int) -> None:
+        with self._lock:
+            for name, n in zip(self.ROWS, (window, summary, written)):
+                self._rows[name] += n
+
     def by_rung(self) -> Dict[int, int]:
         with self._lock:
             return dict(self._steps)
@@ -107,6 +118,10 @@ class RungCount:
     def totals(self) -> Dict[str, int]:
         with self._lock:
             return dict(self._totals)
+
+    def rows(self) -> Dict[str, int]:
+        with self._lock:
+            return dict(self._rows)
 
 
 class TinyDecoderModel(Model):
@@ -140,6 +155,13 @@ class TinyDecoderModel(Model):
         # has no such program: the Pallas kernel takes the whole cache of
         # every slot, and a subclass that jits a step of its own brings none
         self._round_fn = None
+        # a prompt's chunk into a slot of that table, for a decoder whose
+        # prompts are too long for a round a token: ``(params, table, fed,
+        # tokens, ctl, live=)`` gives ``(fed, table)`` (stream_rounds.py);
+        # None where a prompt rides the rounds a token at a time; and the
+        # positions a chunk holds
+        self._slot_prefill_fn = None
+        self._slot_prefill_chunk = 0
         # the lengths ``_step_fn`` takes as ``live``, shortest first: the
         # ladder where ``_build`` below made the step, the whole length alone
         # where a subclass jits a step of its own (decoder_tp.py)
@@ -467,6 +489,12 @@ class TinyDecoderModel(Model):
                 for live in self._rungs:
                     _, caches = self._step_at(caches, 0, 0, live)
             self._warm = True
+
+    def count_positions(self, count: RungCount, positions, decoding: bool) -> None:
+        """What the tokens at ``positions`` (a prompt's, or decode steps')
+        read and wrote beside their steps, counted from the positions alone:
+        nothing, for this decoder; one whose state is of more kinds than a
+        row a position counts here (``RungCount.add_rows``)."""
 
     def decode_step(self, caches, token: int, pos: int,
                     count: Optional[RungCount] = None):

@@ -15,8 +15,11 @@ scheduling logic:
 
 **The streams share a round** where the decoder offers the round program
 and the stacked caches (``decoder._round_fn``: ``TinyDecoderModel`` and its
-subclasses with ``attention_impl="einsum"``). ``stream_rounds.py`` has the
-mechanism; in short:
+subclasses with ``attention_impl="einsum"``, and
+``WindowSummaryDecoderModel``, which also offers a slot prefill: its prompts
+go a chunk a dispatch into their slots between the rounds, where the others'
+ride the rounds a token at a time). ``stream_rounds.py`` has the mechanism;
+in short:
 - a table of ``slots`` caches is reserved once at build (default
   ``DEFAULT_SLOTS``), donated to every round and written in place. A stream
   takes the lowest free slot at admission, gives it back when its budget is

@@ -168,6 +168,28 @@ def plain_scale(path: Tuple[str, ...], leaf):
     return 1.0 if path[-1] == "embed" else leaf.shape[-2] ** -0.5
 
 
+def seeded_params(shapes, seed: int, init_scale: Callable):
+    """Weights of the shapes' tree drawn on the host from ``seed``: each leaf
+    normal at the deviation (or ``(mean, deviation)``) that
+    ``init_scale(path, leaf)`` gives it, ``path`` being its keys as
+    strings."""
+    import jax
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(seed)
+    paths, tree = jax.tree_util.tree_flatten_with_path(shapes)
+
+    def draw(path, leaf):
+        drawn = init_scale(
+            tuple(str(getattr(k, "key", getattr(k, "idx", k))) for k in path), leaf)
+        mean, scale = drawn if isinstance(drawn, tuple) else (0.0, drawn)
+        return jnp.asarray(mean + scale * rng.standard_normal(
+            leaf.shape).astype(np.float32), dtype=leaf.dtype)
+
+    return jax.tree_util.tree_unflatten(
+        tree, [draw(path, leaf) for path, leaf in paths])
+
+
 # -- the block's parts: pure functions of arrays ------------------------------
 
 def rms(x, gain, eps: float):
@@ -403,22 +425,8 @@ class RoutedDecoderModel(TinyDecoderModel):
         s = self.sizes
         first, held = self.experts_held
         shapes = param_shapes(s, held)
-        if self._seed is None:
-            self._params = shapes
-        else:
-            rng = np.random.default_rng(self._seed)
-            paths, tree = jax.tree_util.tree_flatten_with_path(shapes)
-
-            def draw(path, leaf):
-                drawn = self._init_scale(
-                    tuple(str(getattr(k, "key", getattr(k, "idx", k))) for k in path),
-                    leaf)
-                mean, scale = drawn if isinstance(drawn, tuple) else (0.0, drawn)
-                return jnp.asarray(mean + scale * rng.standard_normal(
-                    leaf.shape).astype(np.float32), dtype=leaf.dtype)
-
-            self._params = jax.tree_util.tree_unflatten(
-                tree, [draw(path, leaf) for path, leaf in paths])
+        self._params = (shapes if self._seed is None
+                        else seeded_params(shapes, self._seed, self._init_scale))
 
         # the type of the weights is the type of the caches and of every
         # matrix product's operands and result (bfloat16 as served)

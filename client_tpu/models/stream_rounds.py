@@ -45,6 +45,19 @@ before the worker takes its first stream. (A ladder of compile-time widths
 was built first and measured: a program more is 0.6 to 1.3 s of a warm
 set-up, which does not shrink side by side, and the set-up's bound paid for
 three of the six; PERF.md section 6, PR 33.)
+
+**A prompt a chunk a dispatch, where the decoder offers it.** A decoder
+whose prompts are too long for a round a token (a byte model's are four
+times a token model's) brings a slot prefill (``decoder._slot_prefill_fn``:
+one program that takes the table, a slot and a chunk of prompt positions and
+writes that slot's state). A stream of such a decoder is seated as any other
+but is no member of the rounds while its prompt is being taken: each turn
+the worker dispatches at most one chunk, of the oldest such prompt, and then
+the round, so a decoding stream waits one chunk at most between two tokens.
+The prompt's last chunk chooses the stream's first token on the device and
+leaves it where the next round reads it; that dispatch is read back as a
+round is. A decoder without one (``None``) takes the code it took before:
+no program more, a prompt a token a round.
 """
 
 from __future__ import annotations
@@ -62,6 +75,7 @@ from .decoder import slots_a_turn
 from ..server.timeline import (
     SPAN_DISPATCH,
     SPAN_FRESH_CACHE,
+    SPAN_PREFILL_CHUNK,
     SPAN_READBACK,
     StreamMarks,
     span,
@@ -135,11 +149,15 @@ class StreamRounds:
         self._fed = jnp.zeros((self.slots,), jnp.int32)
         self._arrivals: "queue.SimpleQueue[Optional[Stream]]" = queue.SimpleQueue()
         self._taking = True
-        # the worker's own: the seated streams by slot, the free slots (a
-        # heap: the lowest first), the streams that wait for one, and the
-        # rounds dispatched and not yet read back, oldest first: ``(choices
-        # on the device, [(stream, slot, index of the token)], dispatch)``
+        # the worker's own: the rounds' members by slot, the seated streams
+        # whose prompts are being taken a chunk a dispatch (oldest first; none
+        # where the decoder has no slot prefill), the free slots (a heap: the
+        # lowest first), the streams that wait for one, and the rounds
+        # dispatched and not yet read back, oldest first: ``(choices on the
+        # device, [(stream, slot, index of the token)], dispatch)``
         self._members: Dict[int, Stream] = {}
+        self._prompts: Deque[Stream] = collections.deque()
+        self._slot_prefill = decoder._slot_prefill_fn
         self._free = list(range(self.slots))
         self._waiting: Deque[Stream] = collections.deque()
         self._in_flight: Deque[Tuple[Any, List[Tuple[Stream, int, int]], span]] = (
@@ -149,6 +167,9 @@ class StreamRounds:
         nobody = np.zeros((3, self.slots), np.int32)
         for live in decoder._rungs:
             self._step(nobody, live)
+            if self._slot_prefill is not None:  # a chunk of no token
+                self._chunk(np.zeros(decoder._slot_prefill_chunk, np.int32),
+                            np.zeros(5, np.int32), live)
         jax.block_until_ready(self._fed)  # (under ``eval_shape`` a shape)
         self._worker = threading.Thread(
             target=self._run, name="stream-rounds", daemon=True)
@@ -176,6 +197,13 @@ class StreamRounds:
         self._fed, self._caches = decoder._round_fn(
             decoder._params, self._caches, self._fed, ctl, live=live)
 
+    def _chunk(self, block: np.ndarray, ctl: np.ndarray, live: int) -> None:
+        """One dispatch of the slot prefill of that rung: a chunk of a
+        prompt into its slot of the table."""
+        decoder = self._decoder
+        self._fed, self._caches = self._slot_prefill(
+            decoder._params, self._caches, self._fed, block, ctl, live=live)
+
     # -- the worker: one turn a round ----------------------------------------
     def _run(self) -> None:
         while self._taking:
@@ -189,6 +217,8 @@ class StreamRounds:
 
     def _turn(self) -> None:
         self._admit()
+        if self._prompts and self._taking:
+            self._take_chunk()
         if self._members and self._taking:
             self._dispatch()
         while self._in_flight and (len(self._in_flight) >= ROUNDS_IN_FLIGHT
@@ -200,9 +230,11 @@ class StreamRounds:
         back; what has arrived queues behind those that wait; the free slots
         are taken, lowest first, first come first seated. With nothing to
         run it waits here for a stream."""
-        for stream in [s for s in self._members.values() if s.gone]:
+        for stream in [s for s in (*self._members.values(), *self._prompts)
+                       if s.gone]:
             self._end(stream)
-        idle = not (self._members or self._in_flight or self._waiting)
+        idle = not (self._members or self._prompts or self._in_flight
+                    or self._waiting)
         while True:
             try:
                 stream = self._arrivals.get(block=idle)
@@ -219,15 +251,55 @@ class StreamRounds:
                 continue
             with span(SPAN_FRESH_CACHE) as taken:
                 stream.slot = heapq.heappop(self._free)
-                self._members[stream.slot] = stream
+                if self._slot_prefill is None:
+                    self._members[stream.slot] = stream
+                else:
+                    self._prompts.append(stream)
             stream.marks.cache_ready = taken.end_ns
         for stream in self._waiting:
             if not stream.waited:
                 stream.waited = True
                 self._model.slot_waits += 1
 
+    def _take_chunk(self) -> None:
+        """The next chunk of the oldest prompt that is being taken, into its
+        slot. The prompt's last chunk chooses the first token on the device:
+        the stream joins the rounds, and the dispatch is read back as a round
+        that gave that one token."""
+        import jax
+
+        decoder, count = self._decoder, self._model.steps_by_rung
+        stream = self._prompts[0]
+        size = decoder._slot_prefill_chunk
+        base, n = stream.at, min(size, len(stream.prompt) - stream.at)
+        last = base + n == len(stream.prompt)
+        block = np.zeros(size, np.int32)
+        block[:n] = stream.prompt[base:base + n]
+        with span(SPAN_PREFILL_CHUNK) as dispatch:
+            self._chunk(block, np.array([stream.slot, base, 0, n, last], np.int32),
+                        decoder.rung_for(base + n))
+        stream.at += n
+        decoder.count_positions(count, np.arange(base, base + n), decoding=False)
+        if not last:
+            if not self._members:
+                # nobody decodes: nothing else paces the worker, so a chunk
+                # is waited for before the next is dispatched
+                jax.block_until_ready(self._fed)
+            return
+        self._fed.copy_to_host_async()
+        self._prompts.popleft()
+        self._members[stream.slot] = stream
+        stream.pos = len(stream.prompt)
+        stream.marks.prefill_done = dispatch.end_ns
+        count.add_prefill(len(stream.prompt), chunks=-(-len(stream.prompt) // size))
+        count.add_prefill_ns(dispatch.end_ns - stream.marks.cache_ready)
+        self._in_flight.append((self._fed, [(stream, stream.slot, 0)], dispatch))
+        stream.due = 1
+        if stream.due == stream.budget:
+            self._vacate(stream)
+
     def _dispatch(self) -> None:
-        """One round: the next token of every seated stream."""
+        """One round: the next token of every member."""
         model, decoder = self._model, self._decoder
         members = sorted(self._members.items())
         ctl = np.zeros((3, self.slots), np.int32)
@@ -244,6 +316,7 @@ class StreamRounds:
         # whole turns
         live = decoder.rung_for(int(ctl[1].max()) + 1)
         width = -(-(members[-1][0] + 1) // self._a_turn) * self._a_turn
+        decoder.count_positions(model.steps_by_rung, ctl[1][ctl[2] > 0], decoding=True)
         with span(SPAN_DISPATCH) as dispatch:
             self._step(ctl, live)
         # the transfer begins when the round ends, with no host thread
@@ -298,7 +371,11 @@ class StreamRounds:
     def _vacate(self, stream: Stream) -> None:
         if self._members.get(stream.slot) is stream:
             del self._members[stream.slot]
-            heapq.heappush(self._free, stream.slot)
+        elif stream in self._prompts:
+            self._prompts.remove(stream)
+        else:
+            return
+        heapq.heappush(self._free, stream.slot)
 
     def _end(self, stream: Stream, failed: Optional[BaseException] = None) -> None:
         self._vacate(stream)
@@ -314,7 +391,7 @@ class StreamRounds:
         import jax
         import jax.numpy as jnp
 
-        begun = list(self._members.values())
+        begun = [*self._members.values(), *self._prompts]
         for _, gives, _ in self._in_flight:
             begun.extend(stream for stream, _, _ in gives)
         self._in_flight.clear()

@@ -591,7 +591,7 @@ class ServerCore:
             "Streams that found no free slot at their admission and waited "
             "for one", ("model",))
         # what the decoder counts beside its steps (models/decoder.py:
-        # RungCount.TOTALS), one series a served model each
+        # RungCount.TOTALS and ROWS), one series a served model each
         decoder_totals = {
             "selecting_steps": reg.gauge(
                 "client_tpu_server_selecting_steps",
@@ -608,6 +608,17 @@ class ServerCore:
                 "client_tpu_server_prefill_ns",
                 "Host time of the streams' prefills, cache_ready to "
                 "prefill_done", ("model",)),
+            "window_rows_read": reg.gauge(
+                "client_tpu_server_window_rows_read",
+                "Rows of the exact window that decode steps attended to "
+                "(a decoder with a window beside summaries)", ("model",)),
+            "summary_rows_read": reg.gauge(
+                "client_tpu_server_summary_rows_read",
+                "Chunk summaries that decode steps attended to", ("model",)),
+            "summaries_written": reg.gauge(
+                "client_tpu_server_summaries_written",
+                "Chunk summaries written, by prompts' chunks and by decode "
+                "steps", ("model",)),
         }
 
         def collect():
@@ -635,7 +646,7 @@ class ServerCore:
                 if count is not None:  # a model that steps a decoder
                     for rung, steps in count.by_rung().items():
                         decode_steps.labels(name, rung).set(steps)
-                    for total, value in count.totals().items():
+                    for total, value in {**count.totals(), **count.rows()}.items():
                         decoder_totals[total].labels(name).set(value)
                 rounds = getattr(model, "rounds_by_width", None)
                 if rounds:  # it has run rounds: the slot table is in use
