@@ -49,6 +49,11 @@ SHORTEST_RUNG = 256
 SLOTS_A_TURN = 4
 
 
+# the lanes of a tile of the chip's memory: a row of a cache this wide or
+# wider lies along them, and a narrower one has its positions there
+LANES = 128
+
+
 def slots_a_turn(slots: int) -> int:
     """The slots a turn of a round's attention takes of a table of
     ``slots``: the turns are whole, so it divides them."""
@@ -245,8 +250,25 @@ class TinyDecoderModel(Model):
             [W], where ``active`` [W]: one turn an active slot, each writing
             that slot's two rows into the donated buffers where they lie, so
             the work follows the number of active slots and an inactive one
-            (a full one, a freed one) is not touched at all."""
+            (a full one, a freed one) is not touched at all.
+
+            How a turn writes a row goes by how the chip lays the table.
+            Where a row fills a tile's lanes (Dh of 128) it is a tile's row,
+            and the turn updates the row alone: 0.16 ms a member a round of
+            24 layers. Rows narrower than the lanes are laid with their
+            *positions* on the lanes: one position of one slot is a lane of
+            H x Dh / 16 tiles, an update of the row alone costs 6.7 us
+            there (7.8 of a 12.5 ms round of sixteen members), and the turn
+            writes it through its window instead: the aligned ``LANES``
+            positions round the row are read, the row is put into them by a
+            select and they are written back where they were read: whole
+            tiles, which the compiler fuses into one update in place, 2.4 us
+            a row (PERF.md section 6, PR 35). The window's start is unsigned
+            and masked, not divided and not clamped, so that the compiler
+            knows it aligned. Nothing is masked over a cache: for that the
+            compiler lays every stacked cache out anew and back (PR 26)."""
             active_first = jnp.argsort(~active, stable=True)
+            width = jnp.sum(active, dtype=jnp.int32)
 
             def write(turn, caches):
                 slot = active_first[turn]
@@ -257,8 +279,38 @@ class TinyDecoderModel(Model):
                         (slot, 0, pos[slot], 0))
                     for cache, slot_rows in zip(caches, rows))
 
-            width = jnp.sum(active, dtype=jnp.int32)
-            return lax.fori_loop(0, width, write, caches)
+            if Dh >= LANES:
+                return lax.fori_loop(0, width, write, caches)
+
+            length = caches[0].shape[2]
+            # a length that whole windows do not cover is one window
+            span = LANES if length % LANES == 0 else length
+            aligned = jnp.uint32(-span % 2 ** 32 if span < length else 0)
+            lanes = jnp.arange(span, dtype=jnp.uint32)
+            zero = jnp.uint32(0)
+            # by turn, so that a turn reads its slot and its position and
+            # nothing through them
+            slots = active_first.astype(jnp.uint32)
+            ats = jnp.minimum(pos, length - 1).astype(jnp.uint32)[active_first]
+
+            def write_window(turn, caches):
+                slot, at = slots[turn], ats[turn]
+                start = at & aligned
+                window = (slot, zero, start, zero)
+                hit = (lanes == at - start)[None, None, :, None]
+                return tuple(
+                    lax.dynamic_update_slice(
+                        cache,
+                        jnp.where(
+                            hit,
+                            lax.dynamic_index_in_dim(
+                                slot_rows, slot, keepdims=True),
+                            lax.dynamic_slice(
+                                cache, window, (1, H, span, Dh))),
+                        window)
+                    for cache, slot_rows in zip(caches, rows))
+
+            return lax.fori_loop(0, width, write_window, caches)
 
         @jax.custom_batching.custom_vmap
         def write_slot_rows(caches, rows, pos, active):

@@ -36,8 +36,9 @@ dispatched and not yet read back, so a request waits for at most that many
 rounds before its own, however long a prompt beside it is; while the device
 works, the round in flight is the gather. A slot whose sequence has no
 request in the table is masked: the step writes no row of its
-(``active``, decoder.py's ``write_active_rows``) and its logits row goes
-unread, which keeps the executable static-shape — the same compile-once
+(``active``, decoder.py's ``write_active_rows``: a turn an active slot, a
+masked one is not touched) and its logits row goes unread, which keeps the
+executable static-shape — the same compile-once
 property the single-sequence decoder has. Once a rung, that is: a round's
 attention reads the prefix of the caches that covers the furthest of the
 round's members (decoder.py's ladder; a slot that rides along inactive may

@@ -165,36 +165,82 @@ def test_row_too_wide_raises_typed_error():
 # -- the cache is written where it lies --------------------------------------
 
 
-def _published_widths_decoder():
-    """The decoder at gpt2-large's head and cache widths (20 heads of 64,
-    1,024 positions), two layers deep and with the fixture's vocabulary: the
-    chip's compiler chooses layouts by these widths."""
+# width, heads, positions: gpt2-large's (64-wide heads, narrower than a
+# tile's lanes) and cerebras-gpt-1.3b's (128-wide: a row fills the lanes)
+PUBLISHED_WIDTHS = {64: (1280, 20, 1024), 128: (2048, 16, 2048)}
+
+
+def _published_widths_decoder(head=64):
+    """The decoder at a published model's head and cache widths, two layers
+    deep and with the fixture's vocabulary: the chip's compiler chooses
+    layouts by these widths."""
     from client_tpu.models.decoder import TinyDecoderModel
 
+    width, heads, length = PUBLISHED_WIDTHS[head]
     cls = type("WideDecoder", (TinyDecoderModel,), {
-        "D_MODEL": 1280, "HEADS": 20, "LAYERS": 2, "MAX_LEN": 1024})
+        "D_MODEL": width, "HEADS": heads, "LAYERS": 2, "MAX_LEN": length})
     decoder = cls(seed=0)
     decoder._ensure_built()
     return decoder
 
 
-@pytest.mark.parametrize("live", [256, 1024])
+def _cache_updates(text, shape):
+    """Of a compiled program, every in-place update of an array of ``shape``
+    (a stacked cache): ``(the update's dimensions, whether it is fused with
+    what it writes)``."""
+    import re
+
+    found, fused = [], False
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            fused = line.lstrip("%").startswith("fused_computation")
+        update = re.search(
+            rf"= bf16\[{shape}\]\{{[^}}]*\}} dynamic-update-slice\("
+            r"[^,]+, (%?[\w.-]+),", line)
+        if update:  # operands are named without their shapes
+            dims = re.search(
+                rf"{re.escape(update.group(1))} = bf16\[([0-9,]+)\]",
+                text).group(1)
+            found.append((tuple(int(n) for n in dims.split(",")), fused))
+    return found
+
+
+def _is_written_by_windows(text, dims, layers):
+    """A table's rows go in as ``write_table_rows`` says: where a row is
+    narrower than the lanes, through the aligned window of ``LANES``
+    positions round it, read, selected into and written back by one fusion
+    in place (an update of a row alone is the 6.7 us one); where it fills
+    them, a row at a time."""
+    from client_tpu.models.decoder import LANES
+
+    slots, heads, length, dim = dims
+    updates = _cache_updates(text, ",".join(str(n) for n in dims))
+    assert len(updates) == 2 * layers, updates
+    if dim < LANES:
+        assert set(updates) == {((1, heads, LANES, dim), True)}, updates
+    else:
+        assert {update for update, _ in updates} == {(1, heads, 1, dim)}
+
+
+@pytest.mark.parametrize("head, live", [(64, 256), (64, 1024), (128, 512)])
 @pytest.mark.parametrize("program", ["jit_step", "jit_batched_step"])
 def test_the_step_writes_its_donated_caches_in_place_on_the_chip(
-        chip, program, live):
+        chip, program, head, live):
     """What the CPU cannot show: compiled for a v5e, the step aliases every
     cache to an output and moves no whole cache into another layout, at
     every rung of the ladder. (The scatter that ``vmap`` alone makes of the
     batcher's row writes has each stacked cache copied to a row-major layout
     and back every round.) At the short rung the attention takes the prefix
     of the cache as it lies: no slice of it is materialised, and no whole
-    cache is moved through fast memory ahead of the read."""
+    cache is moved through fast memory ahead of the read. The batcher's
+    rows go in through aligned windows where a row is narrower than the
+    lanes."""
     import re
 
     from client_tpu.models.decoder_batched import BatchedDecoderModel
 
-    decoder = _published_widths_decoder()
-    assert decoder._rungs == (256, 1024)
+    decoder = _published_widths_decoder(head)
+    assert live in decoder._rungs[:-1] or live == decoder.MAX_LEN
     scalar = _s((), jnp.int32)
     if program == "jit_step":
         fn, caches = decoder._step_fn, decoder._fresh_cache()
@@ -219,6 +265,8 @@ def test_the_step_writes_its_donated_caches_in_place_on_the_chip(
     entry = text[text.index("\nENTRY "):]
     relaid = re.findall(rf"= bf16\[{shape}\]\{{[^}}]*\}} copy\(", entry)
     assert not relaid, f"{len(relaid)} whole caches copied to another layout"
+    if program == "jit_batched_step":
+        _is_written_by_windows(text, dims, decoder.LAYERS)
     if live < decoder.MAX_LEN:
         prefix = ",".join(str(n) for n in dims[:-2] + (live, dims[-1]))
         sliced = re.findall(rf"= bf16\[{prefix}\]\{{[^}}]*\}} [a-z-]+\(", entry)
@@ -242,20 +290,21 @@ def _outside_fusions(text):
     return "\n".join(lines)
 
 
-@pytest.mark.parametrize("live", [256, 1024])
+@pytest.mark.parametrize("head, live", [(64, 256), (64, 1024), (128, 512)])
 def test_the_round_writes_its_table_in_place_and_reads_it_where_it_lies(
-        chip, live):
+        chip, head, live):
     """The stream model's round (``decoder._round_fn``, traced as
     ``jit_step``) at every rung, held to what the steps above are held to:
     every stacked cache aliased to an output and none laid out anew; and the
     slots a turn of its attention reads, a prefix of their positions, are
     read from the table as it lies: nothing longer or wider is cut out of
-    it, and nothing is set aside in the chip's memory."""
+    it, and nothing is set aside in the chip's memory. Its rows go in
+    through aligned windows where a row is narrower than the lanes."""
     import re
 
     from client_tpu.models.decoder import SLOTS_A_TURN
 
-    decoder = _published_widths_decoder()
+    decoder = _published_widths_decoder(head)
     caches = decoder._fresh_table(16)
     args = jax.tree_util.tree_map(
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip),
@@ -266,8 +315,9 @@ def test_the_round_writes_its_table_in_place_and_reads_it_where_it_lies(
     aliased = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
     assert aliased.count("-alias)") == 2 * decoder.LAYERS
     run = _outside_fusions(text)
-    assert " while(" in run  # the turns of the attention, and the row writes
     slots, heads, length, dim = caches[0]["k"].shape
+    assert " while(" in run  # the turns of the attention, and of the rows
+    _is_written_by_windows(text, (slots, heads, length, dim), decoder.LAYERS)
     relaid = re.findall(
         rf"= bf16\[{slots},{heads},{length},{dim}\]\{{[^}}]*\}} copy\(", run)
     assert not relaid, f"{len(relaid)} whole caches copied to another layout"

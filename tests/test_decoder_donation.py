@@ -90,6 +90,18 @@ def _rows_changed(before, after):
     return np.flatnonzero(differs.any(axis=(0, 2))).tolist()
 
 
+def _assert_rows_written(before, after, written):
+    """Of stacked caches ``before`` (numpy) and ``after``, slot ``n`` of
+    every layer's k and v differs at the positions ``written[n]``, bit for
+    bit, and nowhere else."""
+    for layer, (layer_before, layer_after) in enumerate(zip(before, after)):
+        for half in ("k", "v"):
+            got = np.asarray(layer_after[half])
+            for slot, rows in enumerate(written):
+                changed = _rows_changed(layer_before[half][slot], got[slot])
+                assert changed == rows, (layer, half, slot)
+
+
 @pytest.mark.parametrize("pos, active", [
     # a mixed round
     ([5, 9, 0, 17], [True, False, True, False]),
@@ -109,13 +121,124 @@ def test_a_round_writes_row_pos_of_each_active_slot_and_nothing_else(
     _, after = model._batched_step(
         decoder._params, caches, jnp.arange(SLOTS, dtype=jnp.int32) + 11,
         jnp.asarray(pos, jnp.int32), jnp.asarray(active))
-    for layer_before, layer_after in zip(before, after):
-        for half in ("k", "v"):
-            got = np.asarray(layer_after[half])
-            for slot in range(SLOTS):
-                changed = _rows_changed(layer_before[half][slot], got[slot])
-                assert changed == ([pos[slot]] if active[slot] else []), (
-                    half, slot)
+    _assert_rows_written(
+        before, after, [[p] if a else [] for p, a in zip(pos, active)])
+
+
+@pytest.mark.parametrize("length", [64, 192, 256])
+def test_a_table_of_any_length_is_written_as_the_fixtures_is(length):
+    """The batcher's rows go through a window of positions where whole
+    windows cover the table (256: two), and through one window as long as the
+    table where they do not (192) or it is shorter than one (64)."""
+    import jax.numpy as jnp
+
+    cls = type("OtherLength", (TinyDecoderModel,), {"MAX_LEN": length})
+    model = BatchedDecoderModel(seed=0, slots=SLOTS)
+    model._decoder = cls(seed=0)  # composed before the batcher builds
+    model._ensure_built()
+    try:
+        pos = [0, length - 1, length, length // 2 + 3]
+        active = [True, True, False, True]
+        before, caches = _filled_caches(model, seed=8)
+        _, after = model._batched_step(
+            model._decoder._params, caches,
+            jnp.arange(SLOTS, dtype=jnp.int32) + 11,
+            jnp.asarray(pos, jnp.int32), jnp.asarray(active))
+        _assert_rows_written(
+            before, after, [[p] if a else [] for p, a in zip(pos, active)])
+    finally:
+        model.unload()
+
+
+# -- the same over sixteen slots, for both programs that write a table --------
+
+WIDE = 16  # the stream model's table and the benchmark's batcher
+
+
+@pytest.fixture(scope="module")
+def wide():
+    """The two programs whose rows ``write_table_rows`` writes, each as
+    ``(caches, fed, tokens, pos, active) -> (fed, caches)`` over a table of
+    sixteen slots: the slot batcher's step and the stream model's round
+    (``fed`` is the round's own carry: the choices of the round before,
+    still on the device)."""
+    import jax.numpy as jnp
+
+    model = BatchedDecoderModel(seed=0, slots=WIDE)
+    model._ensure_built()
+    decoder = model._decoder
+
+    def batched_step(caches, fed, tokens, pos, active):
+        logits, caches = model._batched_step(
+            decoder._params, caches, jnp.asarray(tokens, jnp.int32),
+            jnp.asarray(pos, jnp.int32), jnp.asarray(active))
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), caches
+
+    def a_round(caches, fed, tokens, pos, active):
+        ctl = np.stack([tokens, pos, active]).astype(np.int32)
+        return decoder._round_fn(decoder._params, caches, fed, ctl,
+                                 live=MAX_LEN)
+
+    yield model, {"jit_batched_step": batched_step, "jit_step": a_round}
+    model.unload()
+
+
+EVERY = [True] * WIDE
+NOBODY = [False] * WIDE
+APART = [(7 * slot) % 100 + 1 for slot in range(WIDE)]  # no two alike
+BUT_FIVE = [slot != 5 for slot in range(WIDE)]
+SOME = [slot % 3 != 1 for slot in range(WIDE)]
+
+
+def _next(pos, active):
+    return [p + a for p, a in zip(pos, active)]
+
+
+# rounds dispatched one behind the other with nothing read in between, each
+# ``(pos, active)``; the tokens are the round's own
+ROUND_SEQUENCES = {
+    "all_sixteen": [(APART, EVERY)],
+    # the batcher's warm-up: the caches come back as they were
+    "none": [([0] * WIDE, NOBODY), (APART[:-1] + [MAX_LEN], NOBODY)],
+    # a seated slot sits a round out (whatever token rode in its place) and
+    # is a member of the next, at the position it was left at
+    "sits_out_then_joins": [(APART, BUT_FIVE), (_next(APART, BUT_FIVE), EVERY)],
+    # two rounds in flight on one table, as the stream model's worker and
+    # the batcher's dispatch them: the second before the first is read
+    "two_in_flight": [(APART, SOME), (_next(APART, SOME), SOME)],
+}
+
+
+@pytest.mark.parametrize("sequence", ROUND_SEQUENCES)
+@pytest.mark.parametrize("program", ["jit_batched_step", "jit_step"])
+def test_rounds_on_a_table_of_sixteen_write_their_members_rows_alone(
+        wide, program, sequence):
+    import jax.numpy as jnp
+
+    model, programs = wide
+    rounds = ROUND_SEQUENCES[sequence]
+
+    def run(rounds, first=0):
+        before, caches = _filled_caches(model, seed=7)
+        fed = jnp.zeros((WIDE,), jnp.int32)
+        for n, (pos, active) in enumerate(rounds, first):
+            tokens = np.arange(WIDE) + 11 + WIDE * n
+            fed, caches = programs[program](caches, fed, tokens, pos, active)
+        return before, caches
+
+    before, after = run(rounds)
+    # the last round alone, on the same table: its first layer's rows depend
+    # on nothing but its tokens and positions
+    _, alone = run(rounds[-1:], first=len(rounds) - 1)
+    _assert_rows_written(before, after, [
+        [pos[slot] for pos, active in rounds if active[slot]]
+        for slot in range(WIDE)])
+    last_pos, last_active = rounds[-1]
+    for half in ("k", "v"):
+        got, want = np.asarray(after[0][half]), np.asarray(alone[0][half])
+        for slot in np.flatnonzero(last_active):
+            row = (slot, slice(None), last_pos[slot])
+            assert got[row].tobytes() == want[row].tobytes(), (half, slot)
 
 
 def test_the_batched_rows_are_the_single_slot_steps_rows(built):
