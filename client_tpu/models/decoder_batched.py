@@ -64,12 +64,18 @@ import numpy as np
 
 from ..server.timeline import (
     SPAN_ADMIT,
+    SPAN_BATCH_DEVICE_WAIT,
+    SPAN_BATCH_HAND_OUT,
     SPAN_BATCH_READBACK,
+    SPAN_BATCH_RECORD,
+    SPAN_BATCH_TURN,
+    SPAN_BATCH_WAIT_WORK,
     SPAN_COLLECT,
     SPAN_ROUND_DISPATCH,
     SPAN_ROUND_PREPARE,
     SPAN_WAIT_RESULT,
     BatchMarks,
+    Phases,
     current,
     span,
 )
@@ -158,6 +164,9 @@ class BatchedDecoderModel(Model):
         # and per rung of the decoder's ladder
         self.batch_histogram: Dict[int, int] = {}
         self.steps_by_rung = RungCount()
+        # the worker's turns by phase (server/timeline.py: ``Phases``), for
+        # the registry's ``round_phase`` series
+        self.phases = Phases()
         self._warm = False  # every rung's program is compiled
         self._rounds = 0  # rounds dispatched so far: the next round's id
         # ``(width, dispatch_ns)`` of every round, for the statistics verb's
@@ -204,6 +213,9 @@ class BatchedDecoderModel(Model):
             # on-device would cost a blocking readback per request, the
             # exact per-dispatch cost the batcher amortizes
             self._pos = np.zeros((S,), np.int32)
+            # the id of the round that carried each slot's last token, for
+            # a continuation request's ``stride_rounds``
+            self._last_round = [0] * S
             self._slot_of: Dict[Any, int] = {}
             self._free = list(range(S))
             self._worker = threading.Thread(
@@ -326,33 +338,44 @@ class BatchedDecoderModel(Model):
         of 42.6 ms on the chip, dispatch first 34.6 (PERF.md section 6,
         PR 29). An answer waits for that one dispatch call, never for a
         round to run."""
-        with span(SPAN_COLLECT):
-            arrivals = self._arrivals()
-        if arrivals:
-            with span(SPAN_ADMIT):
-                self._admit_arrivals(arrivals)
-        if self._table:
-            self._dispatch_round()
-        self._answer()
-        # with nothing to dispatch there is nothing to wait with either
-        while self._in_flight and (len(self._in_flight) >= ROUNDS_IN_FLIGHT
-                                   or not self._table):
-            self._read_back()
+        phases = self.phases
+        with span(SPAN_BATCH_TURN):
+            waited = []
+            if self._taking and not (self._carry or self._table
+                                     or self._answers):
+                # nothing to run and no answer to hand out (what is carried
+                # joins the next round): wait for a request
+                with span(SPAN_BATCH_WAIT_WORK, into=phases):
+                    waited.append(self._queue.get())
+            with span(SPAN_COLLECT, into=phases):
+                arrivals = self._arrivals(waited)
+            if arrivals:
+                with span(SPAN_ADMIT, into=phases):
+                    self._admit_arrivals(arrivals)
+            if self._table:
+                self._dispatch_round()
+            with span(SPAN_BATCH_HAND_OUT, into=phases):
+                self._answer()
+            # with nothing to dispatch there is nothing to wait with either
+            while self._in_flight and (len(self._in_flight) >= ROUNDS_IN_FLIGHT
+                                       or not self._table):
+                self._read_back()
 
-    def _arrivals(self) -> List[_SeqRequest]:
+    def _arrivals(self, waited: List[Any]) -> List[_SeqRequest]:
         """The requests that join the next round: at most one a sequence,
         and none of a sequence that has a request in the table (two requests
         on a sequence must observe each other's cache updates, so the second
         waits its turn — the reference sequence batcher serializes per CORRID
-        the same way). The carried requests come first, then everything on
-        the queue; with nothing to run and no answer to hand out it waits
-        there for a request."""
+        the same way). The carried requests come first, then what the turn
+        ``waited`` for, if it did, and everything on the queue."""
         arrivals: List[_SeqRequest] = []
         carried, self._carry = self._carry, []
         busy = set(self._table)
 
-        def sort(req: _SeqRequest) -> None:
-            if req.seq_id in busy:
+        def sort(req) -> None:
+            if req is None:  # unload's sentinel
+                self._taking = False
+            elif req.seq_id in busy:
                 # serialize per CORRID but KEEP taking: a fast client's
                 # back-to-back request must not shut other sequences out of
                 # this round
@@ -361,18 +384,13 @@ class BatchedDecoderModel(Model):
                 busy.add(req.seq_id)
                 arrivals.append(req)
 
-        for req in carried:
+        for req in carried + waited:
             sort(req)
         while self._taking:
             try:
-                req = self._queue.get(
-                    block=not (arrivals or self._table or self._answers))
+                sort(self._queue.get_nowait())
             except queue.Empty:
                 break
-            if req is None:
-                self._taking = False
-            else:
-                sort(req)
         return arrivals
 
     def _admit_arrivals(self, arrivals: List[_SeqRequest]) -> None:
@@ -472,8 +490,9 @@ class BatchedDecoderModel(Model):
         """One round: the next token of every request of the table, in one
         dispatch. A request whose tokens are spent leaves the table for the
         round's record, to be answered when the round is read back."""
-        members = list(self._table.values())
-        with span(SPAN_ROUND_PREPARE):
+        phases = self.phases
+        with span(SPAN_ROUND_PREPARE, into=phases):
+            members = list(self._table.values())
             tokens = np.zeros((self.slots,), np.int32)
             active = np.zeros((self.slots,), bool)
             for req, slot in members:
@@ -492,53 +511,64 @@ class BatchedDecoderModel(Model):
             live = self._decoder.rung_for(int(pos[active].max()) + 1)
         try:
             self._ensure_warm()
-            with span(SPAN_ROUND_DISPATCH) as dispatch:
+            with span(SPAN_ROUND_DISPATCH, into=phases) as dispatch:
                 logits, self._caches = self._step_at(tokens, pos, active, live)
         except Exception as e:  # a failed dispatch must not strand callers
             self._table.clear()
             self._fail_round(e, members)
             return
-        self._pos[active] += 1
-        width = len(members)
-        self.batch_histogram[width] = self.batch_histogram.get(width, 0) + 1
-        self.steps_by_rung.add(live)
-        if self.report_batch is not None:
-            self.report_batch(width, dispatch.ns)
-        answered = []
-        for req, slot in members:
-            if not req.marks.rounds_own:
-                req.marks.rounds_waited = self._rounds - req.rounds_before
-            req.marks.round(dispatch, self._rounds, width, last=not req.tokens)
-            if not req.tokens:
-                del self._table[req.seq_id]
-                answered.append((req, slot))
-        if answered:
-            # the transfer begins when the step ends, with no host thread
-            # having to be scheduled in between
-            logits.copy_to_host_async()
-        self._in_flight.append((self._rounds, logits, answered))
-        self._rounds += 1
+        with span(SPAN_BATCH_RECORD, into=phases):
+            self._pos[active] += 1
+            width = len(members)
+            self.batch_histogram[width] = self.batch_histogram.get(width, 0) + 1
+            self.steps_by_rung.add(live)
+            if self.report_batch is not None:
+                self.report_batch(width, dispatch.ns)
+            answered = []
+            for req, slot in members:
+                if not req.marks.rounds_own:
+                    req.marks.rounds_waited = self._rounds - req.rounds_before
+                    if not req.start:
+                        req.marks.stride_rounds = (
+                            self._rounds - self._last_round[slot])
+                self._last_round[slot] = self._rounds
+                req.marks.round(dispatch, self._rounds, width,
+                                last=not req.tokens)
+                if not req.tokens:
+                    del self._table[req.seq_id]
+                    answered.append((req, slot))
+            if answered:
+                # the transfer begins when the step ends, with no host thread
+                # having to be scheduled in between
+                logits.copy_to_host_async()
+            self._in_flight.append((self._rounds, logits, answered))
+            self._rounds += 1
 
     def _read_back(self) -> None:
-        """The oldest round in flight: wait for it and bring its logits to
-        the host in one transfer; each request that ended with it has its
-        row, a view, from here on, and ``sequence_end`` gives its slot up
-        here, so that the next round's arrivals find it. A round that
-        answers nobody (prompts midway) is waited for all the same, which
-        is what holds the bound."""
+        """The oldest round in flight: wait for it (``device_wait``) and then
+        bring its logits to the host in one transfer (``readback``: the
+        transfer and its laying out alone); each request that ended with it
+        has its row, a view, from here on, and ``sequence_end`` gives its
+        slot up here, so that the next round's arrivals find it. A round
+        that answers nobody (prompts midway) is waited for all the same,
+        which is what holds the bound, and has nothing to transfer."""
+        phases = self.phases
         round_id, logits, answered = self._in_flight[0]
-        with span(SPAN_BATCH_READBACK) as readback:
-            if answered:
+        with span(SPAN_BATCH_DEVICE_WAIT, into=phases):
+            logits.block_until_ready()
+        if answered:
+            with span(SPAN_BATCH_READBACK, into=phases) as readback:
                 rows = np.asarray(logits)
-            else:
-                logits.block_until_ready()
         self._in_flight.popleft()
-        for req, slot in answered:
-            if req.end:
-                with self._lock:
-                    self._free_slot(req.seq_id)
-            req.marks.on_host = readback.end_ns
-            self._answers.append((req, rows[slot], round_id))
+        if not answered:
+            return
+        with span(SPAN_BATCH_RECORD, into=phases):
+            for req, slot in answered:
+                if req.end:
+                    with self._lock:
+                        self._free_slot(req.seq_id)
+                req.marks.on_host = readback.end_ns
+                self._answers.append((req, rows[slot], round_id))
 
     def _answer(self) -> None:
         """Hand out the answers that are on the host."""

@@ -80,13 +80,23 @@ from ..server.timeline import (
     SPAN_FRESH_CACHE,
     SPAN_PREFILL,
     SPAN_READBACK,
+    Phases,
     StreamMarks,
     current,
     span,
 )
 from .base import Model, TensorSpec
 from .decoder import RungCount, TinyDecoderModel
-from .stream_rounds import StreamRounds
+from .stream_rounds import Stream, StreamRounds
+
+
+def _handed(stream: Stream, marks: StreamMarks):
+    """The stream's tokens as its own thread takes them off its queue, each
+    marked with how long it lay between the end of the worker's read-back
+    and here."""
+    for token, read_back_ns in stream.tokens():
+        marks.handoff.add(time.perf_counter_ns() - read_back_ns)
+        yield token
 
 
 def _suspended(marks: StreamMarks, token_id: int, index: int):
@@ -95,7 +105,7 @@ def _suspended(marks: StreamMarks, token_id: int, index: int):
     t_yield = time.perf_counter_ns()
     yield {"NEXT_TOKEN": np.array([[token_id]], dtype=np.int32),
            "INDEX": np.array([[index]], dtype=np.int32)}
-    marks.yielded.add(time.perf_counter_ns() - t_yield, index)
+    marks.yielded.add(time.perf_counter_ns() - t_yield)
 
 
 class TinyGenerateModel(Model):
@@ -130,13 +140,15 @@ class TinyGenerateModel(Model):
         # they carried and by the slots their attention read; streams that
         # found no free slot at their admission; and ``(members,
         # dispatch_ns)`` of every round for the statistics verb's
-        # batch_stats (ServerCore.add_model binds its recorder here)
+        # batch_stats (ServerCore.add_model binds its recorder here); and the
+        # worker's turns by phase (server/timeline.py: ``Phases``)
         self.slots = int(slots)
         self._rounds: Optional[StreamRounds] = None
         self.batch_histogram: Dict[int, int] = {}
         self.rounds_by_width: Dict[int, int] = {}
         self.slot_waits = 0
         self.report_batch = None
+        self.phases = Phases()
 
     def inputs(self) -> List[TensorSpec]:
         return [
@@ -264,10 +276,10 @@ class TinyGenerateModel(Model):
         worker's: ``cache_ready`` the slot taken, ``prefill_done`` the
         return of the dispatch of the round that consumed the last prompt
         token, a token's ``dispatch`` and ``readback`` those of the round
-        that carried it."""
+        that carried it; its ``handoff`` is this thread's."""
         stream = rounds.open(prompt, budget, end_id, marks)
         try:
-            tokens = stream.tokens()
+            tokens = _handed(stream, marks)
             with span(SPAN_PREFILL):  # admission, its prompt's rounds, the
                 burst = list(itertools.islice(tokens, 1))  # last's read-back
             emitted = 0
@@ -302,7 +314,7 @@ class TinyGenerateModel(Model):
         emitted = 0
         with span(SPAN_READBACK) as s:
             next_token = int(np.asarray(logits).argmax())
-        marks.readback.add(s.ns, emitted)
+        marks.readback.add(s.ns)
         if chunk == 1:
             # per-token dispatch: one streamed response per device step —
             # honest TTFT/inter-token latency for a perf harness
@@ -315,11 +327,11 @@ class TinyGenerateModel(Model):
                 with span(SPAN_DISPATCH) as s:
                     logits, caches = dec.decode_step(
                         caches, next_token, pos, self.steps_by_rung)
-                marks.dispatch.add(s.ns, emitted)
+                marks.dispatch.add(s.ns)
                 pos += 1
                 with span(SPAN_READBACK) as s:
                     next_token = int(np.asarray(logits).argmax())
-                marks.readback.add(s.ns, emitted)
+                marks.readback.add(s.ns)
             return
 
         # chunked: first token came from prefill; subsequent tokens arrive
@@ -335,11 +347,11 @@ class TinyGenerateModel(Model):
             with span(SPAN_DISPATCH) as s:
                 toks, caches = self._chunk_fn(k)(
                     dec._params, caches, next_token, pos)
-            marks.dispatch.add(s.ns, emitted)
+            marks.dispatch.add(s.ns)
             pos += k
             with span(SPAN_READBACK) as s:
                 toks = np.asarray(toks).reshape(-1)
-            marks.readback.add(s.ns, emitted)
+            marks.readback.add(s.ns)
             for t in toks:
                 yield from _suspended(marks, int(t), emitted)
                 emitted += 1

@@ -73,10 +73,17 @@ import numpy as np
 
 from .decoder import slots_a_turn
 from ..server.timeline import (
+    SPAN_DEVICE_WAIT,
     SPAN_DISPATCH,
     SPAN_FRESH_CACHE,
+    SPAN_HAND_OUT,
     SPAN_PREFILL_CHUNK,
+    SPAN_PREPARE,
     SPAN_READBACK,
+    SPAN_RECORD,
+    SPAN_STREAM_ADMIT,
+    SPAN_TURN,
+    SPAN_WAIT_WORK,
     StreamMarks,
     span,
 )
@@ -92,8 +99,9 @@ _END = object()  # on a stream's queue: no more tokens
 
 class Stream:
     """One stream's place in the rounds. The worker's, but for ``out`` (its
-    tokens, then ``_END`` or what failed it) and ``gone``, which the
-    consumer's side sets when it goes away."""
+    tokens, each beside the clock reading that ended its round's read-back,
+    then ``_END`` or what failed it) and ``gone``, which the consumer's side
+    sets when it goes away."""
 
     __slots__ = ("prompt", "budget", "end_id", "marks", "out", "gone", "slot",
                  "at", "pos", "due", "done", "waited")
@@ -111,8 +119,9 @@ class Stream:
         self.done = False  # ended: nothing more goes on ``out``
         self.waited = False  # counted as having found no free slot
 
-    def tokens(self) -> Iterator[int]:
-        """The stream's tokens as their rounds are read back."""
+    def tokens(self) -> Iterator[Tuple[int, int]]:
+        """The stream's tokens as their rounds are read back, each with the
+        end of that read-back on the worker's clock."""
         while True:
             try:
                 item = self.out.get(timeout=STREAM_TIMEOUT_S)
@@ -133,7 +142,8 @@ class StreamRounds:
     """The table, the worker and the rounds in flight of one stream model.
     ``model`` is the served model whose counts it fills (``steps_by_rung``,
     ``batch_histogram``, ``rounds_by_width``, ``slot_waits``,
-    ``report_batch``) and whose ``_decoder`` it steps; the decoder's
+    ``report_batch``, and ``phases``: the worker's turns by phase) and whose
+    ``_decoder`` it steps; the decoder's
     ``_params`` are read at every dispatch, so new weights are served from
     the next round on."""
 
@@ -154,14 +164,18 @@ class StreamRounds:
         # where the decoder has no slot prefill), the free slots (a heap: the
         # lowest first), the streams that wait for one, and the rounds
         # dispatched and not yet read back, oldest first: ``(choices on the
-        # device, [(stream, slot, index of the token)], dispatch)``
+        # device, [(stream, slot, index of the token)], dispatch, id)``; the
+        # id counts the dispatches that are read back, a round or a prompt's
+        # last chunk
         self._members: Dict[int, Stream] = {}
         self._prompts: Deque[Stream] = collections.deque()
         self._slot_prefill = decoder._slot_prefill_fn
         self._free = list(range(self.slots))
         self._waiting: Deque[Stream] = collections.deque()
-        self._in_flight: Deque[Tuple[Any, List[Tuple[Stream, int, int]], span]] = (
-            collections.deque())
+        self._in_flight: Deque[
+            Tuple[Any, List[Tuple[Stream, int, int]], span, int]] = (
+                collections.deque())
+        self._dispatched = 0
         # every rung's program compiled, by one real round each with nobody
         # seated (no row is written), before the first stream is taken
         nobody = np.zeros((3, self.slots), np.int32)
@@ -216,50 +230,59 @@ class StreamRounds:
             self._end(stream, ValueError("model is shutting down"))
 
     def _turn(self) -> None:
-        self._admit()
-        if self._prompts and self._taking:
-            self._take_chunk()
-        if self._members and self._taking:
-            self._dispatch()
-        while self._in_flight and (len(self._in_flight) >= ROUNDS_IN_FLIGHT
-                                   or not self._members):
-            self._read_back()
+        with span(SPAN_TURN):
+            if not (self._members or self._prompts or self._in_flight
+                    or self._waiting):
+                # nothing to run: wait for a stream
+                with span(SPAN_WAIT_WORK, into=self._model.phases):
+                    self._take(self._arrivals.get())
+            self._admit()
+            if self._prompts and self._taking:
+                self._take_chunk()
+            if self._members and self._taking:
+                self._dispatch()
+            while self._in_flight and (len(self._in_flight) >= ROUNDS_IN_FLIGHT
+                                       or not self._members):
+                self._read_back()
+
+    def _take(self, stream: Optional[Stream]) -> None:
+        """What came off the queue: a stream, which queues behind those
+        that wait, or ``close``'s sentinel."""
+        if stream is None:
+            self._taking = False
+        else:
+            self._waiting.append(stream)
 
     def _admit(self) -> None:
         """The slots of the streams whose consumers went away are given
         back; what has arrived queues behind those that wait; the free slots
-        are taken, lowest first, first come first seated. With nothing to
-        run it waits here for a stream."""
-        for stream in [s for s in (*self._members.values(), *self._prompts)
-                       if s.gone]:
-            self._end(stream)
-        idle = not (self._members or self._prompts or self._in_flight
-                    or self._waiting)
-        while True:
-            try:
-                stream = self._arrivals.get(block=idle)
-            except queue.Empty:
-                break
-            if stream is None:
-                self._taking = False
+        are taken, lowest first, first come first seated."""
+        with span(SPAN_STREAM_ADMIT, into=self._model.phases):
+            for stream in [s for s in (*self._members.values(), *self._prompts)
+                           if s.gone]:
+                self._end(stream)
+            while self._taking:
+                try:
+                    self._take(self._arrivals.get_nowait())
+                except queue.Empty:
+                    break
+            if not self._taking:
                 return
-            self._waiting.append(stream)
-            idle = False
-        while self._waiting and self._free:
-            stream = self._waiting.popleft()
-            if stream.gone:
-                continue
-            with span(SPAN_FRESH_CACHE) as taken:
-                stream.slot = heapq.heappop(self._free)
-                if self._slot_prefill is None:
-                    self._members[stream.slot] = stream
-                else:
-                    self._prompts.append(stream)
-            stream.marks.cache_ready = taken.end_ns
-        for stream in self._waiting:
-            if not stream.waited:
-                stream.waited = True
-                self._model.slot_waits += 1
+            while self._waiting and self._free:
+                stream = self._waiting.popleft()
+                if stream.gone:
+                    continue
+                with span(SPAN_FRESH_CACHE) as taken:
+                    stream.slot = heapq.heappop(self._free)
+                    if self._slot_prefill is None:
+                        self._members[stream.slot] = stream
+                    else:
+                        self._prompts.append(stream)
+                stream.marks.cache_ready = taken.end_ns
+            for stream in self._waiting:
+                if not stream.waited:
+                    stream.waited = True
+                    self._model.slot_waits += 1
 
     def _take_chunk(self) -> None:
         """The next chunk of the oldest prompt that is being taken, into its
@@ -269,104 +292,124 @@ class StreamRounds:
         import jax
 
         decoder, count = self._decoder, self._model.steps_by_rung
-        stream = self._prompts[0]
-        size = decoder._slot_prefill_chunk
-        base, n = stream.at, min(size, len(stream.prompt) - stream.at)
-        last = base + n == len(stream.prompt)
-        block = np.zeros(size, np.int32)
-        block[:n] = stream.prompt[base:base + n]
-        with span(SPAN_PREFILL_CHUNK) as dispatch:
-            self._chunk(block, np.array([stream.slot, base, 0, n, last], np.int32),
-                        decoder.rung_for(base + n))
-        stream.at += n
-        decoder.count_positions(count, np.arange(base, base + n), decoding=False)
-        if not last:
-            if not self._members:
-                # nobody decodes: nothing else paces the worker, so a chunk
-                # is waited for before the next is dispatched
+        phases = self._model.phases
+        with span(SPAN_PREPARE, into=phases):
+            stream = self._prompts[0]
+            size = decoder._slot_prefill_chunk
+            base, n = stream.at, min(size, len(stream.prompt) - stream.at)
+            last = base + n == len(stream.prompt)
+            block = np.zeros(size, np.int32)
+            block[:n] = stream.prompt[base:base + n]
+            ctl = np.array([stream.slot, base, 0, n, last], np.int32)
+        with span(SPAN_PREFILL_CHUNK, into=phases) as dispatch:
+            self._chunk(block, ctl, decoder.rung_for(base + n))
+        with span(SPAN_RECORD, into=phases):
+            stream.at += n
+            decoder.count_positions(count, np.arange(base, base + n), decoding=False)
+            if last:
+                self._fed.copy_to_host_async()
+                self._prompts.popleft()
+                self._members[stream.slot] = stream
+                stream.pos = len(stream.prompt)
+                stream.marks.prefill_done = dispatch.end_ns
+                count.add_prefill(len(stream.prompt),
+                                  chunks=-(-len(stream.prompt) // size))
+                count.add_prefill_ns(dispatch.end_ns - stream.marks.cache_ready)
+                self._sent([(stream, stream.slot, 0)], dispatch)
+                stream.due = 1
+                if stream.due == stream.budget:
+                    self._vacate(stream)
+        if not last and not self._members:
+            # nobody decodes: nothing else paces the worker, so a chunk is
+            # waited for before the next is dispatched
+            with span(SPAN_DEVICE_WAIT, into=phases):
                 jax.block_until_ready(self._fed)
-            return
-        self._fed.copy_to_host_async()
-        self._prompts.popleft()
-        self._members[stream.slot] = stream
-        stream.pos = len(stream.prompt)
-        stream.marks.prefill_done = dispatch.end_ns
-        count.add_prefill(len(stream.prompt), chunks=-(-len(stream.prompt) // size))
-        count.add_prefill_ns(dispatch.end_ns - stream.marks.cache_ready)
-        self._in_flight.append((self._fed, [(stream, stream.slot, 0)], dispatch))
-        stream.due = 1
-        if stream.due == stream.budget:
-            self._vacate(stream)
+
+    def _sent(self, gives: List[Tuple[Stream, int, int]], dispatch: span) -> None:
+        """The dispatch just made, among those to be read back, under the
+        next id."""
+        self._in_flight.append((self._fed, gives, dispatch, self._dispatched))
+        self._dispatched += 1
 
     def _dispatch(self) -> None:
         """One round: the next token of every member."""
         model, decoder = self._model, self._decoder
-        members = sorted(self._members.items())
-        ctl = np.zeros((3, self.slots), np.int32)
-        ctl[0] = -1
-        for slot, stream in members:
-            if stream.at < len(stream.prompt):
-                ctl[0, slot] = stream.prompt[stream.at]
-                stream.at += 1
-            ctl[1, slot] = stream.pos
-            ctl[2, slot] = 1
-            stream.pos += 1
-        # the shortest rung that covers the furthest member; the slots its
-        # attention reads, as the program counts them: the occupied ones, in
-        # whole turns
-        live = decoder.rung_for(int(ctl[1].max()) + 1)
-        width = -(-(members[-1][0] + 1) // self._a_turn) * self._a_turn
-        decoder.count_positions(model.steps_by_rung, ctl[1][ctl[2] > 0], decoding=True)
-        with span(SPAN_DISPATCH) as dispatch:
+        phases, count = model.phases, model.steps_by_rung
+        with span(SPAN_PREPARE, into=phases):
+            members = sorted(self._members.items())
+            ctl = np.zeros((3, self.slots), np.int32)
+            ctl[0] = -1
+            for slot, stream in members:
+                if stream.at < len(stream.prompt):
+                    ctl[0, slot] = stream.prompt[stream.at]
+                    stream.at += 1
+                ctl[1, slot] = stream.pos
+                ctl[2, slot] = 1
+                stream.pos += 1
+            # the shortest rung that covers the furthest member; the slots its
+            # attention reads, as the program counts them: the occupied ones,
+            # in whole turns
+            live = decoder.rung_for(int(ctl[1].max()) + 1)
+            width = -(-(members[-1][0] + 1) // self._a_turn) * self._a_turn
+            decoder.count_positions(count, ctl[1][ctl[2] > 0], decoding=True)
+        with span(SPAN_DISPATCH, into=phases) as dispatch:
             self._step(ctl, live)
-        # the transfer begins when the round ends, with no host thread
-        # having to be scheduled in between
-        self._fed.copy_to_host_async()
-        gives = []
-        count = model.steps_by_rung
-        for slot, stream in members:
-            if stream.at < len(stream.prompt):
-                continue  # midway through its prompt: this round gives none
-            if not stream.due:
-                stream.marks.prefill_done = dispatch.end_ns
-                count.add_prefill(len(stream.prompt), chunks=len(stream.prompt))
-                count.add_prefill_ns(dispatch.end_ns - stream.marks.cache_ready)
-            gives.append((stream, slot, stream.due))
-            stream.due += 1
-            if stream.due == stream.budget:
-                self._vacate(stream)  # its last round: the slot is the next's
-        self._in_flight.append((self._fed, gives, dispatch))
-        count.add(live)
-        carried = model.batch_histogram
-        carried[len(members)] = carried.get(len(members), 0) + 1
-        model.rounds_by_width[width] = model.rounds_by_width.get(width, 0) + 1
-        if model.report_batch is not None:
-            model.report_batch(len(members), dispatch.ns)
+        with span(SPAN_RECORD, into=phases):
+            # the transfer begins when the round ends, with no host thread
+            # having to be scheduled in between
+            self._fed.copy_to_host_async()
+            gives = []
+            for slot, stream in members:
+                if stream.at < len(stream.prompt):
+                    continue  # midway through its prompt: this round gives none
+                if not stream.due:
+                    stream.marks.prefill_done = dispatch.end_ns
+                    count.add_prefill(len(stream.prompt), chunks=len(stream.prompt))
+                    count.add_prefill_ns(dispatch.end_ns - stream.marks.cache_ready)
+                gives.append((stream, slot, stream.due))
+                stream.due += 1
+                if stream.due == stream.budget:
+                    self._vacate(stream)  # its last round: the slot is the next's
+            self._sent(gives, dispatch)
+            count.add(live)
+            carried = model.batch_histogram
+            carried[len(members)] = carried.get(len(members), 0) + 1
+            model.rounds_by_width[width] = model.rounds_by_width.get(width, 0) + 1
+            if model.report_batch is not None:
+                model.report_batch(len(members), dispatch.ns)
 
     def _read_back(self) -> None:
-        """The oldest round in flight: wait for it, bring its choices to the
-        host and hand every stream it gave a token that token."""
-        fed, gives, dispatch = self._in_flight[0]
-        with span(SPAN_READBACK) as readback:
+        """The oldest dispatch in flight: wait for it (``device_wait``), bring
+        its choices to the host (``readback``) and hand every stream it gave
+        a token that token, beside the clock reading that ended the
+        read-back (``hand_out``)."""
+        phases = self._model.phases
+        fed, gives, dispatch, round_id = self._in_flight[0]
+        with span(SPAN_DEVICE_WAIT, into=phases) as wait:
+            fed.block_until_ready()
+        with span(SPAN_READBACK, into=phases) as readback:
             chosen = np.asarray(fed)
-        self._in_flight.popleft()
-        for stream, slot, index in gives:
-            if stream.done:
-                continue  # ended by its END_ID a round ago, or its consumer left
-            token = int(chosen[slot])
-            if index:  # the first token's dispatches are its prefill
-                stream.marks.dispatch.add(dispatch.ns, index)
-            stream.marks.readback.add(readback.ns, index)
-            stream.out.put(token)
-            if index + 1 == stream.budget or token == stream.end_id:
-                self._end(stream)
-        if gives:
-            # the interpreter, once, to the streams that were handed a token:
-            # where the device paces the rounds they would run at the next
-            # read-back anyway; where the host does (a small model, the
-            # CPU) the worker never waits, and each would stand a switch
-            # interval (5 ms) behind it
-            time.sleep(0)
+        with span(SPAN_HAND_OUT, into=phases):
+            self._in_flight.popleft()
+            for stream, slot, index in gives:
+                if stream.done:
+                    continue  # ended by its END_ID a round ago, or its consumer left
+                token = int(chosen[slot])
+                if index:  # the first token's dispatches are its prefill
+                    stream.marks.dispatch.add(dispatch.ns)
+                else:
+                    stream.marks.first_round_id = round_id
+                stream.marks.readback.add(readback.end_ns - wait.start_ns)
+                stream.out.put((token, readback.end_ns))
+                if index + 1 == stream.budget or token == stream.end_id:
+                    self._end(stream)
+            if gives:
+                # the interpreter, once, to the streams that were handed a
+                # token: where the device paces the rounds they would run at
+                # the next read-back anyway; where the host does (a small
+                # model, the CPU) the worker never waits, and each would stand
+                # a switch interval (5 ms) behind it
+                time.sleep(0)
 
     def _vacate(self, stream: Stream) -> None:
         if self._members.get(stream.slot) is stream:
@@ -392,7 +435,7 @@ class StreamRounds:
         import jax.numpy as jnp
 
         begun = [*self._members.values(), *self._prompts]
-        for _, gives, _ in self._in_flight:
+        for _, gives, _, _ in self._in_flight:
             begun.extend(stream for stream, _, _ in gives)
         self._in_flight.clear()
         for stream in begun:

@@ -175,7 +175,9 @@ class _ModelStats:
     """What the statistics verb reports for one model. Every successful
     request counts once in each of Triton's four parts, which add up to its
     ``success`` ns (``Timeline.parts`` says what each holds for a batched
-    model, for a decoupled one and for any other)."""
+    model, for a decoupled one and for any other). Beside them, for the
+    registry, what its requests read of the rounds that carried them
+    (``Timeline.readings``)."""
 
     def __init__(self):
         self.lock = threading.Lock()
@@ -192,14 +194,20 @@ class _ModelStats:
         self.compute_infer = [0, 0]
         self.compute_output = [0, 0]
         self.batches: Dict[int, List[int]] = {}  # batch_size -> [count, ns]
+        self.readings: Dict[str, List[int]] = {}  # reading -> [count, sum]
 
     def record(self, total_ns: int, parts, batch: int,
-               executed: bool = True) -> None:
+               executed: bool = True, readings=()) -> None:
         """One successful request. ``executed=False`` for a request a
         batcher carried: the execution is counted once by record_batch, not
         once per request (reference semantics: execution_count <
-        inference_count under batching)."""
+        inference_count under batching). ``readings``: the request's
+        ``(name, count, sum)`` of ``Timeline.readings``."""
         with self.lock:
+            for name, count, total in readings:
+                row = self.readings.setdefault(name, [0, 0])
+                row[0] += count
+                row[1] += total
             self.inference_count += batch
             if executed:
                 self.execution_count += 1
@@ -433,7 +441,8 @@ class ServerCore:
         tl.close()
         parts = tl.parts()
         self._stats[model_name].record(
-            tl.done - tl.recv, parts, batch, executed=tl.batch is None)
+            tl.done - tl.recv, parts, batch, executed=tl.batch is None,
+            readings=tl.readings())
         ids = None
         if request.get("traceparent"):
             from ..observe import parse_traceparent
@@ -621,6 +630,32 @@ class ServerCore:
                 "steps", ("model",)),
         }
 
+        # the turns of a model's round worker by phase (timeline.PHASES), and
+        # what its requests read of the rounds (Timeline.readings): a pair
+        # of series each, a sum and the count it is over
+        round_phase_ns = reg.gauge(
+            "client_tpu_server_round_phase_ns",
+            "Host time of the round worker's turns, by phase; the phases "
+            "but wait_work add up to the turns", ("model", "phase"))
+        round_phase_count = reg.gauge(
+            "client_tpu_server_round_phase_count",
+            "Times the round worker went through a phase; dispatch counts "
+            "the rounds", ("model", "phase"))
+        readings = {
+            name: (reg.gauge(f"client_tpu_server_{name}_{unit}",
+                             what + ", summed", ("model",)),
+                   reg.gauge(f"client_tpu_server_{name}_count",
+                             what + ": how many", ("model",)))
+            for name, unit, what in (
+                ("sequence_stride", "rounds", "Rounds from the token before "
+                 "to a continuation request's own round (1: no round missed)"),
+                ("answer_wake", "ns", "A batched request's future set to its "
+                 "response built"),
+                ("first_response", "ns", "A stream's entry into the core to "
+                 "its first response built"),
+                ("token_handoff", "ns", "The end of a round's read-back to "
+                 "the stream's own thread holding its token"))}
+
         def collect():
             live.set(1.0 if self.live else 0.0)
             ready.set(1.0 if (self.live and self.ready) else 0.0)
@@ -641,7 +676,19 @@ class ServerCore:
             with self._lock:
                 traced.set(len(self._access))
                 models = list(self._models.items())
+                stats = list(self._stats.items())
+            for name, model_stats in stats:
+                with model_stats.lock:
+                    read = {k: tuple(v) for k, v in model_stats.readings.items()}
+                for reading, (count, total) in read.items():
+                    readings[reading][0].labels(name).set(total)
+                    readings[reading][1].labels(name).set(count)
             for name, model in models:
+                phases = getattr(model, "phases", None)
+                if phases is not None:  # a model with a round worker
+                    for phase, (count, ns) in phases.rows().items():
+                        round_phase_ns.labels(name, phase).set(ns)
+                        round_phase_count.labels(name, phase).set(count)
                 count = getattr(model, "steps_by_rung", None)
                 if count is not None:  # a model that steps a decoder
                     for rung, steps in count.by_rung().items():

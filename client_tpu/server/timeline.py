@@ -7,7 +7,14 @@ hangs its own marks on it, :class:`BatchMarks` or :class:`StreamMarks`; a
 model that marks nothing reports through the core's five marks alone. At the
 end of the request one recorder reads the timeline: the statistics verb
 (:meth:`Timeline.parts`), the Triton trace record and the ``traceparent``
-access record all come from it.
+access record all come from it, and so do the per-request readings of the
+registry (:meth:`Timeline.readings`).
+
+The two round workers (``models/decoder_batched.py``, ``models/stream_rounds.py``)
+cut every turn into **phases**, one shared vocabulary (:data:`PHASES`): each is a
+:func:`span` that also adds its time to the model's :class:`Phases`, so the span
+on the profiler's clock, the per-round counter in the registry and the request's
+marks are one pair of clock readings.
 
 Marks are ``time.perf_counter_ns()`` of the host. **The dispatch marks are
 host times**: a jitted call returns when the program is enqueued, not when
@@ -36,22 +43,61 @@ from typing import Any, Dict, List, Optional, Tuple
 # xplane's host plane (PERF.md section 3 says which reading each is for)
 SPAN_RESOLVE_INPUTS = "client_tpu.core.resolve_inputs"
 SPAN_BUILD_RESPONSE = "client_tpu.core.build_response"
+# the slot batcher's worker, a turn and its phases; ``wait_result`` is the
+# caller's
+SPAN_BATCH_TURN = "client_tpu.batcher.turn"
+SPAN_BATCH_WAIT_WORK = "client_tpu.batcher.wait_work"
 SPAN_COLLECT = "client_tpu.batcher.collect"
 SPAN_ADMIT = "client_tpu.batcher.admit"
 SPAN_ROUND_PREPARE = "client_tpu.batcher.round_prepare"
 SPAN_ROUND_DISPATCH = "client_tpu.batcher.round_dispatch"
-SPAN_WAIT_RESULT = "client_tpu.batcher.wait_result"
+SPAN_BATCH_RECORD = "client_tpu.batcher.record"
+SPAN_BATCH_HAND_OUT = "client_tpu.batcher.hand_out"
+SPAN_BATCH_DEVICE_WAIT = "client_tpu.batcher.device_wait"
 SPAN_BATCH_READBACK = "client_tpu.batcher.readback"
+SPAN_WAIT_RESULT = "client_tpu.batcher.wait_result"
+# the streams: the rounds' worker, a turn and its phases (``fresh_cache``
+# lies inside ``admit``); ``prefill`` is the stream's own thread
+SPAN_TURN = "client_tpu.generate.turn"
+SPAN_WAIT_WORK = "client_tpu.generate.wait_work"
+SPAN_STREAM_ADMIT = "client_tpu.generate.admit"
 SPAN_FRESH_CACHE = "client_tpu.generate.fresh_cache"
 SPAN_PREFILL = "client_tpu.generate.prefill"
 SPAN_PREFILL_CHUNK = "client_tpu.generate.prefill_chunk"
+SPAN_PREPARE = "client_tpu.generate.prepare"
 SPAN_DISPATCH = "client_tpu.generate.dispatch"
+SPAN_RECORD = "client_tpu.generate.record"
+SPAN_HAND_OUT = "client_tpu.generate.hand_out"
+SPAN_DEVICE_WAIT = "client_tpu.generate.device_wait"
 SPAN_READBACK = "client_tpu.generate.readback"
 SPAN_NAMES = (
-    SPAN_RESOLVE_INPUTS, SPAN_BUILD_RESPONSE, SPAN_COLLECT, SPAN_ADMIT,
-    SPAN_ROUND_PREPARE, SPAN_ROUND_DISPATCH, SPAN_WAIT_RESULT,
-    SPAN_BATCH_READBACK, SPAN_FRESH_CACHE, SPAN_PREFILL, SPAN_PREFILL_CHUNK,
-    SPAN_DISPATCH, SPAN_READBACK)
+    SPAN_RESOLVE_INPUTS, SPAN_BUILD_RESPONSE, SPAN_BATCH_TURN,
+    SPAN_BATCH_WAIT_WORK, SPAN_COLLECT, SPAN_ADMIT, SPAN_ROUND_PREPARE,
+    SPAN_ROUND_DISPATCH, SPAN_BATCH_RECORD, SPAN_BATCH_HAND_OUT,
+    SPAN_BATCH_DEVICE_WAIT, SPAN_BATCH_READBACK, SPAN_WAIT_RESULT, SPAN_TURN,
+    SPAN_WAIT_WORK, SPAN_STREAM_ADMIT, SPAN_FRESH_CACHE, SPAN_PREFILL,
+    SPAN_PREFILL_CHUNK, SPAN_PREPARE, SPAN_DISPATCH, SPAN_RECORD,
+    SPAN_HAND_OUT, SPAN_DEVICE_WAIT, SPAN_READBACK)
+
+# a worker's turn, cut into phases that add up to it: one vocabulary, the
+# span of each engine that times a phase keyed to it here (the batcher's
+# ``round_prepare`` and ``round_dispatch`` keep their profiler names); an
+# engine uses the phases it has. ``wait_work`` is the wait for a request or a
+# stream when nothing is in progress, and is left out of every metric;
+# ``between`` has no span: it is what lies between two phases (``Phases``)
+PHASE_OF = {
+    SPAN_BATCH_WAIT_WORK: "wait_work", SPAN_WAIT_WORK: "wait_work",
+    SPAN_COLLECT: "collect",
+    SPAN_ADMIT: "admit", SPAN_STREAM_ADMIT: "admit",
+    SPAN_PREFILL_CHUNK: "prefill_chunk",
+    SPAN_ROUND_PREPARE: "prepare", SPAN_PREPARE: "prepare",
+    SPAN_ROUND_DISPATCH: "dispatch", SPAN_DISPATCH: "dispatch",
+    SPAN_BATCH_RECORD: "record", SPAN_RECORD: "record",
+    SPAN_BATCH_HAND_OUT: "hand_out", SPAN_HAND_OUT: "hand_out",
+    SPAN_BATCH_DEVICE_WAIT: "device_wait", SPAN_DEVICE_WAIT: "device_wait",
+    SPAN_BATCH_READBACK: "readback", SPAN_READBACK: "readback",
+}
+PHASES = tuple(dict.fromkeys(PHASE_OF.values())) + ("between",)
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
@@ -65,18 +111,61 @@ def current() -> Optional["Timeline"]:
     return _CURRENT.get()
 
 
+class Phases:
+    """What one model's round worker took, by phase: ``{phase: [count,
+    ns]}``. Written by the worker alone, through ``span(NAME,
+    into=phases)``, with no lock on the way; the registry's collector reads
+    it as it stands (a row read between its two additions is one count
+    ahead of its ns).
+
+    The phases tile the worker's time: what lies between the end of one
+    phase and the start of the next (the few instructions between two
+    spans, and whatever the thread waited there to be given the processor
+    or the interpreter back) is added as ``between``, from the two readings
+    the spans took anyway. So the rows add up to the time from the worker's
+    first phase to the end of its last, exactly."""
+
+    __slots__ = ("_rows", "_edge")
+
+    def __init__(self):
+        self._rows: Dict[str, List[int]] = {}
+        self._edge: Optional[int] = None  # the end of the last phase
+
+    def add(self, phase: str, start_ns: int, end_ns: int) -> None:
+        if self._edge is not None:
+            self._count("between", start_ns - self._edge)
+        self._count(phase, end_ns - start_ns)
+        self._edge = end_ns
+
+    def _count(self, phase: str, ns: int) -> None:
+        row = self._rows.get(phase)
+        if row is None:
+            row = self._rows[phase] = [0, 0]
+        row[0] += 1
+        row[1] += ns
+
+    def rows(self) -> Dict[str, Tuple[int, int]]:
+        """``{phase: (count, ns)}`` as it stands."""
+        return {phase: (row[0], row[1]) for phase, row in list(self._rows.items())}
+
+
 class span:
     """``with span(NAME) as s:`` — a host span of a fixed name; afterwards
-    ``s.start_ns`` and ``s.end_ns`` are its edges for the marks. Until jax
+    ``s.start_ns`` and ``s.end_ns`` are its edges for the marks. With
+    ``into=phases`` its time is also added there, under the phase its name
+    is keyed to (:data:`PHASE_OF`; a name that is keyed to none raises
+    ``KeyError`` here), from those same two readings. Until jax
     has been imported no profiler session can exist and the span is the two
     clock readings alone."""
 
-    __slots__ = ("_annotation", "start_ns", "end_ns")
+    __slots__ = ("_annotation", "_into", "_phase", "start_ns", "end_ns")
 
-    def __init__(self, name: str):
+    def __init__(self, name: str, into: Optional[Phases] = None):
         jax = sys.modules.get("jax")
         self._annotation = (
             jax.profiler.TraceAnnotation(name) if jax is not None else None)
+        self._into = into
+        self._phase = PHASE_OF[name] if into is not None else None
 
     def __enter__(self) -> "span":
         if self._annotation is not None:
@@ -88,6 +177,8 @@ class span:
         self.end_ns = time.perf_counter_ns()
         if self._annotation is not None:
             self._annotation.__exit__(*exc)
+        if self._into is not None:
+            self._into.add(self._phase, self.start_ns, self.end_ns)
 
     @property
     def ns(self) -> int:
@@ -95,28 +186,21 @@ class span:
 
 
 class Interval:
-    """A host interval that recurs within one request: how often, how long
-    in all, and the longest with the index it fell on."""
+    """A host interval that recurs within one request: how often, and how
+    long in all."""
 
-    __slots__ = ("count", "ns", "longest_ns", "longest_at")
+    __slots__ = ("count", "ns")
 
     def __init__(self):
         self.count = 0
         self.ns = 0
-        self.longest_ns = 0
-        self.longest_at = -1
 
-    def add(self, ns: int, at: int) -> None:
-        """One more of ``ns``, at index ``at`` (a stream's token)."""
+    def add(self, ns: int) -> None:
         self.count += 1
         self.ns += ns
-        if ns > self.longest_ns:
-            self.longest_ns = ns
-            self.longest_at = at
 
     def as_dict(self) -> Dict[str, int]:
-        return {"count": self.count, "ns": self.ns,
-                "longest_ns": self.longest_ns, "longest_at": self.longest_at}
+        return {"count": self.count, "ns": self.ns}
 
 
 class BatchMarks:
@@ -132,11 +216,14 @@ class BatchMarks:
     ``rounds_waited``, the rounds dispatched between ``enqueued`` and
     ``first_dispatch``; ``rounds_held``, the rounds dispatched between its
     last round and ``resolved``; either is at most the batcher's bound on
-    rounds in flight."""
+    rounds in flight; ``stride_rounds``, for a continuation request, its
+    first round's id less the id of the round that carried its sequence's
+    token before (1: the sequence missed no round)."""
 
     __slots__ = ("enqueued", "collected", "first_dispatch", "last_dispatch",
                  "on_host", "resolved", "rounds_own", "rounds_waited",
-                 "rounds_held", "first_round_id", "round_widths")
+                 "rounds_held", "first_round_id", "round_widths",
+                 "stride_rounds")
     MARKS = ("enqueued", "collected", "first_dispatch", "last_dispatch",
              "on_host", "resolved")
 
@@ -149,6 +236,7 @@ class BatchMarks:
         self.rounds_held = 0
         self.first_round_id: Optional[int] = None
         self.round_widths: List[int] = []
+        self.stride_rounds: Optional[int] = None
 
     def round(self, dispatch: span, round_id: Optional[int], width: int,
               last: bool) -> None:
@@ -164,21 +252,27 @@ class BatchMarks:
 
 class StreamMarks:
     """A decoupled generation. ``cache_ready``: its cache allocated;
-    ``prefill_done``: the prompt's steps dispatched; then per token three
-    host intervals: ``dispatch`` (the step call to its return: a wait for
-    the allocator is in here), ``readback`` (the logits to the host and the
-    argmax) and ``yielded`` (suspended at ``yield``: the core builds the
-    response and the frontend writes it)."""
+    ``prefill_done``: the prompt's steps dispatched; then per token the
+    host intervals ``dispatch`` (the step call to its return: a wait for
+    the allocator is in here), ``readback`` (the wait for the device, the
+    logits or the choices to the host and the argmax), ``handoff`` (on
+    rounds: from the end of the worker's read-back to the stream's own
+    thread holding the token: a queue, the interpreter) and ``yielded``
+    (suspended at ``yield``: the core builds the response and the frontend
+    writes it). ``first_round_id``: on rounds, the id of the dispatch that
+    gave the first token."""
 
     __slots__ = ("cache_ready", "prefill_done", "dispatch", "readback",
-                 "yielded")
+                 "handoff", "yielded", "first_round_id")
     MARKS = ("cache_ready", "prefill_done")
 
     def __init__(self):
         self.cache_ready = self.prefill_done = None
         self.dispatch = Interval()
         self.readback = Interval()
+        self.handoff = Interval()
         self.yielded = Interval()
+        self.first_round_id: Optional[int] = None
 
 
 class Timeline:
@@ -255,7 +349,7 @@ class Timeline:
 
     def counts(self) -> Dict[str, Any]:
         """What was counted on the way: a batched request's rounds, a
-        stream's three intervals, the responses, the compile time."""
+        stream's intervals, the responses, the compile time."""
         out: Dict[str, Any] = {"responses": self.responses,
                                "compiled_ns": self.compiled_ns}
         if self.batch is not None:
@@ -263,12 +357,36 @@ class Timeline:
             out.update(rounds_own=b.rounds_own, rounds_waited=b.rounds_waited,
                        rounds_held=b.rounds_held,
                        first_round_id=b.first_round_id,
-                       round_widths=list(b.round_widths))
+                       round_widths=list(b.round_widths),
+                       stride_rounds=b.stride_rounds)
         if self.stream is not None:
             s = self.stream
             out.update(dispatch=s.dispatch.as_dict(),
                        readback=s.readback.as_dict(),
-                       yielded=s.yielded.as_dict())
+                       handoff=s.handoff.as_dict(),
+                       yielded=s.yielded.as_dict(),
+                       first_round_id=s.first_round_id)
+        return out
+
+    def readings(self) -> List[Tuple[str, int, int]]:
+        """What the request read of the rounds that carried it, for the
+        registry's per-request series (a pair a name,
+        ``client_tpu_server_<name>_<unit>`` and ``_count``): ``(name, count,
+        sum)``, each from marks that are set anyway. A batched request: the
+        rounds between two tokens of its sequence, and ``resolved`` to
+        ``done`` (its future set to its response built: the caller's wake).
+        A stream: ``recv`` to ``first_response``, and its tokens'
+        hand-offs."""
+        out: List[Tuple[str, int, int]] = []
+        b, s = self.batch, self.stream
+        if b is not None and b.stride_rounds is not None:
+            out.append(("sequence_stride", 1, b.stride_rounds))
+        if b is not None and b.resolved is not None:
+            out.append(("answer_wake", 1, self.done - b.resolved))
+        if self.first_response is not None:
+            out.append(("first_response", 1, self.first_response - self.recv))
+        if s is not None and s.handoff.count:
+            out.append(("token_handoff", s.handoff.count, s.handoff.ns))
         return out
 
 

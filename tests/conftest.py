@@ -113,6 +113,54 @@ class GatedStep:
             self._gate.release()
 
 
+def spans_into_phases(module, monkeypatch):
+    """In the place of a round engine's ``span`` (``module.span``), one that
+    also notes ``(name, start_ns, end_ns, whether it feeds a Phases)`` of
+    every span, in the order they ended: returns the list."""
+    noted = []
+
+    class noting_span(module.span):
+        __slots__ = ("_name",)
+
+        def __init__(self, name, into=None):
+            super().__init__(name, into)
+            self._name = name
+
+        def __exit__(self, *exc):
+            super().__exit__(*exc)
+            noted.append((self._name, self.start_ns, self.end_ns,
+                          self._into is not None))
+
+    monkeypatch.setattr(module, "span", noting_span)
+    return noted
+
+
+def check_the_phases_tile_the_workers_time(phases, noted):
+    """The spans of one worker that feed its phases (as ``spans_into_phases``
+    noted them), one after another and none inside another;
+    each phase's row is the sum of its spans; ``between`` is what lies
+    between them; and the rows add up to the time from the start of the
+    first to the end of the last: no tolerance, the same clock readings."""
+    from client_tpu.server.timeline import PHASE_OF, PHASES
+
+    noted = [(name, start, end) for name, start, end, feeds in noted if feeds]
+    assert noted
+    rows = phases.rows()
+    assert set(rows) <= set(PHASES), rows
+    for (_, _, end), (_, start, _) in zip(noted, noted[1:]):
+        assert end <= start
+    by_phase = {}
+    for name, start, end in noted:
+        count, ns = by_phase.get(PHASE_OF[name], (0, 0))
+        by_phase[PHASE_OF[name]] = (count + 1, ns + end - start)
+    between = rows.pop("between")
+    assert rows == by_phase
+    assert between == (len(noted) - 1, sum(
+        start - end for (_, _, end), (_, start, _) in zip(noted, noted[1:])))
+    assert sum(ns for _, ns in rows.values()) + between[1] == (
+        noted[-1][2] - noted[0][1])
+
+
 # Two expected failures, both of cases that exist only since PR 34, stated
 # where every run of these tests sees them, in the words of
 # ``tests/benchmark/conftest.py`` (PR 32's, which is under ``BENCHMARK.json``'s

@@ -21,7 +21,11 @@ from client_tpu.models.decoder_batched import (
     BatchedDecoderModel,
 )
 from client_tpu.server import ServerCore
-from tests.conftest import GatedStep
+from tests.conftest import (
+    GatedStep,
+    check_the_phases_tile_the_workers_time,
+    spans_into_phases,
+)
 
 
 def _drive(model, seq, prompt, n=6, jitter=None):
@@ -405,9 +409,9 @@ def test_one_host_transfer_a_round_whatever_the_width(monkeypatch):
     spans = []
 
     class counting_span(decoder_batched.span):
-        def __init__(self, name):
+        def __init__(self, name, into=None):
             spans.append(name)
-            super().__init__(name)
+            super().__init__(name, into)
 
     monkeypatch.setattr(decoder_batched, "span", counting_span)
     model = BatchedDecoderModel(seed=0, slots=4)
@@ -427,8 +431,11 @@ def test_one_host_transfer_a_round_whatever_the_width(monkeypatch):
         model.unload()
     assert not any(isinstance(a, Exception) for a in answers.values()), answers
     assert model.batch_histogram == {1: 2, 4: 1}
-    assert spans.count(decoder_batched.SPAN_BATCH_READBACK) == 3
     assert spans.count(decoder_batched.SPAN_ROUND_DISPATCH) == 3
+    # every round is waited for; the first answers nobody (the prompt is
+    # midway) and has nothing to transfer
+    assert spans.count(decoder_batched.SPAN_BATCH_DEVICE_WAIT) == 3
+    assert spans.count(decoder_batched.SPAN_BATCH_READBACK) == 2
     # the three answered by the four-wide round hold rows of one array: the
     # round's logits came to the host once, and each got a view
     rows = [answers[f"single-{seq}"][0]["outputs"][0]["array"]
@@ -537,3 +544,70 @@ def test_a_turn_that_fails_outside_a_dispatch_strands_nobody():
     finally:
         model.unload()
     assert model.live_sequences() == 0
+
+
+def test_each_phase_is_counted_with_its_rounds_and_the_phases_add_up(monkeypatch):
+    """One user, one request at a time: a prompt of three tokens and four
+    continuation requests are seven rounds, five of which answer somebody."""
+    noted = spans_into_phases(decoder_batched, monkeypatch)
+    model = BatchedDecoderModel(seed=0, slots=2)
+    try:
+        _drive(model, 1, [1, 2, 3], n=5)
+    finally:
+        model.unload()  # the worker's last wait has ended: every span is in
+    counts = {phase: count for phase, (count, _) in model.phases.rows().items()}
+    turns = [name for name, _, _, _ in noted].count(decoder_batched.SPAN_BATCH_TURN)
+    assert sum(model.batch_histogram.values()) == 7
+    assert counts["prepare"] == counts["dispatch"] == counts["device_wait"] == 7
+    assert counts["readback"] == 5  # a prompt midway has nothing to transfer
+    assert counts["record"] == 7 + 5  # after a dispatch, and after a transfer
+    assert counts["admit"] == 5  # a request a turn, in five turns
+    # once a turn, whatever the turn found to do
+    assert counts["collect"] == counts["hand_out"] == turns
+    # a request cannot come before the answer before it: the worker waited
+    # for each, and for the sentinel
+    assert counts["wait_work"] == 5 + 1
+    check_the_phases_tile_the_workers_time(model.phases, noted)
+    # every phase lies inside a turn, the wait for work too
+    turn_spans = [(start, end) for name, start, end, _ in noted
+                  if name == decoder_batched.SPAN_BATCH_TURN]
+    for name, start, end, feeds in noted:
+        if feeds:
+            assert any(s <= start and end <= e for s, e in turn_spans), name
+
+
+def test_a_sequence_that_sits_a_round_out_has_a_stride_of_two():
+    model = BatchedDecoderModel(seed=0, slots=2)
+    core, gate = _traced(model), GatedStep(model)
+    callers = _Callers(core)
+    try:
+        callers.send([5], "a-start", sequence_id=1, sequence_start=True)
+        gate.at(0)  # round 0: sequence 1 alone
+        callers.send([1, 2], "b-prompt", sequence_id=2, sequence_start=True)
+        gate.queued(1)
+        gate.let()
+        gate.at(1)  # round 1: the prompt's first token; sequence 1 sits out
+        callers.send([7], "a-next", sequence_id=1)
+        gate.queued(1)
+        gate.let()
+        gate.at(2)  # round 2: the prompt's second token and sequence 1
+        gate.let()
+        callers.join()
+        callers.send([8], "a-last", sequence_id=1, sequence_end=True)
+        gate.at(3)  # round 3: sequence 1 again, the round after
+        gate.let()
+        callers.join()
+    finally:
+        gate.let(100)
+        model.unload()
+    records = _records(core)
+    assert [records[r]["first_round_id"]
+            for r in ("a-start", "b-prompt", "a-next", "a-last")] == [0, 1, 2, 3]
+    strides = {r: records[r]["counts"]["stride_rounds"] for r in records}
+    # a sequence's first request has no token before it
+    assert strides == {"a-start": None, "b-prompt": None, "a-next": 2, "a-last": 1}
+    series = {name: metric["series"][0]["value"] for name, metric in
+              core.metrics_registry().snapshot().items()
+              if name.startswith("client_tpu_server_sequence_stride")}
+    assert series == {"client_tpu_server_sequence_stride_rounds": 3,
+                      "client_tpu_server_sequence_stride_count": 2}

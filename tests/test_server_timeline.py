@@ -259,14 +259,21 @@ def test_batch_stats_rounds_equal_the_batch_histogram(traced_core):
 
 
 @pytest.mark.parametrize("path, prompt, max_tokens, chunk, want", [
-    ("rounds", [1, 2, 3], 6, 1, {"dispatch": 5, "readback": 6, "yielded": 6}),
-    ("rounds", [4], 1, 1, {"dispatch": 0, "readback": 1, "yielded": 1}),
+    # on rounds every token is handed from the worker to the stream's thread
+    ("rounds", [1, 2, 3], 6, 1,
+     {"dispatch": 5, "readback": 6, "handoff": 6, "yielded": 6}),
+    ("rounds", [4], 1, 1,
+     {"dispatch": 0, "readback": 1, "handoff": 1, "yielded": 1}),
     # a burst's tokens rode a round each
-    ("rounds", [1, 2], 7, 3, {"dispatch": 6, "readback": 7, "yielded": 7}),
-    ("alone", [1, 2, 3], 6, 1, {"dispatch": 5, "readback": 6, "yielded": 6}),
-    ("alone", [4], 1, 1, {"dispatch": 0, "readback": 1, "yielded": 1}),
+    ("rounds", [1, 2], 7, 3,
+     {"dispatch": 6, "readback": 7, "handoff": 7, "yielded": 7}),
+    ("alone", [1, 2, 3], 6, 1,
+     {"dispatch": 5, "readback": 6, "handoff": 0, "yielded": 6}),
+    ("alone", [4], 1, 1,
+     {"dispatch": 0, "readback": 1, "handoff": 0, "yielded": 1}),
     # ``decode_k``: a dispatch and a read-back a burst
-    ("alone", [1, 2], 7, 3, {"dispatch": 2, "readback": 3, "yielded": 7}),
+    ("alone", [1, 2], 7, 3,
+     {"dispatch": 2, "readback": 3, "handoff": 0, "yielded": 7}),
 ], ids=["a-token-a-round", "one-token-a-round", "chunked-on-rounds",
         "a-token-a-dispatch", "one-token", "chunked"])
 def test_a_streams_interval_counts_follow_its_tokens(
@@ -284,14 +291,168 @@ def test_a_streams_interval_counts_follow_its_tokens(
     finally:
         model.unload()
     assert len(responses) == max_tokens
-    counts = core.recent_traces()[-1]["counts"]
+    record = core.recent_traces()[-1]
+    counts = record["counts"]
     assert counts["responses"] == max_tokens
+    # a stream on rounds shares an identifier with the round that gave its
+    # first token (the model's first stream: a prompt's rounds come before)
+    first_round = len(prompt) - 1 if path == "rounds" else None
+    assert record["first_round_id"] == counts["first_round_id"] == first_round
     for name, count in want.items():
         interval = counts[name]
         assert interval["count"] == count, name
-        if count:
-            assert 0 <= interval["longest_at"] < max_tokens
-            assert 0 < interval["longest_ns"] <= interval["ns"]
+        assert (interval["ns"] > 0) == (count > 0), name
+
+
+def test_phases_add_up_and_tile_the_time_between_them():
+    phases = timeline.Phases()
+    assert phases.rows() == {}
+    # (phase, start, end): the first has nothing before it; 3 ns lie between
+    # the first and the second, none between the second and the third
+    for phase, start, end in (("dispatch", 100, 105), ("readback", 108, 115),
+                              ("dispatch", 115, 126)):
+        phases.add(phase, start, end)
+    assert phases.rows() == {"dispatch": (2, 16), "readback": (1, 7),
+                             "between": (2, 3)}
+    # the rows add up to the time from the first phase to the end of the last
+    assert sum(ns for _, ns in phases.rows().values()) == 126 - 100
+    rows = phases.rows()  # a copy: the worker's next turn does not move it
+    phases.add("dispatch", 130, 131)
+    assert rows["dispatch"] == (2, 16) and phases.rows()["dispatch"] == (3, 17)
+    assert phases.rows()["between"] == (3, 7)
+
+
+@pytest.mark.parametrize("name, phase", [
+    (timeline.SPAN_ROUND_PREPARE, "prepare"),
+    (timeline.SPAN_ROUND_DISPATCH, "dispatch"),
+    (timeline.SPAN_BATCH_WAIT_WORK, "wait_work"),
+    (timeline.SPAN_BATCH_READBACK, "readback"),
+    (timeline.SPAN_STREAM_ADMIT, "admit"),
+    (timeline.SPAN_PREFILL_CHUNK, "prefill_chunk"),
+    (timeline.SPAN_HAND_OUT, "hand_out"),
+    (timeline.SPAN_DEVICE_WAIT, "device_wait"),
+])
+def test_a_span_into_phases_feeds_it_from_its_own_edges(name, phase):
+    """The annotation, the marks' edges and the counter are one pair of clock
+    readings: what lands in the phases is ``end_ns - start_ns``, exactly, and
+    what lies between two spans is the second's start less the first's end."""
+    assert phase in timeline.PHASES and name in timeline.SPAN_NAMES
+    phases = timeline.Phases()
+    with timeline.span(name, into=phases) as first:
+        pass
+    assert phases.rows() == {phase: (1, first.end_ns - first.start_ns)}
+    with timeline.span(name, into=phases) as second:
+        sum(range(100))
+    assert first.start_ns <= first.end_ns <= second.start_ns <= second.end_ns
+    assert phases.rows() == {phase: (2, first.ns + second.ns),
+                             "between": (1, second.start_ns - first.end_ns)}
+
+
+def test_a_span_without_into_is_the_two_clock_readings_alone():
+    phases = timeline.Phases()
+    with timeline.span(timeline.SPAN_ROUND_DISPATCH) as s:
+        pass
+    assert s.ns == s.end_ns - s.start_ns >= 0
+    assert phases.rows() == {}
+
+
+def test_the_vocabulary_is_what_the_spans_are_keyed_to():
+    """One dict keys each span that feeds a phase to it: ``PHASES`` is its
+    values and ``between``, each engine's names stand for the phases that
+    engine has, and a span of any other name cannot feed a ``Phases``."""
+    assert set(timeline.PHASE_OF) <= set(timeline.SPAN_NAMES)
+    assert timeline.PHASES == tuple(dict.fromkeys(timeline.PHASE_OF.values())) + (
+        "between",)
+    by_engine = {}
+    for name, phase in timeline.PHASE_OF.items():
+        engine = name.split(".")[1]
+        assert phase not in by_engine.setdefault(engine, set()), name
+        by_engine[engine].add(phase)
+    assert by_engine["batcher"] == set(timeline.PHASES) - {"prefill_chunk", "between"}
+    assert by_engine["generate"] == set(timeline.PHASES) - {"collect", "between"}
+    phases = timeline.Phases()
+    for name in set(timeline.SPAN_NAMES) - set(timeline.PHASE_OF):
+        with pytest.raises(KeyError):
+            timeline.span(name, into=phases)
+        with timeline.span(name):  # without ``into`` it is a span as before
+            pass
+    with pytest.raises(KeyError):
+        timeline.span("client_tpu.generate.round_x", into=phases)
+    assert phases.rows() == {}
+
+
+def _series(core, model):
+    """The registry's series labelled with ``model``, as the benchmark's
+    serving process reads them: ``name{other labels}`` -> value."""
+    out = {}
+    for name, metric in core.metrics_registry().snapshot().items():
+        for series in metric["series"]:
+            labels = dict(series["labels"])
+            if labels.pop("model", None) == model and "value" in series:
+                out[name + "".join(f"{{{k}={v}}}" for k, v in sorted(
+                    labels.items()))] = series["value"]
+    return out
+
+
+def test_the_registry_has_the_phases_and_the_readings_by_model(traced_core):
+    core, batched, matmul = traced_core
+    _drive_all(core, matmul)
+    _generate(core, [1, 2], 3)  # a second stream: the worker's wait ended
+    first = {m: _series(core, m) for m in
+             ("decoder_lm_batched", "tiny_lm_generate", "simple", matmul.name)}
+    phase = lambda kind, name: f"client_tpu_server_round_phase_{kind}{{phase={name}}}"
+    engines = {
+        "decoder_lm_batched": (
+            ("wait_work", "collect", "admit", "prepare", "dispatch", "record",
+             "hand_out", "device_wait", "readback", "between"),
+            ("sequence_stride_rounds", "answer_wake_ns")),
+        "tiny_lm_generate": (
+            ("wait_work", "admit", "prepare", "dispatch", "record", "hand_out",
+             "device_wait", "readback", "between"),
+            ("first_response_ns", "token_handoff_ns")),
+    }
+    for model, (phases, readings) in engines.items():
+        series = first[model]
+        got = {key[key.index("=") + 1:-1] for key in series
+               if key.startswith("client_tpu_server_round_phase_ns")}
+        assert got == set(phases), (model, got)
+        for name in phases:
+            assert series[phase("count", name)] > 0 and series[phase("ns", name)] > 0
+        for name in readings:
+            count = name.rsplit("_", 1)[0] + "_count"
+            assert series["client_tpu_server_" + name] > 0
+            assert series["client_tpu_server_" + count] > 0
+    # 12 continuation requests of three sequences, 15 answers; 2 streams of
+    # 5 and 3 tokens
+    batcher, streams = first["decoder_lm_batched"], first["tiny_lm_generate"]
+    assert batcher["client_tpu_server_sequence_stride_count"] == 12
+    assert batcher["client_tpu_server_sequence_stride_rounds"] >= 12
+    assert batcher["client_tpu_server_answer_wake_count"] == 15
+    assert streams["client_tpu_server_first_response_count"] == 2
+    assert streams["client_tpu_server_token_handoff_count"] == 8
+    assert batcher[phase("count", "dispatch")] == sum(
+        batched.batch_histogram.values())
+    # a model without a round worker has none of them, and a stream no
+    # batcher's reading
+    new = ("round_phase", "sequence_stride", "answer_wake", "first_response",
+           "token_handoff")
+    for model in ("simple", matmul.name):
+        assert not [k for k in first[model] if any(n in k for n in new)]
+    assert "client_tpu_server_answer_wake_ns" not in streams
+    assert "client_tpu_server_token_handoff_ns" not in batcher
+    # cumulative: more traffic moves every series up and none down
+    _session(core, 9, [1, 2, 3], 2)
+    _generate(core, [3], 2)
+    _generate(core, [3], 1)
+    for model in engines:
+        second = _series(core, model)
+        moved = {k: second[k] - v for k, v in first[model].items()
+                 if k.startswith(("client_tpu_server_round_phase",
+                                  "client_tpu_server_sequence_stride",
+                                  "client_tpu_server_answer_wake",
+                                  "client_tpu_server_first_response",
+                                  "client_tpu_server_token_handoff"))}
+        assert moved and all(d > 0 for d in moved.values()), (model, moved)
 
 
 def test_with_trace_level_off_no_record_is_built():
@@ -356,6 +517,9 @@ def test_a_profiler_session_holds_every_span_name(tmp_path, traced_core):
     jax.profiler.start_trace(str(tmp_path))
     try:
         _drive_all(core, matmul)
+        # a second stream inside the session: the rounds' worker has waited
+        # for it from the first one's end, a ``wait_work`` with both edges here
+        _generate(core, [1], 1)
         routed.prefill(routed._fresh_cache(), [1, 2, 3], 0)
     finally:
         jax.profiler.stop_trace()

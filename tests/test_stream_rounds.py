@@ -15,6 +15,10 @@ from client_tpu.models.decoder_tp import TPDecoderModel
 from client_tpu.models.generate import TinyGenerateModel
 from client_tpu.models.stream_rounds import ROUNDS_IN_FLIGHT
 from client_tpu.server import ServerCore, timeline
+from tests.conftest import (
+    check_the_phases_tile_the_workers_time,
+    spans_into_phases,
+)
 
 LongDecoder = type("LongDecoder", (TinyDecoderModel,), {
     "D_MODEL": 64, "HEADS": 2, "LAYERS": 2, "MAX_LEN": 1024})
@@ -531,3 +535,66 @@ def test_the_round_is_traced_as_the_step_and_carries_its_scopes():
     for scope in ("embed", "attn_qkv", "cache_update", "attention", "attn_proj",
                   "mlp", "unembed", "greedy_argmax"):
         assert re.search(rf'["/]{scope}["/]', text), scope
+
+
+# -- the worker's turns by phase ----------------------------------------------
+
+
+def test_each_phase_is_counted_with_its_rounds_and_the_phases_add_up(
+        served, monkeypatch):
+    """One stream, a prompt of three and four tokens: six rounds, each read
+    back and handed out; then a second stream, whose arrival ends the
+    worker's wait."""
+    from client_tpu.models import stream_rounds
+
+    noted = spans_into_phases(stream_rounds, monkeypatch)
+    model = served(2)
+    assert _tokens(model, [1, 2, 3], 4) and _tokens(model, [5], 2)
+    model.unload()  # the worker's last wait has ended: every span is in
+    counts = {phase: count for phase, (count, _) in model.phases.rows().items()}
+    assert set(counts) == {"wait_work", "admit", "prepare", "dispatch", "record",
+                           "device_wait", "readback", "hand_out", "between"}
+    assert sum(model.batch_histogram.values()) == 6 + 2
+    for phase in ("prepare", "dispatch", "record", "device_wait", "readback",
+                  "hand_out"):
+        assert counts[phase] == 8, phase
+    # once a turn
+    turns = [name for name, _, _, _ in noted].count(timeline.SPAN_TURN)
+    assert counts["admit"] == turns
+    # the worker waited for each stream and for the sentinel
+    assert counts["wait_work"] == 3
+    check_the_phases_tile_the_workers_time(model.phases, noted)
+
+
+def test_every_token_has_one_hand_off(served):
+    model = served(4)
+    core = ServerCore([model])
+    core.trace_settings.update(trace_level=["TIMESTAMPS"], trace_rate="1")
+
+    def stream(prompt, max_tokens, **parameters):
+        return len(list(core.infer_stream("tiny_lm_generate", "", {
+            "parameters": parameters, "inputs": [
+                {"name": name, "datatype": "INT32", "shape": list(array.shape),
+                 "array": array}
+                for name, array in _inputs(prompt, max_tokens).items()]})))
+
+    assert stream([1, 2, 3], 5) == 5 and stream([4], 7, chunk=3) == 7
+    first, burst = core.recent_traces()
+    for record, tokens in ((first, 5), (burst, 7)):
+        counts = record["counts"]
+        assert counts["handoff"]["count"] == counts["yielded"]["count"] == tokens
+        assert counts["handoff"]["ns"] > 0
+    # the rounds that gave the first tokens: the third, and the sixth after
+    # it (a stream leaves with the dispatch of its last round)
+    assert [first["first_round_id"], burst["first_round_id"]] == [2, 7]
+    series = {name: metric["series"][0]["value"] for name, metric in
+              core.metrics_registry().snapshot().items()
+              if name.startswith(("client_tpu_server_token_handoff",
+                                  "client_tpu_server_first_response"))}
+    assert series["client_tpu_server_token_handoff_count"] == 12
+    assert series["client_tpu_server_token_handoff_ns"] == (
+        first["counts"]["handoff"]["ns"] + burst["counts"]["handoff"]["ns"])
+    stamps = [r["timestamps"] for r in (first, burst)]
+    assert series["client_tpu_server_first_response_count"] == 2
+    assert series["client_tpu_server_first_response_ns"] == sum(
+        s["first_response"] - s["recv"] for s in stamps)

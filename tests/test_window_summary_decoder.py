@@ -397,6 +397,32 @@ def test_served_through_the_core_with_its_counts_in_the_registry(exact):
     assert steps == {"16": 5}  # rounds alone: a chunk is not a step
 
 
+def test_a_prompt_s_chunks_are_a_phase_of_the_worker_s_turns(served, monkeypatch):
+    """Ten bytes are three chunks of four, the last of which gives the first
+    byte; two rounds give the other two. The turns are cut as the GPT-2
+    decoder's are, with ``prefill_chunk`` between ``admit`` and the round."""
+    from client_tpu.models import stream_rounds
+    from tests.conftest import (
+        check_the_phases_tile_the_workers_time,
+        spans_into_phases,
+    )
+
+    noted = spans_into_phases(stream_rounds, monkeypatch)
+    model = served(2)
+    assert len(_tokens(model, [int(t) for t in TOKENS[:10]], 3)) == 3
+    model.unload()
+    counts = {phase: count for phase, (count, _) in model.phases.rows().items()}
+    assert counts["prefill_chunk"] == 3 and counts["dispatch"] == 2
+    # a chunk and a round are each prepared and recorded
+    assert counts["prepare"] == counts["record"] == 3 + 2
+    # read back: the last chunk and the two rounds; the two chunks before it
+    # are waited for as well, nobody decoding beside them
+    assert counts["readback"] == counts["hand_out"] == 3
+    assert counts["device_wait"] == 3 + 2
+    assert counts["wait_work"] == 2  # the stream, and the sentinel
+    check_the_phases_tile_the_workers_time(model.phases, noted)
+
+
 def test_a_profiler_session_holds_a_span_a_slot_prefill_dispatch(tmp_path, served):
     import glob
 
