@@ -367,6 +367,63 @@ class TinyDecoderModel(Model):
                 return jnp.einsum(
                     "hm,hmd->hd", probs, v[:, :live].astype(jnp.float32))
 
+        @jax.custom_batching.custom_vmap
+        def read_slot(q, k, v, pos):
+            """``attention`` of one slot of the slot batcher at the top rung,
+            over the slot's whole cache."""
+            return attention(q, k, v, pos, live=M)
+
+        @read_slot.def_vmap
+        def read_table(slots, batched, q, k, v, pos):
+            """The slot batcher's ``vmap`` of the above, over stacked caches
+            [slots, H, M, Dh]. ``vmap``'s own rule makes one product a
+            stacked cache, and beside the rows' ``while`` the chip's
+            compiler then stages every such cache through fast memory and
+            back each round: 6.4 of a 12.3 ms round of sixteen at 1,024
+            positions (PERF.md section 6, PR 37). Here each product is a
+            ``while`` of two turns, half the positions of every slot a turn,
+            each a slice of the table fused into the product: the table is
+            read where it lies, once. The same float32 products, mask and
+            softmax: the products are at ``HIGHEST`` precision, since at the
+            default the chip rounds their float32 operands to bfloat16 (the
+            probabilities, and the query, which the parent's one product took
+            as the query's product left it, unrounded)."""
+            if not all(batched):
+                raise NotImplementedError("every operand is a slot's own")
+            with jax.named_scope("attention"):
+                half = M // 2
+
+                def rows(cache, n):
+                    return lax.dynamic_slice_in_dim(
+                        cache, n * half, half, axis=2).astype(jnp.float32)
+
+                def product(spec, x, y):
+                    return jnp.einsum(spec, x, y,
+                                      precision=lax.Precision.HIGHEST)
+
+                q32 = q.astype(jnp.float32)
+
+                def score(n, scores):
+                    return lax.dynamic_update_slice_in_dim(
+                        scores, product("shd,shmd->shm", q32, rows(k, n)),
+                        n * half, axis=2)
+
+                scores = lax.fori_loop(
+                    0, 2, score,
+                    jnp.zeros((slots, H, M), jnp.float32)) * (Dh ** -0.5)
+                mask = jnp.arange(M)[None, :] <= pos[:, None]
+                scores = jnp.where(mask[:, None, :], scores, -jnp.inf)
+                probs = jax.nn.softmax(scores, axis=-1)
+
+                def weigh(n, attn):
+                    return attn + product(
+                        "shm,shmd->shd",
+                        lax.dynamic_slice_in_dim(probs, n * half, half, axis=2),
+                        rows(v, n))
+
+                return lax.fori_loop(
+                    0, 2, weigh, jnp.zeros((slots, H, Dh), jnp.float32)), True
+
         def rest_of_layer(layer, x, attn):
             """The layer after its attention ``attn`` [H, Dh]."""
             with jax.named_scope("attn_proj"):
@@ -388,7 +445,12 @@ class TinyDecoderModel(Model):
                 held, rows = (cache["k"], cache["v"]), (k_new, v_new)
                 k, v = (write_rows(held, rows, pos) if active is None
                         else write_slot_rows(held, rows, pos, active))
-            x = rest_of_layer(layer, x, attention(q, k, v, pos, live=live))
+            if (active is None or live < M
+                    or self._attention_impl == "pallas"):
+                attn = attention(q, k, v, pos, live=live)
+            else:  # the slot batcher's top rung
+                attn = read_slot(q, k, v, pos)
+            x = rest_of_layer(layer, x, attn)
             return x, {"k": k, "v": v}
 
         # The slot batcher's layer is a jitted call: every layer has the same

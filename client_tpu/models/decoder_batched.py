@@ -43,7 +43,9 @@ property the single-sequence decoder has. Once a rung, that is: a round's
 attention reads the prefix of the caches that covers the furthest of the
 round's members (decoder.py's ladder; a slot that rides along inactive may
 hold any older position, its row is not read), and the worker compiles
-every rung's program before it takes its first request.
+every rung's program before it takes its first request. At the top rung the
+read is the whole table, which decoder.py's ``read_table`` takes in two turns
+of half its positions, where it lies.
 
 Weights come from a composed TinyDecoderModel (same seed ⇒ greedy tokens
 match the unbatched fixture token-for-token — pinned by the tests).

@@ -267,6 +267,112 @@ def test_the_batched_rows_are_the_single_slot_steps_rows(built):
                 assert got.tobytes() == want.tobytes()
 
 
+# -- the batcher's top rung reads its caches where they lie (PR 37) ----------
+
+# narrow widths and 1,024 positions: two rungs, 256 and the top one
+LongDecoder = type("LongDecoder", (TinyDecoderModel,), {
+    "D_MODEL": 64, "HEADS": 2, "LAYERS": 2, "MAX_LEN": 1024})
+TOP = LongDecoder.MAX_LEN
+TOP_SLOTS = 8
+# members across the rung, some slots out; the second round one on
+TOP_POS = [0, 255, 256, 511, 700, 1000, 1022, 400]
+TOP_ACTIVE = [True, True, False, True, True, False, True, True]
+
+
+def _parent_batched_step(decoder):
+    """The reference: the slot batcher's step as PR 36 left it, ``vmap`` of
+    the single-slot step through a jitted layer whose attention reads the
+    whole cache in one product (``decoder.py:attention`` at the top rung)
+    after the row at ``pos`` is written where ``active``."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    D, H = decoder.D_MODEL, decoder.HEADS
+    Dh, f32, bf16 = D // H, jnp.float32, jnp.bfloat16
+
+    def norm(x):
+        x32 = x.astype(f32)
+        mu = jnp.mean(x32, axis=-1, keepdims=True)
+        var = jnp.var(x32, axis=-1, keepdims=True)
+        return ((x32 - mu) * lax.rsqrt(var + 1e-5)).astype(x.dtype)
+
+    def attention(q, k, v, pos):
+        scores = jnp.einsum("hd,hmd->hm", q.astype(f32),
+                            k.astype(f32)) * (Dh ** -0.5)
+        scores = jnp.where((jnp.arange(TOP) <= pos)[None, :], scores, -jnp.inf)
+        return jnp.einsum("hm,hmd->hd", jax.nn.softmax(scores, axis=-1),
+                          v.astype(f32))
+
+    @jax.jit
+    def layer_of(layer, cache, x, pos, active):
+        q, k_new, v_new = jnp.split(norm(x) @ layer["qkv"], 3)
+        k, v = (jnp.where(active, lax.dynamic_update_slice(
+                    cache[half], row.reshape(H, 1, Dh), (0, pos, 0)),
+                    cache[half])
+                for half, row in (("k", k_new), ("v", v_new)))
+        attn = attention(q.reshape(H, Dh), k, v, pos)
+        x = x + (attn.reshape(D).astype(bf16) @ layer["proj"])
+        x = x + (jax.nn.gelu(norm(x) @ layer["mlp_in"]) @ layer["mlp_out"])
+        return x, {"k": k, "v": v}
+
+    def step(params, caches, token, pos, active):
+        x = params["embed"][token] + params["pos"][pos]
+        new = []
+        for layer, cache in zip(params["layers"], caches):
+            x, cache = layer_of(layer, cache, x, pos, active)
+            new.append(cache)
+        return (norm(x) @ params["unembed"]).astype(f32), new
+
+    return jax.jit(jax.vmap(step, in_axes=(None, 0, 0, 0, 0)))
+
+
+@pytest.fixture(scope="module")
+def two_rungs():
+    model = BatchedDecoderModel(seed=0, slots=TOP_SLOTS)
+    model._decoder = LongDecoder(seed=0)  # composed before the batcher builds
+    model._ensure_built()
+    yield model
+    model.unload()
+
+
+def test_the_top_rung_reads_as_the_parents_form_did(two_rungs):
+    """Two rounds at the top rung, members at positions across it and some
+    slots out: logits and caches are the parent's form's, to what float32
+    sums in another order leave, and a slot that is out of both rounds gets
+    its caches back bit for bit."""
+    import jax
+    import jax.numpy as jnp
+
+    model = two_rungs
+    decoder = model._decoder
+    assert decoder._rungs == (256, TOP)
+    before, caches = _filled_caches(model, seed=9)
+    want_caches = jax.tree_util.tree_map(jnp.asarray, before)
+    reference = _parent_batched_step(decoder)
+    pos, active = TOP_POS, TOP_ACTIVE
+    for n in range(2):
+        tokens = jnp.arange(TOP_SLOTS, dtype=jnp.int32) + 11 + TOP_SLOTS * n
+        args = (tokens, jnp.asarray(pos, jnp.int32), jnp.asarray(active))
+        logits, caches = model._batched_step(
+            decoder._params, caches, *args, live=TOP)
+        want, want_caches = reference(decoder._params, want_caches, *args)
+        members = np.flatnonzero(active)
+        np.testing.assert_allclose(np.asarray(logits)[members],
+                                   np.asarray(want)[members], atol=1e-5)
+        pos = _next(pos, active)
+    for layer, (got, wanted) in enumerate(zip(caches, want_caches)):
+        for half in ("k", "v"):
+            g = np.asarray(got[half], np.float32)
+            w = np.asarray(wanted[half], np.float32)
+            np.testing.assert_allclose(g, w, atol=2e-2)
+            if layer == 0:  # its rows come before any attention
+                assert g.tobytes() == w.tobytes()
+            for slot in np.flatnonzero(~np.asarray(TOP_ACTIVE)):
+                assert (np.asarray(got[half][slot]).tobytes()
+                        == before[layer][half][slot].tobytes()), (layer, slot)
+
+
 # -- a step that fails after it took its caches ------------------------------
 
 

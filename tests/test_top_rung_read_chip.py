@@ -1,0 +1,65 @@
+"""CI tier for tools/top_rung_read_chip.py: the micro-benchmark that decided
+the form of the slot batcher's read at its top rung must run end to end on the
+CPU backend (a table of four slots), so that a chip call never dies on its
+argument handling, and its own check of the forms against the parent's must be
+able to fail."""
+
+import json
+import sys
+from pathlib import Path
+
+import pytest
+
+REPO = Path(__file__).resolve().parent.parent
+sys.path.insert(0, str(REPO / "tools"))
+
+import top_rung_read_chip  # noqa: E402
+
+
+def test_a_small_run_times_every_form():
+    out = top_rung_read_chip.run(small=True, repeats=2)
+    assert out["platform"] == "cpu"
+    assert out["agreement"]["ok"], out["agreement"]
+    rows = out["forms"]
+    assert [r["form"] for r in rows] == [
+        "rows_and_weights", *top_rung_read_chip.FORMS]
+    assert all(r["ms_a_dispatch"] > 0 for r in rows), rows
+    assert all(r["read_gb_s"] >= 0 for r in rows[1:]), rows
+    assert "read_gb_s" not in rows[0]
+
+
+def test_main_prints_what_it_writes_and_exits_0(tmp_path, capsys):
+    path = tmp_path / "top_rung_read.json"
+    rc = top_rung_read_chip.main(
+        ["--small", "--repeats", "1", "--json-out", str(path)])
+    assert rc == 0
+    assert json.loads(path.read_text()) == json.loads(capsys.readouterr().out)
+
+
+@pytest.mark.parametrize("argv", [["--repeats", "many"], ["--large"]])
+def test_main_refuses_what_it_does_not_know(argv, capsys):
+    with pytest.raises(SystemExit) as refused:
+        top_rung_read_chip.main(argv)
+    assert refused.value.code == 2
+    capsys.readouterr()
+
+
+@pytest.mark.parametrize("broken", top_rung_read_chip.FORMS[1:])
+def test_a_form_that_reads_past_its_position_fails_the_run(
+        monkeypatch, capsys, broken):
+    """The tool's exit code is its agreement check: a form whose attention
+    is not the parent's (here, one that lets every slot see one position
+    more) is no candidate."""
+    forms = top_rung_read_chip.forms
+
+    def with_a_fault(jax, jnp, lax, piece):
+        made = forms(jax, jnp, lax, piece)
+        sound = made[broken]
+        made[broken] = lambda q, k, v, pos: sound(q, k, v, pos + 1)
+        return made
+
+    monkeypatch.setattr(top_rung_read_chip, "forms", with_a_fault)
+    assert top_rung_read_chip.main(["--small", "--repeats", "1"]) == 1
+    (case,) = json.loads(capsys.readouterr().out)["agreement"]["cases"]
+    assert [form for form in top_rung_read_chip.FORMS[1:]
+            if not case[form]["agrees"]] == [broken]

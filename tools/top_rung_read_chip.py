@@ -1,0 +1,315 @@
+"""The slot batcher's attention at its top rung on the real chip, a round's
+layers at a time.
+
+At the top rung (``live`` equal to the cache's length) every member of a round
+of the slot batcher attends to its whole cache. Compiled for a v5e, the
+parent's form there, ``vmap`` of ``models/decoder.py:attention``, has every
+stacked cache staged through fast memory and back each round, beside the
+``while`` that writes the round's rows (PERF.md section 6, PR 37). This tool
+times the forms the read could take, each alone, so that the choice among them
+rests on a number that is in the repository and not in prose:
+
+- ``parent``: ``vmap`` of ``attention``: one product a stacked cache, read
+  whole;
+- ``pieces``: the same with the positions cut into static pieces of a
+  shortest rung, each piece read as the shortest rung reads its prefix, the
+  scores put together before one softmax;
+- ``slot_turns``: a ``while`` of turns of ``SLOTS_A_TURN`` slots, each a
+  ``dynamic_slice`` of the table (the stream round's ``turn``);
+- ``table``: one product over the whole table, written without ``vmap``;
+- ``four_turns``: a ``while`` of four turns, a quarter of the positions of
+  every slot a turn (a shortest rung of G's ladder), each a slice of the table
+  fused into its product; both products at ``HIGHEST`` precision, float32
+  as the parent's are (at the default the chip rounds the query and the
+  probabilities to bfloat16);
+- ``two_turns``: the same in two turns of half the positions (what
+  ``models/decoder.py:read_table`` keeps since PR 37);
+
+at G's table (gpt2-large: 16 slots, 20 heads of 64, 1,024 positions, 36
+layers), all sixteen slots members.
+
+One dispatch is a round's layers without their arithmetic: each makes a query
+and a member's rows from the running state (a layer's weights are 12 d^2, as
+the GPT-2 block has them), writes the rows into its donated pair of tables
+(``row_write_chip``'s ``loop_window``, the decoder's own form), reads the pair
+through the form and streams the rest of its weights into the next state. So
+each read waits for the layer before it and shares the chip's memory with the
+weights and the writes, as in the round's program: alone, with queries that
+are inputs, the compiler overlaps the parent's staging with the writes and it
+reads cheaper than it does in a round (PERF.md section 6, PR 37).
+``rows_and_weights`` is the dispatch with no read. Reported: the median
+dispatch in ms and the cache bytes the attention reads (each pair once) over
+it in GB/s. ``agreement`` says that every form's attention equals the
+parent's to a ten-thousandth of its largest value: what float32 sums in
+another order leave, and no mask, position or rounding out of place (the
+parent takes the query as its product left it, unrounded: a form that
+rounds it to bfloat16 reads 6e-4 off on the chip).
+
+Run on the chip (or with --small off the chip for a pipeline check):
+    python tools/top_rung_read_chip.py [--json-out PATH] [--small]
+"""
+
+from __future__ import annotations
+
+import argparse
+import functools
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import row_write_chip  # noqa: E402
+
+# name: (table shape [slots, H, M, Dh], layers a round reads, a shortest rung)
+TABLES = {"gpt2-large": ((16, 20, 1024, 64), 36, 256)}
+SMALL_TABLES = {"small": ((4, 2, 64, 8), 2, 16)}
+FORMS = ("parent", "pieces", "slot_turns", "table", "four_turns", "two_turns")
+# the largest difference from the parent's attention a form may leave, over
+# the parent's largest value
+AGREE = 1e-4
+
+
+def forms(jax, jnp, lax, piece):
+    """Every form, each ``(q, k, v, pos) -> attention`` over the queries
+    [slots, H, Dh] bf16, the stacked caches (k, v) [slots, H, M, Dh] bf16 and
+    the positions int32 [slots]: float32 [slots, H, Dh]."""
+    from client_tpu.models.decoder import slots_a_turn
+
+    f32 = jnp.float32
+
+    def one(q, k, v, pos):
+        # models/decoder.py:attention at the top rung, as the parent read it
+        dim, live = q.shape[-1], k.shape[1]
+        scores = jnp.einsum("hd,hmd->hm", q.astype(f32),
+                            k.astype(f32)) * (dim ** -0.5)
+        scores = jnp.where((jnp.arange(live) <= pos)[None, :], scores, -jnp.inf)
+        return jnp.einsum("hm,hmd->hd", jax.nn.softmax(scores, axis=-1),
+                          v.astype(f32))
+
+    def parent(q, k, v, pos):
+        return jax.vmap(one)(q, k, v, pos)
+
+    def pieces(q, k, v, pos):
+        def each(q, k, v, pos):
+            dim, live = q.shape[-1], k.shape[1]
+            cut = [slice(at, at + piece) for at in range(0, live, piece)]
+            scores = jnp.concatenate(
+                [jnp.einsum("hd,hmd->hm", q.astype(f32), k[:, c].astype(f32))
+                 for c in cut], axis=-1) * (dim ** -0.5)
+            scores = jnp.where((jnp.arange(live) <= pos)[None, :], scores,
+                               -jnp.inf)
+            probs = jax.nn.softmax(scores, axis=-1)
+            return sum(jnp.einsum("hm,hmd->hd", probs[:, c], v[:, c].astype(f32))
+                       for c in cut)
+
+        return jax.vmap(each)(q, k, v, pos)
+
+    def slot_turns(q, k, v, pos):
+        slots, q = q.shape[0], q.astype(f32)
+        a_turn = slots_a_turn(slots)
+        attend = jax.vmap(one)
+
+        def turn(n, attn):
+            those = functools.partial(lax.dynamic_slice_in_dim,
+                                      start_index=n * a_turn, slice_size=a_turn)
+            return lax.dynamic_update_slice_in_dim(
+                attn, attend(those(q), those(k), those(v), those(pos)),
+                n * a_turn, 0)
+
+        return lax.fori_loop(0, slots // a_turn, turn,
+                             jnp.zeros(q.shape, f32))
+
+    def table(q, k, v, pos):
+        live, dim = k.shape[2], q.shape[-1]
+        scores = jnp.einsum("shd,shmd->shm", q.astype(f32),
+                            k.astype(f32)) * (dim ** -0.5)
+        mask = jnp.arange(live)[None, :] <= pos[:, None]
+        scores = jnp.where(mask[:, None, :], scores, -jnp.inf)
+        return jnp.einsum("shm,shmd->shd", jax.nn.softmax(scores, axis=-1),
+                          v.astype(f32))
+
+    def turns(count):
+        def read(q, k, v, pos):
+            slots, heads, live, dim = k.shape
+            span = live // count
+            rows = lambda cache, n: lax.dynamic_slice_in_dim(
+                cache, n * span, span, axis=2)
+
+            def score(n, scores):
+                return lax.dynamic_update_slice_in_dim(
+                    scores,
+                    jnp.einsum("shd,shmd->shm", q.astype(f32),
+                               rows(k, n).astype(f32),
+                               precision=lax.Precision.HIGHEST),
+                    n * span, axis=2)
+
+            scores = lax.fori_loop(0, count, score,
+                                   jnp.zeros((slots, heads, live), f32))
+            mask = jnp.arange(live)[None, :] <= pos[:, None]
+            scores = jnp.where(mask[:, None, :], scores * (dim ** -0.5),
+                               -jnp.inf)
+            probs = jax.nn.softmax(scores, axis=-1)
+
+            def weigh(n, attn):
+                return attn + jnp.einsum(
+                    "shm,shmd->shd",
+                    lax.dynamic_slice_in_dim(probs, n * span, span, axis=2),
+                    rows(v, n).astype(f32), precision=lax.Precision.HIGHEST)
+
+            return lax.fori_loop(0, count, weigh,
+                                 jnp.zeros((slots, heads, dim), f32))
+
+        return read
+
+    return dict(zip(FORMS, (parent, pieces, slot_turns, table, turns(4),
+                            turns(2))))
+
+
+def layered(jax, jnp, lax, read):
+    """One dispatch: the layers of a round without their arithmetic. Each
+    makes a query and a member's rows from the running state, writes the
+    rows into its donated pair of tables, reads the pair through ``read``
+    (or not at all, for ``None``) and streams the rest of its weights into
+    the next state. Returns the tables and every layer's attention."""
+    write = row_write_chip.forms(jnp, lax)["loop_window"]
+
+    def dispatch(tables, weights, x, pos, active):
+        slots, heads, _, dim = tables[0][0].shape
+        split = lambda part: part.reshape(slots, heads, 1, dim)
+        out, attns = [], []
+        for caches, (qkv, rest) in zip(tables, weights):
+            q, k_new, v_new = jnp.split(x @ qkv, 3, axis=-1)
+            caches = write(caches, (split(k_new), split(v_new)), pos, active)
+            out.append(caches)
+            q = q.reshape(slots, heads, dim)
+            if read is None:
+                attn = q.astype(jnp.float32)
+            else:
+                attn = read(q, *caches, pos)
+                attns.append(attn)
+            y = attn.reshape(slots, heads * dim).astype(jnp.bfloat16) @ rest
+            x = x + y.reshape(slots, -1, heads * dim).sum(axis=1).astype(
+                jnp.bfloat16)
+        return out, (jnp.stack(attns) if attns else x)
+
+    return jax.jit(dispatch, donate_argnums=0)
+
+
+def _operands(jax, jnp, np, shape, layers, seed=0):
+    """``row_write_chip``'s tables, a layer's weights (the query's and the
+    rows' 3 d^2 and 9 d^2 more, as the GPT-2 block has 12 d^2) made on the
+    device, a running state, every slot a member at positions across the
+    table."""
+    tables, _, _, _ = row_write_chip._operands(
+        jnp, np, shape, layers, shape[0], seed)
+    slots, heads, length, dim = shape
+    width = heads * dim
+    keys = jax.random.split(jax.random.PRNGKey(seed), 2 * layers + 1)
+    draw = lambda key, *dims: (jax.random.normal(key, dims, jnp.float32)
+                               * width ** -0.5).astype(jnp.bfloat16)
+    weights = [(draw(keys[2 * n], width, 3 * width),
+                draw(keys[2 * n + 1], width, 9 * width))
+               for n in range(layers)]
+    x = draw(keys[-1], slots, width) * width ** 0.5
+    pos = jnp.asarray((length // 2 + 37 * np.arange(slots)) % length,
+                      jnp.int32)
+    return tables, weights, x, pos, jnp.ones((slots,), bool)
+
+
+def check_agreement(jax, jnp, np, lax, tables):
+    """Each form's attention after one dispatch against the parent's."""
+    cases, ok = [], True
+    for name, (shape, layers, piece) in tables.items():
+        made = forms(jax, jnp, lax, piece)
+        got = {}
+        for form_name in FORMS:
+            operands = _operands(jax, jnp, np, shape, 1)
+            _, attn = layered(jax, jnp, lax, made[form_name])(*operands)
+            got[form_name] = np.asarray(attn, np.float64)
+        scale = np.abs(got["parent"]).max()
+        case = {"table": name, "shape": list(shape)}
+        for form_name in FORMS[1:]:
+            worst = float(np.abs(got[form_name] - got["parent"]).max() / scale)
+            case[form_name] = {"agrees": worst <= AGREE, "worst": worst}
+            ok = ok and worst <= AGREE
+        cases.append(case)
+    return {"ok": ok, "cases": cases}
+
+
+def bench_forms(jax, jnp, np, lax, tables, repeats):
+    """The median dispatch of every form, and of the rows and weights
+    alone."""
+    out = []
+    for name, (shape, layers, piece) in tables.items():
+        made = forms(jax, jnp, lax, piece)
+        slots, heads, length, dim = shape
+        read_bytes = 2 * slots * heads * length * dim * 2 * layers
+        for form_name in ("rows_and_weights",) + FORMS:
+            program = layered(jax, jnp, lax, made.get(form_name))
+            operands = _operands(jax, jnp, np, shape, layers)
+            row = {"table": name, "shape": list(shape), "layers": layers,
+                   "form": form_name}
+            try:
+                caches, rest = operands[0], operands[1:]
+                caches, attn = program(caches, *rest)
+                jax.block_until_ready((caches, attn))  # compiled and warm
+                times = []
+                for _ in range(repeats):
+                    t0 = time.perf_counter()
+                    caches, attn = program(caches, *rest)
+                    jax.block_until_ready((caches, attn))
+                    times.append(time.perf_counter() - t0)
+                ms = sorted(times)[len(times) // 2] * 1000
+                row["ms_a_dispatch"] = round(ms, 4)
+                if form_name != "rows_and_weights":
+                    row["read_gb_s"] = round(read_bytes / ms / 1e6, 1)
+                del caches, attn
+            except Exception as e:
+                row["error"] = f"{type(e).__name__}: {e}"[:300]
+            out.append(row)
+    return out
+
+
+def run(small: bool, repeats: int = 15):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax import lax
+
+    tables = SMALL_TABLES if small else TABLES
+    device = jax.devices()[0]
+    result = {"platform": jax.default_backend(),
+              "device_kind": device.device_kind}
+    try:
+        result["agreement"] = check_agreement(jax, jnp, np, lax, tables)
+    except Exception as e:
+        result["agreement"] = {
+            "ok": False, "error": f"{type(e).__name__}: {e}"[:500]}
+    result["forms"] = bench_forms(jax, jnp, np, lax, tables, repeats)
+    return result
+
+
+def main(argv=None):
+    parser = argparse.ArgumentParser()
+    parser.add_argument("--json-out", default=None)
+    parser.add_argument("--small", action="store_true",
+                        help="a table of four slots, two layers: a pipeline "
+                        "check off the chip, no number of the chip's")
+    parser.add_argument("--repeats", type=int, default=15,
+                        help="timed dispatches a form; the median is reported")
+    args = parser.parse_args(argv)
+
+    result = run(args.small, args.repeats)
+    text = json.dumps(result, indent=1)
+    print(text)
+    if args.json_out:
+        with open(args.json_out, "w") as f:
+            f.write(text + "\n")
+    return 0 if result["agreement"].get("ok") else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())
