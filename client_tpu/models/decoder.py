@@ -44,8 +44,9 @@ from .base import Model, TensorSpec
 # the shortest rung of a ladder: under it a step is its weights and the
 # prefix is not worth a program
 SHORTEST_RUNG = 256
-# the slots whose caches one turn of a round's attention reads, at most: the
-# round reads the occupied slots rounded up to a multiple of ``slots_a_turn``
+# the slots whose caches one turn of a round's attention reads, at most: where
+# it reads in turns, the round reads the occupied slots rounded up to a
+# multiple of ``slots_a_turn``
 SLOTS_A_TURN = 4
 
 
@@ -58,6 +59,13 @@ def slots_a_turn(slots: int) -> int:
     """The slots a turn of a round's attention takes of a table of
     ``slots``: the turns are whole, so it divides them."""
     return math.gcd(slots, SLOTS_A_TURN)
+
+
+def in_whole_turns(slots: int, occupied: int) -> int:
+    """The slots that whole turns of ``slots_a_turn(slots)`` take to cover
+    the lowest ``occupied`` of ``slots``."""
+    a_turn = slots_a_turn(slots)
+    return -(-occupied // a_turn) * a_turn
 
 
 def ladder_of(max_len: int) -> Tuple[int, ...]:
@@ -498,21 +506,13 @@ class TinyDecoderModel(Model):
             a trace is read by the plain names)."""
             return jax.vmap(jax.jit(part), in_axes)
 
-        def round_layer(layer, cache, x, pos, active, turns, *, live):
-            """One layer of a round over a table: ``vmap`` of the step's own
-            parts over the slots, round the one row write that takes the
-            whole table where it lies. The products with the weights take
-            every slot (a slot more costs them nothing: they read the
-            weights). The attention takes the slots ``a_turn`` at a time for
-            ``turns`` turns, the occupied ones and no slot's cache beyond:
-            a round costs the caches of its live streams, in width as the
-            rung makes it in length, in one program a rung."""
-            slots = x.shape[0]
+        def slot_turns(q, k, v, pos, turns, *, live):
+            """The attention of a round over the table's slots
+            ``slots_a_turn`` at a time for ``turns`` turns, the occupied ones
+            and no slot's cache beyond, a turn's slots and the prefix of
+            their positions one slice of the table."""
+            slots = q.shape[0]
             a_turn = slots_a_turn(slots)
-            q, k_new, v_new = over_slots(qkv_rows, (None, 0))(layer, x)
-            with jax.named_scope("cache_update"):
-                k, v = write_table_rows((cache["k"], cache["v"]),
-                                        (k_new, v_new), pos, active)
             attend = over_slots(functools.partial(attention, live=live), 0)
 
             def turn(n, attn):
@@ -520,8 +520,6 @@ class TinyDecoderModel(Model):
                 those = functools.partial(
                     lax.dynamic_slice_in_dim, start_index=at,
                     slice_size=a_turn)
-                # the slots of the turn and the prefix of their positions,
-                # one slice of the table as it lies
                 prefix = lambda cache: lax.dynamic_slice(
                     cache, (at, 0, 0, 0), (a_turn, H, live, Dh))
                 return lax.dynamic_update_slice_in_dim(
@@ -529,8 +527,37 @@ class TinyDecoderModel(Model):
                     at, 0)
 
             with jax.named_scope("attention"):  # the loop's own operations
-                attn = lax.fori_loop(
+                return lax.fori_loop(
                     0, turns, turn, jnp.zeros((slots, H, Dh), jnp.float32))
+
+        def round_layer(layer, cache, x, pos, active, turns, *, live):
+            """One layer of a round over a table: ``vmap`` of the step's own
+            parts over the slots, round the one row write that takes the
+            whole table where it lies. The products with the weights take
+            every slot (a slot more costs them nothing: they read the
+            weights). How the attention takes the slots goes by how the chip
+            lays the table (``write_table_rows``). Where a row fills a
+            tile's lanes it takes the occupied slots in turns
+            (``slot_turns``): a round costs the caches of its live streams,
+            in width as the rung makes it in length, in one program a rung.
+            Where rows are narrower, the chip's compiler sets each turn's
+            slice aside in fast memory and lays it out anew before the
+            products read it (sixteen slots' 256 positions a round of 36
+            layers took 3.0 ms so on a v5e, 250 GB/s), and the attention
+            reads every slot's prefix where it lies instead, as the slot
+            batcher does (``one_layer``, ``read_slot`` at the top rung):
+            1.35 ms for the same bytes, occupied slots or not (PERF.md
+            section 6). ``slots_read`` says which slots a round read."""
+            q, k_new, v_new = over_slots(qkv_rows, (None, 0))(layer, x)
+            with jax.named_scope("cache_update"):
+                k, v = write_table_rows((cache["k"], cache["v"]),
+                                        (k_new, v_new), pos, active)
+            if Dh < LANES:
+                read = (functools.partial(attention, live=live) if live < M
+                        else read_slot)
+                attn = over_slots(read, 0)(q, k, v, pos)
+            else:
+                attn = slot_turns(q, k, v, pos, turns, live=live)
             x = over_slots(rest_of_layer, (None, 0, 0))(layer, x, attn)
             return x, {"k": k, "v": v}
 
@@ -603,6 +630,15 @@ class TinyDecoderModel(Model):
                 for live in self._rungs:
                     _, caches = self._step_at(caches, 0, 0, live)
             self._warm = True
+
+    def slots_read(self, slots: int, occupied: int) -> int:
+        """The slots whose caches a round's attention reads over a table of
+        ``slots`` whose highest occupied slot is ``occupied - 1``: every
+        slot where heads are narrower than the lanes, the occupied ones in
+        whole turns where a row fills them (``round_layer``)."""
+        if self.D_MODEL // self.HEADS < LANES:
+            return slots
+        return in_whole_turns(slots, occupied)
 
     def count_positions(self, count: RungCount, positions, decoding: bool) -> None:
         """What the tokens at ``positions`` (a prompt's, or decode steps')

@@ -36,8 +36,10 @@ in short:
 - a round's program has a rung (the live prefix of the positions:
   decoder.py's ladder), and its attention reads the caches of the occupied
   slots alone, ``decoder.SLOTS_A_TURN`` slots a turn of a loop that ends
-  after the highest occupied one: one program a rung, each compiled in
-  ``_ensure_built``, before the first round;
+  after the highest occupied one, or, where heads are narrower than the
+  chip's lanes, every slot's where it lies (``decoder.slots_read``): one
+  program a rung, each compiled in ``_ensure_built``, before the first
+  round;
 - ``chunk`` > 1 keeps its wire meaning on this path: after the first token
   the tokens are delivered K at a time, off the same rounds.
 

@@ -39,12 +39,14 @@ compile-time prefix of the positions (the decoder's ladder: the rung that
 covers the furthest member) and, of the slots, the occupied ones and no
 cache beyond: ``decoder.SLOTS_A_TURN`` slots a turn of a loop that the
 program ends after the highest occupied slot (decoder.py's ``round_layer``;
-lowest-free-first admission keeps the occupied slots compact). So there is
-one program a rung, as for a single sequence, and every rung's is compiled
-before the worker takes its first stream. (A ladder of compile-time widths
-was built first and measured: a program more is 0.6 to 1.3 s of a warm
-set-up, which does not shrink side by side, and the set-up's bound paid for
-three of the six; PERF.md section 6, PR 33.)
+lowest-free-first admission keeps the occupied slots compact). Where heads
+are narrower than the chip's lanes the turns read slowly and the round reads
+every slot instead; the decoder says which slots a round read
+(``slots_read``). So there is one program a rung, as for a single sequence,
+and every rung's is compiled before the worker takes its first stream. (A
+ladder of compile-time widths was built first and measured: a program more
+is 0.6 to 1.3 s of a warm set-up, which does not shrink side by side, and
+the set-up's bound paid for three of the six; PERF.md section 6.)
 
 **A prompt a chunk a dispatch, where the decoder offers it.** A decoder
 whose prompts are too long for a round a token (a byte model's are four
@@ -71,7 +73,6 @@ from typing import Any, Deque, Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
 
-from .decoder import slots_a_turn
 from ..server.timeline import (
     SPAN_DEVICE_WAIT,
     SPAN_DISPATCH,
@@ -154,7 +155,6 @@ class StreamRounds:
         self._model = model
         self._decoder = decoder = model._decoder
         self.slots = int(model.slots)
-        self._a_turn = slots_a_turn(self.slots)
         self._caches = decoder._fresh_table(self.slots)
         self._fed = jnp.zeros((self.slots,), jnp.int32)
         self._arrivals: "queue.SimpleQueue[Optional[Stream]]" = queue.SimpleQueue()
@@ -347,10 +347,9 @@ class StreamRounds:
                 ctl[2, slot] = 1
                 stream.pos += 1
             # the shortest rung that covers the furthest member; the slots its
-            # attention reads, as the program counts them: the occupied ones,
-            # in whole turns
+            # attention reads, as the decoder's program takes them
             live = decoder.rung_for(int(ctl[1].max()) + 1)
-            width = -(-(members[-1][0] + 1) // self._a_turn) * self._a_turn
+            width = decoder.slots_read(self.slots, members[-1][0] + 1)
             decoder.count_positions(count, ctl[1][ctl[2] > 0], decoding=True)
         with span(SPAN_DISPATCH, into=phases) as dispatch:
             self._step(ctl, live)

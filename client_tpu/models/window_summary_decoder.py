@@ -81,7 +81,7 @@ from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 import numpy as np
 
 from ..server.timeline import SPAN_PREFILL_CHUNK, span
-from .decoder import RungCount, TinyDecoderModel, slots_a_turn
+from .decoder import RungCount, TinyDecoderModel, in_whole_turns, slots_a_turn
 from .routed_decoder import rms, rotary, rotary_table, seeded_params
 
 # the shortest rung that reads summaries covers this many windows' worth
@@ -230,6 +230,11 @@ class WindowSummaryDecoderModel(TinyDecoderModel):
         s = self.sizes
         rows = s.summaries_a_window * ((reach - 1) // s.window)
         return next(live for live in self._rungs if live >= rows)
+
+    def slots_read(self, slots: int, occupied: int) -> int:
+        """Its round reads the occupied slots in whole turns at every head
+        width (``over_turns``)."""
+        return in_whole_turns(slots, occupied)
 
     def count_positions(self, count: RungCount, positions, decoding: bool) -> None:
         """What the tokens at ``positions`` read and wrote of the two kinds

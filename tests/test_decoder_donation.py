@@ -373,6 +373,135 @@ def test_the_top_rung_reads_as_the_parents_form_did(two_rungs):
                         == before[layer][half][slot].tobytes()), (layer, slot)
 
 
+# -- a narrow-headed round reads every slot where it lies --------------------
+
+ROUND_SLOTS = 16
+# members spread over the table and none in its last four slots, which the
+# turns' form does not read at all; by rung, the first round's positions
+ROUND_ACTIVE = [slot < 12 and slot % 3 != 1 for slot in range(ROUND_SLOTS)]
+ROUND_POS = {256: [(37 * slot) % 250 for slot in range(ROUND_SLOTS)],
+             TOP: [(61 * slot + 300) % 1022 for slot in range(ROUND_SLOTS)]}
+
+
+def _turns_round(decoder):
+    """The reference: a round whose attention takes the occupied slots in
+    turns of ``slots_a_turn``, each turn's slots and the prefix of their
+    positions a slice of the table read by ``vmap`` of the single slot's
+    attention, the form a round takes where heads fill the lanes."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from client_tpu.models.decoder import slots_a_turn
+
+    D, H, M = decoder.D_MODEL, decoder.HEADS, decoder.MAX_LEN
+    Dh, f32, bf16 = D // H, jnp.float32, jnp.bfloat16
+
+    def norm(x):
+        x32 = x.astype(f32)
+        mu = jnp.mean(x32, axis=-1, keepdims=True)
+        var = jnp.var(x32, axis=-1, keepdims=True)
+        return ((x32 - mu) * lax.rsqrt(var + 1e-5)).astype(x.dtype)
+
+    def attention(q, k, v, pos, live):
+        scores = jnp.einsum("hd,hmd->hm", q.astype(f32),
+                            k[:, :live].astype(f32)) * (Dh ** -0.5)
+        scores = jnp.where((jnp.arange(live) <= pos)[None, :], scores, -jnp.inf)
+        return jnp.einsum("hm,hmd->hd", jax.nn.softmax(scores, axis=-1),
+                          v[:, :live].astype(f32))
+
+    @functools.partial(jax.jit, static_argnames="live")
+    def a_round(params, caches, fed, ctl, *, live):
+        given, pos, active = ctl[0], ctl[1], ctl[2] > 0
+        slots = active.shape[0]
+        a_turn = slots_a_turn(slots)
+        occupied = jnp.max(jnp.where(active, jnp.arange(slots) + 1, 0))
+        token = jnp.where(given >= 0, given, fed)
+        x = params["embed"][token] + params["pos"][pos]
+        at = (jnp.arange(M)[None, :] == pos[:, None]) & active[:, None]
+        new = []
+        for layer, cache in zip(params["layers"], caches):
+            q, k_new, v_new = jnp.split(
+                jax.vmap(lambda x: norm(x) @ layer["qkv"])(x), 3, axis=-1)
+            k, v = (jnp.where(at[:, None, :, None],
+                              row.reshape(slots, H, 1, Dh), cache[half])
+                    for half, row in (("k", k_new), ("v", v_new)))
+            q = q.reshape(slots, H, Dh)
+
+            def turn(n, attn, k=k, v=v, q=q):
+                those = functools.partial(
+                    lax.dynamic_slice_in_dim, start_index=n * a_turn,
+                    slice_size=a_turn)
+                return lax.dynamic_update_slice_in_dim(
+                    attn, jax.vmap(functools.partial(attention, live=live))(
+                        those(q), those(k), those(v), those(pos)),
+                    n * a_turn, 0)
+
+            attn = lax.fori_loop(0, -(-occupied // a_turn), turn,
+                                 jnp.zeros((slots, H, Dh), f32))
+            x = x + jax.vmap(lambda a: a.reshape(D).astype(bf16)
+                             @ layer["proj"])(attn)
+            x = x + jax.vmap(lambda x: jax.nn.gelu(norm(x) @ layer["mlp_in"])
+                             @ layer["mlp_out"])(x)
+            new.append({"k": k, "v": v})
+        logits = jax.vmap(lambda x: (norm(x) @ params["unembed"]).astype(f32))(x)
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), new
+
+    return a_round
+
+
+@pytest.mark.parametrize("live", [256, TOP])
+def test_a_narrow_headed_round_reads_as_its_turns_did(live):
+    """Two rounds of a table of sixteen at each rung of a decoder whose
+    heads are narrower than the lanes, members spread across the table and
+    some slots unoccupied, the second fed the first's choices: the members'
+    tokens are the turns' form's, the caches are its to what float32 sums in
+    another order leave (the first layer's rows, which come before any
+    attention, bit for bit), and an unoccupied slot's caches come back as
+    they were."""
+    import types
+
+    import jax
+    import jax.numpy as jnp
+
+    from client_tpu.models.decoder import LANES
+
+    decoder = LongDecoder(seed=0)
+    decoder._ensure_built()
+    assert decoder._rungs == (256, TOP)
+    assert decoder.D_MODEL // decoder.HEADS < LANES
+    before, caches = _filled_caches(types.SimpleNamespace(
+        _fresh_caches=lambda: decoder._fresh_table(ROUND_SLOTS)), seed=10)
+    want_caches = jax.tree_util.tree_map(jnp.asarray, before)
+    reference = _turns_round(decoder)
+    fed = want_fed = jnp.zeros((ROUND_SLOTS,), jnp.int32)
+    pos, active = ROUND_POS[live], ROUND_ACTIVE
+    members = np.flatnonzero(active)
+    for n in range(2):
+        # a prompt's tokens in the first round, the fed choices in the second
+        given = np.arange(ROUND_SLOTS) + 11 if n == 0 else -np.ones(ROUND_SLOTS)
+        ctl = np.stack([given, pos, active]).astype(np.int32)
+        fed, caches = decoder._round_fn(decoder._params, caches, fed, ctl,
+                                        live=live)
+        want_fed, want_caches = reference(decoder._params, want_caches,
+                                          want_fed, ctl, live=live)
+        np.testing.assert_array_equal(np.asarray(fed)[members],
+                                      np.asarray(want_fed)[members])
+        pos = _next(pos, active)
+    for layer, (got, wanted) in enumerate(zip(caches, want_caches)):
+        for half in ("k", "v"):
+            g = np.asarray(got[half], np.float32)
+            w = np.asarray(wanted[half], np.float32)
+            np.testing.assert_allclose(g, w, atol=2e-2)
+            if layer == 0:
+                assert g.tobytes() == w.tobytes()
+            for slot in np.flatnonzero(~np.asarray(active)):
+                assert (np.asarray(got[half][slot]).tobytes()
+                        == before[layer][half][slot].tobytes()), (layer, slot)
+
+
 # -- a step that fails after it took its caches ------------------------------
 
 

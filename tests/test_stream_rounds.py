@@ -10,7 +10,12 @@ import threading
 import numpy as np
 import pytest
 
-from client_tpu.models.decoder import TinyDecoderModel
+from client_tpu.models.decoder import (
+    LANES,
+    TinyDecoderModel,
+    in_whole_turns,
+    slots_a_turn,
+)
 from client_tpu.models.decoder_tp import TPDecoderModel
 from client_tpu.models.generate import TinyGenerateModel
 from client_tpu.models.stream_rounds import ROUNDS_IN_FLIGHT
@@ -20,8 +25,23 @@ from tests.conftest import (
     spans_into_phases,
 )
 
-LongDecoder = type("LongDecoder", (TinyDecoderModel,), {
-    "D_MODEL": 64, "HEADS": 2, "LAYERS": 2, "MAX_LEN": 1024})
+# the fixture's heads (32 wide: narrower than the chip's lanes, so a round
+# reads every slot) and heads as wide as the lanes (a round reads the
+# occupied slots in whole turns), by head width
+HEAD_SIZES = {32: {}, 128: {"D_MODEL": 256, "HEADS": 2}}
+# at 1,024 positions: two rungs
+LONG_SIZES = {32: {"D_MODEL": 64, "HEADS": 2, "LAYERS": 2, "MAX_LEN": 1024},
+              128: {"D_MODEL": 256, "HEADS": 2, "LAYERS": 2, "MAX_LEN": 1024}}
+
+
+def _decoder(head, sizes=HEAD_SIZES):
+    return type(f"Heads{head}", (TinyDecoderModel,), sizes[head])(seed=0)
+
+
+def _width(head, slots, occupied):
+    """The slots a round reads whose highest occupied slot is
+    ``occupied - 1``."""
+    return slots if head < LANES else in_whole_turns(slots, occupied)
 
 RNG = np.random.default_rng(33)
 PROMPTS = [[int(t) for t in RNG.integers(0, 256, n)]
@@ -55,14 +75,17 @@ def _without_round(decoder):
 @pytest.fixture(scope="module")
 def alone():
     """What each prompt's stream gives on the per-stream path, a stream at a
-    time: ``alone(prompt, max_tokens)``."""
-    model = TinyGenerateModel(decoder=_without_round(TinyDecoderModel(seed=0)))
-    known = {}
+    time: ``alone(prompt, max_tokens, head=32)``, on the fixture's decoder or
+    one of ``HEAD_SIZES``."""
+    models, known = {}, {}
 
-    def tokens(prompt, max_tokens=BUDGET):
-        key = (tuple(prompt), max_tokens)
+    def tokens(prompt, max_tokens=BUDGET, head=32):
+        key = (tuple(prompt), max_tokens, head)
         if key not in known:
-            known[key] = _tokens(model, prompt, max_tokens)
+            if head not in models:
+                models[head] = TinyGenerateModel(
+                    decoder=_without_round(_decoder(head)))
+            known[key] = _tokens(models[head], prompt, max_tokens)
         return known[key]
 
     return tokens
@@ -287,8 +310,9 @@ def test_a_stream_that_ends_by_its_end_id_is_found_a_round_late(served, alone):
     assert needed <= len(gate.calls) <= needed + ROUNDS_IN_FLIGHT - 1
 
 
-def test_more_streams_than_slots_all_finish(served, alone):
-    model = served(2)
+@pytest.mark.parametrize("head", HEAD_SIZES)
+def test_more_streams_than_slots_all_finish(served, alone, head):
+    model = served(2, _decoder(head))
     gate = Gate(model)
     users, out, errors = _concurrently(model, PROMPTS[:1])
     gate.held(1)
@@ -300,21 +324,23 @@ def test_more_streams_than_slots_all_finish(served, alone):
     _joined(users + others, errors + errors_others)
     out.update({i + 1: tokens for i, tokens in out_others.items()})
     for i, prompt in enumerate(PROMPTS[:6]):
-        assert out[i] == alone(prompt), i
+        assert out[i] == alone(prompt, head=head), i
     assert model.slot_waits == 4
     assert max(model.batch_histogram) == 2
     assert set(model.rounds_by_width) == {2}
 
 
-def test_the_lowest_free_slot_keeps_the_round_narrow(served):
+@pytest.mark.parametrize("head", HEAD_SIZES)
+def test_the_lowest_free_slot_keeps_the_round_narrow(served, head):
     """Eight slots, four a turn of the attention: three streams sit in slots
     0-2 and their rounds read four slots' caches; a fifth stream makes the
-    rounds read eight."""
-    model = served(8)
+    rounds read eight. (Where heads are narrower than the lanes every round
+    reads all eight.)"""
+    model = served(8, _decoder(head))
     gate = Gate(model, free=True)
     users, out, errors = _concurrently(model, PROMPTS[:3], 4)
     _joined(users, errors)
-    assert set(model.rounds_by_width) == {4}
+    assert set(model.rounds_by_width) == {_width(head, 8, 3)}
     gate.shut()
     users, out, errors = _concurrently(model, PROMPTS[:1], 4)
     gate.held(1)
@@ -324,32 +350,36 @@ def test_the_lowest_free_slot_keeps_the_round_narrow(served):
     _joined(users + others, errors + errors_others)
     assert [int(ctl[2].sum()) for ctl, _ in gate.calls[:2]] == [1, 5]
     assert list(gate.calls[1][0][2]) == [1, 1, 1, 1, 1, 0, 0, 0]
-    assert set(model.rounds_by_width) == {4, 8}
+    assert set(model.rounds_by_width) == {_width(head, 8, 3), _width(head, 8, 5)}
 
 
+@pytest.mark.parametrize("head", HEAD_SIZES)
 @pytest.mark.parametrize("slots,a_turn", [(16, 4), (8, 4), (6, 2), (3, 1), (1, 1)])
 def test_the_attention_takes_the_occupied_slots_in_whole_turns(
-        served, alone, slots, a_turn):
+        served, alone, slots, a_turn, head):
     """Whatever the table's size the streams get their own tokens, and a
     round counts the slots its attention read: the occupied ones, rounded up
-    to the turn."""
-    model = served(slots)
-    assert model._rounds._a_turn == a_turn
+    to the turn, where heads fill the lanes; every slot where they are
+    narrower."""
+    model = served(slots, _decoder(head))
+    assert slots_a_turn(slots) == a_turn
     users, out, errors = _concurrently(model, PROMPTS[:3])
     _joined(users, errors)
     for i, prompt in enumerate(PROMPTS[:3]):
-        assert out[i] == alone(prompt), i
+        assert out[i] == alone(prompt, head=head), i
     assert all(width % a_turn == 0 and width <= slots
                for width in model.rounds_by_width)
-    assert max(model.rounds_by_width) == min(slots, -(-3 // a_turn) * a_turn)
+    assert max(model.rounds_by_width) == (
+        slots if head < LANES else min(slots, -(-3 // a_turn) * a_turn))
 
 
 # -- nothing compiles once it serves ------------------------------------------
 
 
-def test_every_rung_is_compiled_before_the_first_round(served):
+@pytest.mark.parametrize("head", LONG_SIZES)
+def test_every_rung_is_compiled_before_the_first_round(served, head):
     timeline.COMPILES.listen()
-    model = served(8, LongDecoder(seed=0))
+    model = served(8, _decoder(head, LONG_SIZES))
     decoder = model._decoder
     assert decoder._rungs == (256, 1024)
     assert not decoder._warm  # no single-sequence rung was built for it
@@ -360,7 +390,8 @@ def test_every_rung_is_compiled_before_the_first_round(served):
     _joined(users, errors)
     assert timeline.COMPILES.count == before
     assert {live for _, live in gate.calls} == {256, 1024}
-    assert 8 in model.rounds_by_width and set(model.rounds_by_width) <= {4, 8}
+    assert 8 in model.rounds_by_width and set(model.rounds_by_width) <= {
+        _width(head, 8, occupied) for occupied in range(1, 9)}
     assert model.steps_by_rung.by_rung()[1024] == 5  # positions 256 to 260
     assert sum(model.steps_by_rung.by_rung().values()) == len(gate.calls)
     # (at these positions a single sequence's step rounds a near tie the
@@ -413,8 +444,9 @@ def test_a_decoder_without_a_round_keeps_the_per_stream_loop(make):
 # -- what ran is counted ------------------------------------------------------
 
 
-def test_the_histogram_and_the_registry_count_what_ran(served):
-    model = served(4)
+@pytest.mark.parametrize("head", HEAD_SIZES)
+def test_the_histogram_and_the_registry_count_what_ran(served, head):
+    model = served(4, _decoder(head))
     core = ServerCore([model])
     gate = Gate(model)
     users, out, errors = _concurrently(model, [PROMPTS[0]], 4)
@@ -427,7 +459,9 @@ def test_the_histogram_and_the_registry_count_what_ran(served):
     # tokens each are 6 and 4 rounds
     rounds = len(gate.calls)
     assert model.batch_histogram == {1: rounds - 4, 2: 4}
-    assert model.rounds_by_width == {4: rounds}
+    width = _width(head, 4, 2)
+    assert width == 4  # two slots' turn, or all four
+    assert model.rounds_by_width == {width: rounds}
     assert model.steps_by_rung.by_rung() == {128: rounds}
     assert model.steps_by_rung.totals()["prefill_tokens"] == 4
     assert model.steps_by_rung.totals()["prefill_chunks"] == 4
@@ -437,12 +471,12 @@ def test_the_histogram_and_the_registry_count_what_ran(served):
         tuple(v for k, v in sorted(row["labels"].items()) if k != "model"):
         row["value"] for row in snapshot[name]["series"]
         if row["labels"]["model"] == "tiny_lm_generate"}
-    assert series("client_tpu_server_stream_rounds") == {("4",): rounds}
+    assert series("client_tpu_server_stream_rounds") == {(str(width),): rounds}
     assert series("client_tpu_server_stream_slot_waits") == {(): 0}
     assert series("client_tpu_server_decode_steps") == {("128",): rounds}
     text = core.metrics_registry().prometheus_text()
     assert ('client_tpu_server_stream_rounds{model="tiny_lm_generate",'
-            f'width="4"}} {rounds}') in text
+            f'width="{width}"}} {rounds}') in text
     # the statistics verb's batch_stats: a round is an execution
     row = core.statistics("tiny_lm_generate")["model_stats"][0]
     assert {r["batch_size"]: r["compute_infer"]["count"]

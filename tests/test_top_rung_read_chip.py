@@ -1,8 +1,8 @@
 """CI tier for tools/top_rung_read_chip.py: the micro-benchmark that decided
-the form of the slot batcher's read at its top rung must run end to end on the
-CPU backend (a table of four slots), so that a chip call never dies on its
-argument handling, and its own check of the forms against the parent's must be
-able to fail."""
+the form of the slot batcher's read at its top rung, and of the stream round's
+at a shorter rung, must run end to end on the CPU backend (tables of four and
+eight slots), so that a chip call never dies on its argument handling, and its
+own checks of the forms against the parent's must be able to fail."""
 
 import json
 import sys
@@ -26,6 +26,49 @@ def test_a_small_run_times_every_form():
     assert all(r["ms_a_dispatch"] > 0 for r in rows), rows
     assert all(r["read_gb_s"] >= 0 for r in rows[1:]), rows
     assert "read_gb_s" not in rows[0]
+
+
+def test_a_small_run_times_every_round_form_at_each_membership():
+    out = top_rung_read_chip.run(small=True, repeats=2)
+    assert out["round_agreement"]["ok"], out["round_agreement"]
+    (shape, _, live, memberships), = top_rung_read_chip.SMALL_ROUND_TABLES.values()
+    assert [case["members"] for case in out["round_agreement"]["cases"]] == list(
+        memberships)
+    rows = out["round_forms"]
+    forms = ["rows_and_weights", *top_rung_read_chip.ROUND_FORMS]
+    assert [(r["members"], r["form"]) for r in rows] == [
+        (members, form) for members in memberships for form in forms]
+    assert all(r["ms_a_dispatch"] > 0 and r["live"] == live for r in rows), rows
+    # eight slots, four a turn: three members are one turn, eight are two
+    read = {(r["members"], r["form"]): r.get("slots_read") for r in rows}
+    assert read == {
+        (members, form): (None if form == "rows_and_weights" else
+                          shape[0] if form == "every_slot" or members > 4
+                          else 4)
+        for members in memberships for form in forms}
+
+
+@pytest.mark.parametrize("broken", top_rung_read_chip.ROUND_FORMS[1:])
+def test_a_round_form_that_reads_past_its_position_fails_the_run(
+        monkeypatch, capsys, broken):
+    """The round's forms are held to the parent's round, over the members:
+    one that lets every slot see one position more is no candidate, at every
+    number of members."""
+    round_forms = top_rung_read_chip.round_forms
+
+    def with_a_fault(jax, jnp, lax, live):
+        made = round_forms(jax, jnp, lax, live)
+        sound = made[broken]
+        made[broken] = lambda q, k, v, pos, active: sound(q, k, v, pos + 1, active)
+        return made
+
+    monkeypatch.setattr(top_rung_read_chip, "round_forms", with_a_fault)
+    assert top_rung_read_chip.main(["--small", "--repeats", "1"]) == 1
+    out = json.loads(capsys.readouterr().out)
+    assert out["agreement"]["ok"]
+    for case in out["round_agreement"]["cases"]:
+        assert [form for form in top_rung_read_chip.ROUND_FORMS[1:]
+                if not case[form]["agrees"]] == [broken]
 
 
 def test_main_prints_what_it_writes_and_exits_0(tmp_path, capsys):

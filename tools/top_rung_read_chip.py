@@ -1,5 +1,5 @@
-"""The slot batcher's attention at its top rung on the real chip, a round's
-layers at a time.
+"""The slot batcher's attention at its top rung, and the stream round's at a
+shorter rung, on the real chip, a round's layers at a time.
 
 At the top rung (``live`` equal to the cache's length) every member of a round
 of the slot batcher attends to its whole cache. Compiled for a v5e, the
@@ -27,6 +27,28 @@ rests on a number that is in the repository and not in prose:
 
 at G's table (gpt2-large: 16 slots, 20 heads of 64, 1,024 positions, 36
 layers), all sixteen slots members.
+
+The stream round (``models/decoder.py:round_layer``) reads the same table at
+C's rung, 256 of the 1,024 positions, in the forms it could take
+(``ROUND_FORMS``), each at 16 members of 16 and at 6 of 16 (the lowest
+slots, as lowest-free-first admission seats them):
+
+- ``slot_turns``: a ``while`` of turns of ``SLOTS_A_TURN`` slots over the
+  occupied ones, each a ``dynamic_slice`` of the table's prefix read by
+  ``vmap`` of ``attention`` (the round's form where heads fill the lanes);
+- ``every_slot``: ``vmap`` of ``attention`` over every slot's prefix, no
+  turns: what the slot batcher's rung-256 step compiles to;
+- ``slot_turns_mxu``: ``slot_turns`` with each turn's two products over its
+  slice written as one product on the matrix unit, both at ``HIGHEST``, as
+  ``read_table`` writes them.
+
+(Position turns over every slot, ``read_table``'s form at the top rung, are
+no candidate here: compiled for a v5e, ``every_slot`` sets nothing of the
+table aside at rung 256.) Its rows report the slots each form reads and the
+form's read, its dispatch less the rows and weights' at the same members,
+in ms and in GB/s of those slots' prefixes; the agreement is with
+``slot_turns`` over the members alone (a slot that is no member reads what
+it may).
 
 One dispatch is a round's layers without their arithmetic: each makes a query
 and a member's rows from the running state (a layer's weights are 12 d^2, as
@@ -67,6 +89,10 @@ import row_write_chip  # noqa: E402
 TABLES = {"gpt2-large": ((16, 20, 1024, 64), 36, 256)}
 SMALL_TABLES = {"small": ((4, 2, 64, 8), 2, 16)}
 FORMS = ("parent", "pieces", "slot_turns", "table", "four_turns", "two_turns")
+# name: (table shape, layers a round reads, the rung, members of a round)
+ROUND_TABLES = {"gpt2-large": ((16, 20, 1024, 64), 36, 256, (16, 6))}
+SMALL_ROUND_TABLES = {"small": ((8, 2, 64, 8), 2, 16, (8, 3))}
+ROUND_FORMS = ("slot_turns", "every_slot", "slot_turns_mxu")
 # the largest difference from the parent's attention a form may leave, over
 # the parent's largest value
 AGREE = 1e-4
@@ -168,6 +194,76 @@ def forms(jax, jnp, lax, piece):
                             turns(2))))
 
 
+def round_forms(jax, jnp, lax, live):
+    """The stream round's forms at rung ``live``, each ``(q, k, v, pos,
+    active) -> attention`` over the operands of ``forms`` and the round's
+    members ``active`` bool [slots]: float32 [slots, H, Dh], a member's
+    row its attention over its cache's first ``live`` positions."""
+    from client_tpu.models.decoder import slots_a_turn
+
+    f32 = jnp.float32
+
+    def one(q, k, v, pos):
+        # models/decoder.py:attention at rung ``live``
+        dim = q.shape[-1]
+        scores = jnp.einsum("hd,hmd->hm", q.astype(f32),
+                            k[:, :live].astype(f32)) * (dim ** -0.5)
+        scores = jnp.where((jnp.arange(live) <= pos)[None, :], scores, -jnp.inf)
+        return jnp.einsum("hm,hmd->hd", jax.nn.softmax(scores, axis=-1),
+                          v[:, :live].astype(f32))
+
+    def on_the_matrix_unit(q, k, v, pos):
+        # the same over slots [n, H, live, Dh], each product one of the
+        # matrix unit's, as models/decoder.py:read_table writes them
+        product = functools.partial(jnp.einsum, precision=lax.Precision.HIGHEST)
+        scores = product("shd,shmd->shm", q.astype(f32),
+                         k.astype(f32)) * (q.shape[-1] ** -0.5)
+        mask = jnp.arange(live)[None, :] <= pos[:, None]
+        scores = jnp.where(mask[:, None, :], scores, -jnp.inf)
+        return product("shm,shmd->shd", jax.nn.softmax(scores, axis=-1),
+                       v.astype(f32))
+
+    def turns(attend):
+        def read(q, k, v, pos, active):
+            slots, heads, _, dim = k.shape
+            a_turn = slots_a_turn(slots)
+            occupied = jnp.max(jnp.where(active, jnp.arange(slots) + 1, 0))
+
+            def turn(n, attn):
+                at = n * a_turn
+                those = functools.partial(
+                    lax.dynamic_slice_in_dim, start_index=at, slice_size=a_turn)
+                prefix = lambda cache: lax.dynamic_slice(
+                    cache, (at, 0, 0, 0), (a_turn, heads, live, dim))
+                return lax.dynamic_update_slice_in_dim(
+                    attn, attend(those(q), prefix(k), prefix(v), those(pos)),
+                    at, 0)
+
+            return lax.fori_loop(0, -(-occupied // a_turn), turn,
+                                 jnp.zeros((slots, heads, dim), f32))
+
+        return read
+
+    def every_slot(q, k, v, pos, active):
+        return jax.vmap(one)(q, k, v, pos)
+
+    return dict(zip(ROUND_FORMS, (turns(jax.vmap(one)), every_slot,
+                                  turns(on_the_matrix_unit))))
+
+
+def slots_read(form, slots, members):
+    """The slots whose caches a round form reads, of a table of ``slots``
+    with its ``members`` lowest slots occupied."""
+    from client_tpu.models.decoder import in_whole_turns
+
+    return slots if form == "every_slot" else in_whole_turns(slots, members)
+
+
+def _top_rung(read):
+    """A top-rung form as ``layered`` calls a read: every slot a member."""
+    return read and (lambda q, k, v, pos, active: read(q, k, v, pos))
+
+
 def layered(jax, jnp, lax, read):
     """One dispatch: the layers of a round without their arithmetic. Each
     makes a query and a member's rows from the running state, writes the
@@ -188,7 +284,7 @@ def layered(jax, jnp, lax, read):
             if read is None:
                 attn = q.astype(jnp.float32)
             else:
-                attn = read(q, *caches, pos)
+                attn = read(q, *caches, pos, active)
                 attns.append(attn)
             y = attn.reshape(slots, heads * dim).astype(jnp.bfloat16) @ rest
             x = x + y.reshape(slots, -1, heads * dim).sum(axis=1).astype(
@@ -198,11 +294,12 @@ def layered(jax, jnp, lax, read):
     return jax.jit(dispatch, donate_argnums=0)
 
 
-def _operands(jax, jnp, np, shape, layers, seed=0):
+def _operands(jax, jnp, np, shape, layers, seed=0, reach=None, members=None):
     """``row_write_chip``'s tables, a layer's weights (the query's and the
     rows' 3 d^2 and 9 d^2 more, as the GPT-2 block has 12 d^2) made on the
-    device, a running state, every slot a member at positions across the
-    table."""
+    device, a running state, the ``members`` lowest slots members (every
+    slot, unless given) at positions across the first ``reach`` of the
+    table (all of it, unless given)."""
     tables, _, _, _ = row_write_chip._operands(
         jnp, np, shape, layers, shape[0], seed)
     slots, heads, length, dim = shape
@@ -214,9 +311,10 @@ def _operands(jax, jnp, np, shape, layers, seed=0):
                 draw(keys[2 * n + 1], width, 9 * width))
                for n in range(layers)]
     x = draw(keys[-1], slots, width) * width ** 0.5
-    pos = jnp.asarray((length // 2 + 37 * np.arange(slots)) % length,
-                      jnp.int32)
-    return tables, weights, x, pos, jnp.ones((slots,), bool)
+    reach = reach or length
+    pos = jnp.asarray((reach // 2 + 37 * np.arange(slots)) % reach, jnp.int32)
+    members = slots if members is None else members
+    return tables, weights, x, pos, jnp.asarray(np.arange(slots) < members)
 
 
 def check_agreement(jax, jnp, np, lax, tables):
@@ -227,7 +325,8 @@ def check_agreement(jax, jnp, np, lax, tables):
         got = {}
         for form_name in FORMS:
             operands = _operands(jax, jnp, np, shape, 1)
-            _, attn = layered(jax, jnp, lax, made[form_name])(*operands)
+            _, attn = layered(jax, jnp, lax,
+                              _top_rung(made[form_name]))(*operands)
             got[form_name] = np.asarray(attn, np.float64)
         scale = np.abs(got["parent"]).max()
         case = {"table": name, "shape": list(shape)}
@@ -248,28 +347,93 @@ def bench_forms(jax, jnp, np, lax, tables, repeats):
         slots, heads, length, dim = shape
         read_bytes = 2 * slots * heads * length * dim * 2 * layers
         for form_name in ("rows_and_weights",) + FORMS:
-            program = layered(jax, jnp, lax, made.get(form_name))
+            program = layered(jax, jnp, lax, _top_rung(made.get(form_name)))
             operands = _operands(jax, jnp, np, shape, layers)
             row = {"table": name, "shape": list(shape), "layers": layers,
                    "form": form_name}
-            try:
-                caches, rest = operands[0], operands[1:]
-                caches, attn = program(caches, *rest)
-                jax.block_until_ready((caches, attn))  # compiled and warm
-                times = []
-                for _ in range(repeats):
-                    t0 = time.perf_counter()
-                    caches, attn = program(caches, *rest)
-                    jax.block_until_ready((caches, attn))
-                    times.append(time.perf_counter() - t0)
-                ms = sorted(times)[len(times) // 2] * 1000
-                row["ms_a_dispatch"] = round(ms, 4)
-                if form_name != "rows_and_weights":
-                    row["read_gb_s"] = round(read_bytes / ms / 1e6, 1)
-                del caches, attn
-            except Exception as e:
-                row["error"] = f"{type(e).__name__}: {e}"[:300]
+            ms = _time(jax, program, operands, repeats, row)
+            if ms and form_name != "rows_and_weights":
+                row["read_gb_s"] = round(read_bytes / ms / 1e6, 1)
             out.append(row)
+    return out
+
+
+def _time(jax, program, operands, repeats, row):
+    """The median of ``repeats`` dispatches of ``program`` after a first, in
+    ms, also into ``row``; an error in ``row`` and None where it fails."""
+    try:
+        caches, rest = operands[0], operands[1:]
+        caches, attn = program(caches, *rest)
+        jax.block_until_ready((caches, attn))  # compiled and warm
+        times = []
+        for _ in range(repeats):
+            t0 = time.perf_counter()
+            caches, attn = program(caches, *rest)
+            jax.block_until_ready((caches, attn))
+            times.append(time.perf_counter() - t0)
+        ms = sorted(times)[len(times) // 2] * 1000
+        row["ms_a_dispatch"] = round(ms, 4)
+        del caches, attn
+        return ms
+    except Exception as e:
+        row["error"] = f"{type(e).__name__}: {e}"[:300]
+        return None
+
+
+def check_round_agreement(jax, jnp, np, lax, tables):
+    """Each round form's attention of the members after one dispatch
+    against ``slot_turns``'s, at every number of members."""
+    cases, ok = [], True
+    for name, (shape, _, live, memberships) in tables.items():
+        made = round_forms(jax, jnp, lax, live)
+        for members in memberships:
+            got = {}
+            for form_name in ROUND_FORMS:
+                operands = _operands(jax, jnp, np, shape, 1, reach=live,
+                                     members=members)
+                _, attn = layered(jax, jnp, lax, made[form_name])(*operands)
+                got[form_name] = np.asarray(attn, np.float64)[:, :members]
+            scale = np.abs(got["slot_turns"]).max()
+            case = {"table": name, "shape": list(shape), "live": live,
+                    "members": members}
+            for form_name in ROUND_FORMS[1:]:
+                worst = float(
+                    np.abs(got[form_name] - got["slot_turns"]).max() / scale)
+                case[form_name] = {"agrees": worst <= AGREE, "worst": worst}
+                ok = ok and worst <= AGREE
+            cases.append(case)
+    return {"ok": ok, "cases": cases}
+
+
+def bench_round_forms(jax, jnp, np, lax, tables, repeats):
+    """The median dispatch of every round form, and of the rows and weights
+    alone, at every number of members; a form's read is its dispatch less
+    the rows and weights', and its GB/s are of the slots it reads over
+    that."""
+    out = []
+    for name, (shape, layers, live, memberships) in tables.items():
+        made = round_forms(jax, jnp, lax, live)
+        slots, heads, _, dim = shape
+        for members in memberships:
+            rest = None
+            for form_name in ("rows_and_weights",) + ROUND_FORMS:
+                program = layered(jax, jnp, lax, made.get(form_name))
+                operands = _operands(jax, jnp, np, shape, layers, reach=live,
+                                     members=members)
+                row = {"table": name, "shape": list(shape), "layers": layers,
+                       "live": live, "members": members, "form": form_name}
+                ms = _time(jax, program, operands, repeats, row)
+                if form_name == "rows_and_weights":
+                    rest = ms
+                else:
+                    row["slots_read"] = slots_read(form_name, slots, members)
+                    if ms and rest:
+                        row["read_ms"] = round(ms - rest, 4)
+                    if ms and rest and ms > rest:
+                        row["read_gb_s"] = round(
+                            2 * row["slots_read"] * heads * live * dim * 2
+                            * layers / (ms - rest) / 1e6, 1)
+                out.append(row)
     return out
 
 
@@ -280,15 +444,19 @@ def run(small: bool, repeats: int = 15):
     from jax import lax
 
     tables = SMALL_TABLES if small else TABLES
+    round_tables = SMALL_ROUND_TABLES if small else ROUND_TABLES
     device = jax.devices()[0]
     result = {"platform": jax.default_backend(),
               "device_kind": device.device_kind}
-    try:
-        result["agreement"] = check_agreement(jax, jnp, np, lax, tables)
-    except Exception as e:
-        result["agreement"] = {
-            "ok": False, "error": f"{type(e).__name__}: {e}"[:500]}
-    result["forms"] = bench_forms(jax, jnp, np, lax, tables, repeats)
+    for key, check, bench, of in (
+            ("", check_agreement, bench_forms, tables),
+            ("round_", check_round_agreement, bench_round_forms, round_tables)):
+        try:
+            result[key + "agreement"] = check(jax, jnp, np, lax, of)
+        except Exception as e:
+            result[key + "agreement"] = {
+                "ok": False, "error": f"{type(e).__name__}: {e}"[:500]}
+        result[key + "forms"] = bench(jax, jnp, np, lax, of, repeats)
     return result
 
 
@@ -296,8 +464,8 @@ def main(argv=None):
     parser = argparse.ArgumentParser()
     parser.add_argument("--json-out", default=None)
     parser.add_argument("--small", action="store_true",
-                        help="a table of four slots, two layers: a pipeline "
-                        "check off the chip, no number of the chip's")
+                        help="tables of four and eight slots, two layers: a "
+                        "pipeline check off the chip, no number of the chip's")
     parser.add_argument("--repeats", type=int, default=15,
                         help="timed dispatches a form; the median is reported")
     args = parser.parse_args(argv)
@@ -308,7 +476,9 @@ def main(argv=None):
     if args.json_out:
         with open(args.json_out, "w") as f:
             f.write(text + "\n")
-    return 0 if result["agreement"].get("ok") else 1
+    agreed = all(result[key].get("ok")
+                 for key in ("agreement", "round_agreement"))
+    return 0 if agreed else 1
 
 
 if __name__ == "__main__":
