@@ -90,7 +90,9 @@ class RungCount:
     keeps a window beside summaries of what came before it, the rows of each
     kind its decode steps attended to and the summaries its tokens completed
     (``window_rows_read``, ``summary_rows_read``, ``summaries_written``), all
-    counted on the host from positions."""
+    counted on the host from positions; and, for a decoder whose dispatches
+    tally on the device the experts their grouped products read, those and
+    the dispatches, by program (``reached``)."""
 
     TOTALS = ("selecting_steps", "prefill_tokens", "prefill_chunks", "prefill_ns")
     ROWS = ("window_rows_read", "summary_rows_read", "summaries_written")
@@ -100,6 +102,7 @@ class RungCount:
         self._steps: Dict[int, int] = {}
         self._totals = dict.fromkeys(self.TOTALS, 0)
         self._rows = dict.fromkeys(self.ROWS, 0)
+        self._reached: Dict[str, Tuple[int, int]] = {}
 
     def add(self, live: int) -> None:
         with self._lock:
@@ -124,6 +127,13 @@ class RungCount:
             for name, n in zip(self.ROWS, (window, summary, written)):
                 self._rows[name] += n
 
+    def add_reached(self, program: str, experts: int, dispatches: int) -> None:
+        """``dispatches`` of ``program`` whose routed layers read ``experts``
+        experts, summed over the layers."""
+        with self._lock:
+            reached, n = self._reached.get(program, (0, 0))
+            self._reached[program] = (reached + experts, n + dispatches)
+
     def by_rung(self) -> Dict[int, int]:
         with self._lock:
             return dict(self._steps)
@@ -135,6 +145,12 @@ class RungCount:
     def rows(self) -> Dict[str, int]:
         with self._lock:
             return dict(self._rows)
+
+    def reached(self) -> Dict[str, Tuple[int, int]]:
+        """``{program: (experts, dispatches)}``, empty for a decoder that
+        tallies none."""
+        with self._lock:
+            return dict(self._reached)
 
 
 class TinyDecoderModel(Model):

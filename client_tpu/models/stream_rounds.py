@@ -156,7 +156,11 @@ class StreamRounds:
         self._decoder = decoder = model._decoder
         self.slots = int(model.slots)
         self._caches = decoder._fresh_table(self.slots)
-        self._fed = jnp.zeros((self.slots,), jnp.int32)
+        # a decoder may keep a tally of its own behind the slots' choices
+        # (``decoder.fed_tally`` int32, running sums that wrap), which comes
+        # back with them and goes to ``decoder.count_tally`` by difference
+        self._tally = getattr(decoder, "fed_tally", 0)
+        self._fed = jnp.zeros((self.slots + self._tally,), jnp.int32)
         self._arrivals: "queue.SimpleQueue[Optional[Stream]]" = queue.SimpleQueue()
         self._taking = True
         # the worker's own: the rounds' members by slot, the seated streams
@@ -185,6 +189,8 @@ class StreamRounds:
                 self._chunk(np.zeros(decoder._slot_prefill_chunk, np.int32),
                             np.zeros(5, np.int32), live)
         jax.block_until_ready(self._fed)  # (under ``eval_shape`` a shape)
+        if self._tally:  # what the set-up's dispatches tallied is not counted
+            self._tallied = np.asarray(self._fed)[self.slots:]
         self._worker = threading.Thread(
             target=self._run, name="stream-rounds", daemon=True)
         self._worker.start()
@@ -388,6 +394,12 @@ class StreamRounds:
             fed.block_until_ready()
         with span(SPAN_READBACK, into=phases) as readback:
             chosen = np.asarray(fed)
+            if self._tally:
+                tally = chosen[self.slots:]
+                self._decoder.count_tally(
+                    self._model.steps_by_rung,
+                    tally.astype(np.uint32) - self._tallied.astype(np.uint32))
+                self._tallied = tally
         with span(SPAN_HAND_OUT, into=phases):
             self._in_flight.popleft()
             for stream, slot, index in gives:
@@ -443,4 +455,5 @@ class StreamRounds:
                 leaf.is_deleted() for leaf in jax.tree_util.tree_leaves(
                     (self._caches, self._fed))):
             self._caches = self._decoder._fresh_table(self.slots)
-            self._fed = jnp.zeros((self.slots,), jnp.int32)
+            self._fed = jnp.zeros((self.slots + self._tally,), jnp.int32)
+            self._tallied = np.zeros(self._tally, np.int32)

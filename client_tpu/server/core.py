@@ -630,6 +630,18 @@ class ServerCore:
                 "steps", ("model",)),
         }
 
+        # what a decoder's routed layers read (RungCount.reached): a series
+        # a served model and program only where its dispatches tally it
+        experts_reached = reg.gauge(
+            "client_tpu_server_experts_reached",
+            "Distinct experts the grouped products of a program's routed "
+            "layers read, summed over the layers and the dispatches",
+            ("model", "program"))
+        experts_reached_rounds = reg.gauge(
+            "client_tpu_server_experts_reached_rounds",
+            "Dispatches of the program over which experts_reached is summed",
+            ("model", "program"))
+
         # the turns of a model's round worker by phase (timeline.PHASES), and
         # what its requests read of the rounds (Timeline.readings): a pair
         # of series each, a sum and the count it is over
@@ -695,6 +707,9 @@ class ServerCore:
                         decode_steps.labels(name, rung).set(steps)
                     for total, value in {**count.totals(), **count.rows()}.items():
                         decoder_totals[total].labels(name).set(value)
+                    for program, (reached, n) in count.reached().items():
+                        experts_reached.labels(name, program).set(reached)
+                        experts_reached_rounds.labels(name, program).set(n)
                 rounds = getattr(model, "rounds_by_width", None)
                 if rounds:  # it has run rounds: the slot table is in use
                     for width, n in dict(rounds).items():
