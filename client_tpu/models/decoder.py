@@ -55,6 +55,13 @@ SLOTS_A_TURN = 4
 LANES = 128
 
 
+def heads_a_row(heads: int, head_dim: int) -> int:
+    """The heads that one row of a table of caches holds side by side: as
+    many as fill the ``LANES``, and a divisor of ``heads``; one where a head
+    fills them alone."""
+    return math.gcd(heads, max(1, LANES // head_dim))
+
+
 def slots_a_turn(slots: int) -> int:
     """The slots a turn of a round's attention takes of a table of
     ``slots``: the turns are whole, so it divides them."""
@@ -263,36 +270,31 @@ class TinyDecoderModel(Model):
             return ((x32 - mu) * lax.rsqrt(var + 1e-5)).astype(x.dtype)
 
         def write_rows(caches, rows, pos):
-            """``caches`` (k, v), each [H, M, Dh], with ``rows``, each
-            [H, 1, Dh], at ``pos``."""
+            """``caches`` (k, v), each laid as ``_fresh_cache`` or a slot of
+            ``_fresh_table`` lays it, with ``rows``, each laid alike one
+            position long (``as_rows``), at ``pos``."""
             return tuple(lax.dynamic_update_slice(cache, row, (0, pos, 0))
                          for cache, row in zip(caches, rows))
 
         def write_table_rows(caches, rows, pos, active):
-            """Stacked caches (k, v), each [slots, H, M, Dh], with ``rows``,
-            each [W, H, 1, Dh], of the table's leading W slots at ``pos``
-            [W], where ``active`` [W]: one turn an active slot, each writing
-            that slot's two rows into the donated buffers where they lie, so
-            the work follows the number of active slots and an inactive one
-            (a full one, a freed one) is not touched at all.
+            """Stacked caches (k, v), each [slots, H/P, M, P x Dh] as
+            ``_fresh_table`` lays them, with ``rows``, each [W, H/P, 1,
+            P x Dh], of the table's leading W slots at ``pos`` [W], where
+            ``active`` [W]: one turn an active slot, each writing that
+            slot's two rows into the donated buffers where they lie, so the
+            work follows the number of active slots and an inactive one (a
+            full one, a freed one) is not touched at all.
 
-            How a turn writes a row goes by how the chip lays the table.
-            Where a row fills a tile's lanes (Dh of 128) it is a tile's row,
-            and the turn updates the row alone: 0.16 ms a member a round of
-            24 layers. Rows narrower than the lanes are laid with their
-            *positions* on the lanes: one position of one slot is a lane of
-            H x Dh / 16 tiles, an update of the row alone costs 6.7 us
-            there (7.8 of a 12.5 ms round of sixteen members), and the turn
-            writes it through its window instead: the aligned ``LANES``
-            positions round the row are read, the row is put into them by a
-            select and they are written back where they were read: whole
-            tiles, which the compiler fuses into one update in place, 2.4 us
-            a row (PERF.md section 6, PR 35). The window's start is unsigned
-            and masked, not divided and not clamped, so that the compiler
-            knows it aligned. Nothing is masked over a cache: for that the
-            compiler lays every stacked cache out anew and back (PR 26)."""
+            The table holds ``P`` heads a row of the chip's ``LANES``
+            (``heads_a_row``), so that a position's row of a slot fills a
+            tile's lanes at every head width: it is one sublane of H x Dh /
+            128 tiles, and the turn updates it alone, 1.05 us a row at
+            heads of 128 (PERF.md section 6). (Laid a head a row, rows of 64
+            have their *positions* on the lanes, where an update of the row
+            alone costs 6.7 us and one of the aligned 128 positions round it
+            2.4.) Nothing is masked over a cache: for that the compiler lays
+            every stacked cache out anew and back."""
             active_first = jnp.argsort(~active, stable=True)
-            width = jnp.sum(active, dtype=jnp.int32)
 
             def write(turn, caches):
                 slot = active_first[turn]
@@ -303,38 +305,8 @@ class TinyDecoderModel(Model):
                         (slot, 0, pos[slot], 0))
                     for cache, slot_rows in zip(caches, rows))
 
-            if Dh >= LANES:
-                return lax.fori_loop(0, width, write, caches)
-
-            length = caches[0].shape[2]
-            # a length that whole windows do not cover is one window
-            span = LANES if length % LANES == 0 else length
-            aligned = jnp.uint32(-span % 2 ** 32 if span < length else 0)
-            lanes = jnp.arange(span, dtype=jnp.uint32)
-            zero = jnp.uint32(0)
-            # by turn, so that a turn reads its slot and its position and
-            # nothing through them
-            slots = active_first.astype(jnp.uint32)
-            ats = jnp.minimum(pos, length - 1).astype(jnp.uint32)[active_first]
-
-            def write_window(turn, caches):
-                slot, at = slots[turn], ats[turn]
-                start = at & aligned
-                window = (slot, zero, start, zero)
-                hit = (lanes == at - start)[None, None, :, None]
-                return tuple(
-                    lax.dynamic_update_slice(
-                        cache,
-                        jnp.where(
-                            hit,
-                            lax.dynamic_index_in_dim(
-                                slot_rows, slot, keepdims=True),
-                            lax.dynamic_slice(
-                                cache, window, (1, H, span, Dh))),
-                        window)
-                    for cache, slot_rows in zip(caches, rows))
-
-            return lax.fori_loop(0, width, write_window, caches)
+            return lax.fori_loop(0, jnp.sum(active, dtype=jnp.int32), write,
+                                 caches)
 
         @jax.custom_batching.custom_vmap
         def write_slot_rows(caches, rows, pos, active):
@@ -368,6 +340,60 @@ class TinyDecoderModel(Model):
                 return (q.reshape(H, Dh), k_new.reshape(H, 1, Dh),
                         v_new.reshape(H, 1, Dh))
 
+        def as_rows(new, cache):
+            """A token's new rows [.., H, 1, Dh] laid as ``cache`` [.., H/P,
+            M, P x Dh] lays a position: heads ``P n`` to ``P n + P - 1`` lie
+            side by side in ``qkv``'s output, so this is a reshape."""
+            return new.reshape(new.shape[:-3] + (cache.shape[-3], 1,
+                                                 cache.shape[-1]))
+
+        # How a product reads a cache that holds ``P`` heads a row
+        # (``_fresh_table``, ``heads_a_row``): the query of each head takes
+        # its own Dh lanes of a [H/P, P, P x Dh] operand, zeros elsewhere,
+        # and one product contracts the whole row; the weighing gives every
+        # head the whole row, and each keeps its own lanes. The bytes read
+        # are the cache's, where it lies; the zeros add exact zeros. Such a
+        # product is the matrix unit's, which takes float32 in bfloat16
+        # passes at the default precision: on a v5e it read 1e-3 to 2e-3 off
+        # a head's own products of a head a row, which are float32 (PERF.md
+        # section 6). So it is at ``HIGHEST``: their float32 math. At one
+        # head a row (P of 1) the products are the plain ones at
+        # ``precision``. ``at`` is the einsum letters of the dimensions
+        # before the heads.
+        highest = lax.Precision.HIGHEST
+
+        def scored(at, q32, k32, precision=None):
+            """``q32`` [.., H, Dh] against ``k32`` [.., H/P, m, P x Dh]:
+            [.., H, m]."""
+            if k32.shape[-1] == Dh:
+                return jnp.einsum(f"{at}hd,{at}hmd->{at}hm", q32, k32,
+                                  precision=precision)
+            rows, P, lead = k32.shape[-3], k32.shape[-1] // Dh, q32.shape[:-2]
+            own = np.eye(P, dtype=np.float32)[:, :, None]
+            spread = (q32.reshape(lead + (rows, P, 1, Dh)) * own).reshape(
+                lead + (rows, P, P * Dh))
+            return jnp.einsum(f"{at}hjc,{at}hmc->{at}hjm", spread, k32,
+                              precision=highest).reshape(
+                                  lead + (H, k32.shape[-2]))
+
+        def weighed(at, probs, v32, precision=None):
+            """``probs`` [.., H, m] over ``v32`` [.., H/P, m, P x Dh]:
+            [.., H, Dh]."""
+            if v32.shape[-1] == Dh:
+                return jnp.einsum(f"{at}hm,{at}hmd->{at}hd", probs, v32,
+                                  precision=precision)
+            rows, P, lead = v32.shape[-3], v32.shape[-1] // Dh, probs.shape[:-2]
+            whole = jnp.einsum(f"{at}hjm,{at}hmc->{at}hjc", probs.reshape(
+                lead + (rows, P, probs.shape[-1])), v32, precision=highest)
+            # each head keeps its own lanes by a select and a sum over the
+            # heads, not by slices: the chip's compiler gave the second
+            # head of a row the first one's lanes where they were sliced,
+            # and served tokens 3.5 to 5 under the reference's best logit
+            # (PERF.md section 6)
+            own = np.arange(P * Dh) // Dh == np.arange(P)[:, None]
+            return jnp.sum(jnp.where(own, whole, 0.0), axis=-2).reshape(
+                lead + (H, Dh))
+
         def attention(q, k, v, pos, *, live):
             """The attention of one token over a sequence's cache ``k``,
             ``v`` with the row at ``pos`` written: [H, Dh]."""
@@ -382,14 +408,14 @@ class TinyDecoderModel(Model):
                 # position-based mask: only slots <= pos attend, and they
                 # lie in the prefix (the row just written at ``pos`` among
                 # them)
-                scores = jnp.einsum(
-                    "hd,hmd->hm", q.astype(jnp.float32),
+                scores = scored(
+                    "", q.astype(jnp.float32),
                     k[:, :live].astype(jnp.float32)) * (Dh ** -0.5)
                 mask = jnp.arange(live) <= pos
                 scores = jnp.where(mask[None, :], scores, -jnp.inf)
                 probs = jax.nn.softmax(scores, axis=-1)
-                return jnp.einsum(
-                    "hm,hmd->hd", probs, v[:, :live].astype(jnp.float32))
+                return weighed(
+                    "", probs, v[:, :live].astype(jnp.float32))
 
         @jax.custom_batching.custom_vmap
         def read_slot(q, k, v, pos):
@@ -421,15 +447,11 @@ class TinyDecoderModel(Model):
                     return lax.dynamic_slice_in_dim(
                         cache, n * half, half, axis=2).astype(jnp.float32)
 
-                def product(spec, x, y):
-                    return jnp.einsum(spec, x, y,
-                                      precision=lax.Precision.HIGHEST)
-
                 q32 = q.astype(jnp.float32)
 
                 def score(n, scores):
                     return lax.dynamic_update_slice_in_dim(
-                        scores, product("shd,shmd->shm", q32, rows(k, n)),
+                        scores, scored("s", q32, rows(k, n), highest),
                         n * half, axis=2)
 
                 scores = lax.fori_loop(
@@ -440,10 +462,10 @@ class TinyDecoderModel(Model):
                 probs = jax.nn.softmax(scores, axis=-1)
 
                 def weigh(n, attn):
-                    return attn + product(
-                        "shm,shmd->shd",
+                    return attn + weighed(
+                        "s",
                         lax.dynamic_slice_in_dim(probs, n * half, half, axis=2),
-                        rows(v, n))
+                        rows(v, n), highest)
 
                 return lax.fori_loop(
                     0, 2, weigh, jnp.zeros((slots, H, Dh), jnp.float32)), True
@@ -466,7 +488,8 @@ class TinyDecoderModel(Model):
             written."""
             q, k_new, v_new = qkv_rows(layer, x)
             with jax.named_scope("cache_update"):
-                held, rows = (cache["k"], cache["v"]), (k_new, v_new)
+                held = cache["k"], cache["v"]
+                rows = as_rows(k_new, held[0]), as_rows(v_new, held[1])
                 k, v = (write_rows(held, rows, pos) if active is None
                         else write_slot_rows(held, rows, pos, active))
             if (active is None or live < M
@@ -537,7 +560,8 @@ class TinyDecoderModel(Model):
                     lax.dynamic_slice_in_dim, start_index=at,
                     slice_size=a_turn)
                 prefix = lambda cache: lax.dynamic_slice(
-                    cache, (at, 0, 0, 0), (a_turn, H, live, Dh))
+                    cache, (at, 0, 0, 0),
+                    (a_turn, cache.shape[1], live, cache.shape[3]))
                 return lax.dynamic_update_slice_in_dim(
                     attn, attend(those(q), prefix(k), prefix(v), those(pos)),
                     at, 0)
@@ -551,23 +575,24 @@ class TinyDecoderModel(Model):
             parts over the slots, round the one row write that takes the
             whole table where it lies. The products with the weights take
             every slot (a slot more costs them nothing: they read the
-            weights). How the attention takes the slots goes by how the chip
-            lays the table (``write_table_rows``). Where a row fills a
-            tile's lanes it takes the occupied slots in turns
-            (``slot_turns``): a round costs the caches of its live streams,
-            in width as the rung makes it in length, in one program a rung.
-            Where rows are narrower, the chip's compiler sets each turn's
-            slice aside in fast memory and lays it out anew before the
-            products read it (sixteen slots' 256 positions a round of 36
-            layers took 3.0 ms so on a v5e, 250 GB/s), and the attention
-            reads every slot's prefix where it lies instead, as the slot
-            batcher does (``one_layer``, ``read_slot`` at the top rung):
-            1.35 ms for the same bytes, occupied slots or not (PERF.md
-            section 6). ``slots_read`` says which slots a round read."""
+            weights). How the attention takes the slots goes by the head's
+            width. Where a head fills a tile's lanes it takes the occupied
+            slots in turns (``slot_turns``): a round costs the caches of its
+            live streams, in width as the rung makes it in length, in one
+            program a rung. Where heads are narrower (a row of the table
+            holds several, ``_fresh_table``), the attention reads every
+            slot's prefix where it lies, as the slot batcher does
+            (``one_layer``, ``read_slot`` at the top rung): for sixteen
+            members that read 1.56 ms of a round of 36 layers on a v5e and
+            the turns 2.34 (laid a head a row, the compiler set each turn's
+            slice aside and laid it out anew: 3.0 ms; PERF.md section 6).
+            ``slots_read`` says which slots a round read."""
             q, k_new, v_new = over_slots(qkv_rows, (None, 0))(layer, x)
             with jax.named_scope("cache_update"):
-                k, v = write_table_rows((cache["k"], cache["v"]),
-                                        (k_new, v_new), pos, active)
+                k, v = write_table_rows(
+                    (cache["k"], cache["v"]),
+                    (as_rows(k_new, cache["k"]), as_rows(v_new, cache["v"])),
+                    pos, active)
             if Dh < LANES:
                 read = (functools.partial(attention, live=live) if live < M
                         else read_slot)
@@ -719,11 +744,17 @@ class TinyDecoderModel(Model):
         ]
 
     def _fresh_table(self, slots: int):
-        """``slots`` caches, stacked: [slots, heads, max_len, head_dim] a
-        layer, zeros."""
+        """``slots`` caches, stacked: [slots, heads / P, max_len, P x
+        head_dim] a layer, zeros, a position's row of a slot ``P =
+        heads_a_row(heads, head_dim)`` heads side by side, so that it fills
+        the chip's lanes (``write_table_rows``). The Pallas kernel takes a
+        head a row."""
         import jax.numpy as jnp
 
-        shape = (slots, self.HEADS, self.MAX_LEN, self.D_MODEL // self.HEADS)
+        Dh = self.D_MODEL // self.HEADS
+        P = (heads_a_row(self.HEADS, Dh) if self._attention_impl == "einsum"
+             else 1)
+        shape = (slots, self.HEADS // P, self.MAX_LEN, P * Dh)
         return [{"k": jnp.zeros(shape, jnp.bfloat16),
                  "v": jnp.zeros(shape, jnp.bfloat16)}
                 for _ in range(self.LAYERS)]

@@ -10,12 +10,13 @@ regime batching exists for, since an [S, ...] step costs barely more than
 a [1, ...] step until S fills the MXU tile.
 
 ``decoder_lm_batched`` is the TPU-first version: per-slot KV caches live
-stacked on device ([slots, heads, max_len, head_dim] per layer), and ONE
-jitted batched step (``jax.vmap`` of the decoder's single-sequence step —
-the identical math, so tokens are bit-comparable) advances every sequence
-that has a request in progress. The step owns the stacked caches (they are
-donated to it) and writes one [heads, 1, head_dim] row a layer for every
-active slot, in place.
+stacked on device (decoder.py's ``_fresh_table``: [slots, heads / P,
+max_len, P x head_dim] per layer, P heads side by side a row so that a row
+fills the chip's lanes), and ONE jitted batched step (``jax.vmap`` of the
+decoder's single-sequence step, which reads a cache as it is laid) advances
+every sequence that has a request in progress. The step owns the stacked
+caches (they are donated to it) and writes one position's row a layer for
+every active slot, in place.
 
 **The unit of scheduling is the round**, one dispatch of that step. The
 worker keeps one table of the requests in progress, one a sequence. Before

@@ -205,21 +205,60 @@ def _cache_updates(text, shape):
     return found
 
 
-def _is_written_by_windows(text, dims, layers):
-    """A table's rows go in as ``write_table_rows`` says: where a row is
-    narrower than the lanes, through the aligned window of ``LANES``
-    positions round it, read, selected into and written back by one fusion
-    in place (an update of a row alone is the 6.7 us one); where it fills
-    them, a row at a time."""
+def _is_written_a_row_at_a_time(text, dims, layers):
+    """A table's rows go in as ``write_table_rows`` says: the table holds as
+    many heads a row as fill the lanes, [slots, H x Dh / 128, M, 128], and
+    every update is one position's row of one slot, [1, H x Dh / 128, 1,
+    128], at every head width: no window of positions round it (what a
+    table a head a row needed at heads of 64) and nothing wider."""
     from client_tpu.models.decoder import LANES
 
-    slots, heads, length, dim = dims
+    slots, rows, length, width = dims
+    assert width == LANES, dims
     updates = _cache_updates(text, ",".join(str(n) for n in dims))
     assert len(updates) == 2 * layers, updates
-    if dim < LANES:
-        assert set(updates) == {((1, heads, LANES, dim), True)}, updates
-    else:
-        assert {update for update, _ in updates} == {(1, heads, 1, dim)}
+    assert {update for update, _ in updates} == {(1, rows, 1, width)}, updates
+
+
+def _reads_heads_side_by_side_exactly(text, head):
+    """Where a table holds heads side by side (heads narrower than the
+    lanes), the products that read it (``decoder.py:scored``, ``weighed``:
+    each head's query in its own lanes, one product over the row) are at
+    ``HIGHEST``: at the default precision the chip's matrix unit takes their
+    float32 in bfloat16 passes, 1e-3 to 2e-3 off a head's own float32
+    products. And the weighing gives the whole row, and no bitcast changes
+    how many elements an array holds: where each head's lanes were cut out
+    of the weighing by slices, the compiler gave the second head the first
+    one's lanes, by a bitcast of [.., 2, 128] to [.., 2, 64] or by a
+    product of 64 lanes, and the cells served tokens 3.5 to 5 under the
+    reference's best logit."""
+    import math
+    import re
+
+    from client_tpu.models.decoder import LANES
+
+    sizes = {name: dims for name, dims in re.findall(
+        r"%([\w.-]+) = \w+\[([0-9,]*)\]", text)}
+    count = lambda dims: math.prod(int(n) for n in dims.split(",") if n)
+    resized = [(name, dims, sizes.get(source)) for name, dims, source in
+               re.findall(r"%([\w.-]+) = \w+\[([0-9,]*)\]\{[^}]*\} "
+                          r"bitcast\(%([\w.-]+)\)", text)
+               if source in sizes and count(dims) != count(sizes[source])]
+    assert not resized, resized[:2]
+
+    products = [line for line in text.splitlines()
+                if re.search(r"= \S+ (?:convolution|dot)\(", line)
+                and re.search(r"hj[cm],s?hmc->s?hj[cm]", line)]
+    if head >= LANES:
+        assert not products, products[:1]
+        return
+    assert products, "no product over a row of heads side by side"
+    slow = [p for p in products
+            if "operand_precision={highest,highest}" not in p]
+    assert not slow, f"{len(slow)} of {len(products)}: {slow[0][:300]}"
+    narrow = [p for p in products if re.search(r"hjm,s?hmc", p)
+              and not re.search(rf"= \w+\[[0-9,]*,{LANES}\]", p)]
+    assert not narrow, narrow[0][:300]
 
 
 @pytest.mark.parametrize("program, head, live", [
@@ -242,8 +281,9 @@ def test_the_step_writes_its_donated_caches_in_place_on_the_chip(
     cache is moved through fast memory ahead of the read. The batcher reads
     its caches where they lie at its top rung too (its one product a stacked
     cache had every cache staged through fast memory and back: PR 37). The
-    batcher's rows go in through aligned windows where a row is narrower
-    than the lanes."""
+    batcher's rows go in a position's row of a slot at a time, as many heads
+    a row as fill the lanes, and the products over such rows read each
+    head's float32 math."""
     import re
 
     from client_tpu.models.decoder_batched import BatchedDecoderModel
@@ -275,7 +315,8 @@ def test_the_step_writes_its_donated_caches_in_place_on_the_chip(
     relaid = re.findall(rf"= bf16\[{shape}\]\{{[^}}]*\}} copy\(", entry)
     assert not relaid, f"{len(relaid)} whole caches copied to another layout"
     if program == "jit_batched_step":
-        _is_written_by_windows(text, dims, decoder.LAYERS)
+        _is_written_a_row_at_a_time(text, dims, decoder.LAYERS)
+        _reads_heads_side_by_side_exactly(text, head)
     if live < decoder.MAX_LEN:
         prefix = ",".join(str(n) for n in dims[:-2] + (live, dims[-1]))
         sliced = re.findall(rf"= bf16\[{prefix}\]\{{[^}}]*\}} [a-z-]+\(", entry)
@@ -310,14 +351,15 @@ def test_the_round_writes_its_table_in_place_and_reads_it_where_it_lies(
     every stacked cache aliased to an output and none laid out anew; and
     what its attention reads, a prefix of the slots' positions, is read from
     the table as it lies: nothing longer or wider is cut out of it. Where a
-    row fills the lanes, the slots of a turn may be set aside in fast memory
-    and nowhere else; where rows are narrower (there the compiler laid each
-    turn's slice out anew, 3.0 of a 7.0 ms round on a v5e), nothing of the
-    table is set aside at all. Its rows go in through aligned windows where
-    a row is narrower than the lanes."""
+    head fills the lanes, the slots of a turn may be set aside in fast
+    memory and nowhere else; where heads are narrower (there, a head a row,
+    the compiler laid each turn's slice out anew, 3.0 of a 7.0 ms round on a
+    v5e), nothing of the table is set aside at all. Its rows go in a
+    position's row of a slot at a time, at every head width, and the
+    products over heads side by side read each head's float32 math."""
     import re
 
-    from client_tpu.models.decoder import LANES, SLOTS_A_TURN
+    from client_tpu.models.decoder import LANES, SLOTS_A_TURN, heads_a_row
 
     decoder = _published_widths_decoder(head)
     caches = decoder._fresh_table(16)
@@ -332,7 +374,9 @@ def test_the_round_writes_its_table_in_place_and_reads_it_where_it_lies(
     run = _outside_fusions(text)
     slots, heads, length, dim = caches[0]["k"].shape
     assert " while(" in run  # the rows' turns, and the attention's
-    _is_written_by_windows(text, (slots, heads, length, dim), decoder.LAYERS)
+    _is_written_a_row_at_a_time(text, (slots, heads, length, dim),
+                                decoder.LAYERS)
+    _reads_heads_side_by_side_exactly(text, head)
     relaid = re.findall(
         rf"= bf16\[{slots},{heads},{length},{dim}\]\{{[^}}]*\}} copy\(", run)
     assert not relaid, f"{len(relaid)} whole caches copied to another layout"
@@ -342,9 +386,11 @@ def test_the_round_writes_its_table_in_place_and_reads_it_where_it_lies(
         r"(?!parameter|get-tuple-element|while|tuple|dynamic-update-slice)",
         run)
     for some, positions, layout in set_aside:
-        if int(positions) == 1:
-            continue  # a token's new row, on its way into the table
-        assert dim >= LANES, (some, positions, layout)
+        if int(positions) in (1, heads_a_row(decoder.HEADS, head)):
+            # a token's new row, on its way into the table; the queries of
+            # a row's heads, each in its own lanes
+            continue
+        assert head >= LANES, (some, positions, layout)
         assert (int(some), int(positions)) == (SLOTS_A_TURN, live), (
             some, positions)
         assert "S(1)" in layout, layout

@@ -1,7 +1,7 @@
 """CI tier for tools/row_write_chip.py: the micro-benchmark that decided the
 form of a round's row writes must run end to end on the CPU backend (a table
 of four slots), so that a chip call never dies on its argument handling, and
-its own check of the three forms against each other must be able to fail."""
+its own check of the forms against each other must be able to fail."""
 
 import json
 import sys
@@ -33,7 +33,34 @@ def test_main_prints_what_it_writes_and_exits_0(tmp_path, capsys):
     assert json.loads(path.read_text()) == json.loads(capsys.readouterr().out)
 
 
-@pytest.mark.parametrize("argv", [["--repeats", "many"], ["--large"]])
+def test_the_packed_form_writes_the_table_as_the_decoder_lays_it():
+    """``packed`` times ``loop`` over the table laid ``heads_a_row`` heads a
+    row, as ``_fresh_table`` lays it, and is checked a head a row."""
+    from client_tpu.models.decoder import heads_a_row
+
+    out = row_write_chip.run(small=True, repeats=1,
+                             chosen=("loop_window", "packed"))
+    assert out["agreement"]["ok"], out["agreement"]
+    (case,) = out["agreement"]["cases"]
+    assert set(case) == {"table", "shape", "loop_window", "packed"}
+    (slots, heads, length, dim), _ = row_write_chip.SMALL_TABLES["small"]
+    P = heads_a_row(heads, dim)
+    assert P > 1
+    shapes = {(r["form"], tuple(r["shape"])) for r in out["forms"]}
+    assert shapes == {("loop_window", (slots, heads, length, dim)),
+                      ("packed", (slots, heads // P, length, P * dim))}
+
+
+def test_main_times_the_forms_it_is_given(capsys):
+    assert row_write_chip.main(
+        ["--small", "--repeats", "1", "--forms", "packed"]) == 0
+    out = json.loads(capsys.readouterr().out)
+    assert {r["form"] for r in out["forms"]} == {"packed"}
+    assert set(out["agreement"]["cases"][0]) == {"table", "shape", "packed"}
+
+
+@pytest.mark.parametrize("argv", [["--repeats", "many"], ["--large"],
+                                  ["--forms", "loop,elsewhere"]])
 def test_main_refuses_what_it_does_not_know(argv, capsys):
     with pytest.raises(SystemExit) as refused:
         row_write_chip.main(argv)

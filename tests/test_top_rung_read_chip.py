@@ -22,10 +22,10 @@ def test_a_small_run_times_every_form():
     assert out["agreement"]["ok"], out["agreement"]
     rows = out["forms"]
     assert [r["form"] for r in rows] == [
-        "rows_and_weights", *top_rung_read_chip.FORMS]
+        *top_rung_read_chip.BASELINES, *top_rung_read_chip.FORMS]
     assert all(r["ms_a_dispatch"] > 0 for r in rows), rows
-    assert all(r["read_gb_s"] >= 0 for r in rows[1:]), rows
-    assert "read_gb_s" not in rows[0]
+    assert all(r["read_gb_s"] >= 0 for r in rows[2:]), rows
+    assert not any("read_gb_s" in r for r in rows[:2])
 
 
 def test_a_small_run_times_every_round_form_at_each_membership():
@@ -35,17 +35,48 @@ def test_a_small_run_times_every_round_form_at_each_membership():
     assert [case["members"] for case in out["round_agreement"]["cases"]] == list(
         memberships)
     rows = out["round_forms"]
-    forms = ["rows_and_weights", *top_rung_read_chip.ROUND_FORMS]
+    baselines = top_rung_read_chip.BASELINES
+    forms = [*baselines, *top_rung_read_chip.ROUND_FORMS]
     assert [(r["members"], r["form"]) for r in rows] == [
         (members, form) for members in memberships for form in forms]
     assert all(r["ms_a_dispatch"] > 0 and r["live"] == live for r in rows), rows
     # eight slots, four a turn: three members are one turn, eight are two
     read = {(r["members"], r["form"]): r.get("slots_read") for r in rows}
     assert read == {
-        (members, form): (None if form == "rows_and_weights" else
-                          shape[0] if form == "every_slot" or members > 4
+        (members, form): (None if form in baselines else
+                          shape[0] if form.endswith("every_slot") or members > 4
                           else 4)
         for members in memberships for form in forms}
+
+
+def test_the_packed_forms_read_the_table_as_the_decoder_lays_it():
+    """A packed form reads (and its rows and weights write) the table laid
+    ``heads_a_row`` heads a row, as ``_fresh_table`` lays it, and is held to
+    the parent's attention, read a head a row; only the baselines the chosen
+    forms' layouts need are timed."""
+    from client_tpu.models.decoder import heads_a_row
+
+    out = top_rung_read_chip.run(
+        small=True, repeats=1, chosen=("packed_two_turns",),
+        round_chosen=("every_slot", "packed_every_slot"))
+    assert out["agreement"]["ok"] and out["round_agreement"]["ok"], out
+    (case,) = out["agreement"]["cases"]
+    assert set(case) == {"table", "shape", "packed_two_turns"}
+    assert [r["form"] for r in out["forms"]] == [
+        "packed_rows_and_weights", "packed_two_turns"]
+    (slots, heads, length, dim), _, _ = top_rung_read_chip.SMALL_TABLES[
+        "small"]
+    P = heads_a_row(heads, dim)
+    assert P > 1
+    assert {tuple(r["shape"]) for r in out["forms"]} == {
+        (slots, heads // P, length, P * dim)}
+    round_rows = out["round_forms"]
+    assert {r["form"] for r in round_rows} == {
+        *top_rung_read_chip.BASELINES, "every_slot", "packed_every_slot"}
+    assert all(r["shape"][-1] == (P * dim if "packed" in r["form"] else dim)
+               for r in round_rows), round_rows
+    assert all("read_ms" in r for r in round_rows
+               if r["form"] not in top_rung_read_chip.BASELINES), round_rows
 
 
 @pytest.mark.parametrize("broken", top_rung_read_chip.ROUND_FORMS[1:])
@@ -56,8 +87,8 @@ def test_a_round_form_that_reads_past_its_position_fails_the_run(
     number of members."""
     round_forms = top_rung_read_chip.round_forms
 
-    def with_a_fault(jax, jnp, lax, live):
-        made = round_forms(jax, jnp, lax, live)
+    def with_a_fault(jax, jnp, lax, live, head=None):
+        made = round_forms(jax, jnp, lax, live, head)
         sound = made[broken]
         made[broken] = lambda q, k, v, pos, active: sound(q, k, v, pos + 1, active)
         return made
@@ -79,7 +110,9 @@ def test_main_prints_what_it_writes_and_exits_0(tmp_path, capsys):
     assert json.loads(path.read_text()) == json.loads(capsys.readouterr().out)
 
 
-@pytest.mark.parametrize("argv", [["--repeats", "many"], ["--large"]])
+@pytest.mark.parametrize("argv", [["--repeats", "many"], ["--large"],
+                                  ["--forms", "every_slot"],
+                                  ["--round-forms", "two_turns"]])
 def test_main_refuses_what_it_does_not_know(argv, capsys):
     with pytest.raises(SystemExit) as refused:
         top_rung_read_chip.main(argv)
@@ -95,8 +128,8 @@ def test_a_form_that_reads_past_its_position_fails_the_run(
     more) is no candidate."""
     forms = top_rung_read_chip.forms
 
-    def with_a_fault(jax, jnp, lax, piece):
-        made = forms(jax, jnp, lax, piece)
+    def with_a_fault(jax, jnp, lax, piece, head=None):
+        made = forms(jax, jnp, lax, piece, head)
         sound = made[broken]
         made[broken] = lambda q, k, v, pos: sound(q, k, v, pos + 1)
         return made

@@ -19,9 +19,15 @@ number that is in the repository and not in prose:
 - ``slots_window``: the same update in a ``while`` of one turn a slot,
   active or not;
 - ``loop_window``: the same update in ``loop``'s own ``while``, one turn an
-  active slot (what stands since PR 35 where a row is narrower than the
-  lanes: sixteen updates written out flat double the program that the chip
-  has to load, and sixteen turns cost A's rounds of seven more than seven).
+  active slot (what the decoder took where a row is narrower than the
+  lanes, until it laid its table ``heads_a_row`` heads a row: sixteen
+  updates written out flat double the program that the chip has to load,
+  and sixteen turns cost A's rounds of seven more than seven);
+- ``packed``: ``loop`` over the same table laid as the decoder lays it,
+  ``heads_a_row`` heads side by side a row, [slots, H/P, M, P x Dh]
+  (gpt2-large's [16, 10, 1024, 128]): a position's row of a slot fills the
+  lanes, and the turn updates it alone (the table and the rows are the
+  other forms', laid so; at heads of 128 it is ``loop``);
 
 at the two tables the benchmark's cells hold (gpt2-large: 16 slots, 20 heads
 of 64, 1,024 positions, 36 layers; cerebras-gpt-1.3b: 16 heads of 128, 2,048
@@ -34,10 +40,12 @@ dispatch and not once a layer); the rows are inputs, so no product is in the
 time. Reported: the median dispatch in ms (a round's row writes at that
 depth) and us a layer. ``agreement`` says that the forms leave the same
 table wherever a step could read it: bit for bit ``loop``'s, but for
-``flat_noread`` at row ``pos`` of a slot that is not active.
+``flat_noread`` at row ``pos`` of a slot that is not active (``packed``'s
+read a head a row).
 
 Run on the chip (or with --small off the chip for a pipeline check):
     python tools/row_write_chip.py [--json-out PATH] [--small]
+        [--forms loop_window,packed]
 """
 
 from __future__ import annotations
@@ -57,15 +65,32 @@ TABLES = {
 }
 SMALL_TABLES = {"small": ((4, 2, 16, 8), 2)}
 FORMS = ("loop", "flat_readback", "flat_noread", "flat_window", "slots_window",
-         "loop_window")
+         "loop_window", "packed")
 # the positions a window holds: the lanes of a tile
 WINDOW = 128
 
 
+def packed(table, heads=None):
+    """A table [.., H, M, Dh] (or a round's rows, M of 1) laid as the decoder
+    lays it, ``heads_a_row`` heads a row: [.., H/P, M, P x Dh]; with
+    ``heads``, a table so laid back a head a row."""
+    from client_tpu.models.decoder import heads_a_row
+
+    *lead, rows, length, width = table.shape
+    if heads is not None:
+        P = heads // rows
+        return table.reshape(*lead, rows, length, P, width // P).swapaxes(
+            -3, -2).reshape(*lead, heads, length, width // P)
+    P = heads_a_row(rows, width)
+    return table.reshape(*lead, rows // P, P, length, width).swapaxes(
+        -3, -2).reshape(*lead, rows // P, length, P * width)
+
+
 def forms(jnp, lax):
-    """The three forms, each ``(caches, rows, pos, active) -> caches`` over
+    """Every form, each ``(caches, rows, pos, active) -> caches`` over
     caches (k, v) of [slots, H, M, Dh], rows of [slots, H, 1, Dh], ``pos``
-    int32 [slots] and ``active`` bool [slots]."""
+    int32 [slots] and ``active`` bool [slots]; ``packed`` over both laid as
+    ``packed`` lays them."""
 
     def loop(caches, rows, pos, active):
         active_first = jnp.argsort(~active, stable=True)
@@ -151,7 +176,7 @@ def forms(jnp, lax):
         return write
 
     return dict(zip(FORMS, (loop, flat(True), flat(False), window("flat"),
-                            window("slots"), window("active"))))
+                            window("slots"), window("active"), loop)))
 
 
 def layered(jax, form):
@@ -165,10 +190,11 @@ def layered(jax, form):
     return jax.jit(dispatch, donate_argnums=0)
 
 
-def _operands(jnp, np, shape, layers, width, seed=0):
+def _operands(jnp, np, shape, layers, width, seed=0, form=None):
     """A pair of tables of noise a layer, every layer's rows for every slot,
     positions apart from each other, and ``width`` (a divisor of the slots)
-    slots active."""
+    slots active; tables and rows laid as ``packed`` lays them for the
+    ``packed`` form."""
     slots, heads, length, dim = shape
     rng = np.random.default_rng(seed)
     noise = lambda *dims: jnp.asarray(
@@ -180,6 +206,9 @@ def _operands(jnp, np, shape, layers, width, seed=0):
                         for _ in range(layers - 1)]
     rows = (noise(layers, slots, heads, 1, dim),
             noise(layers, slots, heads, 1, dim))
+    if form == "packed":
+        tables = [tuple(packed(cache) for cache in pair) for pair in tables]
+        rows = tuple(packed(r) for r in rows)
     pos = jnp.asarray((3 + 5 * np.arange(slots)) % length, jnp.int32)
     # the active slots spread over the table, as a table that streams have
     # come to and left holds them
@@ -188,17 +217,22 @@ def _operands(jnp, np, shape, layers, width, seed=0):
     return tables, rows, pos, jnp.asarray(active)
 
 
-def check_agreement(jax, jnp, np, lax, tables):
-    """Each form's first pair of tables after one dispatch against
-    ``loop``'s."""
+def check_agreement(jax, jnp, np, lax, tables, chosen=FORMS):
+    """Each form of ``chosen`` its first pair of tables after one dispatch
+    against ``loop``'s, read a head a row."""
     rows_out, ok = [], True
     for name, (shape, layers) in tables.items():
         slots, length = shape[0], shape[2]
         width = max(1, slots // 2)
         left = {}
         for form_name, form in forms(jnp, lax).items():
-            pairs, rows, pos, active = _operands(jnp, np, shape, 1, width)
+            if form_name != "loop" and form_name not in chosen:
+                continue
+            pairs, rows, pos, active = _operands(jnp, np, shape, 1, width,
+                                                 form=form_name)
             out = layered(jax, form)(pairs, rows, pos, active)
+            if form_name == "packed":
+                out = [[packed(c, shape[1]) for c in out[0]]]
             left[form_name] = [np.asarray(c).view(np.uint16) for c in out[0]]
         pos, active = np.asarray(pos), np.asarray(active)
         # where a step could read: everything but the row an inactive slot
@@ -206,7 +240,7 @@ def check_agreement(jax, jnp, np, lax, tables):
         readable = np.ones((slots, 1, length, 1), bool)
         readable[~active, 0, pos[~active], 0] = False
         case = {"table": name, "shape": list(shape)}
-        for form_name in FORMS[1:]:
+        for form_name in [f for f in FORMS[1:] if f in left]:
             where = readable if form_name == "flat_noread" else True
             case[form_name] = all(
                 np.array_equal(a * where, b * where)
@@ -216,17 +250,20 @@ def check_agreement(jax, jnp, np, lax, tables):
     return {"ok": ok, "cases": rows_out}
 
 
-def bench_forms(jax, jnp, np, lax, tables, widths, repeats):
-    """The median dispatch of every form at every table and width."""
+def bench_forms(jax, jnp, np, lax, tables, widths, repeats, chosen=FORMS):
+    """The median dispatch of every form of ``chosen`` at every table and
+    width; the shape a row reports is the table as the form lays it."""
     out = []
     for name, (shape, layers) in tables.items():
         for form_name, form in forms(jnp, lax).items():
+            if form_name not in chosen:
+                continue
             program = layered(jax, form)
             for width in widths:
                 caches, rows, pos, active = _operands(
-                    jnp, np, shape, layers, width)
-                row = {"table": name, "shape": list(shape), "layers": layers,
-                       "form": form_name, "active": width}
+                    jnp, np, shape, layers, width, form=form_name)
+                row = {"table": name, "shape": list(caches[0][0].shape),
+                       "layers": layers, "form": form_name, "active": width}
                 try:
                     caches = program(caches, rows, pos, active)
                     jax.block_until_ready(caches)  # compiled and warm
@@ -246,7 +283,7 @@ def bench_forms(jax, jnp, np, lax, tables, widths, repeats):
     return out
 
 
-def run(small: bool, repeats: int = 15):
+def run(small: bool, repeats: int = 15, chosen=FORMS):
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -258,12 +295,26 @@ def run(small: bool, repeats: int = 15):
     result = {"platform": jax.default_backend(),
               "device_kind": device.device_kind}
     try:
-        result["agreement"] = check_agreement(jax, jnp, np, lax, tables)
+        result["agreement"] = check_agreement(jax, jnp, np, lax, tables,
+                                              chosen)
     except Exception as e:
         result["agreement"] = {
             "ok": False, "error": f"{type(e).__name__}: {e}"[:500]}
-    result["forms"] = bench_forms(jax, jnp, np, lax, tables, widths, repeats)
+    result["forms"] = bench_forms(jax, jnp, np, lax, tables, widths, repeats,
+                                  chosen)
     return result
+
+
+def _chosen(names):
+    """An argument's type: a comma-separated choice among ``names``."""
+    def parse(text):
+        chosen = tuple(text.split(","))
+        unknown = [name for name in chosen if name not in names]
+        if unknown:
+            raise argparse.ArgumentTypeError(f"no form {unknown[0]!r}")
+        return chosen
+
+    return parse
 
 
 def main(argv=None):
@@ -274,9 +325,13 @@ def main(argv=None):
                         "check off the chip, no number of the chip's")
     parser.add_argument("--repeats", type=int, default=15,
                         help="timed dispatches a row; the median is reported")
+    parser.add_argument("--forms", type=_chosen(FORMS), default=FORMS,
+                        help="the forms to time, by name, a comma between "
+                        "(every one unless given); each is checked against "
+                        "loop")
     args = parser.parse_args(argv)
 
-    result = run(args.small, args.repeats)
+    result = run(args.small, args.repeats, args.forms)
     text = json.dumps(result, indent=1)
     print(text)
     if args.json_out:

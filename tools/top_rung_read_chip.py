@@ -24,6 +24,14 @@ rests on a number that is in the repository and not in prose:
   probabilities to bfloat16);
 - ``two_turns``: the same in two turns of half the positions (what
   ``models/decoder.py:read_table`` keeps since PR 37);
+- ``packed_two_turns``: ``two_turns`` over the table laid as the decoder
+  lays it, ``heads_a_row`` heads side by side a row ([16, 10,
+  1024, 128]; ``row_write_chip.packed``): each head's query takes its own
+  lanes of a [P, 128] operand, zeros elsewhere, one product contracts the
+  whole row, and each head keeps its own lanes of the weighing (what
+  ``read_table`` does);
+- ``packed_lanes_two_turns``: the same table, each turn's rows cut into
+  their heads' lanes, [.., P, Dh], and each head's products its own;
 
 at G's table (gpt2-large: 16 slots, 20 heads of 64, 1,024 positions, 36
 layers), all sixteen slots members.
@@ -40,7 +48,13 @@ slots, as lowest-free-first admission seats them):
   turns: what the slot batcher's rung-256 step compiles to;
 - ``slot_turns_mxu``: ``slot_turns`` with each turn's two products over its
   slice written as one product on the matrix unit, both at ``HIGHEST``, as
-  ``read_table`` writes them.
+  ``read_table`` writes them;
+- ``packed_every_slot``, ``packed_lanes_every_slot``, ``packed_slot_turns``:
+  ``every_slot`` with ``packed_two_turns``' products at ``HIGHEST`` (the
+  one-slot ``attention``: at the default precision the chip read them 1e-3
+  to 2e-3 off), the same with ``packed_lanes_two_turns``' products at the
+  default, and ``slot_turns`` with the former, over the table laid
+  ``heads_a_row`` heads a row.
 
 (Position turns over every slot, ``read_table``'s form at the top rung, are
 no candidate here: compiled for a v5e, ``every_slot`` sets nothing of the
@@ -59,7 +73,10 @@ each read waits for the layer before it and shares the chip's memory with the
 weights and the writes, as in the round's program: alone, with queries that
 are inputs, the compiler overlaps the parent's staging with the writes and it
 reads cheaper than it does in a round (PERF.md section 6, PR 37).
-``rows_and_weights`` is the dispatch with no read. Reported: the median
+``rows_and_weights`` is the dispatch with no read (``packed_rows_and_weights``
+over the table laid ``heads_a_row`` heads a row, whose rows ``row_write_chip``'s
+``packed`` writes; a packed form's read is its dispatch less that one's). The
+``shape`` a row reports is the table as its form lays it. Reported: the median
 dispatch in ms and the cache bytes the attention reads (each pair once) over
 it in GB/s. ``agreement`` says that every form's attention equals the
 parent's to a ten-thousandth of its largest value: what float32 sums in
@@ -69,6 +86,8 @@ rounds it to bfloat16 reads 6e-4 off on the chip).
 
 Run on the chip (or with --small off the chip for a pipeline check):
     python tools/top_rung_read_chip.py [--json-out PATH] [--small]
+        [--forms two_turns,packed_two_turns]
+        [--round-forms every_slot,packed_every_slot]
 """
 
 from __future__ import annotations
@@ -88,20 +107,103 @@ import row_write_chip  # noqa: E402
 # name: (table shape [slots, H, M, Dh], layers a round reads, a shortest rung)
 TABLES = {"gpt2-large": ((16, 20, 1024, 64), 36, 256)}
 SMALL_TABLES = {"small": ((4, 2, 64, 8), 2, 16)}
-FORMS = ("parent", "pieces", "slot_turns", "table", "four_turns", "two_turns")
+FORMS = ("parent", "pieces", "slot_turns", "table", "four_turns", "two_turns",
+         "packed_two_turns", "packed_lanes_two_turns")
 # name: (table shape, layers a round reads, the rung, members of a round)
 ROUND_TABLES = {"gpt2-large": ((16, 20, 1024, 64), 36, 256, (16, 6))}
 SMALL_ROUND_TABLES = {"small": ((8, 2, 64, 8), 2, 16, (8, 3))}
-ROUND_FORMS = ("slot_turns", "every_slot", "slot_turns_mxu")
+ROUND_FORMS = ("slot_turns", "every_slot", "slot_turns_mxu",
+               "packed_every_slot", "packed_lanes_every_slot",
+               "packed_slot_turns")
 # the largest difference from the parent's attention a form may leave, over
 # the parent's largest value
 AGREE = 1e-4
 
 
-def forms(jax, jnp, lax, piece):
+def is_packed(form):
+    """Whether ``form`` reads the table laid ``heads_a_row`` heads a row."""
+    return form.startswith("packed")
+
+
+def packed_products(jnp, head, precision=None):
+    """``(score, weigh)`` over a table laid ``heads_a_row`` heads a row, for
+    heads of ``head``: ``score(q, k)`` of the queries [.., H/P, P x Dh] (a
+    row's heads side by side, as the table lays a position) and the keys
+    [.., H/P, m, P x Dh] is [.., H/P, P, m]; ``weigh(probs, v)`` of those
+    over the values is [.., H/P, P x Dh], each head in its own lanes. Each
+    head's query takes its own lanes of a [P, P x Dh] operand, zeros
+    elsewhere, and one product contracts the whole row; each head keeps its
+    own lanes of the weighing by a select and a sum (``decoder.py:weighed``).
+    """
+    f32 = jnp.float32
+
+    def score(q, k):
+        *lead, rows, width = q.shape
+        P = width // head
+        spread = (q.astype(f32).reshape(*lead, rows, P, 1, head)
+                  * jnp.eye(P, dtype=f32)[:, :, None]).reshape(
+                      *lead, rows, P, width)
+        return jnp.einsum("...hjc,...hmc->...hjm", spread, k.astype(f32),
+                          precision=precision)
+
+    def weigh(probs, v):
+        whole = jnp.einsum("...hjm,...hmc->...hjc", probs, v.astype(f32),
+                           precision=precision)
+        P, width = whole.shape[-2:]
+        own = jnp.arange(width) // head == jnp.arange(P)[:, None]
+        return jnp.sum(jnp.where(own, whole, 0.0), axis=-2)
+
+    return score, weigh
+
+
+def lanes_products(jnp, head, precision=None):
+    """``packed_products``' pair with each row cut into its heads' lanes,
+    [.., P, Dh], and each head's products its own."""
+    f32 = jnp.float32
+
+    def score(q, k):
+        *lead, rows, width = q.shape
+        P = width // head
+        return jnp.einsum(
+            "...hpd,...hmpd->...hpm",
+            q.astype(f32).reshape(*lead, rows, P, head),
+            k.astype(f32).reshape(*k.shape[:-1], P, head),
+            precision=precision)
+
+    def weigh(probs, v):
+        P = probs.shape[-2]
+        out = jnp.einsum("...hpm,...hmpd->...hpd", probs,
+                         v.astype(f32).reshape(*v.shape[:-1], P, head),
+                         precision=precision)
+        return out.reshape(*out.shape[:-2], P * head)
+
+    return score, weigh
+
+
+def packed_read(jax, jnp, products, head, live=None):
+    """One slot's attention at rung ``live`` (its whole cache, unless given)
+    through ``products`` (``packed_products`` or ``lanes_products``): the
+    query [H/P, P x Dh], the caches [H/P, M, P x Dh], float32 [H/P, P x
+    Dh]."""
+    score, weigh = products
+
+    def one(q, k, v, pos):
+        reach = live or k.shape[1]
+        scores = score(q, k[:, :reach]) * (head ** -0.5)
+        scores = jnp.where((jnp.arange(reach) <= pos)[None, None, :],
+                           scores, -jnp.inf)
+        return weigh(jax.nn.softmax(scores, axis=-1), v[:, :reach])
+
+    return one
+
+
+def forms(jax, jnp, lax, piece, head=None):
     """Every form, each ``(q, k, v, pos) -> attention`` over the queries
     [slots, H, Dh] bf16, the stacked caches (k, v) [slots, H, M, Dh] bf16 and
-    the positions int32 [slots]: float32 [slots, H, Dh]."""
+    the positions int32 [slots]: float32 [slots, H, Dh]; a packed form over
+    queries [slots, H/P, P x Dh] and caches [slots, H/P, M, P x Dh] laid as
+    ``row_write_chip.packed`` lays them, heads of ``head``: float32 [slots,
+    H/P, P x Dh]."""
     from client_tpu.models.decoder import slots_a_turn
 
     f32 = jnp.float32
@@ -190,15 +292,49 @@ def forms(jax, jnp, lax, piece):
 
         return read
 
-    return dict(zip(FORMS, (parent, pieces, slot_turns, table, turns(4),
-                            turns(2))))
+    def packed_turns(products):
+        score, weigh = products
+
+        def read(q, k, v, pos):
+            slots, rows, live, width = k.shape
+            span, P = live // 2, width // head
+            cut = lambda a, n: lax.dynamic_slice_in_dim(a, n * span, span,
+                                                        axis=2)
+
+            def scored(n, scores):
+                return lax.dynamic_update_slice_in_dim(
+                    scores, score(q, cut(k, n)), n * span, axis=3)
+
+            scores = lax.fori_loop(
+                0, 2, scored, jnp.zeros((slots, rows, P, live), f32))
+            mask = jnp.arange(live)[None, :] <= pos[:, None]
+            scores = jnp.where(mask[:, None, None, :], scores * (head ** -0.5),
+                               -jnp.inf)
+            probs = jax.nn.softmax(scores, axis=-1)
+
+            def weighed(n, attn):
+                return attn + weigh(
+                    lax.dynamic_slice_in_dim(probs, n * span, span, axis=3),
+                    cut(v, n))
+
+            return lax.fori_loop(0, 2, weighed,
+                                 jnp.zeros((slots, rows, width), f32))
+
+        return read
+
+    highest = lax.Precision.HIGHEST
+    return dict(zip(FORMS, (
+        parent, pieces, slot_turns, table, turns(4), turns(2),
+        packed_turns(packed_products(jnp, head, highest)),
+        packed_turns(lanes_products(jnp, head, highest)))))
 
 
-def round_forms(jax, jnp, lax, live):
+def round_forms(jax, jnp, lax, live, head=None):
     """The stream round's forms at rung ``live``, each ``(q, k, v, pos,
     active) -> attention`` over the operands of ``forms`` and the round's
-    members ``active`` bool [slots]: float32 [slots, H, Dh], a member's
-    row its attention over its cache's first ``live`` positions."""
+    members ``active`` bool [slots]: float32 [slots, H, Dh] (a packed
+    form's as ``forms`` has them), a member's row its attention over its
+    cache's first ``live`` positions."""
     from client_tpu.models.decoder import slots_a_turn
 
     f32 = jnp.float32
@@ -247,8 +383,14 @@ def round_forms(jax, jnp, lax, live):
     def every_slot(q, k, v, pos, active):
         return jax.vmap(one)(q, k, v, pos)
 
-    return dict(zip(ROUND_FORMS, (turns(jax.vmap(one)), every_slot,
-                                  turns(on_the_matrix_unit))))
+    packed_one = packed_read(
+        jax, jnp, packed_products(jnp, head, lax.Precision.HIGHEST), head, live)
+    lanes_one = packed_read(jax, jnp, lanes_products(jnp, head), head, live)
+    return dict(zip(ROUND_FORMS, (
+        turns(jax.vmap(one)), every_slot, turns(on_the_matrix_unit),
+        lambda q, k, v, pos, active: jax.vmap(packed_one)(q, k, v, pos),
+        lambda q, k, v, pos, active: jax.vmap(lanes_one)(q, k, v, pos),
+        turns(jax.vmap(packed_one)))))
 
 
 def slots_read(form, slots, members):
@@ -256,7 +398,8 @@ def slots_read(form, slots, members):
     with its ``members`` lowest slots occupied."""
     from client_tpu.models.decoder import in_whole_turns
 
-    return slots if form == "every_slot" else in_whole_turns(slots, members)
+    return (slots if form.endswith("every_slot")
+            else in_whole_turns(slots, members))
 
 
 def _top_rung(read):
@@ -264,13 +407,16 @@ def _top_rung(read):
     return read and (lambda q, k, v, pos, active: read(q, k, v, pos))
 
 
-def layered(jax, jnp, lax, read):
+def layered(jax, jnp, lax, read, packed=False):
     """One dispatch: the layers of a round without their arithmetic. Each
     makes a query and a member's rows from the running state, writes the
-    rows into its donated pair of tables, reads the pair through ``read``
-    (or not at all, for ``None``) and streams the rest of its weights into
-    the next state. Returns the tables and every layer's attention."""
-    write = row_write_chip.forms(jnp, lax)["loop_window"]
+    rows into its donated pair of tables (``row_write_chip``'s ``packed``
+    where the tables are laid so), reads the pair through ``read`` (or not
+    at all, for ``None``) and streams the rest of its weights into the next
+    state. Returns the tables and every layer's attention. The query and
+    the rows are laid as a position of the table."""
+    write = row_write_chip.forms(jnp, lax)[
+        "packed" if packed else "loop_window"]
 
     def dispatch(tables, weights, x, pos, active):
         slots, heads, _, dim = tables[0][0].shape
@@ -294,14 +440,17 @@ def layered(jax, jnp, lax, read):
     return jax.jit(dispatch, donate_argnums=0)
 
 
-def _operands(jax, jnp, np, shape, layers, seed=0, reach=None, members=None):
-    """``row_write_chip``'s tables, a layer's weights (the query's and the
-    rows' 3 d^2 and 9 d^2 more, as the GPT-2 block has 12 d^2) made on the
-    device, a running state, the ``members`` lowest slots members (every
-    slot, unless given) at positions across the first ``reach`` of the
-    table (all of it, unless given)."""
+def _operands(jax, jnp, np, shape, layers, seed=0, reach=None, members=None,
+              packed=False):
+    """``row_write_chip``'s tables (laid as its ``packed`` form lays them,
+    where ``packed``), a layer's weights (the query's and the rows' 3 d^2
+    and 9 d^2 more, as the GPT-2 block has 12 d^2) made on the device, a
+    running state, the ``members`` lowest slots members (every slot, unless
+    given) at positions across the first ``reach`` of the table (all of it,
+    unless given)."""
     tables, _, _, _ = row_write_chip._operands(
-        jnp, np, shape, layers, shape[0], seed)
+        jnp, np, shape, layers, shape[0], seed,
+        form="packed" if packed else None)
     slots, heads, length, dim = shape
     width = heads * dim
     keys = jax.random.split(jax.random.PRNGKey(seed), 2 * layers + 1)
@@ -317,20 +466,30 @@ def _operands(jax, jnp, np, shape, layers, seed=0, reach=None, members=None):
     return tables, weights, x, pos, jnp.asarray(np.arange(slots) < members)
 
 
-def check_agreement(jax, jnp, np, lax, tables):
-    """Each form's attention after one dispatch against the parent's."""
+def _by_slot(attn, np):
+    """Every layer's attention [layers, slots, ..] as [layers, slots, H x
+    Dh], a head's lanes after another's in either layout."""
+    attn = np.asarray(attn, np.float64)
+    return attn.reshape(attn.shape[0], attn.shape[1], -1)
+
+
+def check_agreement(jax, jnp, np, lax, tables, chosen=FORMS):
+    """Each form of ``chosen`` its attention after one dispatch against the
+    parent's."""
     cases, ok = [], True
     for name, (shape, layers, piece) in tables.items():
-        made = forms(jax, jnp, lax, piece)
+        made = forms(jax, jnp, lax, piece, shape[3])
         got = {}
-        for form_name in FORMS:
-            operands = _operands(jax, jnp, np, shape, 1)
-            _, attn = layered(jax, jnp, lax,
-                              _top_rung(made[form_name]))(*operands)
-            got[form_name] = np.asarray(attn, np.float64)
+        for form_name in ("parent",) + tuple(f for f in chosen
+                                             if f != "parent"):
+            packed = is_packed(form_name)
+            operands = _operands(jax, jnp, np, shape, 1, packed=packed)
+            _, attn = layered(jax, jnp, lax, _top_rung(made[form_name]),
+                              packed)(*operands)
+            got[form_name] = _by_slot(attn, np)
         scale = np.abs(got["parent"]).max()
         case = {"table": name, "shape": list(shape)}
-        for form_name in FORMS[1:]:
+        for form_name in [f for f in FORMS[1:] if f in got]:
             worst = float(np.abs(got[form_name] - got["parent"]).max() / scale)
             case[form_name] = {"agrees": worst <= AGREE, "worst": worst}
             ok = ok and worst <= AGREE
@@ -338,21 +497,33 @@ def check_agreement(jax, jnp, np, lax, tables):
     return {"ok": ok, "cases": cases}
 
 
-def bench_forms(jax, jnp, np, lax, tables, repeats):
-    """The median dispatch of every form, and of the rows and weights
-    alone."""
+BASELINES = ("rows_and_weights", "packed_rows_and_weights")
+
+
+def _baselines(chosen):
+    """The dispatches with no read that ``chosen`` forms are read against:
+    one a layout they take."""
+    return tuple(b for b in BASELINES
+                 if any(is_packed(f) == is_packed(b) for f in chosen))
+
+
+def bench_forms(jax, jnp, np, lax, tables, repeats, chosen=FORMS):
+    """The median dispatch of every form of ``chosen``, and of the rows and
+    weights alone."""
     out = []
     for name, (shape, layers, piece) in tables.items():
-        made = forms(jax, jnp, lax, piece)
+        made = forms(jax, jnp, lax, piece, shape[3])
         slots, heads, length, dim = shape
         read_bytes = 2 * slots * heads * length * dim * 2 * layers
-        for form_name in ("rows_and_weights",) + FORMS:
-            program = layered(jax, jnp, lax, _top_rung(made.get(form_name)))
-            operands = _operands(jax, jnp, np, shape, layers)
-            row = {"table": name, "shape": list(shape), "layers": layers,
-                   "form": form_name}
+        for form_name in _baselines(chosen) + tuple(chosen):
+            packed = is_packed(form_name)
+            program = layered(jax, jnp, lax, _top_rung(made.get(form_name)),
+                              packed)
+            operands = _operands(jax, jnp, np, shape, layers, packed=packed)
+            row = {"table": name, "shape": list(operands[0][0][0].shape),
+                   "layers": layers, "form": form_name}
             ms = _time(jax, program, operands, repeats, row)
-            if ms and form_name != "rows_and_weights":
+            if ms and form_name not in BASELINES:
                 row["read_gb_s"] = round(read_bytes / ms / 1e6, 1)
             out.append(row)
     return out
@@ -380,23 +551,26 @@ def _time(jax, program, operands, repeats, row):
         return None
 
 
-def check_round_agreement(jax, jnp, np, lax, tables):
-    """Each round form's attention of the members after one dispatch
-    against ``slot_turns``'s, at every number of members."""
+def check_round_agreement(jax, jnp, np, lax, tables, chosen=ROUND_FORMS):
+    """Each round form of ``chosen`` its attention of the members after one
+    dispatch against ``slot_turns``'s, at every number of members."""
     cases, ok = [], True
     for name, (shape, _, live, memberships) in tables.items():
-        made = round_forms(jax, jnp, lax, live)
+        made = round_forms(jax, jnp, lax, live, shape[3])
         for members in memberships:
             got = {}
-            for form_name in ROUND_FORMS:
+            for form_name in ("slot_turns",) + tuple(
+                    f for f in chosen if f != "slot_turns"):
+                packed = is_packed(form_name)
                 operands = _operands(jax, jnp, np, shape, 1, reach=live,
-                                     members=members)
-                _, attn = layered(jax, jnp, lax, made[form_name])(*operands)
-                got[form_name] = np.asarray(attn, np.float64)[:, :members]
+                                     members=members, packed=packed)
+                _, attn = layered(jax, jnp, lax, made[form_name],
+                                  packed)(*operands)
+                got[form_name] = _by_slot(attn, np)[:, :members]
             scale = np.abs(got["slot_turns"]).max()
             case = {"table": name, "shape": list(shape), "live": live,
                     "members": members}
-            for form_name in ROUND_FORMS[1:]:
+            for form_name in [f for f in ROUND_FORMS[1:] if f in got]:
                 worst = float(
                     np.abs(got[form_name] - got["slot_turns"]).max() / scale)
                 case[form_name] = {"agrees": worst <= AGREE, "worst": worst}
@@ -405,27 +579,30 @@ def check_round_agreement(jax, jnp, np, lax, tables):
     return {"ok": ok, "cases": cases}
 
 
-def bench_round_forms(jax, jnp, np, lax, tables, repeats):
-    """The median dispatch of every round form, and of the rows and weights
-    alone, at every number of members; a form's read is its dispatch less
-    the rows and weights', and its GB/s are of the slots it reads over
-    that."""
+def bench_round_forms(jax, jnp, np, lax, tables, repeats, chosen=ROUND_FORMS):
+    """The median dispatch of every round form of ``chosen``, and of the
+    rows and weights alone, at every number of members; a form's read is
+    its dispatch less the rows and weights' in its layout, and its GB/s are
+    of the slots it reads over that."""
     out = []
     for name, (shape, layers, live, memberships) in tables.items():
-        made = round_forms(jax, jnp, lax, live)
+        made = round_forms(jax, jnp, lax, live, shape[3])
         slots, heads, _, dim = shape
         for members in memberships:
-            rest = None
-            for form_name in ("rows_and_weights",) + ROUND_FORMS:
-                program = layered(jax, jnp, lax, made.get(form_name))
+            alone = {}
+            for form_name in _baselines(chosen) + tuple(chosen):
+                packed = is_packed(form_name)
+                program = layered(jax, jnp, lax, made.get(form_name), packed)
                 operands = _operands(jax, jnp, np, shape, layers, reach=live,
-                                     members=members)
-                row = {"table": name, "shape": list(shape), "layers": layers,
-                       "live": live, "members": members, "form": form_name}
+                                     members=members, packed=packed)
+                row = {"table": name, "shape": list(operands[0][0][0].shape),
+                       "layers": layers, "live": live, "members": members,
+                       "form": form_name}
                 ms = _time(jax, program, operands, repeats, row)
-                if form_name == "rows_and_weights":
-                    rest = ms
+                if form_name in BASELINES:
+                    alone[packed] = ms
                 else:
+                    rest = alone.get(packed)
                     row["slots_read"] = slots_read(form_name, slots, members)
                     if ms and rest:
                         row["read_ms"] = round(ms - rest, 4)
@@ -437,7 +614,8 @@ def bench_round_forms(jax, jnp, np, lax, tables, repeats):
     return out
 
 
-def run(small: bool, repeats: int = 15):
+def run(small: bool, repeats: int = 15, chosen=FORMS,
+        round_chosen=ROUND_FORMS):
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -448,15 +626,16 @@ def run(small: bool, repeats: int = 15):
     device = jax.devices()[0]
     result = {"platform": jax.default_backend(),
               "device_kind": device.device_kind}
-    for key, check, bench, of in (
-            ("", check_agreement, bench_forms, tables),
-            ("round_", check_round_agreement, bench_round_forms, round_tables)):
+    for key, check, bench, of, these in (
+            ("", check_agreement, bench_forms, tables, chosen),
+            ("round_", check_round_agreement, bench_round_forms, round_tables,
+             round_chosen)):
         try:
-            result[key + "agreement"] = check(jax, jnp, np, lax, of)
+            result[key + "agreement"] = check(jax, jnp, np, lax, of, these)
         except Exception as e:
             result[key + "agreement"] = {
                 "ok": False, "error": f"{type(e).__name__}: {e}"[:500]}
-        result[key + "forms"] = bench(jax, jnp, np, lax, of, repeats)
+        result[key + "forms"] = bench(jax, jnp, np, lax, of, repeats, these)
     return result
 
 
@@ -468,9 +647,17 @@ def main(argv=None):
                         "pipeline check off the chip, no number of the chip's")
     parser.add_argument("--repeats", type=int, default=15,
                         help="timed dispatches a form; the median is reported")
+    parser.add_argument("--forms", type=row_write_chip._chosen(FORMS),
+                        default=FORMS, help="the top rung's forms to time, a "
+                        "comma between (every one unless given); each is "
+                        "checked against parent")
+    parser.add_argument("--round-forms", type=row_write_chip._chosen(
+                            ROUND_FORMS), default=ROUND_FORMS,
+                        help="the round's forms to time, the same way; each "
+                        "is checked against slot_turns")
     args = parser.parse_args(argv)
 
-    result = run(args.small, args.repeats)
+    result = run(args.small, args.repeats, args.forms, args.round_forms)
     text = json.dumps(result, indent=1)
     print(text)
     if args.json_out:
