@@ -86,6 +86,27 @@ def ladder_of(max_len: int) -> Tuple[int, ...]:
     return tuple(rungs)
 
 
+def write_rows_in_turns(caches, rows, pos, active):
+    """``TinyDecoderModel``'s ``write_table_rows`` off the chip: a ``while``
+    of one turn an active slot, each writing that slot's two rows with one
+    update a cache, where they lie."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    active_first = jnp.argsort(~active, stable=True)
+
+    def write(turn, caches):
+        slot = active_first[turn]
+        return tuple(
+            lax.dynamic_update_slice(
+                cache,
+                lax.dynamic_index_in_dim(slot_rows, slot, keepdims=True),
+                (slot, 0, pos[slot], 0))
+            for cache, slot_rows in zip(caches, rows))
+
+    return lax.fori_loop(0, jnp.sum(active, dtype=jnp.int32), write, caches)
+
+
 class RungCount:
     """What a served model's decoder was asked for, counted: steps
     dispatched by the rung they read, which ``ServerCore.metrics_registry``
@@ -99,7 +120,9 @@ class RungCount:
     (``window_rows_read``, ``summary_rows_read``, ``summaries_written``), all
     counted on the host from positions; and, for a decoder whose dispatches
     tally on the device the experts their grouped products read, those and
-    the dispatches, by program (``reached``)."""
+    the dispatches, by program (``reached``); and the key and value rows
+    that rounds wrote into a table, by how they were written (``written``:
+    ``TinyDecoderModel.rows_path``)."""
 
     TOTALS = ("selecting_steps", "prefill_tokens", "prefill_chunks", "prefill_ns")
     ROWS = ("window_rows_read", "summary_rows_read", "summaries_written")
@@ -110,6 +133,7 @@ class RungCount:
         self._totals = dict.fromkeys(self.TOTALS, 0)
         self._rows = dict.fromkeys(self.ROWS, 0)
         self._reached: Dict[str, Tuple[int, int]] = {}
+        self._written: Dict[str, int] = {}
 
     def add(self, live: int) -> None:
         with self._lock:
@@ -141,6 +165,10 @@ class RungCount:
             reached, n = self._reached.get(program, (0, 0))
             self._reached[program] = (reached + experts, n + dispatches)
 
+    def add_written(self, path: str, rows: int) -> None:
+        with self._lock:
+            self._written[path] = self._written.get(path, 0) + rows
+
     def by_rung(self) -> Dict[int, int]:
         with self._lock:
             return dict(self._steps)
@@ -158,6 +186,11 @@ class RungCount:
         tallies none."""
         with self._lock:
             return dict(self._reached)
+
+    def written(self) -> Dict[str, int]:
+        """``{path: rows}``, empty for a decoder whose rounds wrote none."""
+        with self._lock:
+            return dict(self._written)
 
 
 class TinyDecoderModel(Model):
@@ -204,6 +237,10 @@ class TinyDecoderModel(Model):
         self._rungs = (self.MAX_LEN,)
         self._warm = False  # every rung's program is compiled
         self._warm_lock = threading.Lock()
+        # how ``write_table_rows`` writes a round's rows where this decoder's
+        # programs call it: "kernel" or "loop" (set by ``_build``); None
+        # where they never call it
+        self.rows_path: Optional[str] = None
         self.steps_by_rung = RungCount()
         self._sequences: Dict[Any, Dict[str, Any]] = {}
         # per-sequence serialization: concurrent requests on one sequence_id
@@ -236,6 +273,9 @@ class TinyDecoderModel(Model):
         import jax
         import jax.numpy as jnp
         from jax import lax
+
+        from .. import ops
+        from ..ops import row_write
 
         D, H, L, V, M = (self.D_MODEL, self.HEADS, self.LAYERS, self.VOCAB,
                          self.MAX_LEN)
@@ -293,20 +333,20 @@ class TinyDecoderModel(Model):
             have their *positions* on the lanes, where an update of the row
             alone costs 6.7 us and one of the aligned 128 positions round it
             2.4.) Nothing is masked over a cache: for that the compiler lays
-            every stacked cache out anew and back."""
-            active_first = jnp.argsort(~active, stable=True)
+            every stacked cache out anew and back.
 
-            def write(turn, caches):
-                slot = active_first[turn]
-                return tuple(
-                    lax.dynamic_update_slice(
-                        cache,
-                        lax.dynamic_index_in_dim(slot_rows, slot, keepdims=True),
-                        (slot, 0, pos[slot], 0))
-                    for cache, slot_rows in zip(caches, rows))
-
-            return lax.fori_loop(0, jnp.sum(active, dtype=jnp.int32), write,
-                                 caches)
+            On the chip (``rows_path`` "kernel", where the kernel ``takes``
+            the table: rows across whole tiles of lanes) one Pallas kernel a
+            layer writes every active slot's rows by DMA instead
+            (``ops/row_write.py``): the loop's turns, and not its bytes, cost
+            2.75 ms of a 5.54 ms round of sixteen gpt2-large members on a
+            v5e (PERF.md section 5). Elsewhere the loop stands: the CPU's
+            compiler has no lanes, and a kernel a layer in interpret mode
+            would slow every round there."""
+            if self.rows_path == "kernel":
+                return row_write.write_table_rows(
+                    caches, rows, pos, active, interpret=not ops._on_tpu())
+            return write_rows_in_turns(caches, rows, pos, active)
 
         @jax.custom_batching.custom_vmap
         def write_slot_rows(caches, rows, pos, active):
@@ -634,6 +674,8 @@ class TinyDecoderModel(Model):
             return jax.jit(step, donate_argnums=1, static_argnames="live")
 
         self._params = params
+        self.rows_path = ("kernel" if ops._on_tpu() and row_write.takes(
+            self._table_shape(1)) else "loop")
         self._step_fn = jax.jit(step, donate_argnums=1,
                                 static_argnames="live")
         if self._attention_impl == "einsum":
@@ -680,6 +722,13 @@ class TinyDecoderModel(Model):
         if self.D_MODEL // self.HEADS < LANES:
             return slots
         return in_whole_turns(slots, occupied)
+
+    def count_rows_written(self, count: RungCount, members: int) -> None:
+        """A round's key and value rows of ``members`` streams, a pair a
+        layer, by how ``write_table_rows`` wrote them (``rows_path``);
+        nothing where this decoder's programs never call it."""
+        if self.rows_path is not None:
+            count.add_written(self.rows_path, 2 * self.LAYERS * members)
 
     def count_positions(self, count: RungCount, positions, decoding: bool) -> None:
         """What the tokens at ``positions`` (a prompt's, or decode steps')
@@ -743,6 +792,13 @@ class TinyDecoderModel(Model):
             for _ in range(self.LAYERS)
         ]
 
+    def _table_shape(self, slots: int) -> Tuple[int, int, int, int]:
+        """A layer's k (or v) of ``_fresh_table(slots)``."""
+        Dh = self.D_MODEL // self.HEADS
+        P = (heads_a_row(self.HEADS, Dh) if self._attention_impl == "einsum"
+             else 1)
+        return (slots, self.HEADS // P, self.MAX_LEN, P * Dh)
+
     def _fresh_table(self, slots: int):
         """``slots`` caches, stacked: [slots, heads / P, max_len, P x
         head_dim] a layer, zeros, a position's row of a slot ``P =
@@ -751,10 +807,7 @@ class TinyDecoderModel(Model):
         head a row."""
         import jax.numpy as jnp
 
-        Dh = self.D_MODEL // self.HEADS
-        P = (heads_a_row(self.HEADS, Dh) if self._attention_impl == "einsum"
-             else 1)
-        shape = (slots, self.HEADS // P, self.MAX_LEN, P * Dh)
+        shape = self._table_shape(slots)
         return [{"k": jnp.zeros(shape, jnp.bfloat16),
                  "v": jnp.zeros(shape, jnp.bfloat16)}
                 for _ in range(self.LAYERS)]

@@ -525,6 +525,7 @@ class BatchedDecoderModel(Model):
             width = len(members)
             self.batch_histogram[width] = self.batch_histogram.get(width, 0) + 1
             self.steps_by_rung.add(live)
+            self._decoder.count_rows_written(self.steps_by_rung, width)
             if self.report_batch is not None:
                 self.report_batch(width, dispatch.ns)
             answered = []

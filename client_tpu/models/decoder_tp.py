@@ -176,6 +176,7 @@ class TPDecoderModel(TinyDecoderModel):
 
         self._rungs = (M,)  # this step reads the whole cache
         self._round_fn = None  # the base's round reads the base's weights
+        self.rows_path = None  # nor does its step write a table's rows
         self._step_fn = jax.jit(
             step, out_shardings=(
                 NamedSharding(mesh, P()),

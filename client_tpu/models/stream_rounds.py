@@ -377,6 +377,7 @@ class StreamRounds:
                     self._vacate(stream)  # its last round: the slot is the next's
             self._sent(gives, dispatch)
             count.add(live)
+            decoder.count_rows_written(count, len(members))
             carried = model.batch_histogram
             carried[len(members)] = carried.get(len(members), 0) + 1
             model.rounds_by_width[width] = model.rounds_by_width.get(width, 0) + 1

@@ -642,6 +642,14 @@ class ServerCore:
             "Dispatches of the program over which experts_reached is summed",
             ("model", "program"))
 
+        # the rows a model's rounds wrote into its table (RungCount.written)
+        rows_written = reg.gauge(
+            "client_tpu_server_rows_written",
+            "Key and value rows that a model's rounds wrote into its table of "
+            "caches, counted at dispatch, by how they were written: kernel "
+            "(one DMA kernel a layer) or loop (a turn a member)",
+            ("model", "path"))
+
         # the turns of a model's round worker by phase (timeline.PHASES), and
         # what its requests read of the rounds (Timeline.readings): a pair
         # of series each, a sum and the count it is over
@@ -710,6 +718,8 @@ class ServerCore:
                     for program, (reached, n) in count.reached().items():
                         experts_reached.labels(name, program).set(reached)
                         experts_reached_rounds.labels(name, program).set(n)
+                    for path, rows in count.written().items():
+                        rows_written.labels(name, path).set(rows)
                 rounds = getattr(model, "rounds_by_width", None)
                 if rounds:  # it has run rounds: the slot table is in use
                     for width, n in dict(rounds).items():

@@ -206,18 +206,33 @@ def _cache_updates(text, shape):
 
 
 def _is_written_a_row_at_a_time(text, dims, layers):
-    """A table's rows go in as ``write_table_rows`` says: the table holds as
-    many heads a row as fill the lanes, [slots, H x Dh / 128, M, 128], and
-    every update is one position's row of one slot, [1, H x Dh / 128, 1,
-    128], at every head width: no window of positions round it (what a
-    table a head a row needed at heads of 64) and nothing wider."""
+    """A table's rows go in as ``write_table_rows`` says on the chip: the
+    table holds as many heads a row as fill the lanes, [slots, H x Dh / 128,
+    M, 128], and a layer's pair of tables is written by one ``row_write``
+    kernel (ops/row_write.py) that takes both where they lie in HBM and
+    aliases them to its outputs: no other operation updates a table, and
+    neither the kernel's operands nor its outputs are set aside in fast
+    memory (``S(1)``), which the compiler did round a kernel whose outputs
+    named no memory."""
+    import re
+
     from client_tpu.models.decoder import LANES
 
     slots, rows, length, width = dims
     assert width == LANES, dims
-    updates = _cache_updates(text, ",".join(str(n) for n in dims))
-    assert len(updates) == 2 * layers, updates
-    assert {update for update, _ in updates} == {(1, rows, 1, width)}, updates
+    shape = ",".join(str(n) for n in dims)
+    assert not _cache_updates(text, shape), _cache_updates(text, shape)
+    table = rf"bf16\[{shape}\]\{{[^}}S]*\}}"
+    kernels = re.findall(
+        rf"%row_write[\w.]* = \({table}, {table}\) custom-call\((.*)", text)
+    assert len(kernels) == layers, len(kernels)
+    for kernel in kernels:
+        assert 'custom_call_target="tpu_custom_call"' in kernel, kernel[:300]
+        # operands: order, count, pos, the two rows, the two tables
+        assert ("output_to_operand_aliasing={{0}: (5, {}), {1}: (6, {})}"
+                in kernel), kernel[:600]
+    near = re.findall(rf"= bf16\[{shape}\]\{{[^}}]*S\(1\)", text)
+    assert not near, f"{len(near)} tables in fast memory: {near[:2]}"
 
 
 def _reads_heads_side_by_side_exactly(text, head):
@@ -271,7 +286,7 @@ def _reads_heads_side_by_side_exactly(text, head):
     ("jit_batched_step", 128, 2048),
 ])
 def test_the_step_writes_its_donated_caches_in_place_on_the_chip(
-        chip, program, head, live):
+        chip, as_on_chip, program, head, live):
     """What the CPU cannot show: compiled for a v5e, the step aliases every
     cache to an output and moves no whole cache into another layout, at
     every rung of the ladder. (The scatter that ``vmap`` alone makes of the
@@ -281,9 +296,9 @@ def test_the_step_writes_its_donated_caches_in_place_on_the_chip(
     cache is moved through fast memory ahead of the read. The batcher reads
     its caches where they lie at its top rung too (its one product a stacked
     cache had every cache staged through fast memory and back: PR 37). The
-    batcher's rows go in a position's row of a slot at a time, as many heads
-    a row as fill the lanes, and the products over such rows read each
-    head's float32 math."""
+    batcher's rows go in by one DMA kernel a layer over its tables where
+    they lie, as many heads a row as fill the lanes, and the products over
+    such rows read each head's float32 math."""
     import re
 
     from client_tpu.models.decoder_batched import BatchedDecoderModel
@@ -345,7 +360,7 @@ def _outside_fusions(text):
 @pytest.mark.parametrize("head, live", [
     (64, 256), (64, 1024), (128, 512), (128, 2048)])
 def test_the_round_writes_its_table_in_place_and_reads_it_where_it_lies(
-        chip, head, live):
+        chip, as_on_chip, head, live):
     """The stream model's round (``decoder._round_fn``, traced as
     ``jit_step``) at every rung, held to what the steps above are held to:
     every stacked cache aliased to an output and none laid out anew; and
@@ -354,9 +369,9 @@ def test_the_round_writes_its_table_in_place_and_reads_it_where_it_lies(
     head fills the lanes, the slots of a turn may be set aside in fast
     memory and nowhere else; where heads are narrower (there, a head a row,
     the compiler laid each turn's slice out anew, 3.0 of a 7.0 ms round on a
-    v5e), nothing of the table is set aside at all. Its rows go in a
-    position's row of a slot at a time, at every head width, and the
-    products over heads side by side read each head's float32 math."""
+    v5e), nothing of the table is set aside at all. Its rows go in by one
+    DMA kernel a layer, at every head width, and the products over heads
+    side by side read each head's float32 math."""
     import re
 
     from client_tpu.models.decoder import LANES, SLOTS_A_TURN, heads_a_row
@@ -373,7 +388,9 @@ def test_the_round_writes_its_table_in_place_and_reads_it_where_it_lies(
     assert aliased.count("-alias)") == 2 * decoder.LAYERS
     run = _outside_fusions(text)
     slots, heads, length, dim = caches[0]["k"].shape
-    assert " while(" in run  # the rows' turns, and the attention's
+    # the attention's turns, where it takes the slots in turns or reads the
+    # top rung in halves; the rows' turns are the kernel's own
+    assert (" while(" in run) == (head >= LANES or live == length), head
     _is_written_a_row_at_a_time(text, (slots, heads, length, dim),
                                 decoder.LAYERS)
     _reads_heads_side_by_side_exactly(text, head)

@@ -51,6 +51,24 @@ def test_the_packed_form_writes_the_table_as_the_decoder_lays_it():
                       ("packed", (slots, heads // P, length, P * dim))}
 
 
+def test_a_small_run_of_the_kernel_writes_the_loops_table(capsys):
+    """``kernel``, ops/row_write.py in interpret mode off the chip, over the
+    table as the decoder lays it, is checked bit for bit against ``loop``
+    read a head a row."""
+    from client_tpu.models.decoder import heads_a_row
+
+    assert row_write_chip.main(
+        ["--small", "--repeats", "1", "--forms", "kernel"]) == 0
+    out = json.loads(capsys.readouterr().out)
+    (case,) = out["agreement"]["cases"]
+    assert case["kernel"] is True
+    (slots, heads, length, dim), _ = row_write_chip.SMALL_TABLES["small"]
+    P = heads_a_row(heads, dim)
+    assert [(r["form"], r["active"], tuple(r["shape"])) for r in out["forms"]] == [
+        ("kernel", width, (slots, heads // P, length, P * dim))
+        for width in (1, 4)]
+
+
 def test_main_times_the_forms_it_is_given(capsys):
     assert row_write_chip.main(
         ["--small", "--repeats", "1", "--forms", "packed"]) == 0

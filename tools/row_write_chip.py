@@ -28,6 +28,10 @@ number that is in the repository and not in prose:
   (gpt2-large's [16, 10, 1024, 128]): a position's row of a slot fills the
   lanes, and the turn updates it alone (the table and the rows are the
   other forms', laid so; at heads of 128 it is ``loop``);
+- ``kernel``: the same table and rows written by ``ops/row_write.py``, one
+  Pallas kernel a dispatch's layer that DMAs every active slot's aligned
+  window of positions round its row into fast memory, puts the row in, and
+  DMAs it back (what the decoder takes on the chip);
 
 at the two tables the benchmark's cells hold (gpt2-large: 16 slots, 20 heads
 of 64, 1,024 positions, 36 layers; cerebras-gpt-1.3b: 16 heads of 128, 2,048
@@ -41,11 +45,11 @@ time. Reported: the median dispatch in ms (a round's row writes at that
 depth) and us a layer. ``agreement`` says that the forms leave the same
 table wherever a step could read it: bit for bit ``loop``'s, but for
 ``flat_noread`` at row ``pos`` of a slot that is not active (``packed``'s
-read a head a row).
+and ``kernel``'s read a head a row).
 
 Run on the chip (or with --small off the chip for a pipeline check):
     python tools/row_write_chip.py [--json-out PATH] [--small]
-        [--forms loop_window,packed]
+        [--forms loop_window,packed,kernel]
 """
 
 from __future__ import annotations
@@ -63,9 +67,13 @@ TABLES = {
     "gpt2-large": ((16, 20, 1024, 64), 36),
     "cerebras-gpt-1.3b": ((16, 16, 2048, 128), 24),
 }
-SMALL_TABLES = {"small": ((4, 2, 16, 8), 2)}
+# heads of 64, two a row of 128 lanes as the decoder lays them: a table the
+# ``kernel`` form takes (``ops/row_write.py:takes``)
+SMALL_TABLES = {"small": ((4, 2, 16, 64), 2)}
 FORMS = ("loop", "flat_readback", "flat_noread", "flat_window", "slots_window",
-         "loop_window", "packed")
+         "loop_window", "packed", "kernel")
+# the forms over the table as the decoder lays it (``packed``)
+PACKED = ("packed", "kernel")
 # the positions a window holds: the lanes of a tile
 WINDOW = 128
 
@@ -89,8 +97,10 @@ def packed(table, heads=None):
 def forms(jnp, lax):
     """Every form, each ``(caches, rows, pos, active) -> caches`` over
     caches (k, v) of [slots, H, M, Dh], rows of [slots, H, 1, Dh], ``pos``
-    int32 [slots] and ``active`` bool [slots]; ``packed`` over both laid as
-    ``packed`` lays them."""
+    int32 [slots] and ``active`` bool [slots]; those of ``PACKED`` over
+    both laid as ``packed`` lays them."""
+    from client_tpu import ops
+    from client_tpu.ops.row_write import write_table_rows
 
     def loop(caches, rows, pos, active):
         active_first = jnp.argsort(~active, stable=True)
@@ -175,8 +185,12 @@ def forms(jnp, lax):
 
         return write
 
+    def kernel(caches, rows, pos, active):
+        return write_table_rows(caches, rows, pos, active,
+                                interpret=not ops._on_tpu())
+
     return dict(zip(FORMS, (loop, flat(True), flat(False), window("flat"),
-                            window("slots"), window("active"), loop)))
+                            window("slots"), window("active"), loop, kernel)))
 
 
 def layered(jax, form):
@@ -194,7 +208,7 @@ def _operands(jnp, np, shape, layers, width, seed=0, form=None):
     """A pair of tables of noise a layer, every layer's rows for every slot,
     positions apart from each other, and ``width`` (a divisor of the slots)
     slots active; tables and rows laid as ``packed`` lays them for the
-    ``packed`` form."""
+    forms of ``PACKED``."""
     slots, heads, length, dim = shape
     rng = np.random.default_rng(seed)
     noise = lambda *dims: jnp.asarray(
@@ -206,7 +220,7 @@ def _operands(jnp, np, shape, layers, width, seed=0, form=None):
                         for _ in range(layers - 1)]
     rows = (noise(layers, slots, heads, 1, dim),
             noise(layers, slots, heads, 1, dim))
-    if form == "packed":
+    if form in PACKED:
         tables = [tuple(packed(cache) for cache in pair) for pair in tables]
         rows = tuple(packed(r) for r in rows)
     pos = jnp.asarray((3 + 5 * np.arange(slots)) % length, jnp.int32)
@@ -228,10 +242,13 @@ def check_agreement(jax, jnp, np, lax, tables, chosen=FORMS):
         for form_name, form in forms(jnp, lax).items():
             if form_name != "loop" and form_name not in chosen:
                 continue
-            pairs, rows, pos, active = _operands(jnp, np, shape, 1, width,
+            # two layers: a dispatch of one kernel alone on a donated pair
+            # is refused by the chip's compiler (an output in another memory
+            # than the buffer it aliases), which no served program is
+            pairs, rows, pos, active = _operands(jnp, np, shape, 2, width,
                                                  form=form_name)
             out = layered(jax, form)(pairs, rows, pos, active)
-            if form_name == "packed":
+            if form_name in PACKED:
                 out = [[packed(c, shape[1]) for c in out[0]]]
             left[form_name] = [np.asarray(c).view(np.uint16) for c in out[0]]
         pos, active = np.asarray(pos), np.asarray(active)
