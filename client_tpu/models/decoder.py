@@ -194,7 +194,9 @@ class RungCount:
 
 
 class TinyDecoderModel(Model):
-    """``decoder_lm``: 2-layer pre-norm transformer decoder fixture."""
+    """``decoder_lm``: a pre-norm transformer decoder of the GPT-2 family,
+    at the fixture's sizes below unless a subclass sets them
+    (``benchmark/builders.py`` does)."""
 
     name = "decoder_lm"
     platform = "jax"
@@ -219,10 +221,13 @@ class TinyDecoderModel(Model):
         self._lock = threading.Lock()
         self._params = None
         self._step_fn = None
-        # the round of a table of slots (``_fresh_table``), for a model whose
-        # streams share a dispatch (generate.py); None where this decoder
-        # has no such program: the Pallas kernel takes the whole cache of
-        # every slot, and a subclass that jits a step of its own brings none
+        # the step of a table of slots (``_fresh_table``) as the slot
+        # batcher dispatches it (decoder_batched.py), and the round built on
+        # the same step for a model whose streams share a dispatch
+        # (generate.py); None where this decoder has no such program: the
+        # stream path takes no Pallas kernel, and a subclass that jits a
+        # step of its own brings neither
+        self._table_fn = None
         self._round_fn = None
         # a prompt's chunk into a slot of that table, for a decoder whose
         # prompts are too long for a round a token: ``(params, table, fed,
@@ -309,13 +314,6 @@ class TinyDecoderModel(Model):
             var = jnp.var(x32, axis=-1, keepdims=True)
             return ((x32 - mu) * lax.rsqrt(var + 1e-5)).astype(x.dtype)
 
-        def write_rows(caches, rows, pos):
-            """``caches`` (k, v), each laid as ``_fresh_cache`` or a slot of
-            ``_fresh_table`` lays it, with ``rows``, each laid alike one
-            position long (``as_rows``), at ``pos``."""
-            return tuple(lax.dynamic_update_slice(cache, row, (0, pos, 0))
-                         for cache, row in zip(caches, rows))
-
         def write_table_rows(caches, rows, pos, active):
             """Stacked caches (k, v), each [slots, H/P, M, P x Dh] as
             ``_fresh_table`` lays them, with ``rows``, each [W, H/P, 1,
@@ -347,24 +345,6 @@ class TinyDecoderModel(Model):
                 return row_write.write_table_rows(
                     caches, rows, pos, active, interpret=not ops._on_tpu())
             return write_rows_in_turns(caches, rows, pos, active)
-
-        @jax.custom_batching.custom_vmap
-        def write_slot_rows(caches, rows, pos, active):
-            """``write_rows`` for one slot of the batcher: where ``active``
-            is false the slot's caches stay as they are."""
-            return lax.cond(active, lambda: write_rows(caches, rows, pos),
-                            lambda: caches)
-
-        @write_slot_rows.def_vmap
-        def write_active_rows(slots, batched, caches, rows, pos, active):
-            """The slot batcher's ``vmap`` of the above, over stacked caches:
-            ``write_table_rows``. ``vmap``'s own rule makes a scatter of the
-            per-slot position (and a select of the ``cond``), for which the
-            chip's compiler lays every stacked cache out anew and back again
-            each round."""
-            if not all(jax.tree_util.tree_leaves(batched)):
-                raise NotImplementedError("every operand is a slot's own")
-            return write_table_rows(caches, rows, pos, active), (True, True)
 
         def embed(params, token, pos):
             with jax.named_scope("embed"):
@@ -457,29 +437,20 @@ class TinyDecoderModel(Model):
                 return weighed(
                     "", probs, v[:, :live].astype(jnp.float32))
 
-        @jax.custom_batching.custom_vmap
-        def read_slot(q, k, v, pos):
-            """``attention`` of one slot of the slot batcher at the top rung,
-            over the slot's whole cache."""
-            return attention(q, k, v, pos, live=M)
-
-        @read_slot.def_vmap
-        def read_table(slots, batched, q, k, v, pos):
-            """The slot batcher's ``vmap`` of the above, over stacked caches
-            [slots, H, M, Dh]. ``vmap``'s own rule makes one product a
-            stacked cache, and beside the rows' ``while`` the chip's
-            compiler then stages every such cache through fast memory and
-            back each round: 6.4 of a 12.3 ms round of sixteen at 1,024
-            positions (PERF.md section 6, PR 37). Here each product is a
-            ``while`` of two turns, half the positions of every slot a turn,
-            each a slice of the table fused into the product: the table is
-            read where it lies, once. The same float32 products, mask and
-            softmax: the products are at ``HIGHEST`` precision, since at the
-            default the chip rounds their float32 operands to bfloat16 (the
-            probabilities, and the query, which the parent's one product took
-            as the query's product left it, unrounded)."""
-            if not all(batched):
-                raise NotImplementedError("every operand is a slot's own")
+        def read_table(q, k, v, pos):
+            """The attention of every slot of a table [slots, H/P, M, P x
+            Dh] over its whole cache, the top rung: ``attention`` under
+            ``vmap`` makes one product a table, and beside the rows'
+            ``while`` the chip's compiler then stages every table through
+            fast memory and back each round: 6.4 of a 12.3 ms round of
+            sixteen at 1,024 positions (PERF.md section 6). Here each
+            product is a ``while`` of two turns, half the positions of every
+            slot a turn, each a slice of the table fused into the product:
+            the table is read where it lies, once. The same float32
+            products, mask and softmax: the products are at ``HIGHEST``
+            precision, since at the default the chip rounds their float32
+            operands to bfloat16."""
+            slots = q.shape[0]
             with jax.named_scope("attention"):
                 half = M // 2
 
@@ -508,7 +479,7 @@ class TinyDecoderModel(Model):
                         rows(v, n), highest)
 
                 return lax.fori_loop(
-                    0, 2, weigh, jnp.zeros((slots, H, Dh), jnp.float32)), True
+                    0, 2, weigh, jnp.zeros((slots, H, Dh), jnp.float32))
 
         def rest_of_layer(layer, x, attn):
             """The layer after its attention ``attn`` [H, Dh]."""
@@ -523,36 +494,19 @@ class TinyDecoderModel(Model):
             with jax.named_scope("unembed"):
                 return (norm(x) @ params["unembed"]).astype(jnp.float32)
 
-        def one_layer(layer, cache, x, pos, active, *, live):
-            """One layer of the step: ``(x, cache)`` with the row at ``pos``
-            written."""
+        def one_layer(layer, cache, x, pos, *, live):
+            """One layer of a single sequence's step: ``(x, cache)`` with
+            the row at ``pos`` written."""
             q, k_new, v_new = qkv_rows(layer, x)
             with jax.named_scope("cache_update"):
-                held = cache["k"], cache["v"]
-                rows = as_rows(k_new, held[0]), as_rows(v_new, held[1])
-                k, v = (write_rows(held, rows, pos) if active is None
-                        else write_slot_rows(held, rows, pos, active))
-            if (active is None or live < M
-                    or self._attention_impl == "pallas"):
-                attn = attention(q, k, v, pos, live=live)
-            else:  # the slot batcher's top rung
-                attn = read_slot(q, k, v, pos)
-            x = rest_of_layer(layer, x, attn)
+                k, v = (lax.dynamic_update_slice(held, as_rows(new, held),
+                                                 (0, pos, 0))
+                        for held, new in ((cache["k"], k_new),
+                                          (cache["v"], v_new)))
+            x = rest_of_layer(layer, x, attention(q, k, v, pos, live=live))
             return x, {"k": k, "v": v}
 
-        # The slot batcher's layer is a jitted call: every layer has the same
-        # shapes, so its program traces the body, batches it under ``vmap``
-        # and lowers it once and not once a layer, which was most of what a
-        # program costs a warm set-up (1.4 s of Python at 36 layers, 0.15 s
-        # so), and there are as many programs as rungs. The chip's compiler
-        # inlines the calls: operation for operation the program it made
-        # without them. A single sequence's step stays one flat trace: the
-        # CPU's compiler rounds a bfloat16 carried across a call that it
-        # keeps in float32 within one computation, and decoder_tp.py's step
-        # is held bit-equal to this one there.
-        one_slot_layer = jax.jit(one_layer, static_argnames="live")
-
-        def step(params, caches, token, pos, active=None, *, live=M):
+        def step(params, caches, token, pos, *, live=M):
             """One decode step. caches: [L] dicts of k/v [H, M, Dh].
 
             ``live`` is a compile-time length, a rung of the ladder that
@@ -563,18 +517,14 @@ class TinyDecoderModel(Model):
             The step owns ``caches``: the jitted programs donate them, so
             the returned caches are the same buffers with row ``pos``
             written and the caller's handle on the old ones is dead.
-            ``active`` is the slot batcher's alone (a slot that rides along
-            with ``active`` false writes no row); the single-sequence
-            programs never pass it.
 
             The named scopes are compile-time metadata: the device
             operations of a trace carry them, so that device time reads by
             part of the step whatever the compiler names its fusions."""
             x = embed(params, token, pos)
             new_caches = []
-            a_layer = one_layer if active is None else one_slot_layer
             for layer, cache in zip(params["layers"], caches):
-                x, cache = a_layer(layer, cache, x, pos, active, live=live)
+                x, cache = one_layer(layer, cache, x, pos, live=live)
                 new_caches.append(cache)
             return unembed(params, x), new_caches
 
@@ -610,74 +560,103 @@ class TinyDecoderModel(Model):
                 return lax.fori_loop(
                     0, turns, turn, jnp.zeros((slots, H, Dh), jnp.float32))
 
+        def read_round(q, k, v, pos, turns, *, live):
+            """The attention of a round over a table, the one rule for how a
+            table is read: [slots, H, Dh]. Where a round reads every slot
+            (``reads_every_slot``) it reads each slot's prefix where it
+            lies, ``attention`` over the slots, and at the top rung the
+            whole table in ``read_table``'s two turns (the Pallas kernel
+            takes every slot's whole cache at its one rung): for sixteen
+            gpt2-large members that read 1.56 ms of a round of 36 layers on
+            a v5e and the turns 2.34 (laid a head a row, the compiler set
+            each turn's slice aside and laid it out anew: 3.0 ms; PERF.md
+            section 6). Where a head fills a tile's lanes it reads the
+            occupied slots in ``turns`` (``slot_turns``): a round costs the
+            caches of its live streams, in width as the rung makes it in
+            length. ``slots_read`` counts what it read."""
+            if not self.reads_every_slot():
+                return slot_turns(q, k, v, pos, turns, live=live)
+            if live == M and self._attention_impl == "einsum":
+                return read_table(q, k, v, pos)
+            return over_slots(functools.partial(attention, live=live), 0)(
+                q, k, v, pos)
+
         def round_layer(layer, cache, x, pos, active, turns, *, live):
             """One layer of a round over a table: ``vmap`` of the step's own
             parts over the slots, round the one row write that takes the
-            whole table where it lies. The products with the weights take
-            every slot (a slot more costs them nothing: they read the
-            weights). How the attention takes the slots goes by the head's
-            width. Where a head fills a tile's lanes it takes the occupied
-            slots in turns (``slot_turns``): a round costs the caches of its
-            live streams, in width as the rung makes it in length, in one
-            program a rung. Where heads are narrower (a row of the table
-            holds several, ``_fresh_table``), the attention reads every
-            slot's prefix where it lies, as the slot batcher does
-            (``one_layer``, ``read_slot`` at the top rung): for sixteen
-            members that read 1.56 ms of a round of 36 layers on a v5e and
-            the turns 2.34 (laid a head a row, the compiler set each turn's
-            slice aside and laid it out anew: 3.0 ms; PERF.md section 6).
-            ``slots_read`` says which slots a round read."""
+            whole table where it lies and the one read (``read_round``).
+            The products with the weights take every slot (a slot more costs
+            them nothing: they read the weights)."""
             q, k_new, v_new = over_slots(qkv_rows, (None, 0))(layer, x)
             with jax.named_scope("cache_update"):
                 k, v = write_table_rows(
                     (cache["k"], cache["v"]),
                     (as_rows(k_new, cache["k"]), as_rows(v_new, cache["v"])),
                     pos, active)
-            if Dh < LANES:
-                read = (functools.partial(attention, live=live) if live < M
-                        else read_slot)
-                attn = over_slots(read, 0)(q, k, v, pos)
-            else:
-                attn = slot_turns(q, k, v, pos, turns, live=live)
+            attn = read_round(q, k, v, pos, turns, live=live)
             x = over_slots(rest_of_layer, (None, 0, 0))(layer, x, attn)
             return x, {"k": k, "v": v}
 
+        # A jitted call: every layer has the same shapes, so a program traces
+        # and lowers the layer once and not once a layer, which was most of
+        # what a program costs a warm set-up (1.4 s of Python at 36 layers,
+        # 0.15 s so). The chip's compiler inlines the calls. A single
+        # sequence's step stays one flat trace: the CPU's compiler rounds a
+        # bfloat16 carried across a call that it keeps in float32 within one
+        # computation, and decoder_tp.py's step is held bit-equal to it there.
         a_round_layer = jax.jit(round_layer, static_argnames="live")
+
+        def table_step(params, caches, token, pos, active, *, live):
+            """One step of every slot of a table. caches: [L] dicts of k/v
+            as ``_fresh_table`` lays them. ``token``, ``pos`` int32 [slots];
+            ``active`` bool [slots]: whether the slot's sequence has a token
+            in the step. An inactive slot (a full one, a freed one) has no
+            row written and its logits go unread. Returns the logits,
+            float32 [slots, V], and the caches."""
+            slots = active.shape[0]
+            occupied = jnp.max(jnp.where(active, jnp.arange(slots) + 1, 0))
+            turns = -(-occupied // slots_a_turn(slots))
+            x = over_slots(embed, (None, 0, 0))(params, token, pos)
+            new_caches = []
+            for layer, cache in zip(params["layers"], caches):
+                x, cache = a_round_layer(
+                    layer, cache, x, pos, active, turns, live=live)
+                new_caches.append(cache)
+            return over_slots(unembed, (None, 0))(params, x), new_caches
 
         def round_program():
             # traced as ``jit_step``: a round is the step of a model whose
             # streams share it, and a trace is read by that name
             def step(params, caches, fed, ctl, *, live):
-                """One round over a table of slots. caches: [L] dicts of k/v
-                [slots, H, M, Dh], donated. ``fed`` int32 [slots]: the
-                tokens the round before chose, still on the device. ``ctl``
-                int32 [3, slots], the host's word a slot: a token of its own
-                (a prompt's) or -1 for the fed one; the position; whether a
-                stream sits there. Returns the greedy choice of every slot,
-                int32 [slots], and the caches."""
+                """One round over a table of slots, caches donated. ``fed``
+                int32 [slots]: the tokens the round before chose, still on
+                the device. ``ctl`` int32 [3, slots], the host's word a
+                slot: a token of its own (a prompt's) or -1 for the fed one;
+                the position; whether a stream sits there. Returns the
+                greedy choice of every slot, int32 [slots], and the
+                caches."""
                 given, pos, active = ctl[0], ctl[1], ctl[2] > 0
-                slots = active.shape[0]
-                occupied = jnp.max(jnp.where(active, jnp.arange(slots) + 1, 0))
-                turns = -(-occupied // slots_a_turn(slots))
                 token = jnp.where(given >= 0, given, fed)
-                x = over_slots(embed, (None, 0, 0))(params, token, pos)
-                new_caches = []
-                for layer, cache in zip(params["layers"], caches):
-                    x, cache = a_round_layer(
-                        layer, cache, x, pos, active, turns, live=live)
-                    new_caches.append(cache)
-                logits = over_slots(unembed, (None, 0))(params, x)
+                logits, caches = table_step(params, caches, token, pos,
+                                            active, live=live)
                 with jax.named_scope("greedy_argmax"):
                     return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
-                            new_caches)
+                            caches)
 
             return jax.jit(step, donate_argnums=1, static_argnames="live")
+
+        def batched_step(params, caches, tokens, pos, active, *, live=M):
+            """The slot batcher's round (decoder_batched.py), caches
+            donated: ``table_step``, the logits of every slot."""
+            return table_step(params, caches, tokens, pos, active, live=live)
 
         self._params = params
         self.rows_path = ("kernel" if ops._on_tpu() and row_write.takes(
             self._table_shape(1)) else "loop")
         self._step_fn = jax.jit(step, donate_argnums=1,
                                 static_argnames="live")
+        self._table_fn = jax.jit(batched_step, donate_argnums=1,
+                                 static_argnames="live")
         if self._attention_impl == "einsum":
             self._rungs = self.ladder()
             self._round_fn = round_program()
@@ -714,12 +693,20 @@ class TinyDecoderModel(Model):
                     _, caches = self._step_at(caches, 0, 0, live)
             self._warm = True
 
+    def reads_every_slot(self) -> bool:
+        """Whether a round's attention reads every slot of its table
+        (``read_round``): where heads are narrower than the lanes, and
+        through the Pallas kernel; where a head fills them it reads the
+        occupied slots in turns."""
+        return (self._attention_impl == "pallas"
+                or self.D_MODEL // self.HEADS < LANES)
+
     def slots_read(self, slots: int, occupied: int) -> int:
         """The slots whose caches a round's attention reads over a table of
-        ``slots`` whose highest occupied slot is ``occupied - 1``: every
-        slot where heads are narrower than the lanes, the occupied ones in
-        whole turns where a row fills them (``round_layer``)."""
-        if self.D_MODEL // self.HEADS < LANES:
+        ``slots`` whose highest occupied slot is ``occupied - 1``
+        (``reads_every_slot``): every slot, or the occupied ones in whole
+        turns."""
+        if self.reads_every_slot():
             return slots
         return in_whole_turns(slots, occupied)
 

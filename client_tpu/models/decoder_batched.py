@@ -12,11 +12,12 @@ a [1, ...] step until S fills the MXU tile.
 ``decoder_lm_batched`` is the TPU-first version: per-slot KV caches live
 stacked on device (decoder.py's ``_fresh_table``: [slots, heads / P,
 max_len, P x head_dim] per layer, P heads side by side a row so that a row
-fills the chip's lanes), and ONE jitted batched step (``jax.vmap`` of the
-decoder's single-sequence step, which reads a cache as it is laid) advances
-every sequence that has a request in progress. The step owns the stacked
-caches (they are donated to it) and writes one position's row a layer for
-every active slot, in place.
+fills the chip's lanes), and ONE jitted step of the whole table advances
+every sequence that has a request in progress: decoder.py's table step, the
+one the stream model's round is built on (``_table_fn``). The decoder owns
+that program and this module the schedule. The step owns the stacked caches
+(they are donated to it) and writes one position's row a layer for every
+active slot, in place.
 
 **The unit of scheduling is the round**, one dispatch of that step. The
 worker keeps one table of the requests in progress, one a sequence. Before
@@ -37,16 +38,15 @@ dispatched and not yet read back, so a request waits for at most that many
 rounds before its own, however long a prompt beside it is; while the device
 works, the round in flight is the gather. A slot whose sequence has no
 request in the table is masked: the step writes no row of its
-(``active``, decoder.py's ``write_active_rows``: a turn an active slot, a
-masked one is not touched) and its logits row goes unread, which keeps the
-executable static-shape — the same compile-once
-property the single-sequence decoder has. Once a rung, that is: a round's
-attention reads the prefix of the caches that covers the furthest of the
-round's members (decoder.py's ladder; a slot that rides along inactive may
-hold any older position, its row is not read), and the worker compiles
-every rung's program before it takes its first request. At the top rung the
-read is the whole table, which decoder.py's ``read_table`` takes in two turns
-of half its positions, where it lies.
+(``active``, decoder.py's ``write_table_rows``: a masked slot is not
+touched) and its logits row goes unread, which keeps the executable
+static-shape — the same compile-once property the single-sequence decoder
+has. Once a rung, that is: a round's attention reads the prefix of the
+caches that covers the furthest of the round's members (decoder.py's
+ladder; a slot that rides along inactive may hold any older position, its
+row is not read), and the worker compiles every rung's program before it
+takes its first request. Which slots and positions the attention reads is
+decoder.py's one rule for a table (``read_round``).
 
 Weights come from a composed TinyDecoderModel (same seed ⇒ greedy tokens
 match the unbatched fixture token-for-token — pinned by the tests).
@@ -55,7 +55,6 @@ match the unbatched fixture token-for-token — pinned by the tests).
 from __future__ import annotations
 
 import collections
-import functools
 import queue
 import threading
 import time
@@ -192,23 +191,12 @@ class BatchedDecoderModel(Model):
             if self._built:
                 return
             self._decoder._ensure_built()
-            import jax
-
-            dec = self._decoder
             S = self.slots
-            # (params, caches, token, pos, active) per sequence; a slot
-            # that is not ``active`` writes no cache row, inside the step.
-            # ``live`` is the round's, one compile-time length for all slots
-
-            def batched_step(params, caches, tokens, pos, active, *,
-                             live=dec.MAX_LEN):
-                return jax.vmap(
-                    functools.partial(dec._step_fn, live=live),
-                    in_axes=(None, 0, 0, 0, 0))(
-                        params, caches, tokens, pos, active)
-
-            self._batched_step = jax.jit(batched_step, donate_argnums=1,
-                                         static_argnames="live")
+            # the decoder's table step, traced as ``jit_batched_step``:
+            # (params, caches, tokens, pos, active) a slot each, caches
+            # donated, ``live`` the round's one compile-time length; a slot
+            # that is not ``active`` has no row written
+            self._batched_step = self._decoder._table_fn
             self._caches = self._fresh_caches()
             # positions live HOST-side (0 on start, +1 per active token —
             # fully derivable without a device readback) and ship to the

@@ -175,7 +175,8 @@ class TPDecoderModel(TinyDecoderModel):
             return logits, new_caches
 
         self._rungs = (M,)  # this step reads the whole cache
-        self._round_fn = None  # the base's round reads the base's weights
+        # the base's table step and round read the base's weights
+        self._table_fn = self._round_fn = None
         self.rows_path = None  # nor does its step write a table's rows
         self._step_fn = jax.jit(
             step, out_shardings=(

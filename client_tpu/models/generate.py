@@ -34,12 +34,14 @@ in short:
   ``execute_decoupled`` validates, hands over its prompt, budget and
   ``END_ID`` and then only waits for its tokens and yields them;
 - a round's program has a rung (the live prefix of the positions:
-  decoder.py's ladder), and its attention reads the caches of the occupied
-  slots alone, ``decoder.SLOTS_A_TURN`` slots a turn of a loop that ends
-  after the highest occupied one, or, where heads are narrower than the
-  chip's lanes, every slot's where it lies (``decoder.slots_read``): one
-  program a rung, each compiled in ``_ensure_built``, before the first
-  round;
+  decoder.py's ladder), and is the decoder's table step, the slot
+  batcher's too, between the token's select and the argmax. Its attention
+  reads by decoder.py's one rule for a table (``read_round``): the caches
+  of the occupied slots alone, ``decoder.SLOTS_A_TURN`` slots a turn of a
+  loop that ends after the highest occupied one, or, where heads are
+  narrower than the chip's lanes, every slot's where it lies
+  (``decoder.slots_read`` counts which): one program a rung, each compiled
+  in ``_ensure_built``, before the first round;
 - ``chunk`` > 1 keeps its wire meaning on this path: after the first token
   the tokens are delivered K at a time, off the same rounds.
 

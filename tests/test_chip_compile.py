@@ -87,7 +87,7 @@ def test_decode_attention_compiles(chip, batch, heads, max_len, dim):
 
 
 def test_decode_attention_compiles_under_vmap(chip):
-    """The sequence batcher maps the single-sequence step over its slots."""
+    """The table step maps a single slot's attention over its slots."""
     bf16 = jnp.bfloat16
     _compiles_with_kernel(
         jax.vmap(lambda q, k, v, p: decode_mod.decode_attention(
@@ -289,9 +289,9 @@ def test_the_step_writes_its_donated_caches_in_place_on_the_chip(
         chip, as_on_chip, program, head, live):
     """What the CPU cannot show: compiled for a v5e, the step aliases every
     cache to an output and moves no whole cache into another layout, at
-    every rung of the ladder. (The scatter that ``vmap`` alone makes of the
-    batcher's row writes has each stacked cache copied to a row-major layout
-    and back every round.) At the short rung the attention takes the prefix
+    every rung of the ladder. (A scatter of the rows, which ``vmap`` of a
+    single slot's row write makes, has each stacked cache copied to a
+    row-major layout and back every round.) At the short rung the attention takes the prefix
     of the cache as it lies: no slice of it is materialised, and no whole
     cache is moved through fast memory ahead of the read. The batcher reads
     its caches where they lie at its top rung too (its one product a stacked

@@ -253,8 +253,9 @@ def test_rounds_on_a_table_of_sixteen_write_their_members_rows_alone(
 
 
 def test_the_batched_rows_are_the_single_slot_steps_rows(built):
-    """The batcher's rule for the row writes against the one-slot definition
-    it stands for: each slot through ``jit_step`` with its own ``active``."""
+    """The batcher's row writes against the one-slot definition they stand
+    for: an active slot's caches are its own through ``jit_step``, and an
+    inactive slot's are what they were before the round."""
     import jax
     import jax.numpy as jnp
 
@@ -267,8 +268,9 @@ def test_the_batched_rows_are_the_single_slot_steps_rows(built):
         jnp.asarray(pos, jnp.int32), jnp.asarray(active))
     for slot in range(SLOTS):
         one = jax.tree_util.tree_map(lambda a: jnp.asarray(a[slot]), before)
-        _, one = decoder._step_fn(
-            decoder._params, one, tokens[slot], pos[slot], active[slot])
+        if active[slot]:
+            _, one = decoder._step_fn(
+                decoder._params, one, tokens[slot], pos[slot])
         for got, want in zip(_leaves(after), _leaves(one)):
             got = np.asarray(got[slot], np.float32)
             want = np.asarray(want, np.float32)
@@ -607,6 +609,48 @@ def test_a_table_of_heads_side_by_side_reads_as_a_head_a_row(head):
         _top_rung_against_the_parents_form(model, by_head=True)
         for live in (256, TOP):
             _round_against_its_turns(decoder, live, by_head=True)
+    finally:
+        model.unload()
+
+
+# -- the batcher and the round are one table step ----------------------------
+
+
+@pytest.mark.parametrize("live", [256, TOP], ids=["short", "top"])
+@pytest.mark.parametrize("head", [32, 64, 128])
+def test_the_batchers_step_and_the_round_are_one_table_step(head, live):
+    """At heads of 32, 64 and 128, at a short rung and the top one: from one
+    table of sixteen with the same members, positions and tokens, the slot
+    batcher's logits choose what the stream model's round chooses for every
+    member, and the two tables after the call are bit-equal."""
+    import jax
+    import jax.numpy as jnp
+
+    cls = type(f"Heads{head}", (TinyDecoderModel,), {
+        "D_MODEL": 256, "HEADS": 256 // head, "LAYERS": 2, "MAX_LEN": TOP})
+    model = BatchedDecoderModel(seed=0, slots=ROUND_SLOTS)
+    model._decoder = cls(seed=0)  # composed before the batcher builds
+    model._ensure_built()
+    try:
+        decoder = model._decoder
+        assert decoder._rungs == (256, TOP)
+        tokens = np.arange(ROUND_SLOTS) + 11
+        pos = [(61 * slot + 300) % (live - 1) for slot in range(ROUND_SLOTS)]
+        before, caches = _filled_caches(model, seed=11)
+        logits, batched = model._batched_step(
+            decoder._params, caches, jnp.asarray(tokens, jnp.int32),
+            jnp.asarray(pos, jnp.int32), jnp.asarray(ROUND_ACTIVE),
+            live=live)
+        ctl = np.stack([tokens, pos, ROUND_ACTIVE]).astype(np.int32)
+        chosen, rounded = decoder._round_fn(
+            decoder._params, jax.tree_util.tree_map(jnp.asarray, before),
+            jnp.zeros((ROUND_SLOTS,), jnp.int32), ctl, live=live)
+        members = np.flatnonzero(ROUND_ACTIVE)
+        np.testing.assert_array_equal(
+            np.argmax(np.asarray(logits), axis=-1)[members],
+            np.asarray(chosen)[members])
+        for got, want in zip(_leaves(batched), _leaves(rounded)):
+            assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
     finally:
         model.unload()
 
