@@ -122,7 +122,10 @@ class RungCount:
     tally on the device the experts their grouped products read, those and
     the dispatches, by program (``reached``); and the key and value rows
     that rounds wrote into a table, by how they were written (``written``:
-    ``TinyDecoderModel.rows_path``)."""
+    ``TinyDecoderModel.rows_path``); and, for a decoder whose layers attend
+    either to a window or to the whole prefix, the rows its decode steps
+    attended to by kind, summed over the layers of that kind, counted on the
+    host from positions (``attention_rows``)."""
 
     TOTALS = ("selecting_steps", "prefill_tokens", "prefill_chunks", "prefill_ns")
     ROWS = ("window_rows_read", "summary_rows_read", "summaries_written")
@@ -134,6 +137,7 @@ class RungCount:
         self._rows = dict.fromkeys(self.ROWS, 0)
         self._reached: Dict[str, Tuple[int, int]] = {}
         self._written: Dict[str, int] = {}
+        self._attention: Dict[str, int] = {}
 
     def add(self, live: int) -> None:
         with self._lock:
@@ -169,6 +173,10 @@ class RungCount:
         with self._lock:
             self._written[path] = self._written.get(path, 0) + rows
 
+    def add_attention_rows(self, kind: str, rows: int) -> None:
+        with self._lock:
+            self._attention[kind] = self._attention.get(kind, 0) + rows
+
     def by_rung(self) -> Dict[int, int]:
         with self._lock:
             return dict(self._steps)
@@ -191,6 +199,11 @@ class RungCount:
         """``{path: rows}``, empty for a decoder whose rounds wrote none."""
         with self._lock:
             return dict(self._written)
+
+    def attention_rows(self) -> Dict[str, int]:
+        """``{kind: rows}``, empty for a decoder that counts none."""
+        with self._lock:
+            return dict(self._attention)
 
 
 class TinyDecoderModel(Model):

@@ -245,6 +245,22 @@ def sigmoid_route(h2, router, bias, s: Sizes):
     return which.astype(jnp.int32), gates * s.scaling
 
 
+def in_turn(flags, body, carry):
+    """``body(slot, carry)`` for each slot flagged, lowest first, one turn of
+    a loop each (their order by a comparison of ranks: a sort is a program of
+    its own on the chip)."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    places = jnp.arange(flags.shape[0])
+    rank = jnp.cumsum(flags) - 1
+    order = jnp.sum(jnp.where(
+        flags[None, :] & (rank[None, :] == places[:, None]), places[None, :], 0),
+        axis=1)
+    return lax.fori_loop(0, jnp.sum(flags, dtype=jnp.int32),
+                         lambda n, carry: body(order[n], carry), carry)
+
+
 class GatedConvDecoderModel(TinyDecoderModel):
     """``gated_conv_lm``: the block above at a configuration's sizes."""
 
@@ -298,18 +314,6 @@ class GatedConvDecoderModel(TinyDecoderModel):
         # query head h's group of key-value heads, as a mask over a row's lanes
         own = jnp.asarray((np.arange(KV * Dh)[None, :] // Dh)
                           == (np.arange(H)[:, None] // (H // KV)))
-
-        def in_turn(flags, body, carry):
-            """``body(slot, carry)`` for each slot flagged, lowest first, one
-            turn of a loop each (their order by a comparison of ranks: a sort
-            is a program of its own on the chip)."""
-            places = jnp.arange(flags.shape[0])
-            rank = jnp.cumsum(flags) - 1
-            order = jnp.sum(jnp.where(
-                flags[None, :] & (rank[None, :] == places[:, None]), places[None, :], 0),
-                axis=1)
-            return lax.fori_loop(0, jnp.sum(flags, dtype=jnp.int32),
-                                 lambda n, carry: body(order[n], carry), carry)
 
         # -- the parts of a layer ------------------------------------------
         def conv_in(layer, x):

@@ -342,13 +342,15 @@ def route(h2, router, s: Sizes):
 
 
 def expert_layer(h2, which, gates, layer, s: Sizes, first: int = 0,
-                 interpret: Optional[bool] = None):
+                 interpret: Optional[bool] = None,
+                 activation: Optional[Callable] = None):
     """What the experts held here (``first`` onward, as many as ``layer``'s
     stacks hold) give the tokens ``h2`` [tokens, d] routed as ``which`` and
     weighted as ``gates`` [tokens, k]: [tokens, d] in float32, with nothing
     for a pair whose expert lives elsewhere. The pairs are sorted by expert;
     the grouped product reads the weights of each expert that some pair
-    reaches once."""
+    reaches once. ``activation`` is the gate's (``silu`` where left out:
+    SwiGLU experts; ``relu`` makes them ReGLU)."""
     import jax
     import jax.numpy as jnp
     from jax.experimental.pallas.ops.tpu.megablox import gmm
@@ -378,7 +380,8 @@ def expert_layer(h2, which, gates, layer, s: Sizes, first: int = 0,
 
     gate = grouped(rows, layer["experts_gate"], jnp.float32)
     up = grouped(rows, layer["experts_up"], jnp.float32)
-    hidden = (jax.nn.silu(gate) * up).astype(rows.dtype)
+    activation = jax.nn.silu if activation is None else activation
+    hidden = (activation(gate) * up).astype(rows.dtype)
     down = grouped(hidden, layer["experts_down"], jnp.float32)
     # back to the pairs' own order, each weighted, a token's pairs added up
     back = jnp.argsort(order)

@@ -650,6 +650,14 @@ class ServerCore:
             "(one DMA kernel a layer) or loop (a turn a member)",
             ("model", "path"))
 
+        # the rows a decoder's decode steps attended to by the kind of layer
+        # (RungCount.attention_rows): a series only where a decoder counts them
+        attention_rows_read = reg.gauge(
+            "client_tpu_server_attention_rows_read",
+            "Key and value rows that decode steps attended to, summed over "
+            "the layers of a kind: sliding (a window's ring) or full (the "
+            "whole prefix)", ("model", "kind"))
+
         # the turns of a model's round worker by phase (timeline.PHASES), and
         # what its requests read of the rounds (Timeline.readings): a pair
         # of series each, a sum and the count it is over
@@ -720,6 +728,8 @@ class ServerCore:
                         experts_reached_rounds.labels(name, program).set(n)
                     for path, rows in count.written().items():
                         rows_written.labels(name, path).set(rows)
+                    for kind, rows in count.attention_rows().items():
+                        attention_rows_read.labels(name, kind).set(rows)
                 rounds = getattr(model, "rounds_by_width", None)
                 if rounds:  # it has run rounds: the slot table is in use
                     for width, n in dict(rounds).items():
